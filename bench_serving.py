@@ -51,7 +51,7 @@ def _build_module(batch=1):
     net = sym.FullyConnected(net, num_hidden=DOUT, name="fc2")
     net = sym.tanh(net, name="out")
     mod = Module(symbol=net, data_names=("data",), label_names=None,
-                 context=mx.cpu())
+                 context=mx.current_context())
     mod.bind(data_shapes=[("data", (batch, DIN))], label_shapes=None,
              for_training=False)
     mod.init_params(initializer=mx.initializer.Uniform(0.07))
@@ -172,9 +172,11 @@ def run(smoke=False):
         summary = slo.summary()
         stats = srv.stats()
 
+    dev = jax.devices()[0]
     result = {
         "metric": "serving",
-        "backend": jax.default_backend(),
+        "platform": dev.platform, "device_kind": dev.device_kind,
+        "device_count": len(jax.devices()),
         "model": "mlp_%d_%d_%d" % (DIN, DHID, DOUT),
         "serve_parity": parity,
         "serve_batch_mode": serving.serve_batch_mode(),

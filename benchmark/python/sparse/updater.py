@@ -10,8 +10,7 @@ densified.  Prints JSON lines.
 consecutive updates flush as ONE XLA dispatch — the configuration that
 matters for training loops (the reference bulks optimizer updates inside
 train segments, threaded_engine.h:472-509).  Without it the lazy path
-pays per-op dispatch floors that dwarf its bandwidth win on this
-transport (docs/bench_results_r04/README.md:89).
+pays one host dispatch per op, which dwarfs its bandwidth win.
 """
 import argparse
 import json

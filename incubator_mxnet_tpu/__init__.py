@@ -66,6 +66,7 @@ from . import visualization
 from . import visualization as viz
 
 # env-var driven startup behavior (SURVEY §5.6 config layer)
+config.apply_compile_cache()
 if config.get_bool("PROFILER_AUTOSTART"):
     import atexit as _atexit
     profiler.set_config(continuous_dump=True)

@@ -2,15 +2,15 @@
 
 The data plane (RecordIO parsing, threaded prefetch) and the C predict
 ABI are native code like the reference's (SURVEY §1 layers 7/8); Python
-binds them through ctypes.  Everything degrades gracefully: when the
-libraries are absent and the toolchain can't build them, the pure-Python
-paths serve instead.
+binds them through ctypes.  When the toolchain cannot build them the
+pure-Python paths serve instead, and the loader says so once on stderr.
 """
 from __future__ import annotations
 
 import ctypes
 import os
 import subprocess
+import sys
 
 _SRC_DIR = os.path.join(os.path.dirname(__file__), "..", "src")
 _BUILD_DIR = os.path.join(_SRC_DIR, "build")
@@ -19,24 +19,30 @@ _io_lib = None
 _io_tried = False
 
 
-def _try_build():
+def _build():
+    """Bring ``src/build`` up to date with the sources.  Runs before every
+    first load, not only when the library is missing: ``src/build/`` is
+    not tracked by git, so a file already on disk may have been compiled
+    from older sources, and ``make`` rebuilds only what is stale."""
     try:
-        subprocess.run(["make", "-C", _SRC_DIR],
-                       capture_output=True, timeout=120, check=True)
+        subprocess.run(["make", "-C", _SRC_DIR], capture_output=True,
+                       text=True, timeout=300, check=True)
         return True
-    except Exception:
+    except (OSError, subprocess.SubprocessError) as exc:
+        detail = (getattr(exc, "stderr", None) or str(exc)).strip()
+        print("incubator_mxnet_tpu: `make -C %s` failed, the pure-Python "
+              "RecordIO reader takes over: %s"
+              % (os.path.normpath(_SRC_DIR),
+                 detail.splitlines()[-1] if detail else type(exc).__name__),
+              file=sys.stderr)
         return False
 
 
 def _load(name):
-    path = os.path.join(_BUILD_DIR, name)
-    if not os.path.exists(path):
-        if not _try_build():
-            return None
-    if not os.path.exists(path):
+    if not _build():
         return None
     try:
-        return ctypes.CDLL(path)
+        return ctypes.CDLL(os.path.join(_BUILD_DIR, name))
     except OSError:
         return None
 

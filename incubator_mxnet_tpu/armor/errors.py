@@ -1,4 +1,4 @@
-"""graftarmor typed failure taxonomy.
+"""graftarmor typed failure classes.
 
 Every failure the armor subsystem can surface is a *typed* exception
 carrying the evidence a supervisor needs to act: which RPC command gave

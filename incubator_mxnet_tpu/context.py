@@ -99,10 +99,9 @@ class Context:
     def default_ctx():
         import jax
 
-        try:
-            plat = jax.default_backend()
-        except Exception:
-            plat = "cpu"
+        # a backend that cannot initialise raises here: answering "cpu"
+        # would run the whole program on the host without a word
+        plat = jax.default_backend()
         return Context("tpu" if plat in ("tpu", "gpu") else "cpu", 0)
 
     def empty_cache(self):

@@ -368,7 +368,7 @@ def in_bulk():
 
     The graftstep compiled dispatch consults this to decide whether its
     pre-dispatch ``flush(cause="step_compile")`` has anything to land —
-    keeping the flush-cause taxonomy honest (no zero-op "step_compile"
+    keeping the flush-cause labels honest (no zero-op "step_compile"
     causes on the common non-bulk path)."""
     return _current() is not None
 

@@ -145,7 +145,7 @@ class Parameter(object):
                         self.name, str(ctx), str(self.list_ctx()))
                 ctx = self._deferred_init[1]
             elif ctx is None:
-                ctx = [cpu()]
+                ctx = [current_context()]
             self._init_impl(data, ctx)
         else:
             assert ctx is None or set(ctx) == set(self.list_ctx()), \

@@ -10,8 +10,12 @@ maps onto the MXU with O(seq) memory.
   block_k tiles; running max/sum rescaling). Grid = (batch*heads,
   seq_q/block_q); the K loop is a fori_loop inside the kernel so the MXU
   sees back-to-back (block_q×d)·(d×block_k) matmuls.
-* On non-TPU backends (the CPU test mesh) the same math runs as jnp — the
-  kernel is numerics-identical by construction and tested against it.
+* Off the TPU (the CPU test mesh), and for lengths that are not multiples
+  of 128, the same math runs as jnp — the kernel is numerics-identical by
+  construction and tested against it.  The platform is the one the call is
+  lowered for, not the process default; each path runs under its own
+  ``named_scope`` and every trace is counted by path
+  (``graft_flash_attention_traces_total``).
 * Registered as op ``_contrib_FlashAttention`` so both eager NDArray code
   and Symbol graphs can call it (one registry, two modes).
 """
@@ -25,6 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..telemetry import metrics as _metrics
 from .registry import register
 
 _NEG_INF = -1e30
@@ -140,16 +145,36 @@ def _flash_forward_pallas(q, k, v, causal, scale, block_q=128, block_k=128):
 def flash_attention(q, k, v, causal=False, scale=None):
     """softmax(QKᵀ·scale)·V with O(seq) memory.
 
-    Pallas kernel on TPU; numerics-identical jnp path elsewhere. Backward
+    Pallas kernel where the call runs on a TPU and both lengths are
+    multiples of 128; the numerics-identical jnp path otherwise.  Backward
     recomputes attention (flash-style rematerialization) instead of storing
     the (Sq×Sk) probability matrix.
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    if jax.default_backend() == "tpu" and q.shape[2] % 128 == 0 and \
-            k.shape[2] % 128 == 0:
-        return _flash_forward_pallas(q, k, v, causal, scale)
-    return _attention_reference(q, k, v, causal, scale)
+
+    def pallas(q, k, v):
+        with jax.named_scope("flash_attention_pallas"):
+            return _flash_forward_pallas(q, k, v, causal, scale)
+
+    def reference(q, k, v):
+        with jax.named_scope("flash_attention_reference"):
+            return _attention_reference(q, k, v, causal, scale)
+
+    if q.shape[2] % 128 or k.shape[2] % 128:
+        # O(S²) memory on any platform: worth a counter of its own
+        _metrics.flash_attention_trace("reference_unaligned")
+        return reference(q, k, v)
+    if isinstance(q, jax.core.Tracer):
+        # a tracer has no device: the program it is staged into picks the
+        # branch when it is lowered for the platform its operands live on
+        _metrics.flash_attention_trace("lowering_platform")
+        return lax.platform_dependent(q, k, v, tpu=pallas, default=reference)
+    if all(d.platform == "tpu" for d in q.devices()):
+        _metrics.flash_attention_trace("pallas")
+        return pallas(q, k, v)
+    _metrics.flash_attention_trace("reference_off_tpu")
+    return reference(q, k, v)
 
 
 def _kv_block_size(sk):

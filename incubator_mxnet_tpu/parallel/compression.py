@@ -138,8 +138,7 @@ def _rs_jitted(mesh, W, k, sum_dtype):
     key = (mesh, W, k, sum_dtype)
     fn = _rs_jit_cache.get(key)
     if fn is None:
-        from .._jax_compat import shard_map
-        from jax import lax
+        from jax import lax, shard_map
 
         def body(block):                       # (1, W*k) uint32
             shards = block[0].reshape(W, k)    # row j → destination j

@@ -138,11 +138,19 @@ class DataParallelTrainer(object):
     # -- parameter plumbing ------------------------------------------------
     def _gather_params(self, example_x):
         blk_params = self.block.collect_params()
-        for p in blk_params.values():
-            if p._data is None and p._deferred_init:
-                # resolve deferred shapes with one eager pass over the data
-                self.block._run_deferred_init(NDArray(example_x))
-                break
+        if any(p._data is None and p._deferred_init
+               for p in blk_params.values()):
+            # Resolve deferred shapes with one eager pass over ONE example
+            # row: parameter shapes do not depend on the batch, and the
+            # whole global batch would sit on the default device alone
+            # (dp=4: four chips' worth of activations on chip 0).  The row
+            # must be on the SAME device as the Block's params (default
+            # backend) and at compute dtype — host-pinned uint8 pipeline
+            # batches are neither, so it round-trips through numpy here
+            row = jnp.asarray(np.asarray(example_x[:1]))
+            if jnp.issubdtype(row.dtype, jnp.integer):
+                row = row.astype(jnp.float32)
+            self.block._run_deferred_init(NDArray(row))
         repl = NamedSharding(self.mesh, P())
         multihost = _spans_processes(repl)
         vals = {n: p.data()._read() for n, p in blk_params.items()}
@@ -322,20 +330,14 @@ class DataParallelTrainer(object):
 
     def _prepare_inputs(self, data, label, batch_spec, multi=False):
         """Shared dispatch prologue: resolve params (deferred init runs on
-        the raw single-device batch, BEFORE mesh sharding), device-resident
+        one single-device example row, BEFORE mesh sharding), device-resident
         rng/lr, batch arrays laid out per ``batch_spec`` (resharding
         skipped when already placed)."""
         x = data._read() if isinstance(data, NDArray) else data
         y = label._read() if isinstance(label, NDArray) else label
         if self._params is None:
-            # the eager deferred-init pass must see the example on the
-            # SAME device as the Block's params (default backend), and at
-            # compute dtype — host-pinned uint8 pipeline batches are
-            # neither, so round-trip through numpy once here
-            ex = jnp.asarray(np.asarray(x))
-            if jnp.issubdtype(ex.dtype, jnp.integer):
-                ex = ex.astype(jnp.float32)
-            self._gather_params(ex[0] if multi else ex)
+            rows = x if hasattr(x, "shape") else np.asarray(x)
+            self._gather_params(rows[0] if multi else rows)
         repl = NamedSharding(self.mesh, P())
         batch_sh = NamedSharding(self.mesh, batch_spec)
         multihost = _spans_processes(repl)

@@ -17,6 +17,7 @@ from __future__ import annotations
 import numpy as np
 
 import jax
+from jax.interpreters.partial_eval import DynamicJaxprTracer
 from jax.sharding import Mesh, PartitionSpec, NamedSharding
 
 __all__ = ["make_mesh", "data_parallel_mesh", "P", "NamedSharding", "Mesh",
@@ -64,10 +65,6 @@ def is_staging(x):
     """True when ``x`` is a tracer from an enclosing jit's staging trace
     (as opposed to a concrete array OR an eager-autodiff tracer whose
     primitives execute immediately)."""
-    try:
-        from jax.interpreters.partial_eval import DynamicJaxprTracer
-    except ImportError:  # pragma: no cover - jax internals moved
-        return False
     return isinstance(x, DynamicJaxprTracer)
 
 

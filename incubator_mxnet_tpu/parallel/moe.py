@@ -26,8 +26,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-from .._jax_compat import shard_map
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..gluon.block import HybridBlock

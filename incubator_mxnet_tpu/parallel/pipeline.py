@@ -20,9 +20,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
-from .._jax_compat import shard_map
 
 __all__ = ["pipeline_apply", "pipeline_train_step", "make_pipeline_trainer",
            "PipelineTrainer"]

@@ -207,8 +207,7 @@ def _payload_reduce_jitted(mesh, W, kb, block, mode):
     key = (mesh, W, kb, block, mode)
     fn = _reduce_jit_cache.get(key)
     if fn is None:
-        from .._jax_compat import shard_map
-        from jax import lax
+        from jax import lax, shard_map
         bw = block // _LANES
 
         def body(codes_blk, scales_blk):

@@ -25,9 +25,8 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
-from .._jax_compat import shard_map
 
 __all__ = ["ring_attention", "ring_attention_sharded"]
 

@@ -215,7 +215,7 @@ def device_memory():
     storage.cc:77-79; here the XLA per-device allocator IS the storage
     manager).  Primary source: ``Device.memory_stats()`` (real TPU
     runtimes report allocator counters incl. true peak).  Backends that
-    report nothing (host CPU, tunneled devices) fall back to summing
+    report nothing (the host CPU backend) fall back to summing
     ``jax.live_arrays()`` shards per device — exact live bytes, with
     ``peak_bytes_in_use`` the max live bytes ever *sampled* by this
     function (``source`` says which accounting answered)."""

@@ -7,8 +7,10 @@ wraps user kernel functions, ``get_kernel().launch(...)`` places the
 pallas_call and hands NDArrays through — same module/kernel/launch
 shape as the reference API, with grid dims playing the same role.
 
-On non-TPU backends the kernel runs through Pallas interpret mode, so
-kernels remain testable on the CPU mesh.
+``launch`` compiles the kernel for the device it runs on, or raises.
+``launch(..., interpret=True)`` runs it through the Pallas interpreter
+instead, which is how kernels are tested on the CPU mesh; the mode is the
+caller's choice and never follows the platform.
 """
 from __future__ import annotations
 
@@ -57,10 +59,12 @@ class PallasKernel(object):
         self._compiled = {}
 
     def launch(self, args, ctx=None, grid_dims=(1,), block_dims=None,
-               shared_mem=0):
+               shared_mem=0, interpret=False):
         """Run the kernel over NDArray args; returns the output NDArray
         (ref: rtc.py CudaKernel.launch:185 — grid_dims maps to the Pallas
         grid; block_dims/shared_mem are CUDA-isms the TPU compiler owns).
+        ``interpret=True`` evaluates the kernel with the Pallas
+        interpreter (any backend); the default compiles it.
         """
         from jax.experimental import pallas as pl
 
@@ -78,11 +82,12 @@ class PallasKernel(object):
                      else tuple(vals[0].shape))
         out_dtype = (self._out_dtype if self._out_dtype is not None
                      else vals[0].dtype)
+        interpret = bool(interpret)
         key = (tuple(v.shape for v in vals), tuple(str(v.dtype)
-                                                   for v in vals), grid)
+                                                   for v in vals), grid,
+               interpret)
         call = self._compiled.get(key)
         if call is None:
-            interpret = jax.default_backend() != "tpu"
             call = jax.jit(pl.pallas_call(
                 self._fn, grid=grid,
                 out_shape=jax.ShapeDtypeStruct(out_shape, out_dtype),

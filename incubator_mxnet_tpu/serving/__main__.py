@@ -39,7 +39,7 @@ def _build_net(seed=0, din=16, dh=32, dout=8, scale=1.0):
             return F.tanh(self.d2(self.d1(x)))
 
     net = MLP()
-    net.initialize(ctx=mx.cpu())
+    net.initialize()    # default context: the selftest pins jax to the CPU
     net.hybridize()
     rs = np.random.RandomState(seed)
     net(mx.nd.array(rs.randn(1, din).astype(np.float32)))  # shapes
@@ -51,7 +51,7 @@ def _build_net(seed=0, din=16, dh=32, dout=8, scale=1.0):
 
 def selftest():
     import jax
-    jax.config.update("jax_platforms", "cpu")
+    jax.config.update("jax_platforms", "cpu")  # lint tier: a CPU program
     import incubator_mxnet_tpu as mx
     from incubator_mxnet_tpu import serving
     from incubator_mxnet_tpu.telemetry import blackbox
@@ -159,7 +159,6 @@ def selftest():
 
 def demo(as_json=False):
     import jax
-    jax.config.update("jax_platforms", "cpu")
     import incubator_mxnet_tpu as mx
     from incubator_mxnet_tpu import serving
 
@@ -173,12 +172,16 @@ def demo(as_json=False):
         for f in futs:
             f.get(timeout=30.0)
         stats = srv.stats()
+    dev = jax.devices()[0]
+    stats["device"] = {"platform": dev.platform, "kind": dev.device_kind,
+                       "count": len(jax.devices())}
     if as_json:
         print(json.dumps(stats, default=str))
     else:
         s = stats["slo"]
-        print("graftserve demo: %d requests, %d batches "
-              "(mean batch %.1f)" % (stats["requests"], stats["batches"],
+        print("graftserve demo on %s (%s): %d requests, %d batches "
+              "(mean batch %.1f)" % (dev.platform, dev.device_kind,
+                                     stats["requests"], stats["batches"],
                                      s.get("mean_batch_size", 0)))
         print("  latency p50 %.3fms p99 %.3fms | components (mean ms): %s"
               % (s.get("p50_ms", 0), s.get("p99_ms", 0),

@@ -674,6 +674,20 @@ def trainer_compiled_fallback(reason):
                       ("reason",)).inc(reason=reason)
 
 
+def flash_attention_trace(path):
+    """One trace of ``ops.attention.flash_attention``, labeled by the path
+    it took: ``pallas`` / ``reference_off_tpu`` (concrete operands, chosen
+    by where they live), ``lowering_platform`` (traced operands: Pallas
+    when the enclosing program is lowered for a TPU, jnp otherwise) or
+    ``reference_unaligned`` (a length that is not a multiple of 128 —
+    the O(S²) jnp path on every platform)."""
+    if not enabled():
+        return
+    _REGISTRY.counter("graft_flash_attention_traces_total",
+                      "flash_attention traces by execution path",
+                      ("path",)).inc(path=path)
+
+
 def step_retrace(reason):
     """One compiled-step guard miss, labeled by WHICH guard-key
     component churned (graftguard diff: input-sig / param-meta /

@@ -432,7 +432,9 @@ _KILL_RESUME_WORKER = _skipwrap("""
         trainer.step(2)
         return float(loss.asnumpy())
 
-    ckdir = os.path.join(os.getcwd(), "graft-ckpt-%d" % os.getpid())
+    # beside this worker script, i.e. in the test's tmp_path, not in cwd
+    ckdir = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                         "graft-ckpt-%d" % os.getpid())
     cp = trainer.checkpointer(ckdir, keep=3, emergency=False)
     first = []
     for i in range(6):

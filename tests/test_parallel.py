@@ -152,7 +152,7 @@ def test_dp_sp_2d_mesh_attention():
     k = jnp.asarray(rs.randn(B, H, S, D).astype(np.float32))
     v = jnp.asarray(rs.randn(B, H, S, D).astype(np.float32))
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from incubator_mxnet_tpu._jax_compat import shard_map
+    from jax import shard_map
     import functools
     from incubator_mxnet_tpu.parallel.ring_attention import _ring_body
     spec = P("dp", None, "sp", None)

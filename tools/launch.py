@@ -52,10 +52,16 @@ def main():
             env = dict(os.environ)
             env.update({"MX_COORDINATOR": coordinator,
                         "MX_NUM_PROCESSES": str(args.num_workers),
-                        "MX_PROCESS_ID": str(r),
-                        # each local process simulates one host: restrict it
-                        # to the CPU platform unless the caller overrides
-                        "JAX_PLATFORMS": env.get("JAX_PLATFORMS", "cpu")})
+                        "MX_PROCESS_ID": str(r)})
+            if "JAX_PLATFORMS" not in env:
+                # Each local process simulates one host of the gloo test
+                # harness, on the CPU.  A chip belongs to one process, so
+                # N local processes each wanting one is not a layout this
+                # launcher supports: one process drives all local chips.
+                env["JAX_PLATFORMS"] = "cpu"
+                print("launch.py: worker %d runs with JAX_PLATFORMS=cpu "
+                      "(local workers simulate hosts; set JAX_PLATFORMS to "
+                      "override)" % r, file=sys.stderr)
             procs.append(subprocess.Popen(args.command, env=env))
         rc = 0
         for p in procs:
