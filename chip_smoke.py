@@ -18,8 +18,10 @@ made from a seed:
                 step must come out of the persistent compile cache
 
 Any failed check raises: the exit code is non-zero and no result line is
-printed.  The last line of stdout is one JSON object,
-``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}, ...}``.
+printed.  The facts of the run are the second-last line of stdout,
+``[result] {...}``; the last line is one JSON object with these keys and
+no others, the device as JAX reports it:
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}``.
 
     python chip_smoke.py              one chip
     python chip_smoke.py --chips 4    phase 2 over a dp=4 mesh, global b1024
@@ -440,9 +442,11 @@ def main(argv=None):
             return facts
         return {k: v for k, v in facts.items() if not k.endswith("compiles")}
 
-    print(json.dumps({
-        "ok": True,
-        "device": {k: device[k] for k in ("platform", "kind", "count")},
+    verdict = {"ok": True, "device": {
+        "platform": device["platform"], "kind": device["kind"],
+        "count": device["count"]}}
+    print("[result] %s" % json.dumps({
+        **verdict,
         "rehearsal": args.rehearse,
         "versions": {k: device[k] for k in ("jax", "jaxlib", "libtpu")},
         "chips_used": args.chips,
@@ -452,6 +456,8 @@ def main(argv=None):
         "resnet": brief(resnet), "flash_attention": flash,
         "transformer_lm": brief(lm), "second_compile": brief(cache),
     }))
+    # the last line: exactly these keys, for whoever runs the script
+    print(json.dumps(verdict))
     return 0
 
 

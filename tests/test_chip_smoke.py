@@ -38,9 +38,19 @@ def test_rehearsal_runs_and_caches_where_the_environment_says(tmp_path):
         env=_env(JAX_COMPILATION_CACHE_DIR=str(cache)),
         capture_output=True, text=True, timeout=600)
     assert r.returncode == 0, r.stderr[-3000:]
-    out = json.loads(r.stdout.strip().splitlines()[-1])
+    lines = r.stdout.strip().splitlines()
+    # the last line holds the verdict and the device, and no other key
+    last = json.loads(lines[-1])
+    assert sorted(last) == ["device", "ok"] and last["ok"] is True
+    assert sorted(last["device"]) == ["count", "kind", "platform"]
+    assert last["device"]["platform"] == "cpu"
+    assert isinstance(last["device"]["kind"], str)
+    assert type(last["device"]["count"]) is int
+    # the facts of the run are the line before it
+    assert lines[-2].startswith("[result] ")
+    out = json.loads(lines[-2][len("[result] "):])
     assert out["ok"] is True and out["rehearsal"] is True
-    assert out["device"]["platform"] == "cpu"
+    assert out["device"] == last["device"]
     # the Pallas phases are named, and named as skipped
     assert out["flash_attention"] == "skipped: no chip"
     assert out["transformer_lm"] == "skipped: no chip"
