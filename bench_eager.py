@@ -67,14 +67,6 @@ whole-step path (graftstep) timed with the EH3xx runtime auditor armed
 sweep — but NO sentinel replay) vs off.  Same < 2% bar; the off mode
 additionally asserts the hot-path flag is a cached list-index load.
 
-Round 18 (graftxray) adds ``xray_overhead_pct``: the same compiled step
-with the capture harness ARMED (GRAFT_XRAY=1 — dispatch_begin/end
-bracketing every dispatch) but no trigger firing, vs unarmed.  Same
-< 2% bar: armed-idle must cost one memoized env read per bracket.  The
-smoke run then forces ONE capture and reports the per-phase device
-split (``xray_phase_device_us``) as the attribution regression
-sentinel — phases must be present and the partition conservation-exact.
-
 Round 20 (graftelastic) adds ``elastic_overhead_pct``: the enabled-idle
 membership fence (GRAFT_ELASTIC=1, Membership attached, no change ever
 queued).  The fence's gate — one memoized env read + an empty-deque
@@ -1025,107 +1017,6 @@ def _compile_check_overhead_bench(iters=50, repeats=9):
     }
 
 
-def _xray_overhead_bench(iters=50, repeats=9):
-    """graftxray inertness: the capture harness ARMED (GRAFT_XRAY=1 —
-    ``dispatch_begin``/``dispatch_end`` bracketing every compiled
-    dispatch) but with no trigger firing, vs unarmed, on the same
-    CompiledStep.  Same PAIRED estimator as the graftguard bench: each
-    iteration times one unarmed and one armed call back-to-back
-    (alternating order so warm-cache bias cancels) and the figure is
-    the median per-pair delta over the pooled median unarmed time —
-    the armed-idle cost is a memoized env read + one lock check per
-    bracket, far below this box's window-to-window drift.  The
-    slow-step trigger is disabled for the timed rounds (a GC hiccup
-    tripping a capture would poison the deltas).  Afterwards ONE
-    capture is forced across 2 dispatches and the per-phase device
-    split is returned as the attribution regression sentinel: phases
-    must be present and the partition conservation-EXACT."""
-    import os
-    import statistics
-
-    import incubator_mxnet_tpu as mx
-    from incubator_mxnet_tpu import gluon
-    from incubator_mxnet_tpu.gluon import step_compile as sc
-    from incubator_mxnet_tpu.telemetry import xray
-
-    net = sc._make_net("bench_xray_", n_params=8, shape=(16, 16))
-    sc._seed_params(net)
-    tr = gluon.Trainer(net.collect_params(), "sgd",
-                       {"learning_rate": 0.01, "momentum": 0.9},
-                       kvstore=None)
-    cstep = sc.CompiledStep(tr, net, enabled=True)
-    x = mx.nd.array(
-        np.random.RandomState(5).rand(16, 16).astype(np.float32))
-
-    saved = {k: os.environ.pop(k, None)
-             for k in ("GRAFT_XRAY", "GRAFT_XRAY_EVERY",
-                       "GRAFT_XRAY_STEPS", "GRAFT_XRAY_SLOW_X")}
-    xray.reset()
-    # a scheduler stall on one armed call must request a capture-stat,
-    # not a capture: park the slow-step trigger out of reach
-    os.environ["GRAFT_XRAY_SLOW_X"] = "1e9"
-    try:
-        for _ in range(3):          # kv init + lazy trace + steady state
-            cstep(x)
-        assert cstep.compiled_steps >= 1, \
-            "bench never reached compiled path"
-        for armed in (True, False):             # warm both modes once
-            if armed:
-                os.environ["GRAFT_XRAY"] = "1"
-            else:
-                os.environ.pop("GRAFT_XRAY", None)
-            for _ in range(4):
-                cstep(x)
-        all_offs, deltas = [], []
-        for r in range(repeats):
-            for i in range(iters):
-                pair = {}
-                order = (False, True) if (i + r) % 2 == 0 \
-                    else (True, False)
-                for armed in order:
-                    if armed:
-                        os.environ["GRAFT_XRAY"] = "1"
-                    else:
-                        os.environ.pop("GRAFT_XRAY", None)
-                    t0 = time.perf_counter()
-                    cstep(x)
-                    pair[armed] = time.perf_counter() - t0
-                all_offs.append(pair[False])
-                deltas.append(pair[True] - pair[False])
-        assert not xray.sessions() and not xray.capture_active(), \
-            "armed-idle bench opened a capture session"
-        # the per-phase sentinel: one forced capture across 2 dispatches
-        os.environ["GRAFT_XRAY"] = "1"
-        os.environ["GRAFT_XRAY_STEPS"] = "2"
-        assert xray.request_capture("bench")
-        for _ in range(3):
-            cstep(x)
-        sess = xray.sessions()
-        assert sess and sess[-1]["ok"], "bench capture failed: %r" % (
-            sess[-1].get("error") if sess else "<no session>")
-        rep = sess[-1]["report"]
-        assert rep["conservation_ok"], \
-            "phase attribution not conservation-exact in bench capture"
-        assert rep["phases"], "no phases attributed in bench capture"
-        phases = {p: round(d["device_s"] * 1e6, 3)
-                  for p, d in rep["phases"].items()}
-        unattr_us = round(rep["unattributed_s"] * 1e6, 3)
-    finally:
-        for k, v in saved.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-        xray.reset()
-    off_med = statistics.median(all_offs)
-    pct = statistics.median(deltas) / off_med * 100.0
-    return {
-        "xray_overhead_pct": round(pct, 2),
-        "xray_phase_device_us": phases,
-        "xray_unattributed_us": unattr_us,
-    }
-
-
 def _elastic_overhead_bench(iters=30, reps=200000, n_params=8,
                             shape=(16, 16)):
     """graftelastic enabled-idle cost: a Membership is attached and
@@ -1239,11 +1130,6 @@ def smoke():
     assert res["compile_check_overhead_pct"] < 2.0, \
         "compile-check auditor overhead %.2f%% >= 2%%" \
         % res["compile_check_overhead_pct"]
-    res.update(_xray_overhead_bench(iters=50, repeats=9))
-    # graftxray acceptance gate: armed-but-idle capture harness must
-    # cost < 2% on the compiled step
-    assert res["xray_overhead_pct"] < 2.0, \
-        "xray armed-idle overhead %.2f%% >= 2%%" % res["xray_overhead_pct"]
     res.update(_elastic_overhead_bench(iters=20, reps=100000))
     # graftelastic acceptance gate: enabled-idle step fence must cost
     # < 2% on the fused step

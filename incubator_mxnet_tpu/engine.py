@@ -787,11 +787,14 @@ def flush(state=None, cause="read"):
             _replay_cache[key] = entry
         fn, replay = entry
         try:
-            # graftwatch bracket: a stalled dispatch shows up in-flight
-            # (the watchdog names this segment when it trips)
-            with _blackbox.in_flight("engine_flush",
-                                     {"segment": seg_id, "cause": cause,
-                                      "nodes": len(instrs)}):
+            # the program span (mx:engine_flush in a profiler trace, a
+            # record in telemetry.spans()) around the graftwatch bracket:
+            # a stalled dispatch shows up in-flight (the watchdog names
+            # this segment when it trips)
+            with _ttracing.phase_span("engine_flush", {"cause": cause}), \
+                    _blackbox.in_flight("engine_flush",
+                                        {"segment": seg_id, "cause": cause,
+                                         "nodes": len(instrs)}):
                 t_dispatch = time.perf_counter()
                 results = fn(ext)
                 t_dispatched = time.perf_counter()

@@ -34,6 +34,8 @@ from .. import ndarray as _nd
 from ..ops.registry import Operator
 from .. import autograd
 from .. import random_state
+from ..telemetry import tracing as _ttracing
+from ..telemetry import xray as _xray
 from .parameter import Parameter, ParameterDict, DeferredInitializationError
 
 __all__ = ["Block", "HybridBlock", "SymbolBlock", "functionalize"]
@@ -279,6 +281,7 @@ class CachedOp(object):
         # _clear_cached_op (→ a fresh CachedOp); cache the walk here
         self._params = block._active_params
         self._param_names = sorted(self._params.keys())
+        self._noted = None      # the cache entry the program registry has
         # forward-use order of the params, recorded by first-touch hooks
         # on the first trace (graftstep pull priority; empty until then)
         self.touch_order = []
@@ -286,7 +289,9 @@ class CachedOp(object):
     def _make_fn(self, param_names, n_inputs, in_fmt, train):
         block = self.block
 
-        def fn(param_vals, input_vals, rng):
+        # the function's name is the compiled program's: what a profiler
+        # trace and telemetry.programs() call it
+        def cachedop_forward(param_vals, input_vals, rng):
             shadows = {name: NDArray(param_vals[name]) for name in param_names}
             if not self.touch_order:
                 _install_first_touch(shadows, self.touch_order)
@@ -308,7 +313,7 @@ class CachedOp(object):
             self._last_out_fmt = out_fmt
             return out_vals, aux_updates
 
-        return fn
+        return cachedop_forward
 
     def __call__(self, *args):
         block = self.block
@@ -340,18 +345,29 @@ class CachedOp(object):
         if entry is None:
             raw = self._make_fn(param_names, len(input_vals), in_fmt, train)
 
-            def vjp_apply(pv, iv, rng_, cts):
+            def cachedop_backward(pv, iv, rng_, cts):
                 # forward rematerializes inside the compiled backward — the
                 # whole fwd+bwd is one XLA program, no Python re-trace per
                 # step (rng_ is the same key, so dropout masks match)
                 _, vjp_fn = jax.vjp(lambda p, i: raw(p, i, rng_)[0], pv, iv)
                 return vjp_fn(cts)
 
-            entry = {"raw": raw, "jit": jax.jit(raw), "vjp": jax.jit(vjp_apply)}
+            entry = {"raw": raw, "jit": jax.jit(raw),
+                     "vjp": jax.jit(cachedop_backward)}
             self._cache[key] = entry
 
         rng = random_state.next_key()
-        out_vals, aux_updates = entry["jit"](param_vals, input_vals, rng)
+        with _ttracing.phase_span("fwd"):
+            out_vals, aux_updates = entry["jit"](param_vals, input_vals, rng)
+        if entry is not self._noted:
+            # the two programs go to the registry once, by shape alone
+            self._noted = entry
+            args = (param_vals, input_vals, rng)
+            _xray.register_program("cachedop_forward", entry["jit"], args,
+                                   phase="forward")
+            _xray.register_program("cachedop_backward", entry["vjp"],
+                                   args + (tuple(out_vals),),
+                                   phase="backward")
         if "out_fmt" not in entry:
             # fn ran (traced) at least once for this entry, setting the fmt
             entry["out_fmt"] = self._last_out_fmt
@@ -564,8 +580,12 @@ class HybridBlock(Block):
         """Defines the forward computation (ref: block.py:561 forward)."""
         if self._trace_shadows is not None:
             # inside an enclosing CachedOp trace: inline into the parent's
-            # single jit (the reference inlines subgraphs too, cached_op.cc:69)
-            return self.hybrid_forward_dispatch(x, *args)
+            # single jit (the reference inlines subgraphs too, cached_op.cc:69).
+            # Only here, while being traced, does the Block put its name on
+            # the name stack: every op staged below carries it in its
+            # op_name path, where a reader of telemetry.programs() finds it
+            with jax.named_scope(self.name):
+                return self.hybrid_forward_dispatch(x, *args)
         if self._active:
             if self._cached_op is None:
                 self._cached_op = CachedOp(self)

@@ -810,12 +810,6 @@ class CompiledStep(object):
                           for k in entry["bake_kinds"]))
             sentinel = aud.sentinel_due()
 
-        # graftxray capture window: one memoized env read when idle;
-        # when a session is due (pending trigger / GRAFT_XRAY_EVERY) it
-        # brackets the next GRAFT_XRAY_STEPS dispatches with
-        # jax.profiler and attributes device ops to the xray:* phases
-        new_w = None
-        _xray.dispatch_begin()
         try:
             with _blackbox.step_journal("trainer", batch_size=batch_size,
                                         fused=True, overlapped=False,
@@ -936,12 +930,6 @@ class CompiledStep(object):
         finally:
             if aud is not None:
                 aud.sweep()
-            # closes an open capture session once it spans
-            # GRAFT_XRAY_STEPS dispatches (blocks on the new weights so
-            # the device work lands inside the trace); one env read when
-            # idle, and an errored dispatch still counts so a session
-            # can't be left open across an exception
-            _xray.dispatch_end(sync=new_w)
         self.compiled_steps += 1
         _tmetrics.trainer_compiled_step(len(entry["trainable"]))
         out_arrays = [NDArray(v, ctx=ctx) for v in outs]

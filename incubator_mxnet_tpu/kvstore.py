@@ -37,6 +37,7 @@ from . import optimizer as opt
 from .telemetry import blackbox as _blackbox
 from .telemetry import lens as _lens
 from .telemetry import metrics as _tmetrics
+from .telemetry import tracing as _ttracing
 
 
 def _nd_bytes(arr):
@@ -47,34 +48,13 @@ def _nd_bytes(arr):
     return n * np.dtype(arr.dtype).itemsize
 
 
-class _NullCtx(object):
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *a):
-        return False
-
-
-_NULL_CTX = _NullCtx()
-
-
-def _xray_boundary(label):
-    """graftxray program-boundary marker: when the capture harness is
-    armed, wrap the host side of the reduce in a profiler
-    ``TraceAnnotation`` so a capture shows exactly where program A ends
-    and program B begins (host event — never enters phase attribution,
-    which counts device ops only).  Unarmed cost: one memoized env
-    read."""
-    from .telemetry import xray as _xray
-    if not _xray.armed():
-        return _NULL_CTX
-    try:
-        import jax.profiler as _jprof
-        return _jprof.TraceAnnotation("xray:kvstore:%s" % (label or "reduce"))
-    except Exception:
-        return _NULL_CTX
+def _reduce_span(label):
+    """The host side of a cross-worker reduce as a program span
+    (``mx:reduce_many`` in a profiler trace): where program A ends and
+    program B begins.  The collective bracket around it books the comm
+    time; this names it in the trace."""
+    return _ttracing.phase_span("reduce_many",
+                                {"label": label} if label else None)
 
 
 def _wire_bytes(nbytes, compressor):
@@ -385,7 +365,7 @@ class KVStore(object):
         extra = {"label": label} if label else {}
         with _blackbox.collective("reduce_many", n_keys=len(values),
                                   nbytes=raw, **extra):
-            with _xray_boundary(label):
+            with _reduce_span(label):
                 return self._cross_worker_reduce_many(list(values))
 
     def reduce_many_async(self, values, label=None):
@@ -458,7 +438,7 @@ class KVStore(object):
         extra = {"label": label} if label else {}
         with _blackbox.collective("reduce_quant", n_keys=len(payloads),
                                   nbytes=wire, keys=[sig], **extra):
-            with _xray_boundary(label):
+            with _reduce_span(label):
                 self._cross_worker_reduce_quantized(
                     list(payloads), list(n_elems), mode, block)
         return payloads

@@ -198,7 +198,17 @@ def _flash_bwd(causal, scale, res, g):
     Pass 1 recovers the softmax log-normalizer with an online max/sum scan;
     pass 2 rebuilds each probability tile from (logits − lse) and
     accumulates dQ (carried) and per-tile dK/dV (scan outputs).
+
+    All of it is staged under one ``named_scope``, so that a trace's
+    reduction finds the operations of both scans by their ``op_name``
+    (they are ``fusion`` ops inside two ``while`` loops, named like every
+    other matmul).
     """
+    with jax.named_scope("flash_attention_bwd"):
+        return _flash_bwd_scans(causal, scale, res, g)
+
+
+def _flash_bwd_scans(causal, scale, res, g):
     q, k, v = res[0], res[1], res[2]
     out = res[3]
     if scale is None:

@@ -867,11 +867,11 @@ def _build_fused_program(kind, cfg, shapes, flat_mode, has_state,
     apply = fused_formula_applier(kind, cfg, has_state)
 
     # graftlint: disable=GL305 -- lr/wd/rescale baked by design here: constants are the only layout bit-identical to the per-param path, and the program cache keys on them (see docstring)
-    def step(weights, grads, states):
+    def trainer_bucket_update(weights, grads, states):
         gs = unflatten(grads, shapes) if flat_mode else grads
         return apply(weights, gs, states, lrs, wds, rescale)
 
-    return jax.jit(step)
+    return jax.jit(trainer_bucket_update)
 
 
 def fused_bucket_update(optimizer, updater, indices, weights, grads,
@@ -908,14 +908,19 @@ def fused_bucket_update(optimizer, updater, indices, weights, grads,
     key = (kind, cfg, shapes, str(dtype), flat_mode, has_state,
            lrs, wds, rescale)
     fn = _FUSED_STEP_CACHE.get(key)
-    if fn is None:
-        fn = _build_fused_program(kind, cfg, shapes, flat_mode, has_state,
-                                  lrs, wds, rescale)
-        _FUSED_STEP_CACHE[key] = fn
     wvals = tuple(w._read() for w in weights)
     gvals = flat_grad._read() if flat_mode \
         else tuple(g._read() for g in grads)
     svals = tuple(tuple(a._read() for a in arrs) for arrs in state_arrays)
+    if fn is None:
+        fn = _build_fused_program(kind, cfg, shapes, flat_mode, has_state,
+                                  lrs, wds, rescale)
+        _FUSED_STEP_CACHE[key] = fn
+        # every bucket's program has this name; the registry keeps the
+        # newest, and needs only that all of them are the update phase
+        from .telemetry import xray as _xray
+        _xray.register_program("trainer_bucket_update", fn,
+                               (wvals, gvals, svals), phase="update")
     outs_w, outs_s = fn(wvals, gvals, svals)
     for k, w in enumerate(weights):
         w._write(outs_w[k])

@@ -27,6 +27,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..ndarray import NDArray
 from .. import autograd, random_state
 from ..ops.registry import get_op
+from ..telemetry import xray as _xray
+from ..telemetry.tracing import phase_span
 from .mesh import data_parallel_mesh
 
 __all__ = ["DataParallelTrainer", "pure_optimizer"]
@@ -134,6 +136,8 @@ class DataParallelTrainer(object):
         self._opt_state = None
         self._trainable = None
         self._jit_cache = {}
+        self._steps = 0            # step id of the program spans
+        self._noted = None         # the program the registry last got
 
     # -- parameter plumbing ------------------------------------------------
     def _gather_params(self, example_x):
@@ -252,26 +256,36 @@ class DataParallelTrainer(object):
             aux = {n: s._read() for n, s in shadows.items() if s._version > 0}
             return jnp.mean(per_sample._read()), aux
 
-        def step(params, opt_state, rng_key, x, y, lr):
+        def dp_train_step(params, opt_state, rng_key, x, y, lr):
             # rng key lives on device across steps: split here, return the
             # next key — no host RNG round trip per step
             next_key, rng = jax.random.split(rng_key)
             tvals = {n: params[n] for n in trainable}
             fvals = {n: v for n, v in params.items() if n not in tvals}
-            (loss_val, aux), grads = jax.value_and_grad(
-                forward_loss, has_aux=True)(tvals, fvals, x, y, rng)
+            # the three phases are staged under the scopes telemetry/xray.py
+            # parses out of the optimized HLO (every op's op_name path then
+            # starts with its phase): jax.vjp and not value_and_grad, which
+            # gives the pullback no call site to put a scope around
+            with jax.named_scope("xray:forward"):
+                loss_val, pullback, aux = jax.vjp(
+                    lambda t: forward_loss(t, fvals, x, y, rng), tvals,
+                    has_aux=True)
+            with jax.named_scope("xray:backward"):
+                grads, = pullback(jnp.ones_like(loss_val))
             new_params = dict(params)
             new_opt = {}
-            for n in trainable:
-                new_w, new_s = opt_update(params[n], grads[n], opt_state[n], lr)
-                new_params[n] = new_w.astype(params[n].dtype)
-                new_opt[n] = new_s
-            for n, v in aux.items():
-                if n not in tvals:
-                    new_params[n] = v.astype(new_params[n].dtype)
+            with jax.named_scope("xray:update"):
+                for n in trainable:
+                    new_w, new_s = opt_update(params[n], grads[n],
+                                              opt_state[n], lr)
+                    new_params[n] = new_w.astype(params[n].dtype)
+                    new_opt[n] = new_s
+                for n, v in aux.items():
+                    if n not in tvals:
+                        new_params[n] = v.astype(new_params[n].dtype)
             return new_params, new_opt, next_key, loss_val
 
-        return step
+        return dp_train_step
 
     def _sharding_trees(self):
         """(param tree, opt-state tree) of NamedShardings — honors
@@ -310,7 +324,7 @@ class DataParallelTrainer(object):
             ptree, otree = self._sharding_trees()
             step = self._make_step(train=True)
 
-            def multi(params, opt_state, rng_key, xs, ys, lr):
+            def dp_train_multi_step(params, opt_state, rng_key, xs, ys, lr):
                 def body(carry, xy):
                     p, s, k = carry
                     x, y = xy
@@ -322,7 +336,7 @@ class DataParallelTrainer(object):
                 return params, opt_state, rng_key, losses[-1]
 
             self._jit_cache[key] = jax.jit(
-                multi,
+                dp_train_multi_step,
                 in_shardings=(ptree, otree, repl, batch, batch, repl),
                 out_shardings=(ptree, otree, repl, repl),
                 donate_argnums=(0, 1, 2) if self._donate else ())
@@ -370,20 +384,35 @@ class DataParallelTrainer(object):
 
         return _place(x), _place(y)
 
+    def _dispatch(self, name, compile_fn, data, label, batch_spec, multi):
+        """One launch of a jitted step, under the program spans ``step``
+        (which carries the step id) > ``place``, ``dispatch``; the program
+        is handed to the registry (``telemetry.programs()``) the first
+        time it runs."""
+        from .mesh import use_mesh
+        self._steps += 1
+        with use_mesh(self.mesh), phase_span("step", step=self._steps):
+            # scope covers deferred-init (in _prepare_inputs) AND the
+            # trace: mesh-aware layers resolve this mesh throughout
+            with phase_span("place"):
+                x, y = self._prepare_inputs(data, label, batch_spec,
+                                            multi=multi)
+            fn = compile_fn(x, y)
+            args = (self._params, self._opt_state, self._rng_key, x, y,
+                    self._lr_dev)
+            if fn is not self._noted:
+                _xray.register_program(name, fn, args)
+                self._noted = fn
+            with phase_span("dispatch"):
+                self._params, self._opt_state, self._rng_key, loss_val = \
+                    fn(*args)
+        return loss_val
+
     def step_multi(self, datas, labels):
         """Run K chained steps in one launch; ``datas`` (K, batch, ...),
         ``labels`` (K, batch).  Returns the last step's device loss."""
-        from .mesh import use_mesh
-        with use_mesh(self.mesh):
-            # scope covers deferred-init (in _prepare_inputs) AND the
-            # trace: mesh-aware layers resolve this mesh throughout
-            xs, ys = self._prepare_inputs(datas, labels, P(None, "dp"),
-                                          multi=True)
-            fn = self.compile_multi(xs, ys)
-            self._params, self._opt_state, self._rng_key, loss_val = fn(
-                self._params, self._opt_state, self._rng_key, xs, ys,
-                self._lr_dev)
-        return loss_val
+        return self._dispatch("dp_train_multi_step", self.compile_multi,
+                              datas, labels, P(None, "dp"), True)
 
     def step(self, data, label):
         """Run one sharded train step; returns the device scalar loss.
@@ -391,16 +420,22 @@ class DataParallelTrainer(object):
         The trainer's mesh is scoped for the trace (parallel.use_mesh), so
         mesh-aware layers (MultiHeadAttention(seq_axis=...), capacity MoE)
         resolve THIS mesh without the caller wrapping every step."""
+        return self._dispatch("dp_train_step", self.compile, data, label,
+                              P("dp"), False)
+
+    def compiled_step(self, data, label):
+        """The compiled program of ``step`` for this batch, a
+        ``jax.stages.Compiled``: its optimized HLO (``as_text()``), its
+        ``memory_analysis()`` and ``cost_analysis()``.  Lowered from the
+        shapes and shardings of the trainer's state, so nothing runs and
+        nothing is donated; a step that has already run is read back from
+        the compile cache."""
         from .mesh import use_mesh
         with use_mesh(self.mesh):
-            # scope covers deferred-init (in _prepare_inputs) AND the
-            # trace: mesh-aware layers resolve this mesh throughout
             x, y = self._prepare_inputs(data, label, P("dp"))
-            fn = self.compile(x, y)
-            self._params, self._opt_state, self._rng_key, loss_val = fn(
+            return self.compile(x, y).lower(*_xray.abstract((
                 self._params, self._opt_state, self._rng_key, x, y,
-                self._lr_dev)
-        return loss_val
+                self._lr_dev))).compile()
 
     @property
     def learning_rate(self):

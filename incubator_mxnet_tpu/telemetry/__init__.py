@@ -30,6 +30,18 @@ Four quarters (see docs/observability.md for the full guide):
   with per-rank tracks, cross-rank flow links per collective, and a
   straggler table (last-to-enter/exit rank + spreads).
 
+What the program marks in itself, for a reader of a profiler trace
+(``benchmark/chip/layer_metrics/`` is one):
+
+* :func:`phase_span` — the one span primitive: ``mx:<name>`` in the JAX
+  profiler's trace beside the device events, and a record (name, start,
+  end, parent, step id, on ``time.perf_counter()``) that :func:`spans`
+  hands out, oldest first.
+* :func:`programs` — the jitted programs of the train paths by the name
+  the trace's ``XLA Modules`` line gives them, each with its phase or its
+  ops' ``op_name`` paths (``xray:forward|backward|update`` scopes, Block
+  names) and its ``memory_analysis()`` numbers, resolved when asked for.
+
 CLI::
 
     python -m incubator_mxnet_tpu.telemetry --summary [--json]
@@ -59,12 +71,15 @@ from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       compact_snapshot, enabled, parse_prometheus_text,
                       registry, set_enabled, write_snapshot)
 from .tracing import phase_span
+from .blackbox import spans
+from .xray import programs
 
 __all__ = ["metrics", "lens", "tracing", "blackbox", "watchdog",
            "aggregate", "xray",
            "Counter", "Gauge", "Histogram", "MetricsRegistry",
            "registry", "enabled", "set_enabled", "parse_prometheus_text",
-           "compact_snapshot", "write_snapshot", "phase_span"]
+           "compact_snapshot", "write_snapshot", "phase_span", "spans",
+           "programs"]
 
 _snapshot_path = _os.environ.get("GRAFT_TELEMETRY_SNAPSHOT")
 if _snapshot_path:

@@ -503,62 +503,6 @@ def run_ingest(path, as_json):
     return 1 if report["problems"] else 0
 
 
-def _render_xray_text(sessions):
-    lines = ["graftxray capture sessions", "=" * 60]
-    if not sessions:
-        lines.append("(no capture sessions — arm with GRAFT_XRAY=1 and "
-                     "trigger via GRAFT_XRAY_EVERY, a slow step, a "
-                     "watchdog trip, or xray.request_capture())")
-    for s in sessions:
-        lines.append("session: reason=%s steps=%s ok=%s"
-                     % (s.get("reason"), s.get("steps"), s.get("ok")))
-        if s.get("error"):
-            lines.append("  ERROR: %s" % s["error"])
-        rep = s.get("report") or {}
-        phases = rep.get("phases") or s.get("phases") or {}
-        for p in sorted(phases):
-            d = phases[p]
-            dev = d["device_s"] if isinstance(d, dict) else d
-            lines.append("  %-22s %10.3f ms" % (p, dev * 1e3))
-        un = rep.get("unattributed_s", s.get("unattributed_s"))
-        tot = rep.get("program_device_s", s.get("program_device_s"))
-        if un is not None:
-            lines.append("  %-22s %10.3f ms" % ("unattributed", un * 1e3))
-        if tot is not None:
-            cons = rep.get("conservation_ok", s.get("conservation_ok"))
-            lines.append("  %-22s %10.3f ms  (conservation %s)"
-                         % ("program span", tot * 1e3,
-                            "EXACT" if cons else "VIOLATED"))
-        for r in (rep.get("top_ops") or s.get("top_ops") or [])[:8]:
-            dev_us = r.get("device_us", r.get("device_s", 0.0) * 1e6)
-            lines.append("    op %-32s phase=%-14s %9.1f us x%s"
-                         % (r["op"][:32], r.get("phase") or "-",
-                            dev_us, r.get("count", "?")))
-    return "\n".join(lines)
-
-
-def run_xray(path, as_json):
-    """``--xray``: render capture sessions — live harness state when no
-    path is given, else the ``xray_capture`` events of a blackbox dump."""
-    from incubator_mxnet_tpu.telemetry import xray
-    if path:
-        with open(path) as f:
-            doc = json.load(f)
-        # dump events nest the fields under "data" ({"ts", "kind",
-        # "data": {...}} — blackbox.events()); flatten for the renderer
-        sessions = [dict(e.get("data") or {},
-                         ok=(e.get("data") or {}).get("ok", True))
-                    for e in doc.get("events", [])
-                    if e.get("kind") == "xray_capture"]
-    else:
-        sessions = xray.sessions()
-    if as_json:
-        print(json.dumps(sessions, indent=2, sort_keys=True, default=str))
-    else:
-        print(_render_xray_text(sessions))
-    return 0
-
-
 def _demo_mem_steps():
     """The --steps demo loop with the exact live-arrays memory sampler
     installed (host CPU reports no allocator counters, so the default
@@ -697,12 +641,6 @@ def main(argv=None):
                          "from a chrome trace (the async-ledger "
                          "fallback when pulse callbacks were "
                          "unavailable)")
-    ap.add_argument("--xray", metavar="DUMP", nargs="?", const="",
-                    default=None,
-                    help="render graftxray capture sessions (phase "
-                         "device-time tables of the compiled step) — "
-                         "live harness state, or the xray_capture "
-                         "events of a blackbox dump PATH")
     ap.add_argument("--top", type=int,
                     default=int(os.environ.get("GRAFT_TELEMETRY_TOPK",
                                                "10")),
@@ -721,9 +659,6 @@ def main(argv=None):
 
     if args.ingest_xla:
         return run_ingest(args.ingest_xla, args.json)
-
-    if args.xray is not None:
-        return run_xray(args.xray, args.json)
 
     if args.steps:
         return run_steps(args.json)

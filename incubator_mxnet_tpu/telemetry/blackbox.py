@@ -47,11 +47,11 @@ from ..analysis import lockstep as _lockstep
 
 __all__ = ["enabled", "set_enabled", "record", "events", "stats",
            "in_flight", "inflight_entries", "progress", "last_progress",
-           "collective", "phase_begin", "phase_end", "step_journal",
+           "collective", "phase_begin", "phase_end", "spans", "step_journal",
            "workers_seen", "set_rank", "set_clock_offset", "dump",
            "snapshot", "default_path", "validate_dump", "summarize_dump",
            "install_hooks", "configure", "selftest", "SCHEMA",
-           "register_emergency", "unregister_emergency", "xray_session"]
+           "register_emergency", "unregister_emergency"]
 
 SCHEMA = "graft-blackbox/1"
 _DEFAULT_SIZE = 4096
@@ -87,6 +87,11 @@ _stats = [0]                    # events recorded ever (dropped = _stats[0]
 #                                 - len(_ring)); single-slot list keeps the
 #                                 increment one bytecode away from atomic —
 #                                 a lost count under contention is harmless
+# closed program spans (tracing.phase_span), oldest first: (name, start,
+# end, parent, step) on time.perf_counter().  A deque of their own, under
+# the ring's switch and size: a loop that flushes 40 times a step would
+# otherwise push a step's spans out of the ring within seconds
+_spans = deque(maxlen=_ring_size())
 _rank = [0]
 _clock_offset = [None]          # latest heartbeat clock/arrival offset
 #                                 estimate vs the freshest-arriving rank
@@ -98,10 +103,11 @@ _started_at = time.time()
 
 def configure(size=None):
     """Re-size the ring (tests / live re-tuning).  Keeps newest events."""
-    global _ring
+    global _ring, _spans
     if size is not None:
         os.environ["GRAFT_BLACKBOX_SIZE"] = str(int(size))
     _ring = deque(_ring, maxlen=_ring_size())
+    _spans = deque(_spans, maxlen=_ring_size())
 
 
 def set_rank(rank):
@@ -396,24 +402,33 @@ def phase_begin(phase):
     return _push_inflight("phase", {"phase": phase})
 
 
-def phase_end(entry, phase, seconds, error=False):
-    """Close the phase bracket; latency lands on the open step journal
-    (or its own ring event when no step is open, e.g. Module fwd/bwd)."""
+def phase_end(entry, phase, start, end, parent=None, step=None,
+              error=False):
+    """Close the phase bracket and keep the span's record: name, start,
+    end (``time.perf_counter()``), the span it was opened in and the step
+    it belongs to.  A span opened directly in a step journal also lands
+    its latency on the journal (spans nested in it are part of it)."""
     if entry is not None:
         _pop_inflight(entry, error="exception in phase %r" % phase
                       if error else None)
     if not enabled():
         return
+    _spans.append((phase, start, end, parent, step))
     j = getattr(_tls, "step", None)
-    if j is not None:
-        j["phases"][phase] = j["phases"].get(phase, 0.0) + seconds
+    if j is not None and parent is None:
+        j["phases"][phase] = j["phases"].get(phase, 0.0) + end - start
         if error:
             j["error_phase"] = phase
-    else:
-        fields = {"phase": phase, "seconds": round(seconds, 6)}
-        if error:
-            fields["error"] = True
-        record("phase", **fields)
+
+
+def spans(since=None):
+    """The closed program spans still held, oldest first, as ``(name,
+    start_s, end_s, parent, step)`` on ``time.perf_counter()``; with
+    ``since``, those that began at or after it."""
+    held = list(_spans)
+    if since is None:
+        return held
+    return [s for s in held if s[1] >= since]
 
 
 def _device_mem_peak():
@@ -518,19 +533,6 @@ def step_journal(origin, **fields):
     return _StepJournal(origin, fields)
 
 
-def xray_session(reason, steps, phases, **extra):
-    """One graftxray capture session (kind ``xray_capture``): the
-    phase→device-seconds table a compiled-step profiler capture
-    attributed, plus its conservation verdict and top ops — the
-    flight-recorder twin of the ``graft_xray_phase_device_seconds``
-    gauges, so a post-mortem dump carries the last in-program device
-    decomposition alongside the host-side step journals."""
-    if not enabled():
-        return
-    record("xray_capture", reason=reason, steps=steps, phases=phases,
-           **{k: v for k, v in extra.items() if v is not None})
-
-
 # ---------------------------------------------------------------------------
 # dist worker table (straggler view)
 # ---------------------------------------------------------------------------
@@ -617,6 +619,10 @@ def snapshot(reason="manual", extra=None):
         "failures": [dict(f) for f in _failures],
         "workers": workers,
         "events": events(),
+        # [name, start, end, parent, step] on the perf_counter clock, which
+        # perf_anchor ties to the events' wall clock
+        "spans": [list(sp) for sp in spans()],
+        "perf_anchor": {"perf_s": time.perf_counter(), "wall_s": now},
         "threads": _thread_stacks(),
     }
     try:

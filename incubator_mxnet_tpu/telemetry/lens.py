@@ -852,26 +852,6 @@ def compact(rec):
     return out
 
 
-def attach_xray(summary, max_records=None):
-    """graftxray feed: annotate the most recent COMPILED ring records
-    (newest first) with a capture session's device-side attribution —
-    the real device span (``span`` t0/t1 in the trace timebase, the
-    per-step device share) and the per-phase device seconds that the
-    host-observed single ``device_async`` span of compiled mode cannot
-    resolve.  Additive only (a new ``xray`` key): the window's
-    host-side six-component conservation is untouched.  Returns the
-    number of records annotated."""
-    n = 0
-    for rec in reversed(_ring):
-        if max_records is not None and n >= max_records:
-            break
-        if not rec.get("compiled") or "xray" in rec:
-            continue
-        rec["xray"] = dict(summary)
-        n += 1
-    return n
-
-
 def steps():
     """The ring, oldest first (copies)."""
     return [dict(r, components=dict(r["components"])) for r in list(_ring)]

@@ -809,31 +809,6 @@ def autotune_signal(name, value):
                     ("signal",)).set(float(value), signal=name)
 
 
-# -- graftxray: in-program phase attribution ---------------------------------
-
-def xray_capture(reason, ok=True):
-    """One completed graftxray capture session (telemetry/xray.py)."""
-    if not enabled():
-        return
-    _REGISTRY.counter("graft_xray_captures_total",
-                      "graftxray capture sessions by trigger",
-                      ("reason", "ok")).inc(
-        reason=reason, ok="true" if ok else "false")
-
-
-def xray_phase_seconds(phase, seconds):
-    """True device seconds one xray phase spent inside the compiled
-    program over the latest capture session.  The phase gauges plus
-    ``unattributed`` sum EXACTLY to the captured program device span
-    (the graftxray conservation contract)."""
-    if not enabled():
-        return
-    _REGISTRY.gauge("graft_xray_phase_device_seconds",
-                    "Device seconds per xray phase, latest capture "
-                    "(phases + unattributed == program device span)",
-                    ("phase",)).set(float(seconds), phase=phase)
-
-
 # -- graftwatch: watchdog + dist liveness ------------------------------------
 
 _SKEW_BUCKETS = (1e-4, 1e-3, 1e-2, 0.1, 0.5, 1.0, 5.0, 30.0)

@@ -18,15 +18,22 @@ PAPERS.md):
   flush) link each deferred op to exactly one flush, so the trace UI
   draws arrows from where an op was *issued* to where its cost *landed*.
 
-Also here: :func:`phase_span`, the per-batch training-loop span
-(fwd/bwd/update/kvstore) used by gluon ``Trainer`` and
-``Module.forward_backward`` — each span both lands in the chrome trace
-(cat ``phase``) and feeds the ``graft_phase_seconds`` histogram.
+Also here: :func:`phase_span`, the program's one span primitive: the
+training-loop phases (fwd/bwd/update/kvstore) of gluon ``Trainer``,
+``CachedOp`` and ``Module.forward_backward``, the fused step's
+step/place/dispatch, engine flushes.  Each span lands in the JAX
+profiler's trace as ``mx:<name>`` (beside the device events, on their
+clock), in the flight recorder's span record (``telemetry.spans()``), in
+the chrome trace (cat ``phase``) and in the ``graft_phase_seconds``
+histogram.
 """
 from __future__ import annotations
 
 import itertools
+import threading
 import time
+
+import jax
 
 from . import blackbox as _blackbox
 from . import lens as _lens
@@ -104,28 +111,60 @@ def segment_flush_span(segment, cause, begin_us, end_us, flow_indices,
                             "args": {"segment": segment}})
 
 
+_open = threading.local()        # .stack: this thread's open spans
+
+
 class _PhaseSpan(object):
-    """Times one training-loop phase; emits a chrome event (cat "phase")
-    when the profiler runs and always feeds graft_phase_seconds.  The
-    span closes on the exception path too — the chrome event (marked
-    ``error``), the histogram observation AND the flight-recorder phase
-    bracket all land, so a crash mid-phase leaves a well-formed trace
-    and a dump that names the phase."""
+    """One program span: a training-loop phase (fwd/bwd/update/kvstore), a
+    fused step and its parts, an engine flush.  It is written where the
+    device events are, as ``mx:<phase>`` in the JAX profiler's trace
+    (``TraceAnnotation``: a flag test while no trace runs), and its record
+    (name, start, end, parent, step id, on ``time.perf_counter()``) goes
+    to the flight recorder (``telemetry.spans()``).  It also emits a
+    chrome event (cat "phase") when ``mx.profiler`` runs and feeds
+    graft_phase_seconds and the lens.  The span closes on the exception
+    path too: the chrome event (marked ``error``), the histogram
+    observation AND the flight-recorder phase bracket all land, so a
+    crash mid-phase leaves a well-formed trace and a dump that names the
+    phase."""
 
-    __slots__ = ("phase", "args", "_begin", "_t0", "_bb")
+    __slots__ = ("phase", "args", "step", "parent", "_begin", "_t0", "_bb",
+                 "_ann")
 
-    def __init__(self, phase, args=None):
+    def __init__(self, phase, args=None, step=None):
         self.phase = phase
         self.args = args
+        self.step = step
 
     def __enter__(self):
-        self._t0 = time.perf_counter()
+        try:
+            stack = _open.stack
+        except AttributeError:
+            stack = _open.stack = []
+        outer = stack[-1] if stack else None
+        self.parent = outer.phase if outer is not None else None
+        if self.step is None:
+            # a span belongs to its parent's step; one opened outside any
+            # other, to the step window the lens has open on this thread
+            self.step = (outer.step if outer is not None
+                         else _lens.current_step())
+        stack.append(self)
         self._begin = _prof()._now_us()
         self._bb = _blackbox.phase_begin(self.phase)
+        self._ann = jax.profiler.TraceAnnotation("mx:" + self.phase,
+                                                 **(self.args or {}))
+        self._t0 = time.perf_counter()
+        self._ann.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        dt = time.perf_counter() - self._t0
+        self._ann.__exit__(exc_type, exc, tb)
+        t1 = time.perf_counter()
+        stack = _open.stack
+        if stack and stack[-1] is self:
+            stack.pop()
+        else:                       # closed out of order: generators
+            stack.remove(self)
         p = _prof()
         if p._P.active():
             args = {"phase": self.phase}
@@ -135,9 +174,10 @@ class _PhaseSpan(object):
                 args.update(self.args)
             p.record_event(self.phase, self._begin, p._now_us(),
                            cat="phase", args=args)
-        _metrics.phase(self.phase, dt)
-        _lens.phase(self.phase, self._t0, self._t0 + dt)
-        _blackbox.phase_end(self._bb, self.phase, dt,
+        _metrics.phase(self.phase, t1 - self._t0)
+        _lens.phase(self.phase, self._t0, t1)
+        _blackbox.phase_end(self._bb, self.phase, self._t0, t1,
+                            parent=self.parent, step=self.step,
                             error=exc_type is not None)
         return False
 
@@ -155,14 +195,16 @@ class _NullSpan(object):
 _NULL = _NullSpan()
 
 
-def phase_span(phase, args=None):
-    """Context manager for one fwd/bwd/update/kvstore phase.  Free when
-    the profiler, telemetry, the flight recorder AND the lens are all
-    off."""
+def phase_span(phase, args=None, step=None):
+    """Context manager for one program span (``_PhaseSpan``).  ``args``
+    (strings and numbers) ride the profiler events; ``step`` is given by
+    a span that starts a step, and inherited by those opened in it.  Free
+    when the profiler, telemetry, the flight recorder AND the lens are
+    all off."""
     if not _metrics.enabled() and not _prof()._P.active() \
             and not _blackbox.enabled() and not _lens.enabled():
         return _NULL
-    return _PhaseSpan(phase, args)
+    return _PhaseSpan(phase, args, step)
 
 
 # ---------------------------------------------------------------------------

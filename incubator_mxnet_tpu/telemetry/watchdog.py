@@ -173,16 +173,6 @@ class Watchdog(threading.Thread):
             "graftwatch: WATCHDOG TRIP — %r in flight for %.1fs "
             "(timeout %.1fs), detail=%r, dead_ranks=%r; dump: %s\n"
             % (entry["site"], age, self.timeout, detail, dead, path))
-        # graftxray: an aged COMPILED bracket (a step_compile journal or
-        # the compiled_step collective) requests a one-shot profiler
-        # capture of the next dispatches — armed()-gated inside, so this
-        # is inert unless GRAFT_XRAY is on
-        if "compiled" in repr(detail):
-            try:
-                from . import xray as _xray
-                _xray.request_capture("watchdog:%s" % entry["site"])
-            except Exception:
-                pass
         try:
             faulthandler.dump_traceback(file=sys.stderr, all_threads=True)
         except Exception:

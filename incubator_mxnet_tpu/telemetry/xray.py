@@ -1,62 +1,45 @@
-"""graftxray — in-program phase attribution + true device timestamps
-for the compiled step.
+"""graftxray — in-program phase attribution for the compiled train steps.
 
-graftstep (whole-step compilation) made the steady-state train step ONE
-donated XLA program — and thereby opaque to graftlens: the per-step
-decomposition that drives the autotuner and the straggler analytics
-collapses to a single host-observed ``device_async`` span in compiled
-mode.  This module reopens the program:
+A fused train step is ONE donated XLA program, opaque from outside: a
+profiler names its device ops after post-fusion HLO instructions.  This
+module reopens the program:
 
-* **Phase provenance at trace time.**  ``step_compile.py`` threads
-  ``jax.named_scope`` markers (``xray:forward``, ``xray:backward``,
-  ``xray:update[bucket_i]``) through its trace, so every HLO op in the
-  compiled program carries the phase in its ``op_name`` metadata —
-  fusion keeps the representative op's scope, so the attribution
-  survives XLA's optimizer.  :func:`scope_map_from_hlo` parses the
-  OPTIMIZED HLO of the compiled executable (the names the profiler
-  trace references) into an op→phase table, registered per program via
-  :func:`note_program`.
+* **Phase provenance at trace time.**  ``parallel/data_parallel.py`` and
+  ``gluon/step_compile.py`` thread ``jax.named_scope`` markers
+  (``xray:forward``, ``xray:backward``, ``xray:update``) through their
+  traces, and a ``HybridBlock`` adds its name while it is traced, so every
+  HLO op of the compiled program carries its phase and its Block in its
+  ``op_name`` metadata — fusion keeps the representative op's scope, so
+  the attribution survives XLA's optimizer.  :func:`op_paths_from_hlo`
+  parses the OPTIMIZED HLO of the compiled executable (the names the
+  profiler trace references).
 
-* **On-demand capture.**  ``GRAFT_XRAY=1`` arms the harness (default
-  off — the disabled path is one memoized env read per dispatch).
-  Armed, a capture session runs ``jax.profiler`` around
-  ``GRAFT_XRAY_STEPS`` (default 3) compiled dispatches, started by any
-  of: ``GRAFT_XRAY_EVERY=N`` (periodic), :func:`request_capture`
-  (manual / tests), a lens slow-step flag (wall > ``GRAFT_XRAY_SLOW_X``
-  × the rolling median of compiled windows), or a watchdog trip on an
-  aged compiled bracket.  The emitted chrome trace is parsed with the
-  SAME core ``aggregate.ingest_xla`` uses offline (one parser, online +
-  offline), device ops map back to phases by scope name, and the
-  result feeds the lens ring, the metrics registry and the blackbox.
+* **The program registry.**  The train paths hand their jitted programs
+  over by name (:func:`register_program`: ``dp_train_step``,
+  ``cachedop_forward``, ``cachedop_backward``, ``trainer_bucket_update``;
+  ``step_compile`` its compiled ones, :func:`note_program`), lazily:
+  nothing is lowered or printed until a reader asks :func:`programs`
+  (``telemetry.programs()``) for a program's ops or memory numbers.
 
-* **Exact-sum conservation.**  Durations accumulate as integer
-  nanoseconds partitioned over phases: ``sum(phase device times) +
-  unattributed == program device span`` holds EXACTLY for every
-  capture (``conservation_ok`` is asserted by tests and the tier-12
-  selftest) — the compiled-mode twin of the lens' six-component
-  host-side contract.
+* **Exact-sum conservation.**  :func:`attribute` partitions a chrome
+  trace's device ops over the phases; durations accumulate as integer
+  nanoseconds: ``sum(phase device times) + unattributed == program device
+  span`` holds EXACTLY (``conservation_ok``).  The capture is whoever's
+  profiler session it is (``mx.profiler.set_config(xprof_dir=...)``, the
+  chip benchmark's tracer); this module starts none.
 
-* **Cost ledger.**  Each compiled program registers its
+* **Cost ledger.**  Each program ``step_compile`` compiles registers its
   ``jax.stages.Compiled.cost_analysis()`` / ``memory_analysis()``
   summary at trace time; retraces diff against the previous build of
   the same program, the diff journals to the blackbox
   (``xray_cost_diff``) and :func:`cost_regressions` hands EH301 storm
   reports a one-line "what got more expensive" summary.
-
-CLI: ``python -m incubator_mxnet_tpu.telemetry --xray [DUMP]`` renders
-capture sessions (live, or from a blackbox dump);
-``python -m incubator_mxnet_tpu.telemetry.xray --selftest`` is the
-lint tier: capture a 3-step compiled loop, assert phase rows +
-conservation.
 """
 from __future__ import annotations
 
 import gzip
 import json
-import os
 import re
-import shutil
-import tempfile
 import threading
 import time
 import weakref
@@ -65,87 +48,21 @@ from collections import deque
 import jax
 
 from . import blackbox as _blackbox
-from . import lens as _lens
-from . import metrics as _metrics
 
 __all__ = [
-    "armed", "capture_every", "capture_steps", "request_capture",
-    "dispatch_begin", "dispatch_end", "sessions", "reset",
-    "note_program", "cost_regressions", "cost_history",
-    "scope_map_from_hlo", "attribute", "parse_trace",
+    "reset", "note_program", "register_program", "programs", "Program", "abstract",
+    "cost_regressions", "cost_history",
+    "scope_map_from_hlo", "op_paths_from_hlo", "phase_of", "attribute",
+    "parse_trace",
     "merge_intervals", "device_pids", "is_device_event", "step_spans",
-    "step_rows", "load_trace", "find_trace_file",
-    "DEVICE_PID_HINTS", "selftest", "main",
+    "step_rows", "load_trace", "DEVICE_PID_HINTS",
 ]
 
 
 # ---------------------------------------------------------------------------
-# env gating — memoized raw-string reads (the lens hot-path pattern):
-# the disabled cost per dispatch is one os.environ lookup + one string
-# identity compare, which is what the bench_eager xray_overhead gate
-# holds under 2%
-# ---------------------------------------------------------------------------
-
-_OFF_VALUES = ("", "0", "false", "no", "off")
-_armed_memo = ["\x00", False]
-_every_memo = ["\x00", 0]
-
-
-def armed():
-    """GRAFT_XRAY (default off): is the capture harness armed?  Armed
-    means triggers are LIVE (periodic, manual, slow-step, watchdog) —
-    it does not by itself capture anything."""
-    raw = os.environ.get("GRAFT_XRAY", "")
-    if raw != _armed_memo[0]:
-        _armed_memo[1] = raw.strip().lower() not in _OFF_VALUES
-        _armed_memo[0] = raw
-    return _armed_memo[1]
-
-
-def capture_every():
-    """GRAFT_XRAY_EVERY=N (default 0 = off): start a capture session on
-    every N-th compiled dispatch."""
-    raw = os.environ.get("GRAFT_XRAY_EVERY", "")
-    if raw != _every_memo[0]:
-        try:
-            _every_memo[1] = max(int(raw), 0)
-        except ValueError:
-            _every_memo[1] = 0
-        _every_memo[0] = raw
-    return _every_memo[1]
-
-
-def capture_steps():
-    """GRAFT_XRAY_STEPS (default 3): compiled dispatches per session."""
-    try:
-        return max(int(os.environ.get("GRAFT_XRAY_STEPS", "3")), 1)
-    except ValueError:
-        return 3
-
-
-_slow_memo = ["\x00", 3.0]
-
-
-def _slow_factor():
-    """GRAFT_XRAY_SLOW_X (default 3.0): a compiled lens window slower
-    than this multiple of the rolling median requests a one-shot
-    capture.  Memoized on the raw string — this runs on every armed
-    compiled lens record."""
-    raw = os.environ.get("GRAFT_XRAY_SLOW_X", "")
-    if raw != _slow_memo[0]:
-        try:
-            _slow_memo[1] = max(float(raw or "3.0"), 1.0)
-        except ValueError:
-            _slow_memo[1] = 3.0
-        _slow_memo[0] = raw
-    return _slow_memo[1]
-
-
-# ---------------------------------------------------------------------------
-# shared trace-parsing core — ONE parser for the online capture path
-# (this module) and the offline ``telemetry --ingest-xla`` CLI
-# (aggregate.ingest_xla delegates here); same interval union, same
-# ``_row`` step-window convention
+# shared trace-parsing core — ONE parser for :func:`attribute` and the
+# offline ``telemetry --ingest-xla`` CLI (aggregate.ingest_xla delegates
+# here); same interval union, same ``_row`` step-window convention
 # ---------------------------------------------------------------------------
 
 DEVICE_PID_HINTS = ("tpu", "gpu", "/device:", "accelerator")
@@ -210,22 +127,6 @@ def load_trace(path_or_doc):
     return events
 
 
-def find_trace_file(logdir):
-    """Newest ``*.trace.json[.gz]`` under a ``jax.profiler.start_trace``
-    log directory (``<dir>/plugins/profile/<ts>/<host>.trace.json.gz``),
-    or None."""
-    best = None
-    for root, _dirs, files in os.walk(logdir):
-        for name in files:
-            if name.endswith(".trace.json") or name.endswith(
-                    ".trace.json.gz"):
-                p = os.path.join(root, name)
-                if best is None or os.path.getmtime(p) > \
-                        os.path.getmtime(best):
-                    best = p
-    return best
-
-
 def step_spans(events):
     """Group device-busy spans by their ``args.step`` stamp (None pools
     the unstamped).  Returns ``(by_step, n_device, dpids)`` —
@@ -253,7 +154,7 @@ def step_spans(events):
 
 def step_rows(by_step):
     """The device-ledger row convention shared by ``--ingest-xla`` and
-    the online capture sessions: per-step busy unions, step windows
+    :func:`attribute`: per-step busy unions, step windows
     chained previous-end → this-end (so ``busy_s + idle_s == wall_s``
     holds exactly per row, the live-lens contract), and a UNION total
     (not a sum — the pooled unattributed row's window overlaps the
@@ -332,26 +233,39 @@ def phase_of(op_name_path):
     return m.group(1) if m else None
 
 
-def scope_map_from_hlo(hlo_text):
+def op_paths_from_hlo(hlo_text):
     """Parse ``metadata={op_name="..."}`` from optimized HLO text into
-    ``{hlo_op_name: phase}`` (ops without an ``xray:`` scope are left
-    out — they pool into "unattributed" at attribution time, which is
-    what the conservation contract accounts for)."""
+    ``{hlo_op_name: op_name path}``: the whole path, in which
+    :func:`phase_of` finds the phase and a reader a ``named_scope`` of its
+    own (``flash_attention_bwd``, a Block's name)."""
     out = {}
     for line in hlo_text.splitlines():
         m = _HLO_META.match(line)
-        if not m:
-            continue
-        phase = phase_of(m.group(2))
-        if phase is not None:
-            out[m.group(1)] = phase
+        if m:
+            out[m.group(1)] = m.group(2)
     return out
 
 
+def _phases(op_paths):
+    """``{op: phase}`` of the ops whose path carries an ``xray:`` scope."""
+    phases = ((op, phase_of(path)) for op, path in op_paths.items())
+    return {op: phase for op, phase in phases if phase is not None}
+
+
+def scope_map_from_hlo(hlo_text):
+    """``{hlo_op_name: phase}`` of optimized HLO text (ops without an
+    ``xray:`` scope are left out — they pool into "unattributed" at
+    attribution time, which is what the conservation contract accounts
+    for)."""
+    return _phases(op_paths_from_hlo(hlo_text))
+
+
 def _norm_module(name):
-    """Trace ``args.hlo_module`` → registry key: strip the ``jit_``
-    prefix and any ``.N`` uniquifier suffix."""
-    name = str(name or "")
+    """A program's name in a trace (``args.hlo_module``, or an event of
+    the ``XLA Modules`` line) → registry key: strip the ``jit_`` prefix,
+    the ``(fingerprint)`` the modules line appends and any ``.N``
+    uniquifier suffix."""
+    name = re.sub(r"\(\d+\)$", "", str(name or ""))
     if name.startswith("jit_"):
         name = name[4:]
     return re.sub(r"\.\d+$", "", name)
@@ -363,7 +277,7 @@ def _norm_module(name):
 # ---------------------------------------------------------------------------
 
 _reg_lock = threading.Lock()
-_programs = {}              # name -> {"ref", "scope_map", "label", "at"}
+_programs = {}              # name -> Program
 _cost_history = {}          # name -> [cost dict, ...] (last few builds)
 _cost_diffs = deque(maxlen=8)   # latest retrace diffs, newest last
 
@@ -411,6 +325,86 @@ def diff_costs(old, new):
     return out
 
 
+def abstract(args):
+    """The shapes, dtypes and shardings of a tree of arrays: what
+    ``jitted.lower`` needs, and nothing that holds a buffer."""
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=getattr(a, "sharding", None)),
+        args)
+
+
+class Program(object):
+    """One registered program of a train path.
+
+    ``name`` is the jitted function's, as the trace's ``XLA Modules`` line
+    has it once through :func:`_norm_module`; ``phase`` is the phase all
+    of its device time belongs to, or None when its ops carry ``xray:``
+    scopes of their own.  ``ops`` ({HLO op: op_name path}, of the
+    optimized HLO) and ``memory`` (:func:`_cost_summary`) are read from
+    the compiled executable the first time either is asked for: the
+    registry holds the jitted function weakly with the abstract arguments
+    it ran on, and lowers and compiles from them then (a read of the
+    compile cache for a program that has run).  A program that is gone,
+    or will not compile, has neither."""
+
+    def __init__(self, name, compiled, phase=None):
+        self.name, self.phase = name, phase
+        self._compiled = compiled       # () -> jax.stages.Compiled or None
+        self._ops = self._memory = None
+        self.error = None               # why ops and memory are empty
+
+    def _resolve(self):
+        if self._ops is None:
+            self._ops, self._memory = {}, {}
+            try:
+                compiled = self._compiled()
+                if compiled is not None:
+                    self._ops = op_paths_from_hlo(compiled.as_text())
+                    self._memory = _cost_summary(compiled)
+                else:
+                    self.error = "the program is gone"
+            except Exception as e:      # observation never fails a step
+                self.error = repr(e)
+
+    @property
+    def ops(self):
+        self._resolve()
+        return self._ops
+
+    @property
+    def memory(self):
+        self._resolve()
+        return self._memory
+
+    def scope_map(self):
+        """``{HLO op: phase}`` of the ops that carry an ``xray:`` scope."""
+        return _phases(self.ops)
+
+
+def register_program(name, jitted, args, phase=None):
+    """Hand a jitted program of a train path to the registry, lazily:
+    the function (held weakly) and the abstract form of the arguments it
+    was just called with.  Nothing is lowered, compiled or printed until
+    a reader asks (:func:`programs`).  ``phase`` says that the whole
+    program belongs to one phase (``forward``, ``backward``, ``update``);
+    without it the program's own ``xray:`` scopes say."""
+    ref, avals = weakref.ref(jitted), abstract(args)
+
+    def compiled():
+        fn = ref()
+        return None if fn is None else fn.lower(*avals).compile()
+
+    with _reg_lock:
+        _programs[name] = Program(name, compiled, phase)
+
+
+def programs():
+    """The registered programs by name (:class:`Program`)."""
+    with _reg_lock:
+        return dict(_programs)
+
+
 def note_program(name, compiled, label=None):
     """Register one compiled program (called by ``CompiledStep`` at
     trace time).  Journals the cost summary to the blackbox
@@ -424,8 +418,7 @@ def note_program(name, compiled, label=None):
         diff = diff_costs(prev[-1], costs) if prev else {}
         _cost_history.setdefault(name, []).append(dict(costs))
         del _cost_history[name][:-4]
-        _programs[name] = {"ref": weakref.ref(compiled), "scope_map": None,
-                           "label": label, "at": time.time()}
+        _programs[name] = Program(name, weakref.ref(compiled))
         if diff:
             _cost_diffs.append({"program": name, "diff": dict(diff),
                                 "at": time.time()})
@@ -464,22 +457,9 @@ def cost_regressions():
 def _scope_maps():
     """Resolve the registry into ``{program_name: {op: phase}}``,
     parsing each live executable's optimized HLO lazily (once per
-    build) — captures pay the as_text() walk, idle-armed dispatches
-    never do."""
-    with _reg_lock:
-        items = list(_programs.items())
-    maps = {}
-    for name, info in items:
-        if info["scope_map"] is None:
-            compiled = info["ref"]()
-            if compiled is None:
-                continue
-            try:
-                info["scope_map"] = scope_map_from_hlo(compiled.as_text())
-            except Exception:
-                info["scope_map"] = {}
-        maps[name] = info["scope_map"]
-    return maps
+    build)."""
+    return {name: prog.scope_map() for name, prog in programs().items()
+            if prog.ops}
 
 
 # ---------------------------------------------------------------------------
@@ -560,359 +540,9 @@ def parse_trace(path_or_doc, scope_maps=None):
     return attribute(load_trace(path_or_doc), scope_maps=scope_maps)
 
 
-# ---------------------------------------------------------------------------
-# capture sessions
-# ---------------------------------------------------------------------------
-
-_session_lock = threading.Lock()
-_active = [None]                # the open session dict, or None
-_pending = []                   # one-shot request reasons (FIFO, cap 4)
-_dispatch_count = [0]
-_sessions = deque(maxlen=16)    # completed session summaries
-_trigger_installed = [False]
-_recent_walls = deque(maxlen=64)
-
-
-def request_capture(reason="manual"):
-    """Arm a one-shot capture starting at the next compiled dispatch.
-    No-op (returns False) when GRAFT_XRAY is off — the triggered paths
-    (watchdog, slow-step) stay inert unless the user armed the
-    harness."""
-    if not armed():
-        return False
-    with _session_lock:
-        if len(_pending) < 4 and reason not in _pending:
-            _pending.append(reason)
-    return True
-
-
-_walls_median = [0.0, 0]        # cached rolling median, records-until-refresh
-
-
-def _lens_trigger(rec):
-    """Lens observer: flag a slow compiled step.  The rolling median of
-    compiled train windows is the baseline; one outlier wall requests a
-    one-shot capture (the capture then explains the NEXT steps — the
-    profile of a recurring stall, not of the one that already
-    passed).  The median is refreshed every 8 records, not per record —
-    this observer rides EVERY armed compiled step, and a per-step
-    sort of the 64-wall ring would show up in the <2% idle-armed
-    budget; an up-to-8-records-stale baseline does not change what
-    counts as a 3x outlier."""
-    if not armed() or not rec.get("compiled"):
-        return
-    wall = rec.get("wall_s", 0.0)
-    n = len(_recent_walls)
-    if n >= 8:
-        if _walls_median[1] <= 0:
-            _walls_median[0] = sorted(_recent_walls)[n // 2]
-            _walls_median[1] = 8
-        else:
-            _walls_median[1] -= 1
-        med = _walls_median[0]
-        if med > 0 and wall > _slow_factor() * med:
-            request_capture("slow-step")
-    _recent_walls.append(wall)
-
-
-def _ensure_trigger():
-    if not _trigger_installed[0]:
-        _trigger_installed[0] = True
-        _lens.add_observer(_lens_trigger)
-
-
-def dispatch_begin():
-    """Called by ``CompiledStep._dispatch`` before the programs run.
-    Starts a profiler session when one is due (pending one-shot request,
-    or the GRAFT_XRAY_EVERY cadence).  Off/idle cost: one memoized env
-    read."""
-    if not armed():
-        return
-    _ensure_trigger()
-    _dispatch_count[0] += 1
-    # lock-free fast path: nothing pending, no cadence due — the
-    # common armed-idle dispatch never takes the lock (GIL-atomic list
-    # reads; a request racing this check starts one dispatch later,
-    # which the one-shot semantics already allow)
-    if _active[0] is None and not _pending:
-        n = capture_every()
-        if n <= 0 or _dispatch_count[0] % n != 0:
-            return
-    with _session_lock:
-        if _active[0] is not None:
-            return
-        reason = None
-        if _pending:
-            reason = _pending.pop(0)
-        else:
-            n = capture_every()
-            if n > 0 and _dispatch_count[0] % n == 0:
-                reason = "every-%d" % n
-        if reason is None:
-            return
-        logdir = tempfile.mkdtemp(prefix="graft_xray_")
-        try:
-            jax.profiler.start_trace(logdir)
-        except Exception as e:
-            # another profiler owns the trace, or the backend refuses:
-            # journal and stand down — capture failures never fail steps
-            shutil.rmtree(logdir, ignore_errors=True)
-            _blackbox.record("xray_capture", reason=reason, error=repr(e),
-                             ok=False)
-            return
-        _active[0] = {"reason": reason, "dir": logdir, "steps": 0,
-                      "want": capture_steps(), "t0": time.time()}
-
-
-def dispatch_end(sync=None):
-    """Called by ``CompiledStep._dispatch`` after write-back.  Counts
-    the dispatch into the open session and closes it once it spans
-    ``GRAFT_XRAY_STEPS`` dispatches — blocking on ``sync`` (the step's
-    output arrays) first so the device work lands inside the trace."""
-    if not armed():
-        return
-    if _active[0] is None:      # lock-free: no session open (sessions
-        return                  # open/close on this thread only)
-    with _session_lock:
-        sess = _active[0]
-        if sess is None:
-            return
-        sess["steps"] += 1
-        if sess["steps"] < sess["want"]:
-            return
-        _active[0] = None
-    _close_session(sess, sync)
-
-
-def _close_session(sess, sync):
-    report = None
-    error = None
-    try:
-        if sync is not None:
-            jax.block_until_ready(sync)
-    except Exception:
-        pass
-    try:
-        jax.profiler.stop_trace()
-    except Exception as e:
-        error = repr(e)
-    if error is None:
-        try:
-            path = find_trace_file(sess["dir"])
-            if path is None:
-                error = "no trace file emitted under %s" % sess["dir"]
-            else:
-                report = attribute(load_trace(path))
-        except Exception as e:
-            error = repr(e)
-    shutil.rmtree(sess["dir"], ignore_errors=True)
-    summary = {
-        "reason": sess["reason"],
-        "steps": sess["steps"],
-        "wall_s": round(time.time() - sess["t0"], 6),
-        "at": time.time(),
-        "ok": error is None and report is not None,
-    }
-    if error is not None:
-        summary["error"] = error
-    if report is not None:
-        summary["report"] = report
-    _sessions.append(summary)
-    _publish(summary)
-    return summary
-
-
-def _publish(summary):
-    report = summary.get("report")
-    phases = {p: round(d["device_s"], 9)
-              for p, d in (report or {}).get("phases", {}).items()}
-    _blackbox.xray_session(
-        summary["reason"], summary["steps"], phases,
-        unattributed_s=round(report["unattributed_s"], 9)
-        if report else None,
-        program_device_s=round(report["program_device_s"], 9)
-        if report else None,
-        conservation_ok=report["conservation_ok"] if report else None,
-        ok=summary["ok"], error=summary.get("error"),
-        top_ops=[{"op": r["op"], "phase": r["phase"],
-                  "device_us": round(r["device_s"] * 1e6, 3)}
-                 for r in (report or {}).get("top_ops", [])[:5]])
-    _metrics.xray_capture(summary["reason"], summary["ok"])
-    if report:
-        for p, d in report["phases"].items():
-            _metrics.xray_phase_seconds(p, d["device_s"])
-        _metrics.xray_phase_seconds("unattributed",
-                                    report["unattributed_s"])
-        _lens.attach_xray({
-            "reason": summary["reason"],
-            "phases": phases,
-            "unattributed_s": round(report["unattributed_s"], 9),
-            "program_device_s": round(report["program_device_s"], 9),
-            "span": report["span"],
-            "per_step_device_s":
-                round(report["program_device_s"] / summary["steps"], 9)
-                if summary["steps"] else 0.0,
-        }, max_records=summary["steps"])
-
-
-def sessions():
-    """Completed capture-session summaries, oldest first (copies)."""
-    with _session_lock:
-        return [dict(s) for s in _sessions]
-
-
-def capture_active():
-    with _session_lock:
-        return _active[0] is not None
-
-
 def reset():
-    """Drop harness state (tests): sessions, pending requests, the
-    dispatch counter, the cost ledger and the program registry.  The
-    lens observer stays installed (it is armed()-gated)."""
-    with _session_lock:
-        _active[0] = None
-        del _pending[:]
-        _dispatch_count[0] = 0
-        _sessions.clear()
+    """Drop the cost ledger and the program registry (tests)."""
     with _reg_lock:
         _programs.clear()
         _cost_history.clear()
         _cost_diffs.clear()
-    _recent_walls.clear()
-    _walls_median[0] = 0.0
-    _walls_median[1] = 0
-
-
-# ---------------------------------------------------------------------------
-# selftest (lint tier 12): capture a 3-step compiled loop, assert phase
-# rows + exact conservation + idle-armed inertness
-# ---------------------------------------------------------------------------
-
-def selftest(verbose=False):
-    """Returns a list of problems — empty means pass."""
-    import numpy as np
-
-    import incubator_mxnet_tpu as mx  # noqa: F401
-    from ..gluon import Trainer
-    from ..gluon import step_compile as sc
-
-    problems = []
-    saved = {k: os.environ.get(k)
-             for k in ("GRAFT_XRAY", "GRAFT_XRAY_EVERY", "GRAFT_XRAY_STEPS")}
-    os.environ["GRAFT_XRAY"] = "1"
-    os.environ.pop("GRAFT_XRAY_EVERY", None)
-    os.environ["GRAFT_XRAY_STEPS"] = "3"
-    reset()
-    try:
-        net = sc._make_net("graftxray_", n_params=4, shape=(1, 5))
-        sc._seed_params(net)
-        tr = Trainer(net.collect_params(), "sgd",
-                     {"learning_rate": 0.05, "momentum": 0.9},
-                     kvstore=None)
-        cstep = sc.CompiledStep(tr, net, enabled=True)
-        rng = np.random.RandomState(11)
-
-        def batch():
-            return mx.nd.array(
-                rng.uniform(0.5, 1.5, (6, 5)).astype(np.float32))
-
-        # step 1 falls back + traces; steps 2-3 are compiled and armed
-        # but idle — no session may open without a trigger
-        for _ in range(3):
-            cstep(batch())
-        if cstep.compiled_steps < 2:
-            problems.append("compiled path not reached (%d compiled)"
-                            % cstep.compiled_steps)
-        if sessions() or capture_active():
-            problems.append("armed-but-idle dispatches opened a capture "
-                            "session (triggers must be explicit)")
-        if not cost_history():
-            problems.append("no cost summaries registered at trace time")
-
-        # triggered capture across 3 compiled dispatches
-        if not request_capture("selftest"):
-            problems.append("request_capture returned False while armed")
-        for _ in range(4):
-            cstep(batch())
-        sess = sessions()
-        if not sess:
-            problems.append("no capture session completed after trigger")
-        else:
-            s = sess[-1]
-            if not s["ok"]:
-                problems.append("capture session failed: %s"
-                                % s.get("error"))
-            else:
-                rep = s["report"]
-                if verbose:
-                    print(json.dumps(rep, indent=2, default=str))
-                if not rep["conservation_ok"]:
-                    problems.append(
-                        "conservation violated: phases %.9fs + "
-                        "unattributed %.9fs != span %.9fs"
-                        % (sum(p["device_s"]
-                               for p in rep["phases"].values()),
-                           rep["unattributed_s"],
-                           rep["program_device_s"]))
-                if not rep["phases"]:
-                    problems.append("no xray phases attributed (scope "
-                                    "metadata missing from the trace?)")
-                else:
-                    names = set(rep["phases"])
-                    if not any(n.startswith(("forward", "backward",
-                                             "update")) for n in names):
-                        problems.append("phases %r carry no step scopes"
-                                        % sorted(names))
-                if not rep["ledger"]["steps"]:
-                    problems.append("shared parser produced no ledger "
-                                    "rows")
-                if s["steps"] != 3:
-                    problems.append("session spanned %d dispatches "
-                                    "(want 3)" % s["steps"])
-        recs = [r for r in _lens.steps() if "xray" in r]
-        if _lens.enabled() and sess and sess[-1]["ok"] and not recs:
-            problems.append("capture did not annotate any lens window")
-    finally:
-        for k, v in saved.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-        reset()
-    return problems
-
-
-def main(argv=None):
-    import argparse
-    import sys
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    ap = argparse.ArgumentParser(
-        prog="python -m incubator_mxnet_tpu.telemetry.xray",
-        description="graftxray compiled-step phase attribution selftest")
-    ap.add_argument("--selftest", action="store_true",
-                    help="capture a 3-step compiled loop; assert phase "
-                         "rows + exact-sum conservation (CI tier 12)")
-    ap.add_argument("--verbose", action="store_true")
-    args = ap.parse_args(argv)
-    if not args.selftest:
-        ap.print_help()
-        return 2
-    problems = selftest(verbose=args.verbose)
-    if problems:
-        for p in problems:
-            print("graftxray selftest FAIL: %s" % p, file=sys.stderr)
-        return 1
-    print("graftxray selftest OK (triggered 3-step capture, phase "
-          "attribution conserved exactly, idle-armed dispatches inert)")
-    return 0
-
-
-if __name__ == "__main__":
-    import sys
-    # ``python -m …telemetry.xray`` loads this file TWICE (once as the
-    # package submodule CompiledStep imports, once as __main__): run the
-    # selftest in the CANONICAL copy so the registry/capture globals it
-    # asserts on are the ones the instrumented step actually touched
-    from incubator_mxnet_tpu.telemetry import xray as _canonical
-    sys.exit(_canonical.main())

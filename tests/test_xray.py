@@ -1,36 +1,24 @@
 """graftxray tests (ISSUE 18): scope-map parsing from optimized HLO,
 conservation-exact phase attribution over synthetic profiler traces,
-the ONE shared parser core behind both the online capture path and the
-offline ``--ingest-xla`` CLI, trigger plumbing (slow-step lens observer,
-watchdog trip, explicit request), off-by-default inertness, the
-at-trace-time cost ledger + retrace cost diffing (the EH301 feed), the
-full compiled-window selftest, and the ``--xray`` renderer."""
+the ONE shared parser core behind both ``attribute`` and the offline
+``--ingest-xla`` CLI, and the at-trace-time cost ledger + retrace cost
+diffing (the EH301 feed).  The triggered-capture harness these once
+covered went in PR 23; the registry's lazy side and the scopes of the
+train paths are in ``test_program_tracing.py``."""
 import json
-import os
 import time
 import types
 import warnings
 
-import numpy as np
 import pytest
 
 import incubator_mxnet_tpu as mx  # noqa: F401
-from incubator_mxnet_tpu.telemetry import aggregate, blackbox, lens, xray
+from incubator_mxnet_tpu.telemetry import aggregate, blackbox, xray
 
 
 @pytest.fixture
-def fresh_xray(monkeypatch):
-    """Armed, clean harness for one test."""
-    monkeypatch.setenv("GRAFT_XRAY", "1")
-    xray.reset()
-    yield xray
-    xray.reset()
-
-
-@pytest.fixture
-def dark_xray(monkeypatch):
-    """Explicitly DISarmed harness."""
-    monkeypatch.delenv("GRAFT_XRAY", raising=False)
+def fresh_xray():
+    """A clean registry and cost ledger for one test."""
     xray.reset()
     yield xray
     xray.reset()
@@ -191,7 +179,7 @@ def test_parse_trace_offline_twin(tmp_path):
 
 # ---------------------------------------------------------------------------
 # parser unification: ONE shared core behind aggregate.ingest_xla and
-# the online capture sessions
+# xray.attribute
 # ---------------------------------------------------------------------------
 
 def test_parser_core_is_shared_not_cloned():
@@ -216,77 +204,6 @@ def test_ingest_xla_and_attribute_agree_on_step_rows(tmp_path):
     online = xray.attribute(events, scope_maps={})
     assert offline["steps"] == online["ledger"]["steps"]
     assert offline["total"] == online["ledger"]["total"]
-
-
-# ---------------------------------------------------------------------------
-# triggers + capture lifecycle
-# ---------------------------------------------------------------------------
-
-def test_unarmed_harness_is_inert(dark_xray):
-    assert not xray.armed()
-    assert xray.request_capture("manual") is False
-    xray.dispatch_begin()
-    xray.dispatch_end(sync=None)
-    assert xray._dispatch_count[0] == 0      # begin returned pre-count
-    assert xray._pending == []
-    assert not xray.capture_active()
-    assert xray.sessions() == []
-    # the triggered paths stay inert too
-    xray._lens_trigger({"compiled": True, "wall_s": 9.9})
-    assert xray._pending == []
-
-
-def test_request_capture_dedups_and_caps(fresh_xray):
-    assert xray.request_capture("manual") is True
-    assert xray.request_capture("manual") is True    # accepted, deduped
-    assert xray._pending == ["manual"]
-    for i in range(10):
-        xray.request_capture("r%d" % i)
-    assert len(xray._pending) == 4                   # FIFO cap
-
-
-def test_slow_step_lens_trigger(fresh_xray):
-    """≥8 compiled walls build the baseline; one outlier past
-    GRAFT_XRAY_SLOW_X × median requests a one-shot capture."""
-    for _ in range(10):
-        xray._lens_trigger({"compiled": True, "wall_s": 0.01})
-    assert xray._pending == []                       # steady state
-    xray._lens_trigger({"compiled": True, "wall_s": 1.0})
-    assert "slow-step" in xray._pending
-    # eager (non-compiled) outliers never trigger — the capture harness
-    # profiles the compiled step only
-    xray.reset()
-    for _ in range(10):
-        xray._lens_trigger({"compiled": True, "wall_s": 0.01})
-    xray._lens_trigger({"compiled": False, "wall_s": 5.0})
-    assert xray._pending == []
-
-
-def test_slow_step_trigger_needs_baseline(fresh_xray):
-    """The first few walls must not trigger — no median yet."""
-    for w in (0.01, 0.02, 5.0):
-        xray._lens_trigger({"compiled": True, "wall_s": w})
-    assert xray._pending == []
-
-
-def test_watchdog_trip_on_compiled_bracket_requests_capture(
-        fresh_xray, monkeypatch, tmp_path):
-    from incubator_mxnet_tpu.telemetry import watchdog as wdmod
-    monkeypatch.setattr(wdmod._blackbox, "dump",
-                        lambda **kw: str(tmp_path / "dump.json"))
-    wd = wdmod.Watchdog(timeout=1.0, abort=False)
-    entry = {"site": "compiled_step", "since": time.time() - 5.0,
-             "detail": {"compiled": True, "programs": 2},
-             "thread": "MainThread"}
-    wd.trip(entry, 5.0)
-    assert "watchdog:compiled_step" in xray._pending
-    # a NON-compiled hang (an eager collective, a loader stall) must
-    # not burn the one-shot on a trace that can't explain it
-    xray.reset()
-    entry = {"site": "ps_push", "since": time.time() - 5.0,
-             "detail": {"keys": 3}, "thread": "MainThread"}
-    wd.trip(entry, 5.0)
-    assert xray._pending == []
 
 
 # ---------------------------------------------------------------------------
@@ -386,133 +303,3 @@ def test_eh301_storm_report_names_cost_growth(fresh_xray):
     msg = str(storm[-1].message)
     assert "cost growth since previous trace" in msg
     assert "gstep_one" in msg and "flops" in msg
-
-
-# ---------------------------------------------------------------------------
-# the full compiled window (the selftest is the acceptance contract)
-# ---------------------------------------------------------------------------
-
-def test_xray_selftest_compiled_window_conserves():
-    """End-to-end: a real compiled 3-step capture on this backend —
-    phase rows present, conservation EXACT, armed-idle dispatches
-    inert.  (The same scenario lint tier 12 runs.)"""
-    problems = xray.selftest()
-    assert problems == [], problems
-
-
-def test_capture_session_publishes_to_lens_and_blackbox(monkeypatch):
-    """Run the selftest scenario manually and check the publication
-    fan-out: blackbox xray_capture event, lens window annotation."""
-    from incubator_mxnet_tpu.gluon import Trainer
-    from incubator_mxnet_tpu.gluon import step_compile as sc
-    monkeypatch.setenv("GRAFT_XRAY", "1")
-    monkeypatch.setenv("GRAFT_XRAY_STEPS", "2")
-    monkeypatch.delenv("GRAFT_XRAY_EVERY", raising=False)
-    xray.reset()
-    marker = time.time()
-    try:
-        net = sc._make_net("graftxraytest_", n_params=3, shape=(1, 4))
-        sc._seed_params(net)
-        tr = Trainer(net.collect_params(), "sgd",
-                     {"learning_rate": 0.05}, kvstore=None)
-        cstep = sc.CompiledStep(tr, net, enabled=True)
-        rng = np.random.RandomState(3)
-
-        def batch():
-            return mx.nd.array(
-                rng.uniform(0.5, 1.5, (4, 4)).astype(np.float32))
-
-        for _ in range(2):
-            cstep(batch())
-        assert cstep.compiled_steps >= 1
-        assert xray.request_capture("test-hook")
-        for _ in range(3):
-            cstep(batch())
-        sess = xray.sessions()
-        assert sess and sess[-1]["ok"], sess
-        s = sess[-1]
-        assert s["reason"] == "test-hook"
-        assert s["steps"] == 2
-        rep = s["report"]
-        assert rep["conservation_ok"]
-        assert rep["phases"]
-        evs = [e for e in blackbox.events()
-               if e["kind"] == "xray_capture" and e["ts"] >= marker]
-        assert evs and evs[-1]["data"]["reason"] == "test-hook"
-        assert evs[-1]["data"]["conservation_ok"] is True
-        if lens.enabled():
-            annotated = [r for r in lens.steps() if "xray" in r]
-            assert annotated
-            x = annotated[-1]["xray"]
-            assert x["reason"] == "test-hook"
-            assert x["program_device_s"] > 0.0
-    finally:
-        xray.reset()
-
-
-# ---------------------------------------------------------------------------
-# the --xray renderer
-# ---------------------------------------------------------------------------
-
-def _fake_session(reason="manual", ok=True):
-    return {"reason": reason, "steps": 3, "wall_s": 0.5,
-            "at": time.time(), "ok": ok,
-            "report": {"phases": {"forward": {"device_s": 1.5e-3,
-                                              "share": 0.6},
-                                  "backward": {"device_s": 0.5e-3,
-                                               "share": 0.2}},
-                       "unattributed_s": 0.5e-3,
-                       "program_device_s": 2.5e-3,
-                       "conservation_ok": True,
-                       "top_ops": [{"op": "fusion.1", "phase": "forward",
-                                    "device_s": 1.0e-3, "count": 3}]}}
-
-
-def test_cli_xray_renders_live_sessions(capsys):
-    from incubator_mxnet_tpu.telemetry.__main__ import main as tmain
-    xray.reset()
-    try:
-        with xray._session_lock:
-            xray._sessions.append(_fake_session("slow-step"))
-        assert tmain(["--xray"]) == 0
-        out = capsys.readouterr().out
-        assert "slow-step" in out
-        assert "forward" in out and "backward" in out
-        assert "conservation EXACT" in out
-        assert "fusion.1" in out
-    finally:
-        xray.reset()
-
-
-def test_cli_xray_renders_blackbox_dump(tmp_path, capsys):
-    """Dump events nest fields under "data" — the renderer must read
-    them there (not flat) and fall back to the flattened phase dict the
-    blackbox publication writes."""
-    from incubator_mxnet_tpu.telemetry.__main__ import main as tmain
-    doc = {"events": [
-        {"ts": 1.0, "kind": "xray_capture",
-         "data": {"reason": "watchdog:compiled_step", "steps": 2,
-                  "ok": True, "phases": {"forward": 0.002},
-                  "unattributed_s": 0.001, "program_device_s": 0.003,
-                  "conservation_ok": True,
-                  "top_ops": [{"op": "sub.3", "phase": "backward",
-                               "device_us": 11.5, "count": 2}]}},
-        {"ts": 2.0, "kind": "other", "data": {}},
-    ]}
-    p = tmp_path / "dump.json"
-    p.write_text(json.dumps(doc))
-    assert tmain(["--xray", str(p)]) == 0
-    out = capsys.readouterr().out
-    assert "watchdog:compiled_step" in out
-    assert "forward" in out
-    assert "conservation EXACT" in out
-    assert tmain(["--xray", str(p), "--json"]) == 0
-    parsed = json.loads(capsys.readouterr().out)
-    assert parsed[0]["reason"] == "watchdog:compiled_step"
-
-
-def test_cli_xray_empty_state_hints_at_arming(capsys):
-    from incubator_mxnet_tpu.telemetry.__main__ import main as tmain
-    xray.reset()
-    assert tmain(["--xray"]) == 0
-    assert "GRAFT_XRAY=1" in capsys.readouterr().out

@@ -75,16 +75,9 @@
 #    op registry; bench_eager --smoke (tier 3) additionally reports
 #    compile_check_overhead_pct (auditor armed, zero findings) against
 #    its < 2% budget in BENCH JSON.
-# 12. graftxray smoke — telemetry.xray --selftest captures a triggered
-#    3-dispatch profiler session around the REAL compiled step and
-#    asserts in-program phase attribution (forward/backward/update[k]
-#    scopes resolved from the executable's optimized HLO against the
-#    trace's hlo_op stream) with EXACT-sum conservation (phase device
-#    ns + unattributed == program device span, integer equality), cost
-#    summaries registered at trace time, and armed-but-idle dispatches
-#    opening no session; bench_eager --smoke (tier 3) additionally
-#    gates xray_overhead_pct (harness armed, no capture) against its
-#    < 2% budget in BENCH JSON.
+# 12. (graftxray's capture-harness smoke went with the harness, PR 23;
+#    its scope map, registry and exact-sum attribution are tier-1 tests:
+#    tests/test_xray.py, tests/test_program_tracing.py.)
 # 13. graftzero smoke — parallel.quant --selftest proves the block-scaled
 #    quantization kernels (int8/2bit encode/decode round-trips, the
 #    documented per-element error bounds, packed-field summability,
@@ -142,9 +135,6 @@ JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
     || exit $?
 JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
     python -m incubator_mxnet_tpu.analysis.compile_safety --selftest \
-    || exit $?
-JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
-    python -m incubator_mxnet_tpu.telemetry.xray --selftest \
     || exit $?
 JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
     python -m incubator_mxnet_tpu.parallel.quant --selftest \
