@@ -55,7 +55,14 @@ FULL = {
                    tol=2e-2),
               # D=64 is zero-padded to the 128 lanes inside the kernel
               dict(shape=(2, 4, 256, 64), dtype="float32", causal=False,
-                   tol=2e-3)],
+                   tol=2e-3),
+              # the benchmark's OPT cell: four 512-blocks a side, six of
+              # the sixteen skipped
+              dict(shape=(4, 32, 2048, 128), dtype="bfloat16", causal=True,
+                   tol=2e-2),
+              # Sq < Sk: the diagonal is anchored at the end of the keys
+              dict(shape=(2, 4, 128, 128), kv_len=256, dtype="float32",
+                   causal=True, tol=2e-3)],
     "lm": dict(vocab=16384, d_model=4096, n_heads=32, d_ffn=16384,
                n_layers=2, seq_len=1024, batch=8),
 }
@@ -272,9 +279,11 @@ def phase_flash(mx, jax, cases):
     out = []
     for case in cases:
         shape, dtype, causal = case["shape"], case["dtype"], case["causal"]
+        kv_shape = shape[:2] + (case.get("kv_len", shape[2]),) + shape[3:]
+        shapes = (shape, kv_shape, kv_shape, shape)         # q, k, v, w
         rs = np.random.RandomState(1)
-        q, k, v, w = (rs.randn(*shape).astype(np.float32) for _ in range(4))
-        *nd, w = (mx.nd.array(a, dtype=dtype) for a in (q, k, v, w))
+        *nd, w = (mx.nd.array(rs.randn(*s).astype(np.float32), dtype=dtype)
+                  for s in shapes)
         for a in nd:
             a.attach_grad()
         with mx.autograd.record():
@@ -284,18 +293,25 @@ def phase_flash(mx, jax, cases):
         got = [o] + [a.grad for a in nd]
 
         # the reference sees the same (rounded) inputs, upcast to f32, and
-        # runs its matmuls at full precision (the TPU default is bf16)
-        *f32, w32 = (jnp.asarray(a.asnumpy().astype(np.float32))
-                     for a in nd + [w])
+        # runs its matmuls at full precision (the TPU default is bf16); a
+        # batch row at a time, its (Sq, Sk) scores are what fills the HBM
+        @jax.jit
+        def reference(q, k, v, w):
+            out, vjp = jax.vjp(
+                lambda a, b, c: _attention_reference(a, b, c, causal),
+                q, k, v)
+            return (out, *vjp(w))
+
+        f32 = [a.asnumpy().astype(np.float32) for a in nd + [w]]
         with jax.default_matmul_precision("highest"):
-            ref_o, vjp = jax.vjp(
-                lambda a, b, c: _attention_reference(a, b, c, causal), *f32)
-            ref = [ref_o] + list(vjp(w32))
+            rows = [reference(*(a[b:b + 1] for a in f32))
+                    for b in range(shape[0])]
+        ref = [np.concatenate([np.asarray(r) for r in part])
+               for part in zip(*rows)]
         errs = {}
         for name, g, r in zip(("out", "dq", "dk", "dv"), got, ref):
             g = g.asnumpy().astype(np.float32)
-            r = np.asarray(r)
-            check(g.shape == tuple(shape) and np.all(np.isfinite(g)),
+            check(g.shape == r.shape and np.all(np.isfinite(g)),
                   "flash %s %s: bad shape or non-finite" % (shape, name))
             errs[name] = float(np.max(np.abs(g - r)) /
                                max(1.0, float(np.max(np.abs(r)))))
@@ -306,20 +322,24 @@ def phase_flash(mx, jax, cases):
         # the same op, attributes, shapes and dtype, lowered for this
         # device: a Mosaic custom call is the compiled Pallas kernel (the
         # interpreter would have left plain HLO, the jnp path no call)
-        spec = jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
+        q_spec, kv_spec = (jax.ShapeDtypeStruct(s, jnp.dtype(dtype))
+                           for s in (shape, kv_shape))
         text = jax.jit(functools.partial(op.fcompute, causal=causal)).lower(
-            spec, spec, spec).as_text()
+            q_spec, kv_spec, kv_spec).as_text()
         check("tpu_custom_call" in text, "flash %s %s lowered without a "
               "Mosaic custom call: the Pallas kernel did not run"
               % (shape, dtype))
-        out.append({"shape": list(shape), "dtype": dtype, "causal": causal,
+        out.append({"shape": list(shape), "kv_len": kv_shape[2],
+                    "dtype": dtype, "causal": causal,
                     "rel_err": {n: float("%.3g" % e)
                                 for n, e in errs.items()},
                     "tol": case["tol"], "mosaic_custom_call": True})
     paths = _flash_counts(mx, since=before)
     check(not any(c for p, c in paths.items() if p.startswith("reference")),
           "flash_attention took a jnp path on the chip: %r" % paths)
-    check(sum(paths.values()) > 0, "flash_attention was never traced")
+    check(any(c for p, c in paths.items() if not p.startswith("bwd_")) and
+          any(c for p, c in paths.items() if p.startswith("bwd_")),
+          "flash_attention was not traced in both directions: %r" % paths)
     facts = {"cases": out, "traces_by_path": paths}
     print("[3 flash] %s" % json.dumps(facts))
     return facts

@@ -5,17 +5,31 @@ The reference framework predates transformer attention entirely (SURVEY
 *new capability* SURVEY §7 phase 11 mandates: long-context attention that
 maps onto the MXU with O(seq) memory.
 
-* ``flash_attention`` — tiled online-softmax attention as a Pallas TPU
-  kernel (one (block_q × d) Q tile resident in VMEM; K/V streamed in
-  block_k tiles; running max/sum rescaling). Grid = (batch*heads,
-  seq_q/block_q); the K loop is a fori_loop inside the kernel so the MXU
-  sees back-to-back (block_q×d)·(d×block_k) matmuls.
+* ``flash_attention`` — tiled online-softmax attention as Pallas TPU
+  kernels in both directions, one ``custom_vjp``.  Forward: grid
+  (batch*heads, Sq/block_q, Sk/block_k), K/V streamed by the grid's last
+  axis, the running max / sum / output in VMEM scratch, so VMEM use does
+  not grow with Sk.  Backward: a dK/dV kernel gridded over key blocks and a
+  dQ kernel gridded over query blocks, both recomputing the scores from
+  q, k and the row statistics (max ``m``, sum ``l``) the forward emits.
+* What the MXU multiplies is what the caller's dtype says.  bf16 / f16
+  operands: one MXU pass, f32 accumulation — a product of two bf16 numbers
+  is exact in f32, so QKᵀ is the six-pass product of the upcast operands
+  to the order of the sums; ``scale`` multiplies the f32 scores after the
+  dot; the probabilities and dS are rounded to the operands' dtype for
+  P·V, dV, dK, dQ, as in every bf16 Dense layer.  f32 operands: every
+  product at ``Precision.HIGHEST``.
+* A causal problem visits only the blocks the mask leaves (the diagonal
+  anchored at the end of the key axis, so Sq != Sk keeps its meaning): a
+  block wholly above the diagonal is neither fetched nor multiplied, and
+  the iota mask is applied only to blocks the diagonal crosses.
 * Off the TPU (the CPU test mesh), and for lengths that are not multiples
-  of 128, the same math runs as jnp — the kernel is numerics-identical by
-  construction and tested against it.  The platform is the one the call is
-  lowered for, not the process default; each path runs under its own
-  ``named_scope`` and every trace is counted by path
-  (``graft_flash_attention_traces_total``).
+  of 128, the same math runs as jnp: a dense forward that returns the same
+  statistics and a chunked scan backward (f32, HIGHEST) that consumes
+  them — the kernels are tested against both.  The platform is the one the
+  call is lowered for, not the process default; each path runs under its
+  own ``named_scope`` and every trace, forward and backward, is counted by
+  path (``graft_flash_attention_traces_total``).
 * Registered as op ``_contrib_FlashAttention`` so both eager NDArray code
   and Symbol graphs can call it (one registry, two modes).
 """
@@ -24,7 +38,6 @@ from __future__ import annotations
 import functools
 import math
 
-import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -33,14 +46,17 @@ from ..telemetry import metrics as _metrics
 from .registry import register
 
 _NEG_INF = -1e30
+_LANES = 128
 
 
 # ---------------------------------------------------------------------------
-# reference (jnp) attention — also the CPU path and the vjp recompute
+# reference (jnp) attention — also the CPU path and the tests' oracle
 # ---------------------------------------------------------------------------
 
-def _attention_reference(q, k, v, causal=False, scale=None):
-    """(B, H, Sq, D), (B, H, Sk, D) → (B, H, Sq, D)."""
+def _attention_reference_stats(q, k, v, causal=False, scale=None):
+    """(B, H, Sq, D), (B, H, Sk, D) → out (B, H, Sq, D) and the softmax's
+    row statistics (B, H, Sq) in f32: the maximum ``m`` of the masked,
+    scaled scores and the sum ``l`` of ``exp(score - m)``."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     logits = jnp.einsum("bhqd,bhkd->bhqk", q, k,
@@ -49,107 +65,372 @@ def _attention_reference(q, k, v, causal=False, scale=None):
         sq, sk = logits.shape[-2], logits.shape[-1]
         mask = jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)
         logits = jnp.where(mask, logits, _NEG_INF)
-    probs = jax.nn.softmax(logits, axis=-1)
-    return jnp.einsum("bhqk,bhkd->bhqd", probs, v.astype(probs.dtype)
-                      ).astype(q.dtype)
+    m = logits.max(axis=-1)
+    e = jnp.exp(logits - m[..., None])
+    l = e.sum(axis=-1)
+    probs = e / l[..., None]
+    out = jnp.einsum("bhqk,bhkd->bhqd", probs, v.astype(probs.dtype)
+                     ).astype(q.dtype)
+    return out, m, l
+
+
+def _attention_reference(q, k, v, causal=False, scale=None):
+    """(B, H, Sq, D), (B, H, Sk, D) → (B, H, Sq, D)."""
+    return _attention_reference_stats(q, k, v, causal, scale)[0]
 
 
 # ---------------------------------------------------------------------------
-# Pallas flash kernel
+# Pallas flash kernels
 # ---------------------------------------------------------------------------
+# Blocks are (block_q × block_k) tiles of the score matrix; query row r sees
+# keys up to r + off, off = Sk - Sq: the diagonal is anchored at the *end* of
+# the key axis, matching the jnp path's tril(k=sk-sq) — essential for
+# KV-cache decode where Sq != Sk.
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k, sq, sk, causal,
-                  scale, block_q):
+_NN = (((1,), (0,)), ((), ()))      # a · b
+_NT = (((1,), (1,)), ((), ()))      # a · bᵀ
+
+
+def _dot(a, b, dims):
+    """MXU product in the operands' dtype with f32 accumulation: one pass
+    for bf16 / f16 (their products are exact in f32), HIGHEST for f32."""
+    precision = lax.Precision.HIGHEST if a.dtype == jnp.float32 else None
+    return lax.dot_general(a, b, dims, precision=precision,
+                           preferred_element_type=jnp.float32)
+
+
+def _block(s):
+    """Largest of 512, 256, 128 dividing the (128-aligned) length: 128-wide
+    tiles starve a v5e MXU between grid steps."""
+    return next(b for b in (512, 256, 128) if s % b == 0)
+
+
+def _visit(qi, ki, bq, bk, off):
+    """Whether block (qi, ki) holds anything the causal mask leaves.  A row
+    that sees no key at all (Sq > Sk) is uniform over *every* key in the
+    reference, so a query block that holds one visits every key block."""
+    seen = (qi + 1) * bq - 1 + off >= ki * bk
+    if off < 0:
+        seen |= qi * bq + off < 0
+    return seen
+
+
+def _crosses(qi, ki, bq, bk, off):
+    """Whether block (qi, ki) holds a masked score at all."""
+    return (ki + 1) * bk - 1 > qi * bq + off
+
+
+def _visible(qi, ki, bq, bk, off, shape, q_axis):
+    """The causal mask of block (qi, ki) as a tile of ``shape`` whose
+    ``q_axis`` runs over the queries."""
+    rows = qi * bq + lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    cols = ki * bk + lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+    return rows + off >= cols
+
+
+def _lanes(x, n):
+    """A (rows, 128) tile whose lanes are equal, widened to n lanes."""
+    return x if n == _LANES else jnp.tile(x, (1, n // _LANES))
+
+
+def _per_block(causal, qi, ki, bq, bk, off, step):
+    """Run ``step(masked)`` for a block: not at all above the diagonal,
+    with the mask where the diagonal crosses, without it below."""
     from jax.experimental import pallas as pl
-    q = q_ref[0].astype(jnp.float32) * scale              # (bq, d)
-    bq, d = q.shape
-    num_kb = sk // block_k
-    q_blk = pl.program_id(1)
+    if not causal:
+        step(False)
+        return
+    visit = _visit(qi, ki, bq, bk, off)
+    crosses = _crosses(qi, ki, bq, bk, off)
+    pl.when(visit & crosses)(lambda: step(True))
+    pl.when(visit & jnp.logical_not(crosses))(lambda: step(False))
 
-    def body(i, carry):
-        acc, m_prev, l_prev = carry
-        k_blk = k_ref[0, pl.dslice(i * block_k, block_k), :].astype(jnp.float32)
-        v_blk = v_ref[0, pl.dslice(i * block_k, block_k), :].astype(jnp.float32)
-        # full f32 MXU passes — the default matmul precision on TPU is bf16,
-        # which is not acceptable for softmax logits
-        s = lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
-                            precision=lax.Precision.HIGHEST)   # (bq, bk)
-        if causal:
-            # query row r may see keys up to r + (sk - sq): the diagonal is
-            # anchored at the *end* of the key axis, matching the jnp path's
-            # tril(k=sk-sq) — essential for KV-cache decode where Sq != Sk
-            q_pos = q_blk * block_q + lax.broadcasted_iota(
-                jnp.int32, (bq, block_k), 0)
-            k_pos = i * block_k + lax.broadcasted_iota(
-                jnp.int32, (bq, block_k), 1)
-            s = jnp.where(q_pos + (sk - sq) >= k_pos, s, _NEG_INF)
-        m_cur = jnp.max(s, axis=-1)
-        m_new = jnp.maximum(m_prev, m_cur)
+
+def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
+                      acc, m_run, l_run, *, causal, scale, off):
+    from jax.experimental import pallas as pl
+    qi, ki = pl.program_id(1), pl.program_id(2)
+    (bq, d), bk = q_ref.shape, k_ref.shape[0]
+
+    @pl.when(ki == 0)
+    def _():
+        acc[...] = jnp.zeros_like(acc)
+        m_run[...] = jnp.full_like(m_run, _NEG_INF)
+        l_run[...] = jnp.zeros_like(l_run)
+
+    def step(masked):
+        v = v_ref[...]
+        s = _dot(q_ref[...], k_ref[...], _NT) * scale          # (bq, bk)
+        if masked:
+            s = jnp.where(_visible(qi, ki, bq, bk, off, s.shape, 0),
+                          s, _NEG_INF)
+        m_prev = m_run[...]                                    # (bq, 128)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_new = l_prev * alpha + p.sum(axis=-1)
-        acc = acc * alpha[:, None] + lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            precision=lax.Precision.HIGHEST)
-        return acc, m_new, l_new
+        p = jnp.exp(s - _lanes(m_new, bk))
+        l_run[...] = alpha * l_run[...] + p.sum(axis=1, keepdims=True)
+        m_run[...] = m_new
+        acc[...] = acc[...] * _lanes(alpha, d) + _dot(p.astype(v.dtype), v,
+                                                      _NN)
 
-    acc0 = jnp.zeros((bq, d), jnp.float32)
-    m0 = jnp.full((bq,), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((bq,), jnp.float32)
-    acc, m, l = lax.fori_loop(0, num_kb, body, (acc0, m0, l0))
-    o_ref[0] = (acc / jnp.maximum(l, 1e-20)[:, None]).astype(o_ref.dtype)
+    _per_block(causal, qi, ki, bq, bk, off, step)
+
+    @pl.when(ki == pl.num_programs(2) - 1)
+    def _():
+        # every row has l >= 1: its maximum contributes exp(0)
+        o_ref[...] = (acc[...] / _lanes(l_run[...], d)).astype(o_ref.dtype)
+        # the statistics leave lane-dense, (1, bq) a block: a (bq, 1) column
+        # would be padded to 128 lanes in HBM
+        m_ref[...] = m_run[...].T[:1]
+        l_ref[...] = l_run[...].T[:1]
 
 
-def _flash_forward_pallas(q, k, v, causal, scale, block_q=128, block_k=128):
+def _probs_and_dscores(s, dp, m, linv, delta, visible, scale, off):
+    """p and dS of a tile, whichever way it lies, from its raw scores, the
+    forward's statistics and dP = dO·Vᵀ; ``visible`` is the causal mask of
+    a block the diagonal crosses, else None."""
+    s = s * scale
+    if visible is not None:
+        s = jnp.where(visible, s, _NEG_INF)
+    p = jnp.exp(s - m) * linv
+    ds = p * (dp - delta) * scale
+    if visible is not None and off < 0:
+        # a row that sees no key has uniform p, not 0, and its masked
+        # scores are constants: they carry no dQ / dK
+        ds = jnp.where(visible, ds, 0.0)
+    return p, ds
+
+
+def _flash_dkv_kernel(q_ref, k_ref, v_ref, g_ref, m_ref, linv_ref, delta_ref,
+                      dk_ref, dv_ref, dk_acc, dv_acc, *, causal, scale, off):
+    """dK, dV of one key block, the query blocks from the diagonal down on
+    the grid's last axis.  Scores are held transposed, (bk, bq), so that
+    the row statistics broadcast as the (1, bq) rows they are stored as."""
+    from jax.experimental import pallas as pl
+    ki, qi = pl.program_id(1), pl.program_id(2)
+    (bq, _), bk = q_ref.shape, k_ref.shape[0]
+
+    @pl.when(qi == 0)
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    def step(masked):
+        q, k, g = q_ref[...], k_ref[...], g_ref[...]
+        visible = (_visible(qi, ki, bq, bk, off, (bk, bq), 1)
+                   if masked else None)
+        pt, dst = _probs_and_dscores(                          # (bk, bq)
+            _dot(k, q, _NT), _dot(v_ref[...], g, _NT), m_ref[...],
+            linv_ref[...], delta_ref[...], visible, scale, off)
+        dv_acc[...] += _dot(pt.astype(g.dtype), g, _NN)
+        dk_acc[...] += _dot(dst.astype(q.dtype), q, _NN)
+
+    _per_block(causal, qi, ki, bq, bk, off, step)
+
+    @pl.when(qi == pl.num_programs(2) - 1)
+    def _():
+        dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _flash_dq_kernel(q_ref, k_ref, v_ref, g_ref, m_ref, linv_ref, delta_ref,
+                     dq_ref, dq_acc, *, causal, scale, off):
+    """dQ of one query block, the key blocks up to the diagonal on the
+    grid's last axis."""
+    from jax.experimental import pallas as pl
+    qi, ki = pl.program_id(1), pl.program_id(2)
+    (bq, _), bk = q_ref.shape, k_ref.shape[0]
+
+    @pl.when(ki == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    def step(masked):
+        k = k_ref[...]
+        m, linv, delta = (jnp.expand_dims(r[0], -1)            # (bq, 1)
+                          for r in (m_ref, linv_ref, delta_ref))
+        visible = (_visible(qi, ki, bq, bk, off, (bq, bk), 0)
+                   if masked else None)
+        _, ds = _probs_and_dscores(                            # (bq, bk)
+            _dot(q_ref[...], k, _NT), _dot(g_ref[...], v_ref[...], _NT),
+            m, linv, delta, visible, scale, off)
+        dq_acc[...] += _dot(ds.astype(k.dtype), k, _NN)
+
+    _per_block(causal, qi, ki, bq, bk, off, step)
+
+    @pl.when(ki == pl.num_programs(2) - 1)
+    def _():
+        dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
+
+
+def _fold(x):
+    """(B, H, S, D) → (B*H, S, Dp).  MXU lanes want D in multiples of 128;
+    typical head dims (64, 96) get zero-padded — padded Q/K columns
+    contribute nothing to QKᵀ and padded V (or dO) columns produce output
+    (or gradient) columns that ``_unfold`` slices off."""
+    b, h, s, d = x.shape
+    dp = -(-d // _LANES) * _LANES
+    if dp != d:
+        x = jnp.pad(x, [(0, 0)] * 3 + [(0, dp - d)])
+    return x.reshape(b * h, s, dp)
+
+
+def _unfold(x, like):
+    b, h, _, d = like.shape
+    return x.reshape(b, h, *x.shape[1:])[..., :d]
+
+
+def _pallas_call(kernel, name, grid, in_specs, out_specs, out_shape, scratch,
+                 flops, operands, interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-
-    B, H, Sq, D = q.shape
-    Sk = k.shape[2]
-    # MXU lanes want D in multiples of 128; typical head dims (64, 96) get
-    # zero-padded — padded Q columns contribute nothing to QKᵀ and padded V
-    # columns produce output columns we slice off
-    Dp = -(-D // 128) * 128
-    if Dp != D:
-        pad = [(0, 0)] * 3 + [(0, Dp - D)]
-        q, k, v = jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad)
-    block_q = min(block_q, Sq)
-    block_k = min(block_k, Sk)
-    qf = q.reshape(B * H, Sq, Dp)
-    kf = k.reshape(B * H, Sk, Dp)
-    vf = v.reshape(B * H, Sk, Dp)
-    grid = (B * H, Sq // block_q)
-    kernel = functools.partial(_flash_kernel, block_k=block_k, sq=Sq, sk=Sk,
-                               causal=causal, scale=scale, block_q=block_q)
-    out = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, Dp), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, Sk, Dp), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, Sk, Dp), lambda b, i: (b, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, Dp), lambda b, i: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, Sq, Dp), q.dtype),
+    outs = jax.tree.leaves(out_shape)
+    return pl.pallas_call(
+        kernel, name=name, grid=grid, in_specs=in_specs, out_specs=out_specs,
+        out_shape=out_shape, scratch_shapes=scratch, interpret=interpret,
         compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=64 * 1024 * 1024),
         cost_estimate=pl.CostEstimate(
-            flops=4 * B * H * Sq * Sk * Dp,
-            bytes_accessed=(qf.size + kf.size + vf.size) * 4,
-            transcendentals=B * H * Sq * Sk),
-    )(qf, kf, vf)
-    return out.reshape(B, H, Sq, Dp)[..., :D]
+            flops=flops,
+            bytes_accessed=sum(x.size * x.dtype.itemsize
+                               for x in (*operands, *outs)),
+            transcendentals=grid[0] * operands[0].shape[1]
+            * operands[1].shape[1]),
+    )(*operands)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def flash_attention(q, k, v, causal=False, scale=None):
-    """softmax(QKᵀ·scale)·V with O(seq) memory.
+def _specs(bq, bk, dp, off, causal, q_major):
+    """Block specs of a (batch*heads, ·, ·) grid whose second axis runs over
+    query blocks (``q_major``: forward, dQ) or key blocks (dK/dV).  The
+    index of a block the causal mask skips is clamped to the nearest one it
+    visits, so a skipped grid step fetches nothing."""
+    from jax.experimental import pallas as pl
 
-    Pallas kernel where the call runs on a TPU and both lengths are
-    multiples of 128; the numerics-identical jnp path otherwise.  Backward
-    recomputes attention (flash-style rematerialization) instead of storing
-    the (Sq×Sk) probability matrix.
-    """
+    clamp = causal and off >= 0
+
+    # plain lax on non-negative operands: an index map is lowered once a
+    # block spec, and jnp's floor division is a jitted function of its own
+    def q_of(i, j):
+        if q_major:
+            return i
+        return lax.max(j, lax.div(i * bk - off, bq)) if clamp else j
+
+    def k_of(i, j):
+        if not q_major:
+            return i
+        return lax.min(j, lax.div((i + 1) * bq - 1 + off, bk)) if clamp else j
+
+    def q_at(b, i, j):
+        return b, q_of(i, j), 0
+
+    def kv_at(b, i, j):
+        return b, k_of(i, j), 0
+
+    def stat_at(b, i, j):
+        return b, 0, q_of(i, j)
+
+    return (pl.BlockSpec((None, bq, dp), q_at),
+            pl.BlockSpec((None, bk, dp), kv_at),
+            pl.BlockSpec((None, 1, bq), stat_at))
+
+
+def _flash_forward_pallas(q, k, v, causal, scale, block_q=None, block_k=None,
+                          interpret=False):
+    """out (B, H, Sq, D) and the row statistics m, l (B, H, Sq) in f32."""
+    from jax.experimental.pallas import tpu as pltpu
+    Sq, Sk = q.shape[2], k.shape[2]
+    bq, bk = block_q or _block(Sq), block_k or _block(Sk)
+    qf, kf, vf = _fold(q), _fold(k), _fold(v)
+    BH, _, Dp = qf.shape
+    off = Sk - Sq
+    q_spec, kv_spec, stat_spec = _specs(bq, bk, Dp, off, causal, True)
+    stat = jax.ShapeDtypeStruct((BH, 1, Sq), jnp.float32)
+    out, m, l = _pallas_call(
+        functools.partial(_flash_fwd_kernel, causal=causal, scale=scale,
+                          off=off),
+        "flash_attention_pallas", (BH, Sq // bq, Sk // bk),
+        [q_spec, kv_spec, kv_spec], [q_spec, stat_spec, stat_spec],
+        [jax.ShapeDtypeStruct(qf.shape, q.dtype), stat, stat],
+        [pltpu.VMEM((bq, Dp), jnp.float32),
+         pltpu.VMEM((bq, _LANES), jnp.float32),
+         pltpu.VMEM((bq, _LANES), jnp.float32)],
+        4 * BH * Sq * Sk * Dp, (qf, kf, vf), interpret)
+    return (_unfold(out, q), m.reshape(q.shape[:3]), l.reshape(q.shape[:3]))
+
+
+def _flash_backward_pallas(q, k, v, out, m, l, g, causal, scale,
+                           block_q=None, block_k=None, interpret=False):
+    from jax.experimental.pallas import tpu as pltpu
+    Sq, Sk = q.shape[2], k.shape[2]
+    bq, bk = block_q or _block(Sq), block_k or _block(Sk)
+    qf, kf, vf, gf = _fold(q), _fold(k), _fold(v), _fold(g)
+    BH, _, Dp = qf.shape
+    off = Sk - Sq
+    delta = (g.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)
+    stats = [x.reshape(BH, 1, Sq) for x in (m, 1.0 / l, delta)]
+    operands = (qf, kf, vf, gf, *stats)
+    flops = 2 * BH * Sq * Sk * Dp
+
+    q_spec, kv_spec, stat_spec = _specs(bq, bk, Dp, off, causal, False)
+    dk, dv = _pallas_call(
+        functools.partial(_flash_dkv_kernel, causal=causal, scale=scale,
+                          off=off),
+        "flash_attention_bwd_dkv", (BH, Sk // bk, Sq // bq),
+        [q_spec, kv_spec, kv_spec, q_spec] + [stat_spec] * 3,
+        [kv_spec, kv_spec],
+        [jax.ShapeDtypeStruct(kf.shape, k.dtype),
+         jax.ShapeDtypeStruct(vf.shape, v.dtype)],
+        [pltpu.VMEM((bk, Dp), jnp.float32)] * 2,
+        4 * flops, operands, interpret)
+
+    q_spec, kv_spec, stat_spec = _specs(bq, bk, Dp, off, causal, True)
+    dq = _pallas_call(
+        functools.partial(_flash_dq_kernel, causal=causal, scale=scale,
+                          off=off),
+        "flash_attention_bwd_dq", (BH, Sq // bq, Sk // bk),
+        [q_spec, kv_spec, kv_spec, q_spec] + [stat_spec] * 3, q_spec,
+        jax.ShapeDtypeStruct(qf.shape, q.dtype),
+        [pltpu.VMEM((bq, Dp), jnp.float32)],
+        3 * flops, operands, interpret)
+    return _unfold(dq, q), _unfold(dk, k), _unfold(dv, v)
+
+
+# ---------------------------------------------------------------------------
+# which path: by alignment, then by where the call runs
+# ---------------------------------------------------------------------------
+
+def _choose(paths, operands, pallas, fallback):
+    """Call ``pallas`` where the lengths are multiples of 128 and the call
+    runs on a TPU, else ``fallback``; count the trace under the label of
+    ``paths`` (unaligned, staged, on a TPU, off it) that names the way."""
+    unaligned, staged, on_tpu, off_tpu = paths
+    q, k = operands[0], operands[1]
+    if q.shape[2] % 128 or k.shape[2] % 128:
+        # O(S²) memory on any platform: worth a counter of its own
+        _metrics.flash_attention_trace(unaligned)
+        return fallback(*operands)
+    if any(isinstance(x, jax.core.Tracer) for x in operands):
+        # a tracer has no device: the program it is staged into picks the
+        # branch when it is lowered for the platform its operands live on
+        _metrics.flash_attention_trace(staged)
+        return lax.platform_dependent(*operands, tpu=pallas, default=fallback)
+    if all(d.platform == "tpu" for d in q.devices()):
+        _metrics.flash_attention_trace(on_tpu)
+        return pallas(*operands)
+    _metrics.flash_attention_trace(off_tpu)
+    return fallback(*operands)
+
+
+_FWD_PATHS = ("reference_unaligned", "lowering_platform", "pallas",
+              "reference_off_tpu")
+# none may begin with "reference": the benchmark refuses those on the chip
+_BWD_PATHS = ("bwd_scan_unaligned", "bwd_lowering_platform", "bwd_pallas",
+              "bwd_scan_off_tpu")
+
+
+def _flash_forward(q, k, v, causal, scale):
+    """out, m, l by whichever path the call takes."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
 
@@ -159,22 +440,44 @@ def flash_attention(q, k, v, causal=False, scale=None):
 
     def reference(q, k, v):
         with jax.named_scope("flash_attention_reference"):
-            return _attention_reference(q, k, v, causal, scale)
+            return _attention_reference_stats(q, k, v, causal, scale)
 
-    if q.shape[2] % 128 or k.shape[2] % 128:
-        # O(S²) memory on any platform: worth a counter of its own
-        _metrics.flash_attention_trace("reference_unaligned")
-        return reference(q, k, v)
-    if isinstance(q, jax.core.Tracer):
-        # a tracer has no device: the program it is staged into picks the
-        # branch when it is lowered for the platform its operands live on
-        _metrics.flash_attention_trace("lowering_platform")
-        return lax.platform_dependent(q, k, v, tpu=pallas, default=reference)
-    if all(d.platform == "tpu" for d in q.devices()):
-        _metrics.flash_attention_trace("pallas")
-        return pallas(q, k, v)
-    _metrics.flash_attention_trace("reference_off_tpu")
-    return reference(q, k, v)
+    return _choose(_FWD_PATHS, (q, k, v), pallas, reference)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def flash_attention(q, k, v, causal=False, scale=None):
+    """softmax(QKᵀ·scale)·V with O(seq) memory.
+
+    Pallas kernels, forward and backward, where the call runs on a TPU and
+    both lengths are multiples of 128; the jnp path otherwise.  The
+    backward recomputes the probabilities from q, k and the row statistics
+    the forward saved (flash-style rematerialization) instead of storing
+    the (Sq×Sk) probability matrix.
+    """
+    return _flash_forward(q, k, v, causal, scale)[0]
+
+
+def _flash_fwd(q, k, v, causal, scale):
+    out, m, l = _flash_forward(q, k, v, causal, scale)
+    return out, (q, k, v, out, m, l)
+
+
+def _flash_bwd(causal, scale, res, g):
+    """Flash-style backward from the saved statistics, never materializing
+    the (Sq × Sk) score matrix.  All of it is staged under one
+    ``named_scope``, so that a trace's reduction finds its operations (the
+    two Mosaic calls and the ``jnp`` around them, or the scan's fusions) by
+    their ``op_name``."""
+    if scale is None:
+        scale = 1.0 / math.sqrt(res[0].shape[-1])
+    with jax.named_scope("flash_attention_bwd"):
+        return _choose(
+            _BWD_PATHS, (*res, g),
+            functools.partial(_flash_backward_pallas, causal=causal,
+                              scale=scale),
+            functools.partial(_flash_backward_scan, causal=causal,
+                              scale=scale))
 
 
 def _kv_block_size(sk):
@@ -185,34 +488,12 @@ def _kv_block_size(sk):
     return sk
 
 
-def _flash_fwd(q, k, v, causal, scale):
-    out = flash_attention(q, k, v, causal, scale)
-    return out, (q, k, v, out)
-
-
-def _flash_bwd(causal, scale, res, g):
-    """Flash-style backward: two chunked passes over the key axis, never
-    materializing the (Sq × Sk) score matrix — backward memory matches the
-    forward's O(Sq · block) profile.
-
-    Pass 1 recovers the softmax log-normalizer with an online max/sum scan;
-    pass 2 rebuilds each probability tile from (logits − lse) and
-    accumulates dQ (carried) and per-tile dK/dV (scan outputs).
-
-    All of it is staged under one ``named_scope``, so that a trace's
-    reduction finds the operations of both scans by their ``op_name``
-    (they are ``fusion`` ops inside two ``while`` loops, named like every
-    other matmul).
-    """
-    with jax.named_scope("flash_attention_bwd"):
-        return _flash_bwd_scans(causal, scale, res, g)
-
-
-def _flash_bwd_scans(causal, scale, res, g):
-    q, k, v = res[0], res[1], res[2]
-    out = res[3]
-    if scale is None:
-        scale = 1.0 / math.sqrt(q.shape[-1])
+def _flash_backward_scan(q, k, v, out, m, l, g, causal, scale):
+    """The backward as jnp: one chunked pass over the key axis that
+    rebuilds each probability tile from the saved (m, l) and accumulates
+    dQ (carried) and per-tile dK/dV (scan outputs) — backward memory
+    matches the forward's O(Sq · block) profile.  In f32 at HIGHEST
+    whatever the operands' dtype: the CPU path, and the kernels' oracle."""
     dtype_in = q.dtype
     Sq, Sk = q.shape[2], k.shape[2]
     block = _kv_block_size(Sk)
@@ -224,47 +505,21 @@ def _flash_bwd_scans(causal, scale, res, g):
     kb = jnp.moveaxis(kb, 2, 0)                       # (nb, B, H, blk, D)
     vb = jnp.moveaxis(vb, 2, 0)
     q_pos = jnp.arange(Sq)[:, None] + (Sk - Sq)       # diag anchored at end
-
-    hi = jax.lax.Precision.HIGHEST  # bf16 MXU passes would desync p from out
-
-    def scores(k_blk, i):
-        s = jnp.einsum("bhqd,bhkd->bhqk", qf, k_blk, precision=hi,
-                       preferred_element_type=jnp.float32) * scale
-        if causal:
-            k_pos = i * block + jnp.arange(block)[None, :]
-            mask = q_pos >= k_pos
-            return jnp.where(mask, s, _NEG_INF), mask
-        return s, None
-
-    def stat_step(carry, xs):
-        m_prev, l_prev = carry
-        k_blk, i = xs
-        s, _ = scores(k_blk, i)
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        l_new = l_prev * jnp.exp(m_prev - m_new) + \
-            jnp.exp(s - m_new[..., None]).sum(axis=-1)
-        return (m_new, l_new), None
-
-    m0 = jnp.full(q.shape[:3], _NEG_INF, jnp.float32)
-    l0 = jnp.zeros(q.shape[:3], jnp.float32)
-    (m, l), _ = lax.scan(stat_step, (m0, l0), (kb, jnp.arange(nb)))
-    # keep (m, l) separate: folding into m + log(l) loses log(l) to float
+    hi = jax.lax.Precision.HIGHEST
+    # (m, l) stay separate: folding into m + log(l) loses log(l) to float
     # absorption when m is the -1e30 sentinel (rows with no visible keys)
-    l_inv = 1.0 / jnp.maximum(l, 1e-20)
+    l_inv = 1.0 / l
     delta = (gf * out.astype(jnp.float32)).sum(-1)    # (B, H, Sq)
 
     def grad_step(dq_acc, xs):
         k_blk, v_blk, i = xs
-        s, mask = scores(k_blk, i)
-        p = jnp.exp(s - m[..., None]) * l_inv[..., None]
-        dv_blk = jnp.einsum("bhqk,bhqd->bhkd", p, gf, precision=hi)
+        s = jnp.einsum("bhqd,bhkd->bhqk", qf, k_blk, precision=hi)
         dp = jnp.einsum("bhqd,bhkd->bhqk", gf, v_blk, precision=hi)
-        ds = p * (dp - delta[..., None]) * scale
-        if mask is not None:
-            # masked logits are constants in the forward (`where` routes the
-            # gradient around them), so they carry no dQ/dK — matters for
-            # rows with no visible keys, where p is uniform, not 0
-            ds = jnp.where(mask, ds, 0.0)
+        mask = (q_pos >= i * block + jnp.arange(block)[None, :]
+                if causal else None)
+        p, ds = _probs_and_dscores(s, dp, m[..., None], l_inv[..., None],
+                                   delta[..., None], mask, scale, Sk - Sq)
+        dv_blk = jnp.einsum("bhqk,bhqd->bhkd", p, gf, precision=hi)
         dq_acc = dq_acc + jnp.einsum("bhqk,bhkd->bhqd", ds, k_blk,
                                      precision=hi)
         dk_blk = jnp.einsum("bhqk,bhqd->bhkd", ds, qf, precision=hi)

@@ -675,12 +675,15 @@ def trainer_compiled_fallback(reason):
 
 
 def flash_attention_trace(path):
-    """One trace of ``ops.attention.flash_attention``, labeled by the path
-    it took: ``pallas`` / ``reference_off_tpu`` (concrete operands, chosen
-    by where they live), ``lowering_platform`` (traced operands: Pallas
-    when the enclosing program is lowered for a TPU, jnp otherwise) or
-    ``reference_unaligned`` (a length that is not a multiple of 128 —
-    the O(S²) jnp path on every platform)."""
+    """One trace of ``ops.attention.flash_attention``, forward or backward,
+    labeled by the path it took.  Forward: ``pallas`` /
+    ``reference_off_tpu`` (concrete operands, chosen by where they live),
+    ``lowering_platform`` (traced operands: Pallas when the enclosing
+    program is lowered for a TPU, jnp otherwise) or ``reference_unaligned``
+    (a length that is not a multiple of 128 — the O(S²) jnp path on every
+    platform).  Backward, in the same order: ``bwd_pallas`` /
+    ``bwd_scan_off_tpu``, ``bwd_lowering_platform``, ``bwd_scan_unaligned``
+    (the chunked jnp scan where the forward took its jnp path)."""
     if not enabled():
         return
     _REGISTRY.counter("graft_flash_attention_traces_total",

@@ -1,0 +1,186 @@
+"""The Pallas flash-attention kernels of ``ops/attention.py``, forward and
+backward: run by the Pallas interpreter on the CPU against autodiff of the
+dense reference, and compiled at the OPT cell's shape for a chip that is
+described, not attached (on-chip-measurement guide, section 2: nothing at
+import, the topology in a fixture).
+"""
+import re
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from incubator_mxnet_tpu import telemetry
+from incubator_mxnet_tpu.ops import attention as A
+
+# the largest error over the largest reference value: f32 kernels differ
+# from the reference in the order of their sums alone; bf16 results are
+# rounded to 8 bits, and so are p and dS on their way into the MXU
+TOLERANCE = {"float32": 1e-5, "bfloat16": 2e-2}
+
+
+def _problem(dtype, sq, sk, d, seed=0):
+    rs = np.random.RandomState(seed)
+    return tuple(jnp.asarray(rs.randn(1, 2, s, d), dtype)
+                 for s in (sq, sk, sk, sq))
+
+
+def _reference(q, k, v, g, causal, scale):
+    """out, m, l, dq, dk, dv of the dense reference on the same (rounded)
+    operands, upcast, with every matmul at full precision."""
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    with jax.default_matmul_precision("highest"):
+        (out, m, l), vjp = jax.vjp(
+            lambda a, b, c: A._attention_reference_stats(a, b, c, causal,
+                                                         scale), *f32)
+        grads = vjp((g.astype(jnp.float32), jnp.zeros_like(m),
+                     jnp.zeros_like(l)))
+    return (out, m, l, *grads)
+
+
+def _kernels(q, k, v, g, causal, scale, block=128):
+    """The same six from the kernels themselves, in 128-blocks so that
+    every length here spans several."""
+    out, m, l = A._flash_forward_pallas(q, k, v, causal, scale, block, block,
+                                        interpret=True)
+    grads = A._flash_backward_pallas(q, k, v, out, m, l, g, causal, scale,
+                                     block, block, interpret=True)
+    return (out, m, l, *grads)
+
+
+def _error(got, want):
+    got, want = (np.asarray(x, np.float32) for x in (got, want))
+    assert got.shape == want.shape and np.all(np.isfinite(got))
+    return float(np.abs(got - want).max() / max(np.abs(want).max(), 1e-30))
+
+
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("sq,sk", [(256, 256), (128, 256), (256, 128)])
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_kernels_match_autodiff_of_the_reference(dtype, causal, sq, sk, d):
+    q, k, v, g = _problem(jnp.dtype(dtype), sq, sk, d)
+    scale = d ** -0.5
+    want = _reference(q, k, v, g, causal, scale)
+    got = _kernels(q, k, v, g, causal, scale)
+    for name, a, b in zip(("out", "m", "l", "dq", "dk", "dv"), got, want):
+        # the statistics are f32 whatever the operands are
+        tol = TOLERANCE["float32" if name in ("m", "l") else dtype]
+        assert _error(a, b) <= tol, name
+    # the jnp scan, fed the kernel's statistics, is the other backward
+    scan = A._flash_backward_scan(q, k, v, *got[:3], g, causal=causal,
+                                  scale=scale)
+    for name, a, b in zip(("dq", "dk", "dv"), scan, want[3:]):
+        assert _error(a, b) <= TOLERANCE[dtype], name
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_rows_that_see_no_key_are_finite_and_carry_no_dq_dk(dtype):
+    """Sq 256 > Sk 128, causal, the diagonal anchored at the end: the first
+    128 rows see nothing.  Their output is the reference's (uniform over
+    every key), they reach dV and nothing else."""
+    sq, sk, d = 256, 128, 128
+    q, k, v, g = _problem(jnp.dtype(dtype), sq, sk, d, seed=1)
+    out, m, l, dq, dk, dv = _kernels(q, k, v, g, True, d ** -0.5)
+    blind = sq - sk
+    for x in (out, m, l, dq, dk, dv):
+        assert np.all(np.isfinite(np.asarray(x, np.float32)))
+    assert np.all(np.asarray(m)[..., :blind] == np.float32(A._NEG_INF))
+    assert np.all(np.asarray(l)[..., :blind] == sk)
+    assert np.all(np.asarray(dq, np.float32)[:, :, :blind] == 0.0)
+    # dK is that of the rows that see: the square problem below them
+    _, _, _, _, dk_seeing, _ = _kernels(
+        q[:, :, blind:], k, v, g[:, :, blind:], True, d ** -0.5)
+    assert _error(dk, dk_seeing) <= TOLERANCE[dtype]
+
+
+def test_one_bf16_pass_is_the_highest_product_of_the_upcast_operands():
+    """A product of two bf16 numbers is exact in f32: one pass with f32
+    accumulation is the six-pass product up to the order of the sums."""
+    rs = np.random.RandomState(2)
+    q, k = (jnp.asarray(rs.randn(128, 128), jnp.bfloat16) for _ in range(2))
+    one_pass = A._dot(q, k, A._NT)
+    assert one_pass.dtype == jnp.float32
+    six = A._dot(q.astype(jnp.float32), k.astype(jnp.float32), A._NT)
+    assert _error(one_pass, six) <= 1e-6
+
+
+def _paths():
+    snap = telemetry.registry().snapshot().get(
+        "graft_flash_attention_traces_total", {"samples": []})
+    return {s["labels"]["path"]: int(s["value"]) for s in snap["samples"]}
+
+
+def test_backward_traces_are_counted_under_bwd_labels():
+    before = _paths()
+    q = jnp.ones((1, 1, 128, 8), jnp.float32)
+    short = q[:, :, :16]
+    loss = lambda a: A.flash_attention(a, a, a, True).sum()   # noqa: E731
+    jax.grad(loss)(q)
+    jax.jit(jax.grad(loss))(q)
+    jax.grad(loss)(short)
+    new = {p: c - before.get(p, 0) for p, c in _paths().items()}
+    new = {p: c for p, c in new.items() if c}
+    assert new == {"reference_off_tpu": 1, "bwd_scan_off_tpu": 1,
+                   "lowering_platform": 1, "bwd_lowering_platform": 1,
+                   "reference_unaligned": 1, "bwd_scan_unaligned": 1}
+    assert not any(p.startswith("reference") for p in A._BWD_PATHS)
+
+
+# ---------------------------------------------------------------------------
+# the three kernels at the cell's shape, for a chip that is described
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # noqa: BLE001 - whatever libtpu raises here
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without a chip: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def test_grad_compiles_to_three_kernels_at_the_opt_cells_shape(
+        topo, no_compile_cache):
+    from jax.sharding import SingleDeviceSharding
+    spec = jax.ShapeDtypeStruct(
+        (4, 32, 2048, 128), jnp.bfloat16,
+        sharding=SingleDeviceSharding(topo.devices[0]))
+
+    def loss(q, k, v):
+        return A.flash_attention(q, k, v, True).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        spec, spec, spec).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    names = [re.match(r"\s*(?:ROOT )?%(\S+) =", line).group(1)
+             for line in calls]
+    op_names = [re.search(r'op_name="([^"]+)"', line).group(1)
+                for line in calls]
+    # what layer_metrics/flash_fwd.py books against the forward's FLOPs
+    forward = [n for n in names if "flash_attention_pallas" in n]
+    assert len(forward) == 1 and len(calls) == 3, names
+    assert all("flash_attention_bwd" in o
+               for n, o in zip(names, op_names) if n not in forward)
+    # the scan is gone from the TPU's program, and its score tiles with it
+    scoped = [line for line in text.splitlines()
+              if "flash_attention_bwd" in line]
+    assert scoped and not any(re.search(r"\bwhile\(", s) for s in scoped)
+    assert not re.search(r"f32\[[\d,]*2048,1024\]", text)
