@@ -4,10 +4,15 @@ Before step *i* is enqueued the loss of step *i*-2 is waited for and the
 clock is stamped.  A loop that blocks on every step measures a drained
 pipeline, which is not how the product runs; one that never blocks has no
 samples.  Step-time samples are the differences of consecutive stamps.
-Throughput is the work of a block of consecutive steps over the median time
-of the window's blocks (``median_block_rate``); the work completed between
-the first and the last stamp over the time between them (``window_rate``)
-is kept beside it, and ``lost_share`` is the distance between the two.
+Throughput is all the work of the window over all of its time: every step
+it enqueued over the time from its start, on an empty pipeline, to the
+moment the last of that work is done (``window_rate``).  A loss can be
+ready before its step is (the MXNet loop's comes out of the forward
+program, ahead of the backward and the update), so the end is stamped
+after ``settle()``, the job's own barrier, not at the last loss.  The rate
+of the window's median block of steps (``median_block_rate``), which a
+stall does not move, is kept beside it for the per-layer readers, and
+``lost_share`` is the distance between the two.
 """
 import collections
 import statistics
@@ -17,7 +22,7 @@ IN_FLIGHT = 2
 BLOCKS = 20                 # a 20 s window: about a second of work a block
 
 
-def run_window(step, seconds, span, on_stamp=None,
+def run_window(step, seconds, span, on_stamp=None, settle=None,
                clock=time.perf_counter):
     """Call ``step()`` (which enqueues one train step and returns its loss,
     a device array) until ``seconds`` have passed, then drain.
@@ -26,8 +31,10 @@ def run_window(step, seconds, span, on_stamp=None,
     (``wait`` around each block, ``enqueue`` around each ``step()`` call).
     ``on_stamp(n)`` is called after the n-th stamp, while the pipeline still
     holds a step, and is where the traced sub-window is started and
-    stopped.  Returns ``(stamps, losses)``: a stamp per completed step and
-    the loss arrays in step order."""
+    stopped.  ``settle()`` returns when everything the steps enqueued is
+    done.  Returns ``(stamps, losses, (start, done))``: a stamp per
+    completed step, the loss arrays in step order, and the clock at the
+    window's start and once its work was done."""
     pending = collections.deque()
     stamps, losses = [], []
 
@@ -40,7 +47,8 @@ def run_window(step, seconds, span, on_stamp=None,
         if on_stamp is not None:
             on_stamp(len(stamps))
 
-    end = clock() + seconds
+    start = clock()
+    end = start + seconds
     while clock() < end:
         if len(pending) == IN_FLIGHT:
             complete()
@@ -48,15 +56,17 @@ def run_window(step, seconds, span, on_stamp=None,
             pending.append(step())
     while pending:
         complete()
-    return stamps, losses
+    if settle is not None:
+        settle()
+    return stamps, losses, (start, clock())
 
 
-def window_rate(stamps, samples_per_step):
-    """Samples completed a second over the window: the steps between the
-    first and the last stamp over the time between them.  Every stall
-    inside the window counts, a single one of the machine's as much as the
-    program's own."""
-    return (len(stamps) - 1) * samples_per_step / (stamps[-1] - stamps[0])
+def window_rate(steps, samples_per_step, start, done):
+    """Samples a second over the window: the work of all its ``steps`` over
+    the time from its start to when that work was done.  Filling the
+    pipeline counts, and every stall inside the window, a single one of the
+    machine's as much as the program's own: the end-to-end rate."""
+    return steps * samples_per_step / (done - start)
 
 
 def block_seconds(stamps):
@@ -69,22 +79,21 @@ def block_seconds(stamps):
 
 
 def median_block_rate(stamps, samples_per_step):
-    """Samples a second in the median block.  Whatever recurs at least once
-    a block (a flush, a collection, a slow step in every ten) is in every
-    block and counts in full, which a median of step times would hide; a
-    stall that holds up fewer than half the blocks, however long, moves
-    nothing.  The machine stalls a run for 6 to 9 s about once in 30
-    (PERF.md): over the window that is a third of the rate, and one such
-    run among six is more spread than any bound allows."""
+    """Samples a second in the median block: the pace of the window with
+    its stalls left out, a per-layer statistic beside the end-to-end rate.
+    Whatever recurs at least once a block (a flush, a collection, a slow
+    step in every ten) is in every block and counts in full, which a median
+    of step times would hide; a stall that holds up fewer than half the
+    blocks, however long, moves nothing."""
     size, seconds = block_seconds(stamps)
     return size * samples_per_step / statistics.median(seconds)
 
 
 def lost_share(stamps, steps=None):
     """The share of the time of ``steps`` (all of the window's by default)
-    that the median block leaves out: 1 - the time they would take at the
-    median block's pace over the time they took.  Near 0 in a window
-    without a stall, a few tenths of a per cent either way."""
+    that went to what the median block leaves out: 1 - the time they would
+    take at the median block's pace over the time they took.  Near 0 in a
+    window without a stall, a few tenths of a per cent either way."""
     steps = step_seconds(stamps) if steps is None else steps
     size, seconds = block_seconds(stamps)
     return 1.0 - len(steps) * statistics.median(seconds) / size / sum(steps)

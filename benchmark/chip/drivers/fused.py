@@ -35,17 +35,18 @@ class Job:
     def temp_bytes(self):
         """``temp_size_in_bytes`` of the compiled step: the allocator's
         peak misses a program's temporaries on this runtime (PERF.md,
-        PR 21).  Lowered again from the function ``trainer.compile``
-        returns, which reads the program from the cache.  The argument
-        trees are the trainer's private ones, as ``chip_smoke.py`` reads
-        them; a public accessor is on PERF.md's list for the tracing
-        issue."""
-        t = self.trainer
-        compiled = t.compile(self.x, self.y).lower(
-            t._params, t._opt_state, t._rng_key, self.x, self.y,
-            t._lr_dev).compile()
-        analysis = compiled.memory_analysis()
+        PR 21).  ``compiled_step`` lowers from the shapes and shardings of
+        the trainer's state, so nothing runs; the program that has just
+        run is read back from the compile cache."""
+        analysis = self.trainer.compiled_step(self.x,
+                                              self.y).memory_analysis()
         return None if analysis is None else int(analysis.temp_size_in_bytes)
+
+    def state(self):
+        """(parameters, optimizer state) as the step holds them on the
+        device, by parameter name, for ``chipbench/first_steps.py``.  The
+        trainer has no accessor for either (PERF.md, open questions)."""
+        return self.trainer._params, self.trainer._opt_state
 
     def checks(self):
         """The loss and every parameter sit on all of the cell's devices."""

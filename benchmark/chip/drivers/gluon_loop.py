@@ -8,6 +8,8 @@ host's work is done by ``CachedOp``, ``autograd``, ``engine.py``,
 ``gluon/trainer.py``'s bucketed update and the always-on observation.
 """
 
+BACKWARD = "cachedop_backward"      # gluon/block.py's name for it
+
 
 def setup(run, net, x, y):
     from incubator_mxnet_tpu import gluon
@@ -42,29 +44,23 @@ class Job:
         return loss._read()
 
     def temp_bytes(self):
-        """This path has no single program whose temporaries can be read
-        through a public entry point, so the cell reports no
-        ``peak_hbm_gb``.  The largest, the CachedOp's compiled backward
-        (which recomputes the forward), is read from the Block's private
-        cache all the same, for ``device.memory_peak_bytes`` alone: the
+        """The temporaries of the loop's largest program, the CachedOp's
+        compiled backward (it recomputes the forward), as the program's own
+        registry has them (``telemetry.programs()``: compiled from the
+        shapes it last ran on, a read of the compile cache).  The
         allocator's own peak misses a program's temporaries (2.8 GB against
-        9.4 GB in this cell, PERF.md), and a cell is judged too small or not
-        by that number.  None when the cache is not where it was."""
-        import jax
-        try:
-            (entry,) = self.net._cached_op._cache.values()
-            names = self.net._cached_op._param_names
-            params = self.net.collect_params()
-            vals = {n: params[n].data()._read() for n in names}
-            out = jax.eval_shape(entry["jit"], vals, [self.x._read()],
-                                 jax.random.PRNGKey(0))[0]
-            analysis = entry["vjp"].lower(
-                vals, [self.x._read()], jax.random.PRNGKey(0),
-                tuple(out)).compile().memory_analysis()
-            return int(analysis.temp_size_in_bytes)
-        except (AttributeError, KeyError, TypeError, ValueError) as e:
-            self.run.facts["gluon_temp_bytes_unreadable"] = repr(e)
+        9.4 GB in this cell, PERF.md), and a cell is judged too small or
+        not by this number (``device.memory_peak_bytes``).  The cell lists
+        itself under no ``peak_hbm_gb``: the loop's ``bytes_in_use`` swings
+        by 2 to 3 % from run to run (PERF.md, PR 25).  None where the
+        registry has no such program or no memory analysis of it."""
+        program = self.run.mx.telemetry.programs().get(BACKWARD)
+        temp = program.memory.get("temp_bytes") if program else None
+        if temp is None:
+            self.run.facts["gluon_temp_bytes_unreadable"] = (
+                program.error if program else "no program %s" % BACKWARD)
             return None
+        return int(temp)
 
     def checks(self):
         return {}

@@ -9,8 +9,8 @@ mix names (``drivers/``).  The run builds the model through ``gluon`` from
 ``--seed``, holds its forward and its first loss to the configuration's
 plain reference, warms up the cell's own shapes (all of that is
 ``setup_s``), then measures train steps for ``--seconds`` with two steps in
-flight; throughput is the samples of a block of consecutive steps over the
-median time of the window's blocks (``chipbench/timing.py``).  Where the
+flight; throughput is every sample of the window's steps over the time from
+its start to when their work is done (``chipbench/timing.py``).  Where the
 configuration's step draws random numbers, the reference checks are made on
 the model its JSON names under ``first_loss_with`` (``set_up``).
 With ``--trace 1`` a few seconds inside the window are traced with the JAX
@@ -44,8 +44,8 @@ HERE = pathlib.Path(__file__).resolve().parent
 if str(HERE) not in sys.path:
     sys.path.insert(0, str(HERE))
 
-from chipbench import (catalog, compile_log, inputs, peaks,  # noqa: E402
-                       timing, trace)
+from chipbench import (catalog, compile_log, first_steps,  # noqa: E402
+                       inputs, peaks, timing, trace)
 
 TRACE_SECONDS = 3.0                 # the traced sub-window
 WARMUP_STEPS = 3
@@ -238,9 +238,12 @@ def set_up(run):
     then names, under ``first_loss_with``, the sizes that switch the
     randomness off; the reference checks are made on a model built with
     them, and the first loss is that of one step of the same driver's job
-    over it: the same builder, seed and program but for the masks.  It is
-    freed before the timed model is built, because a chip does not hold
-    both."""
+    over it: the same builder, seed and program but for the masks.  Where
+    the optimizer has a plain twin (``chipbench/first_steps.py``) that job
+    makes three steps, and once it is freed the reference follows them:
+    the reference's own time, left out of ``setup_s``.  The model is freed
+    before the timed one is built, because a chip does not hold both."""
+    import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec
     jax, mx, module, sizes, traffic = (run.jax, run.mx, run.module, run.sizes,
                                        run.traffic)
@@ -256,14 +259,32 @@ def set_up(run):
     with run.phase("reference"):
         reference_check(run, net, x, y)
     run.first_loss = None           # the timed job's own, unless:
+    run.first_steps = None
     if quiet:
         with run.phase("first_loss"):
+            start = None
+            if first_steps.applies(sizes):
+                cut = len(module.PREFIX)
+                start = {n[cut:]: np.asarray(p.data()._read(), np.float32)
+                         for n, p in net.collect_params().items()}
             job = run.driver.setup(run, net, x, y)
             run.first_loss = mean_loss(job.step())
+            if start is not None:
+                got = first_steps.program_side(run, job, run.first_loss,
+                                               start)
             del job, net
             gc.collect()            # a Block and its children are a cycle
             run.facts["bytes_in_use_once_freed"] = memory_in_use(
                 run.devices)[0]
+        if start is not None:
+            with run.phase("reference_steps"):
+                want = first_steps.reference_side(
+                    jax, module, sizes, start, x, y,
+                    module.check_rows(sizes, traffic), run.devices[0])
+                gaps, where = first_steps.compare(got, want)
+                run.first_steps = {"program": got, "reference": want,
+                                   "gaps": gaps, "where": where}
+                del start
         with run.phase("init"):
             net = seeded_net(run, sizes)
             mx.nd.waitall()
@@ -277,8 +298,29 @@ def set_up(run):
         run.bytes_in_use, _ = memory_in_use(run.devices)
         run.temp_bytes = job.temp_bytes()
     run.setup_mark = run.log.mark()
-    run.setup_s = time.perf_counter() - _T0
+    run.setup_s = (time.perf_counter() - _T0
+                   - run.setup.get("reference_steps", 0.0))
     return job
+
+
+@contextlib.contextmanager
+def collector_log(run):
+    """Python's cyclic collector while the block runs, for ``window_facts``:
+    each collection as ``(generation, clock at its start, seconds)``.  A
+    full collection stops the loop for some 90 ms in the Gluon cell."""
+    run.collections, began = [], [0.0]
+
+    def note(phase, info):
+        if phase == "start":
+            began[0] = time.perf_counter()
+        else:
+            run.collections.append((info["generation"], began[0],
+                                    time.perf_counter() - began[0]))
+    gc.callbacks.append(note)
+    try:
+        yield
+    finally:
+        gc.callbacks.remove(note)
 
 
 def measure(run, job):
@@ -287,9 +329,11 @@ def measure(run, job):
     args = run.args
     tracer = Tracer(run, args.seconds) if args.trace else None
     run.spans.clear()               # the window's own spans, not set-up's
-    run.stamps, run.losses = timing.run_window(
-        job.step, args.seconds, run.span,
-        on_stamp=tracer.on_stamp if tracer else None)
+    with collector_log(run):
+        run.stamps, run.losses, run.window_span = timing.run_window(
+            job.step, args.seconds, run.span,
+            on_stamp=tracer.on_stamp if tracer else None,
+            settle=run.mx.nd.waitall)
     run.window_mark = run.log.mark()
     if tracer:
         tracer.finish()
@@ -304,20 +348,48 @@ def attention_paths(mx):
 
 
 def window_facts(run, steps):
-    """The window as a whole beside its median block: what a reader needs
-    to tell a single stall from a slow stretch, and where it fell."""
+    """The window's median block beside its rate: what a reader needs to
+    tell a single stall from a slow stretch, and where it fell.  The rate
+    of the window's first half is what a run of half the length would have
+    read."""
     size, blocks = timing.block_seconds(run.stamps)
     slowest = sorted(range(len(steps)), key=steps.__getitem__)[-3:][::-1]
+    start, done = run.window_span
+    half = [t for t in run.stamps if t - start <= run.args.seconds / 2.0]
     return {
-        "mean_samples_per_s_per_chip": timing.window_rate(
+        "median_block_samples_per_s_per_chip": timing.median_block_rate(
             run.stamps, run.samples_per_step) / run.chips,
+        "first_half_samples_per_s_per_chip": None if not half else (
+            timing.window_rate(len(half), run.samples_per_step, start,
+                               half[-1]) / run.chips),
         "lost_pct": 100.0 * timing.lost_share(run.stamps),
-        "seconds": run.stamps[-1] - run.stamps[0],
+        "seconds": done - start,
+        "last_loss_to_done_s": done - run.stamps[-1],
         "block_steps": size, "blocks": len(blocks),
-        "slowest_block_s": max(blocks),
-        # [seconds, seconds into the window at which the step began]
-        "slowest_steps": [[steps[i], run.stamps[i] - run.stamps[0]]
-                          for i in slowest]}
+        "slowest_block_s": max(blocks), "block_s": blocks,
+        # [seconds, seconds into the window at which the step began, the
+        # host's spans between the two stamps: the enqueueing of the step
+        # two on and the wait for the next loss]
+        "slowest_steps": [[steps[i], run.stamps[i] - run.stamps[0],
+                           host_spans(run.spans, i)] for i in slowest],
+        "host_spans_p50_s": {k: timing.percentile(v, 50)
+                             for k, v in run.spans.items()},
+        # Python's cyclic collector: [collections, seconds] a generation,
+        # and [seconds into the window, seconds] of each full collection
+        "collector": [[sum(1 for g, _, _ in run.collections if g == n),
+                       sum(d for g, _, d in run.collections if g == n)]
+                      for n in range(3)],
+        "full_collections": [[t - start, d] for g, t, d in run.collections
+                             if g == 2]}
+
+
+def host_spans(spans, i):
+    """The host spans between stamp ``i`` and stamp ``i + 1``: step
+    ``i + 2`` is enqueued (a driver's own spans lie inside ``enqueue``),
+    then loss ``i + 1`` is waited for."""
+    at = {name: i + 1 if name == "wait" else i + 2 for name in spans}
+    return {name: spans[name][k] for name, k in at.items()
+            if k < len(spans[name])}
 
 
 def report(run, job):
@@ -349,17 +421,27 @@ def report(run, job):
         attention_took_no_reference_path=run.rehearse or not any(
             n for p, n in paths.items() if p.startswith("reference")),
         **job.checks())
+    if run.first_steps is not None:
+        suffix = "_rehearsal" if run.rehearse else ""
+        limits = {k: run.module.TOLERANCE[k + suffix]
+                  for k in run.first_steps["gaps"]}
+        run.checks.update({k + "_agrees_with_reference": gap <= limits[k]
+                           for k, gap in run.first_steps["gaps"].items()})
+        run.facts["first_steps"] = dict(
+            {k: {"gap": gap, "limit": limits[k],
+                 "at": run.first_steps["where"][k]}
+             for k, gap in run.first_steps["gaps"].items()},
+            losses=run.first_steps["program"]["losses"],
+            reference_losses=run.first_steps["reference"]["losses"])
 
     in_use_after, allocator_peak = memory_in_use(devices)
     in_use = max(run.bytes_in_use, in_use_after)
     peak_bytes = (None if run.temp_bytes is None
                   else in_use + run.temp_bytes)
-    # the median over blocks of about a second of work each, so that
-    # whatever recurs (a flush, a collection, a slow step in every ten)
-    # shows, which the median step, the train step's own metric, would
-    # hide, and a single stall of the machine does not
-    run.throughput = timing.median_block_rate(
-        run.stamps, run.samples_per_step) / run.chips
+    # all the work of the window over all of its time: a stall inside it
+    # counts, whoever's it is (``window_lost_pct`` says how much went)
+    run.throughput = timing.window_rate(
+        len(run.stamps), run.samples_per_step, *run.window_span) / run.chips
     all_steps = timing.step_seconds(run.stamps)
     run.step_samples = (timing.untraced_steps(run.stamps, run.traced_stamps)
                         or all_steps)
@@ -376,9 +458,14 @@ def report(run, job):
     else:
         have = end_to_end
         wanted = run.catalog.metrics("end_to_end", cell["name"])
-    # a reader that found nothing to read leaves its metric out of the line
-    metrics = {m["name"]: {"value": have[m["name"]], "unit": m["unit"]}
-               for m in wanted if have.get(m["name"]) is not None}
+    # a reader that found nothing to read leaves its metric out of the line.
+    # "<metric>.<anything>" is read as "<metric>": a metric that lists its
+    # cells is given to a later cell by an entry of that cell's own
+    found = {m["name"]: have.get(m["name"],
+                                 have.get(m["name"].partition(".")[0]))
+             for m in wanted}
+    metrics = {m["name"]: {"value": found[m["name"]], "unit": m["unit"]}
+               for m in wanted if found[m["name"]] is not None}
     if run.rehearse:
         # a CPU run gives no device number: the names the cell would
         # report, for the rehearsal to check, and none of the values
@@ -404,6 +491,7 @@ def report(run, job):
                           if m["name"] not in metrics],
         setup_phases_s=run.setup, compile_setup=run.compile_setup,
         compile_window=run.compile_window, steps=len(values),
+        samples_per_step=run.samples_per_step,
         median_step_samples_per_s_per_chip=(
             run.samples_per_step / timing.percentile(run.step_samples, 50)
             / run.chips),

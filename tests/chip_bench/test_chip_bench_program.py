@@ -1,7 +1,9 @@
 """The readers of what the program says of itself (``chipbench/program.py``,
 ``layer_metrics/device_split.py``, ``flash_bwd.py``, ``program_spans.py``),
-on a synthetic trace, registry and span record worked out by hand, and the
-CPU rehearsal naming every new metric in the cells that list it.
+on a synthetic trace, registry and span record worked out by hand; the CPU
+rehearsal naming every one of those metrics in the cells that report it; and
+the drivers' reading of a step's temporaries through the program's public
+accessors against the private trees it used to be read from.
 """
 import importlib
 import json
@@ -14,6 +16,9 @@ ROOT = pathlib.Path(__file__).resolve().parents[2]
 SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
 US = 1000
 
+# the metrics read from the program's own scopes, programs and spans (PR 23):
+# names of metrics, which the rehearsal below looks for wherever a cell
+# reports them; which cells those are is BENCHMARK.json's to say
 NEW = ["fwd_device_ms_per_step", "bwd_device_ms_per_step",
        "update_device_ms_per_step", "device_unattributed_pct",
        "flash_bwd_ms_per_step", "place_span_ms_p50", "dispatch_span_ms_p50",
@@ -295,22 +300,107 @@ def test_flushes_are_counted_over_the_steps_the_record_covers(
     assert 0.7 < facts["window_covered_share"] < 0.8
 
 
-def test_new_metrics_are_declared_for_their_cells():
-    by_name = {m["name"]: m for m in SPEC["per_layer"]}
-    cells = [w["name"] for w in SPEC["workloads"]]
-    fused = {c for c in cells if "fused" in c}
-    assert [m["name"] for m in SPEC["per_layer"]][-len(NEW):] == NEW
-    for name in NEW[:4]:
-        assert set(by_name[name]["workloads"]) == set(cells)
-    assert by_name["flash_bwd_ms_per_step"]["workloads"] == [
-        "opt6b7_fused_s2048"]
-    for name in NEW[5:7]:
-        assert set(by_name[name]["workloads"]) == fused
-        assert by_name[name]["source"] == "program_span"
-    for name in NEW[7:]:
-        assert by_name[name]["workloads"] == ["resnet50_gluon_b128"]
-        assert by_name[name]["layer"] == "gluon loop"
-    assert all(by_name[n]["moves"] == "samples_per_s_per_chip" for n in NEW)
+def test_flash_backward_roofline_by_hand(chip_run, bench_catalog):
+    """The two backward kernels by their names in the trace, a layer each
+    step, against what the causal backward needs: the ``jnp`` around them is
+    in ``flash_bwd_ms_per_step`` and not in the roofline's time."""
+    (reader,) = [r for r in bench_catalog.readers()
+                 if r.__name__.endswith("flash_bwd")]
+    flops, nbytes = reader.needs(batch=4, heads=32, seq=2048, head_dim=128,
+                                 dtype_bytes=2)
+    # seven matmuls over the lower triangle: scores and dP in each of the
+    # two kernels, dV, dK, dQ; each 2*B*H*S*S*D over the square
+    assert flops == 7 * 4 * 32 * 2048 * 2048 * 128
+    # q, k, v, o, dO read, dq, dk, dv written
+    assert nbytes == 8 * 4 * 32 * 2048 * 128 * 2
+    assert flops / 197e12 == pytest.approx(2.4418e-3, rel=1e-4)
+
+    # the window [200, 400] us holds 2 steps of a one-layer model: dK/dV
+    # runs 30 us and dQ 20 us a step, a delta fusion under the same scope 5
+    scope = FLASH.rsplit("/", 3)[0]
+    planes = [
+        {"name": "/device:TPU:0", "lines": [
+            {"name": "XLA Modules", "events": [
+                _ev("jit_dp_train_step(77)", 200, 90),
+                _ev("jit_dp_train_step(77)", 300, 90)]},
+            {"name": "XLA Ops", "events": [
+                _ev("%fusion.7", 200, 5),
+                _ev("%flash_attention_bwd_dkv.2", 210, 30),
+                _ev("%flash_attention_bwd_dq.3", 240, 20),
+                _ev("%flash_attention_pallas.1", 260, 10),
+                _ev("%fusion.7", 300, 5),
+                _ev("%flash_attention_bwd_dkv.2", 310, 30),
+                _ev("%flash_attention_bwd_dq.3", 340, 20)]}]},
+        _host([0, 50, 200, 300, 400]),
+    ]
+    registry = {"dp_train_step": _Program(ops={
+        "fusion.7": scope + "/mul", "flash_attention_bwd_dkv.2": scope,
+        "flash_attention_bwd_dq.3": scope,
+        "flash_attention_pallas.1":
+            "jit(dp_train_step)/xray:forward/flash_attention_pallas"})}
+    run = _run(chip_run, planes, registry,
+               sizes={"num_attention_heads": 32, "hidden_size": 4096,
+                      "num_hidden_layers": 1})
+    run.traffic = {"batch_per_chip": 4, "seq_len": 2048, "dtype": "bfloat16"}
+    run.peaks = {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
+    got = reader.read(run)
+    assert got["flash_bwd_ms_per_step"] == pytest.approx(0.055)
+    # 2.4418 ms at the bf16 peak over 0.050 ms of kernels: a count that is
+    # too high or a time that leaves work out shows as a share over 100
+    assert got["flash_bwd_roofline"] == pytest.approx(
+        100 * 2.4418e-3 / 0.050e-3, rel=1e-4)
+    assert run.facts["flash_bwd"]["roof"] == "bf16 FLOP/s"
+    assert run.facts["flash_bwd"]["kernels_ms_per_step"] == pytest.approx(
+        0.050)
+    # the forward's reader takes the forward's kernel alone
+    (fwd,) = [r for r in bench_catalog.readers()
+              if r.__name__.endswith("flash_fwd")]
+    assert fwd.kernel_seconds(run) == pytest.approx(10e-6)   # the window's
+    # no kernel of that name in the trace (the ``jnp`` path): the time is
+    # reported, the share is not
+    assert set(reader.read(_run(
+        chip_run, FUSED_PLANES, FUSED_REGISTRY,
+        sizes={"num_attention_heads": 2}))) == {"flash_bwd_ms_per_step"}
+
+
+def _private_fused_temp_bytes(job):
+    """As ``drivers/fused.py`` read it until PR 25: lowered from the
+    trainer's private argument trees."""
+    t = job.trainer
+    return int(t.compile(job.x, job.y).lower(
+        t._params, t._opt_state, t._rng_key, job.x, job.y,
+        t._lr_dev).compile().memory_analysis().temp_size_in_bytes)
+
+
+def _private_gluon_temp_bytes(job):
+    """As ``drivers/gluon_loop.py`` read it until PR 25: the CachedOp's
+    backward from the Block's private cache."""
+    import jax
+    (entry,) = job.net._cached_op._cache.values()
+    params = job.net.collect_params()
+    vals = {n: params[n].data()._read()
+            for n in job.net._cached_op._param_names}
+    out = jax.eval_shape(entry["jit"], vals, [job.x._read()],
+                         jax.random.PRNGKey(0))[0]
+    return int(entry["vjp"].lower(
+        vals, [job.x._read()], jax.random.PRNGKey(0),
+        tuple(out)).compile().memory_analysis().temp_size_in_bytes)
+
+
+@pytest.mark.parametrize("cell, private", [
+    ("resnet50_fused_b256", _private_fused_temp_bytes),
+    ("opt6b7_fused_adam", _private_fused_temp_bytes),
+    ("resnet50_gluon_b128", _private_gluon_temp_bytes)])
+def test_temporaries_read_through_public_accessors(chip_run, cell, private):
+    """``temp_bytes`` asks ``DataParallelTrainer.compiled_step`` and
+    ``telemetry.programs()`` and gets, to the byte, what the private trees
+    gave: ``peak_hbm_gb`` is bounded at 1 % and repeats to the byte."""
+    run = chip_run.open_run(chip_run.parse(
+        ["--workload", cell, "--seed", "11", "--rehearse"]), None)
+    job = chip_run.set_up(run)
+    assert run.temp_bytes is not None and run.temp_bytes > 0
+    assert "gluon_temp_bytes_unreadable" not in run.facts
+    assert run.temp_bytes == private(job)
 
 
 @pytest.mark.parametrize("cell", [w["name"] for w in SPEC["workloads"]])
