@@ -13,7 +13,7 @@ from __future__ import annotations
 import numpy as np
 
 from ..block import HybridBlock
-from .basic_layers import Dense
+from .basic_layers import Dense, RMSNorm
 
 __all__ = ["MultiHeadAttention"]
 
@@ -45,17 +45,49 @@ class MultiHeadAttention(HybridBlock):
         sustains far higher throughput than three narrow ones (measured
         ~197 vs ~80 TFLOP/s at E=4096 on v5e), and XLA does not fuse the
         three projections itself.
+    num_kv_heads : int or None
+        Grouped-query attention (Ainslie et al., arXiv:2305.13245): K and V
+        have this many heads of the same head dim D, and each serves
+        ``num_heads // num_kv_heads`` query heads.  The K/V heads are
+        repeated before the kernel, so the kernel's work is that of
+        ``num_heads`` full heads; what is saved is the K/V projections.
+        None (the default): as many as ``num_heads``.
+    qk_norm : bool
+        RMSNorm over the head dim of q and of k, each with its own gains
+        (``q_norm_gamma``, ``k_norm_gamma``), before the positions.
+    rotary_base : float or None
+        Rotary positions on q and k over the whole head (rotate-half), with
+        this base.  None: no positions inside the layer (the caller adds
+        learned ones, as the OPT cells do).
+    qk_norm_epsilon : float
+        The epsilon of ``qk_norm``'s RMSNorm.
+
+    With the last four left at their defaults the layer stages the program
+    it staged before they existed (the chip benchmark's ``opt6b7_fused_s2048``
+    and ``opt6b7_fused_adam`` run it so); ``lfm2moe_fused_s8192`` runs 32
+    query heads over 8 K/V heads of 64 with ``qk_norm`` and base 1e6.
     """
 
     def __init__(self, units, num_heads, causal=False, seq_axis=None,
                  use_bias=True, fused_qkv=False, weight_initializer=None,
-                 **kwargs):
+                 num_kv_heads=None, qk_norm=False, rotary_base=None,
+                 qk_norm_epsilon=1e-5, **kwargs):
         super().__init__(**kwargs)
         if units % num_heads:
             raise ValueError("units (%d) must be divisible by num_heads (%d)"
                              % (units, num_heads))
+        num_kv_heads = num_heads if num_kv_heads is None else num_kv_heads
+        if num_heads % num_kv_heads:
+            raise ValueError("num_heads (%d) must be a multiple of "
+                             "num_kv_heads (%d)" % (num_heads, num_kv_heads))
+        if fused_qkv and num_kv_heads != num_heads:
+            raise ValueError("fused_qkv projects three equal widths; "
+                             "grouped K/V heads need fused_qkv=False")
         self._units = units
         self._num_heads = num_heads
+        self._num_kv_heads = num_kv_heads
+        self._rotary_base = rotary_base
+        kv_units = units // num_heads * num_kv_heads
         self._causal = bool(causal)
         self._seq_axis = seq_axis
         self._fused_qkv = bool(fused_qkv)
@@ -69,20 +101,36 @@ class MultiHeadAttention(HybridBlock):
                 self.proj_q = Dense(units, flatten=False, use_bias=use_bias,
                                     weight_initializer=weight_initializer,
                                     prefix="q_")
-                self.proj_k = Dense(units, flatten=False, use_bias=use_bias,
+                self.proj_k = Dense(kv_units, flatten=False,
+                                    use_bias=use_bias,
                                     weight_initializer=weight_initializer,
                                     prefix="k_")
-                self.proj_v = Dense(units, flatten=False, use_bias=use_bias,
+                self.proj_v = Dense(kv_units, flatten=False,
+                                    use_bias=use_bias,
                                     weight_initializer=weight_initializer,
                                     prefix="v_")
             self.proj_out = Dense(units, flatten=False, use_bias=use_bias,
                                   weight_initializer=weight_initializer,
                                   prefix="out_")
+            head_dim = units // num_heads
+            self.q_norm = self.k_norm = None
+            if qk_norm:
+                self.q_norm = RMSNorm(epsilon=qk_norm_epsilon,
+                                      in_channels=head_dim, prefix="q_norm_")
+                self.k_norm = RMSNorm(epsilon=qk_norm_epsilon,
+                                      in_channels=head_dim, prefix="k_norm_")
 
-    def _split_heads(self, F, x, B, S):
-        # (B, S, E) -> (B, H, S, D)
-        x = F.reshape(x, shape=(B, S, self._num_heads, -1))
-        return F.transpose(x, axes=(0, 2, 1, 3))
+    def _split_heads(self, F, x, B, S, heads=None, norm=None,
+                     positions=False):
+        """(B, S, E) -> (B, H, S, D); for q and k (``positions``) with the
+        head's RMSNorm before and the rotary positions after the move."""
+        x = F.reshape(x, shape=(B, S, heads or self._num_heads, -1))
+        if norm is not None:
+            x = norm(x)
+        x = F.transpose(x, axes=(0, 2, 1, 3))
+        if positions and self._rotary_base is not None:
+            x = F._contrib_RotaryEmbedding(x, base=self._rotary_base)
+        return x
 
     def hybrid_forward(self, F, query, key=None, value=None):
         if self._fused_qkv and (key is not None or value is not None):
@@ -96,15 +144,24 @@ class MultiHeadAttention(HybridBlock):
             qkv = self.proj_qkv(query)                   # (B, S, 3E)
             E = self._units
             q = self._split_heads(
-                F, F.slice_axis(qkv, axis=-1, begin=0, end=E), B, S)
+                F, F.slice_axis(qkv, axis=-1, begin=0, end=E), B, S,
+                norm=self.q_norm, positions=True)
             k = self._split_heads(
-                F, F.slice_axis(qkv, axis=-1, begin=E, end=2 * E), B, Sk)
+                F, F.slice_axis(qkv, axis=-1, begin=E, end=2 * E), B, Sk,
+                norm=self.k_norm, positions=True)
             v = self._split_heads(
                 F, F.slice_axis(qkv, axis=-1, begin=2 * E, end=3 * E), B, Sk)
         else:
-            q = self._split_heads(F, self.proj_q(query), B, S)
-            k = self._split_heads(F, self.proj_k(key), B, Sk)
-            v = self._split_heads(F, self.proj_v(value), B, Sk)
+            kv = self._num_kv_heads
+            q = self._split_heads(F, self.proj_q(query), B, S,
+                                  norm=self.q_norm, positions=True)
+            k = self._split_heads(F, self.proj_k(key), B, Sk, kv,
+                                  norm=self.k_norm, positions=True)
+            v = self._split_heads(F, self.proj_v(value), B, Sk, kv)
+            if kv != self._num_heads:
+                # each K/V head serves a run of consecutive query heads
+                k, v = (F.repeat(t, repeats=self._num_heads // kv, axis=1)
+                        for t in (k, v))
         scale = 1.0 / float(np.sqrt(self._units // self._num_heads))
         if self._seq_axis is None:
             out = F._contrib_FlashAttention(q, k, v, causal=self._causal,

@@ -4,9 +4,14 @@ Same layer set and parameter naming as the reference: Sequential,
 HybridSequential, Dense, Activation, Dropout, BatchNorm, InstanceNorm,
 LayerNorm, Embedding, Flatten, Lambda, HybridLambda.  All compute lowers to
 registry ops (XLA kernels); hybridize() compiles whole stacks into one jit.
+
+Beyond the reference's set, for decoder models published since: RMSNorm,
+GatedMLP (SwiGLU) and ShortConv (the gated short convolution of the LFM2
+family).  The chip benchmark's cell ``lfm2moe_fused_s8192`` runs all three.
 """
 from __future__ import annotations
 
+import jax
 import numpy as np
 
 from ..block import Block, HybridBlock
@@ -14,8 +19,8 @@ from ... import initializer
 from ...ndarray import NDArray
 
 __all__ = ["Sequential", "HybridSequential", "Dense", "Activation", "Dropout",
-           "BatchNorm", "InstanceNorm", "LayerNorm", "Embedding", "Flatten",
-           "Lambda", "HybridLambda"]
+           "BatchNorm", "InstanceNorm", "LayerNorm", "RMSNorm", "GatedMLP",
+           "ShortConv", "Embedding", "Flatten", "Lambda", "HybridLambda"]
 
 
 class Sequential(Block):
@@ -315,6 +320,76 @@ class LayerNorm(HybridBlock):
 
     def hybrid_forward(self, F, x, gamma, beta):
         return F.LayerNorm(x, gamma, beta, **self._kwargs)
+
+
+class RMSNorm(HybridBlock):
+    """Root-mean-square normalization over the last axis: x · rsqrt(mean(x²)
+    + epsilon) · gamma, no mean taken off and no shift (op ``RMSNorm``)."""
+
+    def __init__(self, epsilon=1e-5, in_channels=0, **kwargs):
+        super().__init__(**kwargs)
+        self._epsilon = epsilon
+        self.gamma = self.params.get("gamma", shape=(in_channels,),
+                                     init=initializer.One(),
+                                     allow_deferred_init=True)
+
+    def _pre_infer(self, x):
+        if self.gamma.shape == (0,):
+            self.gamma.shape = (x.shape[-1],)
+
+    def hybrid_forward(self, F, x, gamma):
+        return F.RMSNorm(x, gamma, eps=self._epsilon)
+
+
+class GatedMLP(HybridBlock):
+    """SwiGLU feed-forward block W2 (silu(W1 x) ⊙ W3 x), no bias (Shazeer,
+    arXiv:2002.05202).  The children are named as the published checkpoints
+    name them: ``w1_`` the gate, ``w3_`` the up and ``w2_`` the down
+    projection."""
+
+    def __init__(self, units, hidden_size, **kwargs):
+        super().__init__(**kwargs)
+        with self.name_scope():
+            dense = lambda out, inp, name: Dense(       # noqa: E731
+                out, flatten=False, use_bias=False, in_units=inp, prefix=name)
+            self.w1 = dense(hidden_size, units, "w1_")
+            self.w3 = dense(hidden_size, units, "w3_")
+            self.w2 = dense(units, hidden_size, "w2_")
+
+    def hybrid_forward(self, F, x):
+        gate = F.Activation(self.w1(x), act_type="silu")
+        return self.w2(gate * self.w3(x))
+
+
+class ShortConv(HybridBlock):
+    """The gated short convolution of the LFM2 family (Liquid AI,
+    ``Lfm2ShortConv``) on (B, S, units), no bias: ``(b, c, u) =
+    split3(W_in x)``, ``v = causal_conv(b ⊙ u)`` (depthwise, ``kernel``
+    taps, no output row sees a later input row), ``out = W_out (c ⊙ v)``.
+    The two gates and the convolution are staged under the scope
+    ``short_conv``; the projections carry their own names."""
+
+    def __init__(self, units, kernel=3, **kwargs):
+        super().__init__(**kwargs)
+        self._units, self._kernel = units, int(kernel)
+        with self.name_scope():
+            self.in_proj = Dense(3 * units, flatten=False, use_bias=False,
+                                 in_units=units, prefix="in_")
+            self.conv_weight = self.params.get(
+                "conv_weight", shape=(units, self._kernel))
+            self.out_proj = Dense(units, flatten=False, use_bias=False,
+                                  in_units=units, prefix="out_")
+
+    def hybrid_forward(self, F, x, conv_weight):
+        d = self._units
+        bcu = self.in_proj(x)
+        with jax.named_scope("short_conv"):
+            b, c, u = (F.slice_axis(bcu, axis=-1, begin=i * d, end=(i + 1) * d)
+                       for i in range(3))
+            v = F._contrib_CausalConv1D(b * u, conv_weight,
+                                        kernel=self._kernel)
+            gated = c * v
+        return self.out_proj(gated)
 
 
 class Embedding(HybridBlock):

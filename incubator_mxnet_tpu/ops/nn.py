@@ -365,6 +365,56 @@ def _layer_norm(data, gamma, beta, axis=-1, eps=1e-5, output_mean_var=False):
     return out * gamma.reshape(bshape) + beta.reshape(bshape)
 
 
+@register("RMSNorm", num_inputs=2, input_names=("data", "gamma"),
+          finfer_params=lambda ds, p: {"gamma": (ds[-1],)})
+def _rms_norm(data, gamma, eps=1e-5):
+    """x * rsqrt(mean(x^2) + eps) * gamma over the last axis (Zhang &
+    Sennrich, arXiv:1910.07467): no mean is taken off and there is no
+    shift.  The statistic is taken in float32 whatever the data's type, as
+    the published decoder models do; the result has the data's type."""
+    x = data.astype(jnp.float32)
+    y = x * lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+    return (y * gamma.astype(jnp.float32)).astype(data.dtype)
+
+
+@register("_contrib_RotaryEmbedding", num_inputs=1)
+def _rotary_embedding(data, base=10000.0):
+    """Rotary positions (Su et al., arXiv:2104.09864) over the whole last
+    axis of (B, H, S, D), rotate-half convention: channel i pairs with
+    channel i + D/2, position t (row t of S) turns the pair by
+    t * base^(-2i/D).  Angles and the rotation in float32; the result has
+    the data's type."""
+    d, seq = data.shape[-1], data.shape[-2]
+    if d % 2:
+        raise ValueError("rotary positions need an even head dimension, "
+                         "got %d" % d)
+    with jax.named_scope("rope"):
+        inv = 1.0 / (base ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+        ang = jnp.arange(seq, dtype=jnp.float32)[:, None] * inv
+        cos = jnp.concatenate([jnp.cos(ang)] * 2, axis=-1)
+        sin = jnp.concatenate([jnp.sin(ang)] * 2, axis=-1)
+        x = data.astype(jnp.float32)
+        turned = jnp.concatenate([-x[..., d // 2:], x[..., :d // 2]], axis=-1)
+        return (x * cos + turned * sin).astype(data.dtype)
+
+
+@register("_contrib_CausalConv1D", num_inputs=2,
+          input_names=("data", "weight"),
+          finfer_params=lambda ds, p: {"weight": (ds[-1], p["kernel"])})
+def _causal_conv1d(data, weight, kernel=3):
+    """Depthwise causal convolution along the sequence of (B, S, C) with
+    ``weight`` (C, kernel) and no bias: out[t] = sum_j weight[:, j] *
+    data[t - (kernel - 1) + j], rows before the first taken as zero, so no
+    output row sees a later input row.  ``kernel`` shifted multiply-adds:
+    at kernel 3 that is cheaper on the VPU than a convolution's set-up."""
+    if weight.shape != (data.shape[-1], kernel):
+        raise ValueError("weight %s does not fit data %s at kernel %d"
+                         % (weight.shape, data.shape, kernel))
+    seq = data.shape[1]
+    padded = jnp.pad(data, ((0, 0), (kernel - 1, 0), (0, 0)))
+    return sum(padded[:, j:j + seq] * weight[:, j] for j in range(kernel))
+
+
 @register("InstanceNorm", num_inputs=3,
           input_names=("data", "gamma", "beta"),
           finfer_params=lambda ds, p: {"gamma": (ds[1],), "beta": (ds[1],)})
@@ -404,6 +454,8 @@ def _activation(data, act_type="relu"):
         return jax.nn.softplus(data)
     if act_type == "softsign":
         return jax.nn.soft_sign(data)
+    if act_type == "silu":
+        return jax.nn.silu(data)
     raise ValueError("unknown act_type %r" % act_type)
 
 

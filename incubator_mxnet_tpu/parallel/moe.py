@@ -5,7 +5,7 @@ framework, has no EP row to port — "EP via sharded gather/scatter —
 these are *new capabilities*"): a switch-style MoE feed-forward block
 whose stacked expert weights shard over the mesh "ep" axis.
 
-Two dispatch modes behind one module interface:
+Three dispatch modes behind one module interface:
 
 * ``dispatch="dense"`` (default) — einsums over the expert dimension,
   ``combine[n,e] · (x[n,d] @ W[e,d,h])``, with ``e`` sharded.  GSPMD
@@ -16,6 +16,38 @@ Two dispatch modes behind one module interface:
   their expert's device via explicit ``lax.all_to_all`` and back, so
   compute is independent of num_experts and overflow tokens are dropped
   (``last_drop_fraction`` reports the rate on eager calls).
+* ``dispatch="grouped"`` — dropless top-k for a layer that is *told which
+  experts it holds* (``experts_held=(first, count)``, default all): the
+  router scores all ``num_experts``, every (token, expert) assignment that
+  lands on a held expert is kept, whatever the imbalance, and computed as
+  one grouped matrix product over the held experts' stacked weights
+  (``grouped_dot``: the assignments sorted by expert, the rows of absent
+  experts behind them in no group; JAX's Pallas grouped matmul on the TPU,
+  ``jax.lax.ragged_dot`` off it).  The buffer has a row for every
+  assignment (tokens × top_k), so its shapes are static and nothing can
+  overflow.  What absent experts would have added is left out: on the
+  mesh of a deployment their chips add it through the exchange; on one
+  chip the layer computes its share (the chip benchmark's cell
+  ``lfm2moe_fused_s8192``: experts 0–7 of 32, top-4).  Compute ∝ the
+  assignments held, independent of num_experts.
+
+Routing, shared by ``dense`` and ``grouped`` (``_route``): ``router`` says
+how scores are made of the gate's logits (``"softmax"``, or ``"sigmoid"``
+as DeepSeek-V3 and LFM2 score), ``selection_bias`` adds a buffer that is
+not trained to the scores *for the choice only* (auxiliary-loss-free load
+balancing), ``norm_topk`` divides the chosen experts' scores by their sum
+(over all chosen, held or not), ``scaling`` multiplies them.  ``gated``
+experts are W2 (silu(W1 x) ⊙ W3 x); otherwise relu(x W1) W2.
+
+What has run on the chip: ``grouped`` alone, in ``lfm2moe_fused_s8192``
+(PERF.md).  ``dense`` and ``capacity`` are held by the CPU tests
+(``tests/test_model_parallel.py``, ``tests/test_moe_capacity.py``); no
+benchmark cell runs them, and ``capacity`` needs a mesh with an ``ep`` axis.
+On eager calls the layer counts itself: ``graft_moe_dispatch_traces_total
+{path}`` every routed forward traced, and for ``grouped``
+``graft_moe_assignments_total{held}`` and the gauge
+``graft_moe_expert_load_max_over_mean`` (``last_expert_load`` has the
+counts); inside a traced step nothing is read back.
 """
 from __future__ import annotations
 
@@ -31,6 +63,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..gluon.block import HybridBlock
 from ..ndarray import NDArray
+from ..telemetry import metrics as _metrics
 
 __all__ = ["ExpertParallelMoE"]
 
@@ -106,94 +139,368 @@ def switch_moe_apply(x, gw, w1, w2, mesh, ep_axis="ep",
     return fn(x, gw, w1, w2)
 
 
-class ExpertParallelMoE(HybridBlock):
-    """Switch-style top-k MoE FFN (experts sharded over mesh axis "ep").
+_NORM_EPS = 1e-6       # beside the chosen scores' sum, as LFM2 and DeepSeek
 
-    Parameters live stacked: gate (d, E), expert weights (E, d, h) and
-    (E, h, d).  Set ``ep_axis`` to the mesh axis name that shards the
-    expert dimension (annotated on the parameters; DataParallelTrainer
-    places them accordingly).
+
+def _route(x, gw, bias, *, top_k, router, norm_topk, scaling):
+    """``(scores, chosen, weights)`` of the tokens ``x`` (N, d): the scores
+    (N, E) in float32, the ``top_k`` experts chosen a token (N, k) by score
+    plus ``bias`` (a buffer, not differentiated), and the weights their
+    outputs are summed with (N, k): the scores at the chosen experts,
+    without the bias, over their sum where ``norm_topk``, times
+    ``scaling``.  The gradient reaches the gate through the weights."""
+    with jax.named_scope("moe_router"):
+        logits = jnp.dot(x, gw, preferred_element_type=jnp.float32)
+        scores = (jax.nn.sigmoid(logits) if router == "sigmoid"
+                  else jax.nn.softmax(logits, axis=-1))
+        choose = scores if bias is None else scores + lax.stop_gradient(
+            bias.astype(jnp.float32))
+        _, chosen = lax.top_k(lax.stop_gradient(choose), top_k)
+        # the scores at the chosen experts, by a mask and not a gather: the
+        # gather's transpose is a scatter of N·k scalars
+        picked = jax.nn.one_hot(chosen, scores.shape[-1], dtype=scores.dtype)
+        weights = (scores[:, None, :] * picked).sum(-1)
+        if norm_topk:
+            weights = weights / (weights.sum(-1, keepdims=True) + _NORM_EPS)
+        return scores, chosen, weights * scaling
+
+
+# ---------------------------------------------------------------------------
+# the grouped matrix product: rows sorted by group, one weight matrix a group
+# ---------------------------------------------------------------------------
+# On a TPU, for bf16 / f16 rows, JAX's own Pallas grouped matmul (megablox):
+# it visits the row tiles that lie in a group and no others, so its time
+# follows the assignments held and not the buffer, and its calls keep the
+# ``op_name`` path they were staged under (XLA's expansion of
+# ``lax.ragged_dot`` on the TPU multiplies every row of the buffer and names
+# its calls ``ragged-dot-none``, scope and phase lost).  Off the TPU, and
+# for float32 rows (the eager check at ``highest`` precision, which
+# ``lax.ragged_dot`` honours), ``lax.ragged_dot``.
+
+def _row_tile(rows):
+    """256 rows a tile where they divide the buffer, else 128; None where
+    neither does (the kernel wants whole row tiles).  A group of a
+    thousand rows wastes least in tiles of 256: on the v5e at the
+    benchmark's shapes (PERF.md, PR 26) 256 read 0.49-0.65 ms a product
+    where 512 read 0.59-0.63 and 128 0.50-0.98."""
+    return next((t for t in (256, 128) if rows % t == 0), None)
+
+
+def _tile(n):
+    """The tile of a dimension of ``n``: the largest multiple of 128 up to
+    1024 that divides it, else 128 (megablox masks a ragged last tile)."""
+    return next((t for t in range(1024, 0, -128) if n % t == 0), 128)
+
+
+def _whole(k):
+    """The contracted dimension in one tile where it is at most 2048, so
+    that a row tile's product is finished in one grid step."""
+    return k if k <= 2048 and k % 128 == 0 else _tile(k)
+
+
+def _kernel_or_ragged(operands, kernel, ragged):
+    """``kernel`` where the call runs on a TPU in a 16-bit float type over
+    a multiple of 128 rows, else ``ragged``: by the operands' devices, or
+    for tracers by the platform the enclosing program is lowered for."""
+    lhs = operands[0]
+    if lhs.dtype == jnp.float32 or _row_tile(lhs.shape[0]) is None:
+        return ragged(*operands)
+    if any(isinstance(x, jax.core.Tracer) for x in operands):
+        return lax.platform_dependent(*operands, tpu=kernel, default=ragged)
+    if all(d.platform == "tpu" for d in lhs.devices()):
+        return kernel(*operands)
+    return ragged(*operands)
+
+
+def _ragged(lhs, rhs, group_sizes):
+    return lax.ragged_dot(lhs, rhs, group_sizes,
+                          preferred_element_type=lhs.dtype)
+
+
+def _megablox():
+    """The module ``megablox/gmm.py`` (``gmm``, ``tgmm``); the package's
+    attribute of that name is the function its ``__init__`` exports."""
+    import importlib
+    return importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+
+
+# The kernel's branch of ``platform_dependent`` puts ``branch_0_fun`` on the
+# name stack; the scope is set again inside it, so that a reader of the
+# ops' ``op_name`` paths finds the kernels under the scope they belong to.
+_SCOPE = "moe_experts"
+
+
+def _gmm(lhs, rhs, group_sizes):
+    megablox = _megablox()
+    k, n = rhs.shape[1:]
+    with jax.named_scope(_SCOPE):
+        return megablox.gmm(lhs, rhs, group_sizes, lhs.dtype,
+                            (_row_tile(lhs.shape[0]), _whole(k), _tile(n)))
+
+
+def _ragged_bwd(lhs, rhs, group_sizes, g):
+    _, pullback = jax.vjp(lambda a, b: _ragged(a, b, group_sizes), lhs, rhs)
+    return pullback(g)
+
+
+def _gmm_bwd(lhs, rhs, group_sizes, g):
+    megablox = _megablox()
+    k, n = rhs.shape[1:]
+    rows = _row_tile(lhs.shape[0])
+    with jax.named_scope(_SCOPE):
+        d_lhs = megablox.gmm(g, rhs, group_sizes, lhs.dtype,
+                             (rows, _whole(n), _tile(k)), transpose_rhs=True)
+        d_rhs = megablox.tgmm(lhs.swapaxes(0, 1), g, group_sizes, rhs.dtype,
+                              (rows, _tile(k), _tile(n)))
+    return d_lhs, d_rhs
+
+
+@jax.custom_vjp
+def grouped_dot(lhs, rhs, group_sizes):
+    """``lhs`` (A, k), its rows sorted by group, times ``rhs[g]`` (k, n) for
+    the rows of group g: rows [0, sum(group_sizes)) lie in groups, in
+    order; what is left in the rows behind them is unspecified (the kernel
+    does not visit them), so a caller masks them.  f32 accumulation, the
+    result in lhs's dtype."""
+    return _kernel_or_ragged((lhs, rhs, group_sizes), _gmm, _ragged)
+
+
+def _grouped_dot_fwd(lhs, rhs, group_sizes):
+    return grouped_dot(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
+
+
+def _grouped_dot_bwd(res, g):
+    d_lhs, d_rhs = _kernel_or_ragged((*res, g), _gmm_bwd, _ragged_bwd)
+    return d_lhs, d_rhs, None
+
+
+grouped_dot.defvjp(_grouped_dot_fwd, _grouped_dot_bwd)
+
+
+def _experts(xs, w1, w3, w2, group_sizes, in_group):
+    """Rows ``xs`` (A, d), sorted by expert, through the experts whose
+    stacked weights are given.  ``in_group`` (A, 1) marks the rows that lie
+    in a group; every product's rows behind them are set to zero at once:
+    they are whatever the kernel's buffer held, a NaN among it, and
+    zero times that is no zero in the products of the backward pass.
+    Products accumulate in float32 and leave in xs's dtype, as a Dense
+    layer's do; the activation is taken in float32."""
+    def dot(rows, w):
+        return jnp.where(in_group, grouped_dot(rows, w, group_sizes),
+                         jnp.zeros((), rows.dtype))
+    h = dot(xs, w1).astype(jnp.float32)
+    h = (jax.nn.relu(h) if w3 is None
+         else jax.nn.silu(h) * dot(xs, w3).astype(jnp.float32))
+    return dot(h.astype(xs.dtype), w2)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _permute(rows, index, inverse, repeats=1):
+    """``jnp.repeat(rows, repeats, axis=0)[index]`` for a permutation
+    ``index`` whose inverse is ``inverse``.  The cotangent goes back as
+    ``g[inverse]`` (summed over the repeats): a gather both ways, where the
+    transpose of a plain gather is a scatter-add that the TPU's compiler
+    sorts and serialises."""
+    return rows[index // repeats if repeats > 1 else index]
+
+
+def _permute_fwd(rows, index, inverse, repeats):
+    return _permute(rows, index, inverse, repeats), (index, inverse)
+
+
+def _permute_bwd(repeats, res, g):
+    _, inverse = res
+    g = g[inverse]
+    if repeats > 1:
+        g = g.reshape(-1, repeats, g.shape[-1]).astype(jnp.float32).sum(
+            axis=1).astype(g.dtype)
+    return g, None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+def grouped_moe_apply(x, chosen, weights, w1, w3, w2, first):
+    """Dropless dispatch of ``x`` (N, d) to the experts ``first ..
+    first + w1.shape[0] - 1``: ``chosen`` (N, k) and ``weights`` (N, k)
+    from ``_route``.  Returns the weighted sum (N, d) over the held experts
+    among each token's chosen ones, in x's dtype, and the assignments each
+    held expert got, (count,) int32.
+
+    The A = N·k assignments are sorted by held expert, those of absent
+    experts behind them; both ways the rows move by a permutation of A
+    (the sort's, and its inverse), so neither direction of the gradient
+    adds rows serially."""
+    n, k = chosen.shape
+    count = w1.shape[0]
+    with jax.named_scope("moe_dispatch"):
+        local = chosen.reshape(-1) - first                    # (A,)
+        held = (local >= 0) & (local < count)
+        # absent experts sort behind every held one, as group ``count``
+        key = jnp.where(held, local, count).astype(jnp.int32)
+        order = jnp.argsort(key, stable=True)
+        group_sizes = jnp.bincount(key, length=count + 1)[:count].astype(
+            jnp.int32)
+        back = jnp.argsort(order)                 # the inverse permutation
+        in_group = (jnp.arange(n * k) < group_sizes.sum())[:, None]
+        # rows behind the groups are computed by no expert: zeros go in, and
+        # coming back the mask keeps their gradient, which no kernel wrote,
+        # out of the tokens'
+        xs = jnp.where(in_group, _permute(x, order, back, k),
+                       jnp.zeros((), x.dtype))
+    with jax.named_scope(_SCOPE):
+        ys = _experts(xs, w1, w3, w2, group_sizes, in_group)
+    with jax.named_scope("moe_combine"):
+        # back to (token, choice) order, then the weighted sum over a
+        # token's choices: the weights meet the rows where both lie in the
+        # router's order, so no scalar is gathered
+        out = _permute(ys, back, order).reshape(n, k, -1).astype(jnp.float32)
+        out = (out * weights[:, :, None]).sum(axis=1)
+    return out.astype(x.dtype), group_sizes
+
+
+class ExpertParallelMoE(HybridBlock):
+    """Top-k MoE feed-forward block, (N, d) → (N, d), experts stacked and
+    sharded over the mesh axis ``ep_axis``.
+
+    Parameters live stacked: gate (d, num_experts), expert weights
+    (held, d, h) and (held, h, d), with ``gated`` a second (held, d, h);
+    with ``selection_bias`` a buffer ``expert_bias`` (num_experts,) that no
+    gradient reaches.  ``ep_axis`` is annotated on the expert weights;
+    DataParallelTrainer places them accordingly.
+
+    hidden_size : the experts' width h.
+    num_experts : the router's outputs E.
+    top_k : experts chosen a token.
+    dispatch : ``"dense"``, ``"capacity"`` or ``"grouped"`` (module docstring).
+    capacity_factor : slots an expert, ``capacity`` only.
+    experts_held : ``(first, count)``, the experts whose weights this layer
+        has (``grouped`` only; None: all).  The router still scores all E.
+    router, selection_bias, norm_topk, scaling : the routing (module
+        docstring).  ``selection_bias`` is True (a buffer of zeros) or the
+        buffer's initializer.  ``norm_topk=None`` renormalises where top_k > 1: one
+        renormalised expert would weigh 1.0 and starve the router of
+        gradient.
+    gated : SwiGLU experts.
+    in_units : the tokens' width d; 0 (the default) leaves it to the first
+        input.
+
+    The defaults are the layer as it was (softmax, ReLU experts, dense);
+    the chip benchmark's ``lfm2moe_fused_s8192`` runs ``grouped`` with
+    ``experts_held=(0, 8)`` of 32, top-4, sigmoid scores, a selection bias,
+    renormalised, gated.
     """
 
     def __init__(self, hidden_size, num_experts, top_k=1, ep_axis="ep",
-                 dispatch="dense", capacity_factor=1.25,
-                 prefix=None, params=None, **kwargs):
+                 dispatch="dense", capacity_factor=1.25, experts_held=None,
+                 router="softmax", selection_bias=False, norm_topk=None,
+                 scaling=1.0, gated=False, in_units=0, prefix=None,
+                 params=None, **kwargs):
         super().__init__(prefix=prefix, params=params, **kwargs)
         self._hidden = hidden_size
         self._num_experts = num_experts
         self._top_k = int(top_k)
         self._ep_axis = ep_axis
-        if dispatch not in ("dense", "capacity"):
-            raise ValueError("dispatch must be 'dense' or 'capacity', got %r"
-                             % (dispatch,))
-        if dispatch == "capacity" and self._top_k != 1:
+        if dispatch not in ("dense", "capacity", "grouped"):
+            raise ValueError("dispatch must be 'dense', 'capacity' or "
+                             "'grouped', got %r" % (dispatch,))
+        if router not in ("softmax", "sigmoid"):
+            raise ValueError("router must be 'softmax' or 'sigmoid', got %r"
+                             % (router,))
+        plain = (router == "softmax" and not selection_bias and not gated
+                 and norm_topk is None and scaling == 1.0)
+        if dispatch == "capacity" and (self._top_k != 1 or not plain):
             raise ValueError("capacity dispatch implements top-1 Switch "
-                             "routing; use dispatch='dense' for top_k > 1")
+                             "routing over ReLU experts; use "
+                             "dispatch='grouped' or 'dense' for top_k > 1 "
+                             "and the other routings")
+        first, count = experts_held or (0, num_experts)
+        if not (0 <= first and count > 0 and first + count <= num_experts):
+            raise ValueError("experts_held %r is not a run of the %d experts"
+                             % (experts_held, num_experts))
+        if count != num_experts and dispatch != "grouped":
+            raise ValueError("only dispatch='grouped' computes a share of "
+                             "the experts (experts_held)")
+        self.num_experts, self.experts_held = num_experts, (first, count)
         self._dispatch = dispatch
         self._capacity_factor = float(capacity_factor)
+        self._routing = dict(
+            top_k=self._top_k, router=router, scaling=float(scaling),
+            norm_topk=self._top_k > 1 if norm_topk is None else norm_topk)
         self.last_drop_fraction = None  # updated on eager capacity calls
+        self.last_expert_load = self.last_chosen = None   # eager grouped
         self._last_aux = None           # Switch load-balance loss, lazy
         with self.name_scope():
             self.gate_weight = self.params.get(
-                "gate_weight", shape=(0, num_experts),
+                "gate_weight", shape=(in_units, num_experts),
                 allow_deferred_init=True)
             self.expert_w1 = self.params.get(
-                "expert_w1", shape=(num_experts, 0, hidden_size),
+                "expert_w1", shape=(count, in_units, hidden_size),
                 allow_deferred_init=True)
             self.expert_w2 = self.params.get(
-                "expert_w2", shape=(num_experts, hidden_size, 0),
+                "expert_w2", shape=(count, hidden_size, in_units),
                 allow_deferred_init=True)
+            self.expert_w3 = self.params.get(
+                "expert_w3", shape=(count, in_units, hidden_size),
+                allow_deferred_init=True) if gated else None
+            # True: zeros, as a checkpoint's loader expects to overwrite;
+            # else the buffer's initializer
+            self.expert_bias = self.params.get(
+                "expert_bias", shape=(num_experts,), grad_req="null",
+                init="zeros" if selection_bias is True else selection_bias
+            ) if selection_bias else None
         # shard the expert dimension over "ep": each device owns E/ep
-        # experts' weights and their compute
-        self.expert_w1.sharding = (ep_axis, None, None)
-        self.expert_w2.sharding = (ep_axis, None, None)
+        # experts' weights and their compute.  ``ep_axis=None``: the layer
+        # runs on a mesh without that axis (one chip's share, held whole)
+        for w in (self.expert_w1, self.expert_w2, self.expert_w3):
+            if w is not None and ep_axis is not None:
+                w.sharding = (ep_axis, None, None)
 
     def _pre_infer(self, x):
         """Layer-local deferred-shape fill from the live input."""
         d = int(x.shape[-1])
         if self.gate_weight.shape[0] == 0:
+            count = self.expert_w1.shape[0]
             self.gate_weight.shape = (d, self._num_experts)
-            self.expert_w1.shape = (self._num_experts, d, self._hidden)
-            self.expert_w2.shape = (self._num_experts, self._hidden, d)
+            self.expert_w1.shape = (count, d, self._hidden)
+            self.expert_w2.shape = (count, self._hidden, d)
+            if self.expert_w3 is not None:
+                self.expert_w3.shape = (count, d, self._hidden)
 
     def hybrid_forward(self, F, x, gate_weight=None, expert_w1=None,
-                       expert_w2=None):
-        """x: (N, d) → (N, d).  Top-k gating with probability-weighted
-        combine; the expert einsums carry the sharded E dimension."""
-        xv = x._read() if isinstance(x, NDArray) else x
-        gw = gate_weight._read() if isinstance(gate_weight, NDArray) \
-            else gate_weight
-        w1 = expert_w1._read() if isinstance(expert_w1, NDArray) else expert_w1
-        w2 = expert_w2._read() if isinstance(expert_w2, NDArray) else expert_w2
+                       expert_w2=None, expert_w3=None, expert_bias=None):
+        """x: (N, d) → (N, d).  Top-k routing with a score-weighted
+        combine; the expert products carry the sharded E dimension."""
+        xv, gw, w1, w2, w3, bias = (
+            v._read() if isinstance(v, NDArray) else v
+            for v in (x, gate_weight, expert_w1, expert_w2, expert_w3,
+                      expert_bias))
 
         if self._dispatch == "capacity":
             out = self._capacity_forward(xv, gw, w1, w2)
             return NDArray(out) if isinstance(x, NDArray) else out
 
-        logits = xv @ gw                               # (N, E)
-        probs = jax.nn.softmax(logits, axis=-1)
-        if self._top_k == 1:
-            # Switch combine: raw selected probability (renormalising a
-            # single expert would collapse to 1.0 and starve the router
-            # of gradient)
-            onehot = jax.nn.one_hot(jnp.argmax(probs, axis=-1),
-                                    self._num_experts, dtype=xv.dtype)
-            combine = probs * onehot
-        elif self._top_k < self._num_experts:
-            top_vals, _ = jax.lax.top_k(probs, self._top_k)
-            thresh = top_vals[..., -1:]
-            mask = probs >= thresh
-            gated = jnp.where(mask, probs, 0.0)
-            # renormalize over the selected experts (top-k combine)
-            combine = gated / jnp.maximum(
-                gated.sum(-1, keepdims=True), 1e-9)
+        scores, chosen, weights = _route(xv, gw, bias, **self._routing)
+        _metrics.moe_dispatch_trace(self._dispatch)
+        if self._dispatch == "grouped":
+            out, load = grouped_moe_apply(xv, chosen, weights, w1, w3, w2,
+                                          self.experts_held[0])
+            self._store_load(load, chosen)
         else:
-            combine = probs
-        self._store_aux(combine, probs)
-        # per-expert FFN, expert dim sharded: h[e] = relu(x @ W1[e]) @ W2[e]
-        h = jax.nn.relu(jnp.einsum("nd,edh->neh", xv, w1))
-        y = jnp.einsum("neh,ehd->ned", h, w2)
-        out = jnp.einsum("ne,ned->nd", combine, y)
+            # combine[n, e]: the weight of expert e in token n's sum.  At
+            # top-1 the raw selected score (Switch); with every expert
+            # chosen the scores themselves
+            combine = jnp.zeros_like(scores).at[
+                jnp.arange(scores.shape[0])[:, None], chosen].add(weights)
+            # per-expert FFN, expert dim sharded:
+            # h[e] = act(x @ W1[e]) (⊙ x @ W3[e]); y[e] = h[e] @ W2[e]
+            h = jnp.einsum("nd,edh->neh", xv, w1)
+            h = (jax.nn.relu(h) if w3 is None
+                 else jax.nn.silu(h) * jnp.einsum("nd,edh->neh", xv, w3))
+            y = jnp.einsum("neh,ehd->ned", h, w2)
+            out = jnp.einsum("ne,ned->nd", combine.astype(xv.dtype), y)
+        self._store_aux(chosen, scores)
         return NDArray(out) if isinstance(x, NDArray) else out
 
     @property
@@ -207,18 +514,29 @@ class ExpertParallelMoE(HybridBlock):
     def last_aux_loss(self, v):
         self._last_aux = v
 
-    def _store_aux(self, combine, probs):
+    def _store_aux(self, chosen, probs):
         """Stash the load-balance loss on eager calls without forcing a
         device->host sync on the forward path.  Dispatch fraction uses the
-        top-1 choice (GShard convention) so the stat stays meaningful even
-        for soft routing, where every combine entry is nonzero."""
+        first choice (GShard convention) so the stat stays meaningful even
+        for soft routing, where every expert is chosen."""
         if isinstance(probs, jax.core.Tracer):
             return
-        top = jnp.argmax(probs, axis=-1)
-        frac = jnp.mean(jax.nn.one_hot(top, self._num_experts,
+        frac = jnp.mean(jax.nn.one_hot(chosen[:, 0], self._num_experts,
                                        dtype=probs.dtype), axis=0)
         self._last_aux = self._num_experts * jnp.sum(
             frac * jnp.mean(probs, axis=0))
+
+    def _store_load(self, load, chosen):
+        """On eager ``grouped`` calls: the experts chosen a token
+        (``last_chosen``, (N, k)) and the assignments each held expert got
+        (``last_expert_load``), both device arrays, and the counters
+        ``graft_moe_assignments_total{held}`` and gauge
+        ``graft_moe_expert_load_max_over_mean``, which read the load back.
+        Nothing inside a traced step, which must not sync."""
+        if isinstance(load, jax.core.Tracer):
+            return
+        self.last_chosen, self.last_expert_load = chosen, load
+        _metrics.moe_assignments(np.asarray(load), int(chosen.size))
 
     def _capacity_forward(self, xv, gw, w1, w2):
         """Switch all-to-all dispatch over the scoped mesh's ep axis.

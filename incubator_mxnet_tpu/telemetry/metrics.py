@@ -691,6 +691,39 @@ def flash_attention_trace(path):
                       ("path",)).inc(path=path)
 
 
+def moe_dispatch_trace(path):
+    """One trace of ``parallel.moe.ExpertParallelMoE``'s routed forward,
+    labeled by its dispatch mode (``dense`` / ``grouped``): beside
+    ``graft_flash_attention_traces_total``, what a run's reader asks to see
+    which expert path its program staged."""
+    if not enabled():
+        return
+    _REGISTRY.counter("graft_moe_dispatch_traces_total",
+                      "ExpertParallelMoE forward traces by dispatch mode",
+                      ("path",)).inc(path=path)
+
+
+def moe_assignments(load, assignments):
+    """One eager ``grouped`` call: ``load`` is the assignments each held
+    expert got, ``assignments`` all the (token, expert) pairs the router
+    made.  Counts them by whether a held expert got them, and keeps the
+    last call's largest load over the mean load."""
+    if not enabled():
+        return
+    held = int(sum(load))
+    counter = _REGISTRY.counter(
+        "graft_moe_assignments_total",
+        "Routed (token, expert) assignments by whether the layer holds "
+        "the expert", ("held",))
+    counter.inc(held, held="yes")
+    counter.inc(assignments - held, held="no")
+    if held:
+        _REGISTRY.gauge(
+            "graft_moe_expert_load_max_over_mean",
+            "Largest held expert's assignments over the mean, last eager "
+            "grouped call").set(float(max(load)) * len(load) / held)
+
+
 def step_retrace(reason):
     """One compiled-step guard miss, labeled by WHICH guard-key
     component churned (graftguard diff: input-sig / param-meta /

@@ -1,0 +1,449 @@
+"""The layers LFM2-class hybrid MoE decoders need, against plain ``jax.numpy``:
+``gluon.nn.RMSNorm`` / ``GatedMLP`` / ``ShortConv``, ``MultiHeadAttention``
+with grouped K/V heads, QK RMSNorm and rotary positions, and
+``parallel.ExpertParallelMoE(dispatch="grouped")`` holding a share of the
+router's experts (the chip benchmark's cell ``lfm2moe_fused_s8192`` runs
+them all; its whole model is held to its reference in
+``tests/test_lfm2_chip_bench.py``).
+"""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import incubator_mxnet_tpu as mx
+from incubator_mxnet_tpu import gluon, nd
+from incubator_mxnet_tpu.gluon import nn
+from incubator_mxnet_tpu.parallel import (DataParallelTrainer,
+                                          ExpertParallelMoE, make_mesh)
+
+
+def _w(block, name):
+    (p,) = [p for n, p in block.collect_params().items() if n.endswith(name)]
+    return p.data().asnumpy()
+
+
+def _silu(x):
+    return x / (1.0 + np.exp(-x))
+
+
+def _rms(x, g, eps=1e-5):
+    return x / np.sqrt((x * x).mean(-1, keepdims=True) + eps) * g
+
+
+# ---------------------------------------------------------------------------
+# RMSNorm, GatedMLP, ShortConv
+# ---------------------------------------------------------------------------
+
+def test_rms_norm_matches_the_formula_and_keeps_the_dtype():
+    rs = np.random.RandomState(0)
+    x = rs.randn(3, 5, 16).astype(np.float32) * 3 + 1
+    layer = nn.RMSNorm(epsilon=1e-5)
+    layer.initialize()
+    layer(nd.array(x))
+    gain = rs.rand(16).astype(np.float32) + 0.5
+    layer.gamma.set_data(nd.array(gain))
+    np.testing.assert_allclose(layer(nd.array(x)).asnumpy(),
+                               _rms(x, gain), rtol=2e-6, atol=2e-6)
+    # bf16 in, bf16 out, the statistic in float32
+    out = mx.nd.NDArray(jnp.asarray(x, jnp.bfloat16))
+    got = nd.RMSNorm(out, mx.nd.NDArray(jnp.asarray(gain, jnp.bfloat16)))
+    assert got._read().dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(got._read(), np.float32),
+                               _rms(x, gain), rtol=3e-2, atol=3e-2)
+
+
+def test_gated_mlp_is_swiglu():
+    rs = np.random.RandomState(1)
+    x = rs.randn(2, 7, 8).astype(np.float32)
+    mlp = nn.GatedMLP(8, 20)
+    mlp.initialize(mx.init.Normal(0.3))
+    got = mlp(nd.array(x)).asnumpy()
+    w1, w3, w2 = (_w(mlp, "w%d_weight" % i) for i in (1, 3, 2))
+    want = (_silu(x @ w1.T) * (x @ w3.T)) @ w2.T
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    assert sorted(n.rsplit("_", 2)[-2] for n in mlp.collect_params()) == [
+        "w1", "w2", "w3"]                       # no bias anywhere
+
+
+def _short_conv_loop(x, w_in, taps, w_out):
+    """The gated short convolution row by row, as its equations read."""
+    batch, seq, d = x.shape
+    out = np.zeros_like(x)
+    for b in range(batch):
+        bcu = x[b] @ w_in.T
+        gate_b, gate_c, u = bcu[:, :d], bcu[:, d:2 * d], bcu[:, 2 * d:]
+        bu = gate_b * u
+        for t in range(seq):
+            v = np.zeros(d, x.dtype)
+            for j in range(taps.shape[1]):
+                src = t - (taps.shape[1] - 1) + j
+                if src >= 0:
+                    v += taps[:, j] * bu[src]
+            out[b, t] = (gate_c[t] * v) @ w_out.T
+    return out
+
+
+@pytest.mark.parametrize("kernel", [3, 4])
+def test_short_conv_equals_the_explicit_loop(kernel):
+    rs = np.random.RandomState(2)
+    x = rs.randn(2, 9, 6).astype(np.float32)
+    conv = nn.ShortConv(6, kernel=kernel)
+    conv.initialize(mx.init.Normal(0.5))
+    got = conv(nd.array(x)).asnumpy()
+    want = _short_conv_loop(x, _w(conv, "in_weight"),
+                            _w(conv, "conv_weight"), _w(conv, "out_weight"))
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+
+
+def test_short_conv_sees_no_later_row():
+    """Changing input rows from t on leaves every output row before t as it
+    was, bit for bit; and row t itself does change."""
+    rs = np.random.RandomState(3)
+    x = rs.randn(1, 12, 6).astype(np.float32)
+    conv = nn.ShortConv(6)
+    conv.initialize(mx.init.Normal(0.5))
+    base = conv(nd.array(x)).asnumpy()
+    for t in (1, 5, 11):
+        changed = x.copy()
+        changed[:, t:] += rs.randn(*changed[:, t:].shape).astype(np.float32)
+        out = conv(nd.array(changed)).asnumpy()
+        assert np.array_equal(out[:, :t], base[:, :t])
+        assert not np.allclose(out[:, t], base[:, t])
+
+
+def test_causal_conv_op_checks_its_weight():
+    with pytest.raises(Exception):
+        nd._contrib_CausalConv1D(nd.zeros((1, 4, 6)), nd.zeros((6, 2)),
+                                 kernel=3)
+
+
+# ---------------------------------------------------------------------------
+# MultiHeadAttention: grouped K/V heads, QK norm, rotary positions
+# ---------------------------------------------------------------------------
+
+def _rotate_half(x, base):
+    """x (B, S, H, D): position t turns the pair (i, i + D/2) by
+    t * base^(-2i/D)."""
+    seq, dim = x.shape[1], x.shape[-1]
+    freq = base ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    ang = np.arange(seq)[:, None] * freq[None]
+    cos = np.concatenate([np.cos(ang)] * 2, -1)[None, :, None]
+    sin = np.concatenate([np.sin(ang)] * 2, -1)[None, :, None]
+    turned = np.concatenate([-x[..., dim // 2:], x[..., :dim // 2]], -1)
+    return x * cos + turned * sin
+
+
+def _attention_plain(x, attn, heads, kv, qk_norm, base):
+    batch, seq, d = x.shape
+    dim = d // heads
+    q = (x @ _w(attn, "q_weight").T).reshape(batch, seq, heads, dim)
+    k = (x @ _w(attn, "k_weight").T).reshape(batch, seq, kv, dim)
+    v = (x @ _w(attn, "v_weight").T).reshape(batch, seq, kv, dim)
+    if qk_norm:
+        q = _rms(q, _w(attn, "q_norm_gamma"))
+        k = _rms(k, _w(attn, "k_norm_gamma"))
+    if base is not None:
+        q, k = _rotate_half(q, base), _rotate_half(k, base)
+    out = np.zeros((batch, seq, heads, dim))
+    mask = np.tril(np.ones((seq, seq), bool))
+    for h in range(heads):
+        g = h // (heads // kv)              # the K/V head that serves h
+        s = np.einsum("bqd,bkd->bqk", q[:, :, h], k[:, :, g]) / np.sqrt(dim)
+        s = np.where(mask, s, -np.inf)
+        p = np.exp(s - s.max(-1, keepdims=True))
+        p /= p.sum(-1, keepdims=True)
+        out[:, :, h] = np.einsum("bqk,bkd->bqd", p, v[:, :, g])
+    return out.reshape(batch, seq, d) @ _w(attn, "out_weight").T
+
+
+@pytest.mark.parametrize("kv,qk_norm,base", [
+    (2, True, 1e6), (4, False, 10000.0), (1, True, None), (2, False, None)])
+def test_attention_arguments_against_plain_numpy(kv, qk_norm, base):
+    rs = np.random.RandomState(4)
+    x = rs.randn(2, 10, 16).astype(np.float32)
+    attn = nn.MultiHeadAttention(16, 4, causal=True, use_bias=False,
+                                 num_kv_heads=kv, qk_norm=qk_norm,
+                                 rotary_base=base)
+    attn.initialize(mx.init.Normal(0.4))
+    got = attn(nd.array(x)).asnumpy()
+    if qk_norm:
+        for name in ("q_norm_gamma", "k_norm_gamma"):
+            (p,) = [p for n, p in attn.collect_params().items()
+                    if n.endswith(name)]
+            p.set_data(nd.array(rs.rand(4).astype(np.float32) + 0.5))
+        got = attn(nd.array(x)).asnumpy()
+    assert _w(attn, "k_weight").shape == (kv * 4, 16)
+    want = _attention_plain(x.astype(np.float64), attn, 4, kv, qk_norm, base)
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+
+
+def _parent_attention(x, q_w, k_w, v_w, o_w, biases, heads):
+    """``MultiHeadAttention.hybrid_forward`` as it stood before it took
+    grouped heads, QK norm or positions, op for op."""
+    B, S, E = x.shape
+
+    def split(t):
+        return nd.transpose(nd.reshape(t, shape=(B, S, heads, -1)),
+                            axes=(0, 2, 1, 3))
+
+    def dense(t, w, b):
+        return nd.FullyConnected(t, w, b, no_bias=False, num_hidden=E,
+                                 flatten=False)
+
+    q, k, v = (split(dense(x, w, b))
+               for w, b in zip((q_w, k_w, v_w), biases[:3]))
+    out = nd._contrib_FlashAttention(q, k, v, causal=True,
+                                     scale=1.0 / float(np.sqrt(E // heads)))
+    out = nd.reshape(nd.transpose(out, axes=(0, 2, 1, 3)), shape=(B, S, E))
+    return dense(out, o_w, biases[3])
+
+
+def test_attention_with_todays_arguments_is_bit_identical():
+    """The OPT cells' layer: the new arguments left at their defaults give
+    the parent's parameters, by name and shape, and its output bit for
+    bit; hybridized, the same jaxpr as without the new code paths."""
+    rs = np.random.RandomState(5)
+    x = nd.array(rs.randn(2, 128, 32).astype(np.float32))
+    attn = nn.MultiHeadAttention(32, 4, causal=True, use_bias=True,
+                                 prefix="a_")
+    attn.initialize(mx.init.Normal(0.3))
+    got = attn(x).asnumpy()
+    params = attn.collect_params()
+    assert set(params) == {"a_%s_%s" % (p, k) for p in ("q", "k", "v", "out")
+                           for k in ("weight", "bias")}
+    w = [params["a_%s_weight" % n].data() for n in ("q", "k", "v", "out")]
+    b = [params["a_%s_bias" % n].data() for n in ("q", "k", "v", "out")]
+    want = _parent_attention(x, *w, b, heads=4).asnumpy()
+    assert np.array_equal(got, want)
+    assert attn.q_norm is None and attn.k_norm is None
+
+
+def test_attention_rejects_heads_that_do_not_divide():
+    with pytest.raises(ValueError):
+        nn.MultiHeadAttention(16, 4, num_kv_heads=3)
+    with pytest.raises(ValueError):
+        nn.MultiHeadAttention(16, 4, num_kv_heads=2, fused_qkv=True)
+
+
+# ---------------------------------------------------------------------------
+# ExpertParallelMoE(dispatch="grouped")
+# ---------------------------------------------------------------------------
+
+ROUTING = dict(hidden_size=12, num_experts=8, top_k=3, router="sigmoid",
+               selection_bias=True, gated=True)
+
+
+def _moe_pair(dispatch, x, rs, **kw):
+    """A dense layer with seeded weights and a non-zero selection bias, and
+    a layer of ``dispatch`` sharing its parameters."""
+    mx.random.seed(11)
+    kw = dict(ROUTING, **kw)
+    dense = ExpertParallelMoE(dispatch="dense", prefix="m_", **kw)
+    dense.initialize(mx.init.Normal(0.5))
+    dense(x)
+    if dense.expert_bias is not None:
+        dense.expert_bias.set_data(nd.array(
+            rs.randn(kw["num_experts"]).astype(np.float32) * 0.3))
+    other = ExpertParallelMoE(dispatch=dispatch, prefix="m_",
+                              params=dense.collect_params(), **kw)
+    return dense, other
+
+
+def _moe_plain(x, layer, first=0, count=None):
+    """The routed layer by its equations, every expert for every token:
+    sigmoid scores, the top-k of score + bias, weights the scores at the
+    chosen over (their sum + 1e-6); experts ``first .. first + count - 1``
+    of the layer's stacked weights are summed."""
+    gw, bias = _w(layer, "gate_weight"), _w(layer, "expert_bias")
+    w1, w3, w2 = (_w(layer, "expert_w%d" % i) for i in (1, 3, 2))
+    count = w1.shape[0] if count is None else count
+    k = layer._top_k
+    scores = 1.0 / (1.0 + np.exp(-(x @ gw)))
+    chosen = np.argsort(-(scores + bias), axis=-1, kind="stable")[:, :k]
+    out = np.zeros_like(x)
+    for n in range(x.shape[0]):
+        total = scores[n, chosen[n]].sum() + 1e-6
+        for e in chosen[n]:
+            if first <= e < first + count:
+                y = (_silu(x[n] @ w1[e]) * (x[n] @ w3[e])) @ w2[e]
+                out[n] += scores[n, e] / total * y
+    return out
+
+
+def test_grouped_equals_dense_for_top_k():
+    rs = np.random.RandomState(6)
+    x = nd.array(rs.randn(40, 10).astype(np.float32))
+    dense, grouped = _moe_pair("grouped", x, rs)
+    want = _moe_plain(x.asnumpy(), dense)
+    np.testing.assert_allclose(dense(x).asnumpy(), want, rtol=1e-4,
+                               atol=1e-5)
+    np.testing.assert_allclose(grouped(x).asnumpy(), want, rtol=1e-4,
+                               atol=1e-5)
+    assert int(np.asarray(grouped.last_expert_load).sum()) == 40 * 3
+    # softmax scores, ReLU experts, no bias: the layer as it was
+    dense, grouped = _moe_pair("grouped", x, rs, router="softmax",
+                               selection_bias=False, gated=False)
+    np.testing.assert_allclose(grouped(x).asnumpy(), dense(x).asnumpy(),
+                               rtol=1e-4, atol=1e-5)
+
+
+def _share(full, first, count, x):
+    """A grouped layer holding experts ``first .. first + count - 1`` of
+    ``full``'s, the router and its bias whole."""
+    share = ExpertParallelMoE(dispatch="grouped", experts_held=(first, count),
+                              prefix="s%d_" % first, **ROUTING)
+    share.initialize(mx.init.Normal(0.5))
+    share(x)
+    share.gate_weight.set_data(full.gate_weight.data())
+    share.expert_bias.set_data(full.expert_bias.data())
+    for name in ("expert_w1", "expert_w2", "expert_w3"):
+        getattr(share, name).set_data(
+            getattr(full, name).data()[first:first + count])
+    return share
+
+
+def test_the_four_shares_add_up_to_the_uncut_layer():
+    """A residual layer y = x + FFN(x) cut into four expert-parallel shares
+    of two experts: the shares' routed parts, with the residual counted
+    once, are the uncut layer; and each share is what the equations give
+    for its experts alone."""
+    rs = np.random.RandomState(7)
+    x = nd.array(rs.randn(48, 10).astype(np.float32))
+    full, _ = _moe_pair("grouped", x, rs)
+    uncut = x.asnumpy() + _moe_plain(x.asnumpy(), full)
+    total = x.asnumpy().copy()
+    assignments = 0
+    for first in range(0, 8, 2):
+        share = _share(full, first, 2, x)
+        part = share(x).asnumpy()
+        np.testing.assert_allclose(
+            part, _moe_plain(x.asnumpy(), full, first, 2), rtol=1e-4,
+            atol=1e-5)
+        total += part
+        assignments += int(np.asarray(share.last_expert_load).sum())
+    np.testing.assert_allclose(total, uncut, rtol=1e-4, atol=1e-5)
+    assert assignments == 48 * 3            # every assignment on one share
+
+
+def test_grouped_drops_nothing_under_skew():
+    """A router that sends every token to the same held expert first: that
+    expert gets as many rows as there are tokens, eight times a balanced
+    share, and the result is still the equations'."""
+    rs = np.random.RandomState(8)
+    x = nd.array(np.abs(rs.randn(64, 10)).astype(np.float32))
+    full, _ = _moe_pair("grouped", x, rs)
+    share = _share(full, 2, 2, x)
+    gate = np.zeros((10, 8), np.float32)
+    gate[:, 3] = 5.0                        # positive inputs: expert 3 wins
+    for layer in (full, share):
+        layer.gate_weight.set_data(nd.array(gate))
+    got = share(x).asnumpy()
+    load = np.asarray(share.last_expert_load)
+    assert load[1] == 64                    # expert 3 is the share's second
+    np.testing.assert_allclose(got, _moe_plain(x.asnumpy(), full, 2, 2),
+                               rtol=1e-4, atol=1e-5)
+    # and a share none of whose experts is chosen adds exactly nothing
+    gate[:, 3] = 0.0
+    gate[:, [0, 1, 7]] = 5.0
+    bias = np.zeros(8, np.float32)
+    for layer in (full, share):
+        layer.gate_weight.set_data(nd.array(gate))
+        layer.expert_bias.set_data(nd.array(bias))
+    assert np.array_equal(share(x).asnumpy(), np.zeros((64, 10), np.float32))
+    assert int(np.asarray(share.last_expert_load).sum()) == 0
+
+
+def test_grouped_gradients_match_dense_and_spare_the_bias():
+    """Through the fused step's own route (``jax.vjp`` over the layer):
+    the grouped dispatch's gradients are the dense path's, the router's
+    included, and the selection bias gets none."""
+    from incubator_mxnet_tpu.gluon.block import functionalize
+    rs = np.random.RandomState(9)
+    x = nd.array(rs.randn(32, 10).astype(np.float32))
+    dense, grouped = _moe_pair("grouped", x, rs)
+    grads = {}
+    for name, layer in (("dense", dense), ("grouped", grouped)):
+        fn, params = functionalize(layer, x, train=True)
+
+        def loss(p, inp):
+            out = fn(p, inp)
+            out = out[0] if isinstance(out, (tuple, list)) else out
+            return jnp.sum(jnp.square(out))
+        grads[name] = jax.grad(loss, argnums=(0, 1))(params, x._read())
+    for (a, ga), (b, gb) in zip(sorted(grads["dense"][0].items()),
+                                sorted(grads["grouped"][0].items())):
+        assert a == b
+        np.testing.assert_allclose(np.asarray(ga), np.asarray(gb), rtol=2e-4,
+                                   atol=2e-5, err_msg=a)
+        if a.endswith("expert_bias"):
+            assert not np.asarray(ga).any() and not np.asarray(gb).any()
+        else:
+            assert np.asarray(gb).any(), a
+    np.testing.assert_allclose(np.asarray(grads["dense"][1]),
+                               np.asarray(grads["grouped"][1]), rtol=2e-4,
+                               atol=2e-5)
+
+
+def test_grouped_counters_and_arguments():
+    rs = np.random.RandomState(10)
+    x = nd.array(rs.randn(16, 10).astype(np.float32))
+    registry = mx.telemetry.registry()
+
+    def count(name, **labels):
+        samples = registry.snapshot().get(name, {"samples": []})["samples"]
+        return sum(s["value"] for s in samples if s["labels"] == labels)
+
+    before = {k: count("graft_moe_assignments_total", held=k)
+              for k in ("yes", "no")}
+    traces = count("graft_moe_dispatch_traces_total", path="grouped")
+    full, _ = _moe_pair("grouped", x, rs)
+    share = _share(full, 0, 2, x)
+    share(x)
+    held = int(np.asarray(share.last_expert_load).sum())
+    assert count("graft_moe_dispatch_traces_total", path="grouped") > traces
+    # _share's shape pass and this call: two calls of the share
+    assert count("graft_moe_assignments_total", held="yes") - before[
+        "yes"] >= held
+    assert count("graft_moe_assignments_total", held="no") - before[
+        "no"] >= 16 * 3 - held
+    assert registry.snapshot()[
+        "graft_moe_expert_load_max_over_mean"]["samples"][0]["value"] >= 1.0
+    with pytest.raises(ValueError):
+        ExpertParallelMoE(4, 8, dispatch="dense", experts_held=(0, 2))
+    with pytest.raises(ValueError):
+        ExpertParallelMoE(4, 8, dispatch="grouped", experts_held=(6, 4))
+    with pytest.raises(ValueError):
+        ExpertParallelMoE(4, 8, dispatch="capacity", router="sigmoid")
+    with pytest.raises(ValueError):
+        ExpertParallelMoE(4, 8, router="tanh")
+
+
+def test_grouped_trains_in_the_fused_step_in_bf16():
+    """A share of the experts under ``DataParallelTrainer`` in bf16 over a
+    one-device mesh without an ``ep`` axis: the loss falls and the
+    selection bias (a buffer) stays where it was."""
+    rs = np.random.RandomState(12)
+    x = rs.randn(32, 8).astype(np.float32)
+    y = (rs.rand(32) * 3).astype(np.float32)
+    mx.random.seed(13)
+    net = gluon.nn.HybridSequential()
+    net.add(ExpertParallelMoE(16, 8, top_k=2, dispatch="grouped",
+                              experts_held=(2, 4), router="sigmoid",
+                              selection_bias=mx.init.Normal(0.1),
+                              gated=True, ep_axis=None, in_units=8))
+    net.add(gluon.nn.Dense(3, in_units=8))
+    net.initialize(mx.init.Xavier())
+    bias = net[0].expert_bias.data().asnumpy().copy()
+    assert bias.any()
+    trainer = DataParallelTrainer(
+        net, gluon.loss.SoftmaxCrossEntropyLoss(), optimizer="adam",
+        optimizer_params={"learning_rate": 0.01},
+        mesh=make_mesh({"dp": 1}, jax.devices()[:1]), dtype="bfloat16")
+    first = float(np.asarray(trainer.step(nd.array(x), nd.array(y))))
+    for _ in range(30):
+        last = trainer.step(nd.array(x), nd.array(y))
+    assert float(np.asarray(last)) < first
+    assert np.array_equal(
+        np.asarray(trainer._params[net[0].expert_bias.name]), bias)
