@@ -14,7 +14,11 @@ made from a seed:
 4. lm         — the decoder LM of ``bench_transformer.py`` at its published
                 width, 3 fused train steps (the kernel under
                 ``value_and_grad`` + donation in one program)
-5. cache      — a second ResNet trainer after ``jax.clear_caches()``: its
+5. dropout    — one key gives one dropout mask in every program on the
+                chip's own bit generator: eagerly, under ``jit`` inside
+                other work, and in the Gluon loop's forward and backward
+                programs (``CachedOp`` runs the forward again in the second)
+6. cache      — a second ResNet trainer after ``jax.clear_caches()``: its
                 step must come out of the persistent compile cache
 
 Any failed check raises: the exit code is non-zero and no result line is
@@ -25,7 +29,7 @@ no others, the device as JAX reports it:
 
     python chip_smoke.py              one chip
     python chip_smoke.py --chips 4    phase 2 over a dp=4 mesh, global b1024
-    python chip_smoke.py --rehearse   CPU only: toy sizes, phases 3 and 4
+    python chip_smoke.py --rehearse   CPU only: toy sizes, phases 3, 4 and 5
                                       skipped by name, "rehearsal": true
 
 Times printed here are smoke readings from a handful of steps, not
@@ -176,7 +180,7 @@ def phase_resnet(mx, jax, devices, size, log, n_steps=5):
     t0 = time.perf_counter()
     # A fixed prefix, not Gluon's per-process name counter: parameter
     # names are the keys of the step's argument trees, JAX writes them
-    # into the program, and phase 5's second instance must be the same
+    # into the program, and phase 6's second instance must be the same
     # program to be found in the cache.
     net = getattr(vision, size["model"])(classes=size["classes"],
                                          thumbnail=size["thumbnail"],
@@ -399,6 +403,50 @@ def phase_lm(mx, jax, devices, size, log, n_steps=3):
     return facts
 
 
+def phase_dropout(mx, jax, shape=(4, 2048, 4096), p=0.1):
+    """``RNG_DEFAULT`` is the backend's own algorithm: that the same key
+    and shape give the same mask in two different programs is checked
+    here, on the chip, at the OPT cell's shape (the tier-1 tests hold it
+    on the CPU)."""
+    import jax.numpy as jnp
+    from incubator_mxnet_tpu.gluon import nn
+    from incubator_mxnet_tpu.ops.registry import get_op
+
+    dropout = functools.partial(get_op("Dropout").fcompute, p=p,
+                                is_train=True)
+    x = jnp.ones(shape, jnp.bfloat16)
+    key = jax.random.PRNGKey(27)
+    eager = np.asarray(dropout(x, rng=key) != 0)
+    # a second program: the draw between other work, so XLA fuses it anew
+    staged = jax.jit(lambda x, key: jnp.tanh(dropout(x * 2, rng=key)) + 1)
+    check((eager == np.asarray(staged(x, key) != 1)).all(),
+          "one key drew two masks, eagerly and inside a jitted program")
+    share = float(eager.mean())
+    sigma = (p * (1 - p) / eager.size) ** 0.5
+    check(abs(share - (1 - p)) < 4 * sigma,
+          "dropout kept %.6f of the elements, not %.2f" % (share, 1 - p))
+
+    # the Gluon loop: cachedop_backward runs the forward again with the
+    # step's key and must meet cachedop_forward's mask
+    net = nn.HybridSequential()
+    net.add(nn.Dropout(p))
+    net.initialize()
+    net.hybridize()
+    nd = mx.nd.array(np.ones(shape[1:], np.float32))
+    nd.attach_grad()
+    with mx.autograd.record():
+        out = net(nd)
+    out.backward()
+    out, grad = out.asnumpy(), nd.grad.asnumpy()
+    check(((out != 0) == (grad != 0)).all() and (out == 0).any(),
+          "cachedop_backward drew another mask than cachedop_forward")
+    facts = {"shape": list(shape), "p": p, "kept_share": round(share, 6),
+             "eager_equals_jitted": True,
+             "cachedop_backward_meets_forward": True}
+    print("[5 dropout] %s" % json.dumps(facts))
+    return facts
+
+
 def phase_cache(mx, jax, devices, size, log, first):
     """The same ResNet step again, as a new process would meet it: JAX's
     in-memory caches dropped, a new net and trainer, the persistent cache
@@ -421,7 +469,7 @@ def phase_cache(mx, jax, devices, size, log, first):
         "second_cache_retrieval_s": warm["cache_retrieval_s"],
         "second_shape_pass_compiles": second["shape_pass_compiles"],
     }
-    print("[5 cache] %s" % json.dumps(facts))
+    print("[6 cache] %s" % json.dumps(facts))
     return facts
 
 
@@ -449,10 +497,11 @@ def main(argv=None):
     check(args.rehearse or min(peaks) >= 0.75 * max(peaks),
           "per-device peak memory differs by more than 25%%: %r" % peaks)
     if args.rehearse:
-        flash = lm = SKIPPED
+        flash = lm = dropout = SKIPPED
     else:
         flash = phase_flash(mx, jax, sizes["flash"])
         lm = phase_lm(mx, jax, devices, sizes["lm"], log)
+        dropout = phase_dropout(mx, jax)
     cache = phase_cache(mx, jax, devices, sizes["resnet"], log, resnet)
 
     def brief(facts):
@@ -474,7 +523,8 @@ def main(argv=None):
             "compile_cache_dir", "compile_cache_dir_from_env",
             "compile_cache_min_compile_secs")},
         "resnet": brief(resnet), "flash_attention": flash,
-        "transformer_lm": brief(lm), "second_compile": brief(cache),
+        "transformer_lm": brief(lm), "dropout_masks": dropout,
+        "second_compile": brief(cache),
     }))
     # the last line: exactly these keys, for whoever runs the script
     print(json.dumps(verdict))

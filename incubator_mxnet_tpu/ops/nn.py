@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..telemetry import metrics as _metrics
 from .registry import register
 
 
@@ -502,6 +503,30 @@ def _softmax_activation(data, mode="instance"):
     return jax.nn.softmax(data.reshape(data.shape[0], -1), axis=-1).reshape(data.shape)
 
 
+def inverted_dropout(data, p, mask_shape, rng, op):
+    """``data`` under a fresh dropout mask of ``mask_shape`` (which broadcasts
+    to it): a kept element is ``data / (1 - p)`` in ``data``'s dtype, the
+    rest 0.  Every dropout mask in the package is drawn here.
+
+    ``rng`` is the framework's raw ``uint32[2]`` key.  Its threefry gives the
+    four words of state of the backend's bit generator (XLA's
+    ``RngBitGenerator``: on the TPU the chip's own), which draws 32 bits an
+    element in one op; an element is kept where its word is under
+    ``floor((1 - p) * 2**32)``, so the share kept is exact to 2**-32 and no
+    float is made.  (A ``jax.random.bernoulli`` mask is a threefry of the
+    mask's size, which XLA computes again inside every fusion that reads
+    it.)  The same key and shape give the same mask in any program of one
+    backend and device layout, not across backends (docs/robustness.md).
+    ``op`` labels the trace in ``graft_dropout_mask_traces_total``."""
+    _metrics.dropout_mask_trace(op)
+    keep = 1.0 - p
+    with jax.named_scope("dropout"):
+        state = jax.random.bits(rng, (4,), jnp.uint32)
+        _, bits = lax.rng_bit_generator(state, mask_shape, dtype=jnp.uint32)
+        mask = bits < jnp.uint32(int(keep * 2 ** 32))
+    return jnp.where(mask, data / keep, jnp.zeros((), data.dtype))
+
+
 @register("Dropout", num_inputs=1, needs_rng=True, takes_is_train=True)
 def _dropout(data, p=0.5, mode="training", axes=(), rng=None, is_train=False):
     """Inverted dropout (ref: src/operator/nn/dropout.cc)."""
@@ -510,9 +535,7 @@ def _dropout(data, p=0.5, mode="training", axes=(), rng=None, is_train=False):
     shape = data.shape
     if axes:
         shape = tuple(1 if i in axes else s for i, s in enumerate(shape))
-    keep = 1.0 - p
-    mask = jax.random.bernoulli(rng, keep, shape)
-    return jnp.where(mask, data / keep, jnp.zeros((), data.dtype))
+    return inverted_dropout(data, p, shape, rng, "Dropout")
 
 
 # ---------------------------------------------------------------------------

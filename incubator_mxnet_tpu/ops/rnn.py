@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .nn import inverted_dropout
 from .registry import register
 
 _GATES = {"rnn_relu": 1, "rnn_tanh": 1, "lstm": 4, "gru": 3}
@@ -164,9 +165,7 @@ def _rnn(*inputs, state_size=0, num_layers=1, bidirectional=False, mode="lstm",
         x = outs[0] if dirs == 1 else jnp.concatenate(outs, axis=-1)
         if is_train and p > 0.0 and layer < num_layers - 1:
             k, sub = jax.random.split(k)
-            keep = 1.0 - p
-            mask = jax.random.bernoulli(sub, keep, x.shape)
-            x = jnp.where(mask, x / keep, jnp.zeros((), x.dtype))
+            x = inverted_dropout(x, p, x.shape, sub, "RNN")
     return x, jnp.stack(hy), jnp.stack(cy)
 
 

@@ -703,6 +703,23 @@ def moe_dispatch_trace(path):
                       ("path",)).inc(path=path)
 
 
+def dropout_mask_trace(op):
+    """One trace of ``ops.nn.inverted_dropout``, the function that draws
+    every dropout mask, labeled by the operator that asked (``Dropout`` /
+    ``RNN``): beside the flash and expert counters, what a run's reader
+    asks to see that its program staged a draw and not none.  Sites of one
+    shape, dtype and ``p`` share a traced body (the operator's ``jit``
+    cache), so this counts the distinct sites a process traced; a
+    program's own count is its ``rng-bit-generator`` ops under the scope
+    ``dropout``."""
+    if not enabled():
+        return
+    _REGISTRY.counter("graft_dropout_mask_traces_total",
+                      "Traces of the function that draws dropout masks "
+                      "from the backend's bit generator, by operator",
+                      ("op",)).inc(op=op)
+
+
 def moe_assignments(load, assignments):
     """One eager ``grouped`` call: ``load`` is the assignments each held
     expert got, ``assignments`` all the (token, expert) pairs the router

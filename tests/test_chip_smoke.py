@@ -54,6 +54,8 @@ def test_rehearsal_runs_and_caches_where_the_environment_says(tmp_path):
     # the Pallas phases are named, and named as skipped
     assert out["flash_attention"] == "skipped: no chip"
     assert out["transformer_lm"] == "skipped: no chip"
+    # and the on-chip check of the bit generator's masks with them
+    assert out["dropout_masks"] == "skipped: no chip"
     assert out["resnet"]["losses"][-1] < out["resnet"]["losses"][0]
     assert out["second_compile"]["second_step_from_cache"] is True
     # the cache went where the environment said and nowhere else
