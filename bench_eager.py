@@ -37,21 +37,11 @@ call is timed (the backward is identical either way), the two runs are
 asserted bit-identical, and the measured overlap ratio
 (``graft_trainer_overlap_ratio``) is reported.
 
-Round 8 (graftlens) adds ``lens_overhead_pct``: a real train loop
-(record scope, backward, kvstore collectives, step journal) timed with
-the per-step attribution engine on vs off — same < 2% bar as the flight
-recorder.
-
 Round 10 (grafttsan) adds ``tsan_overhead_pct``: the same real train
 loop (handles issued/waited, scheduler regions, NDArray writes — every
 instrumented site firing) with the happens-before race detector on vs
 off.  The detector is DEFAULT-OFF, so the number is informational; the
 enabled-mode design bar is < 10%.
-
-Round 12 (graftpulse) adds ``pulse_overhead_pct``: a bulked ASYNC train
-loop (no sync mode — flush-boundary reaper enqueues and mem-timeline
-probes firing) with the async device-time ledger on vs off, each round
-draining the reaper inside its own window.  Same < 2% bar as the lens.
 
 Round 19 (graftzero) adds ``quant_step_*`` / ``zero_step_*``: the same
 64-param dist_sync loop with the block-scaled quantized bucket wire
@@ -657,132 +647,11 @@ def _zero_step_child(steps=4, n_params=24, shape=(16, 16),
     }))
 
 
-def _lens_overhead_bench(iters=20, repeats=4, n_params=8, shape=(16, 16)):
-    """graftlens steady-state cost on a real train loop (record scope,
-    backward, kvstore collectives, step journal — every lens source
-    firing): the same loop timed with the lens ON (the default) vs
-    forced OFF, interleaved min-of-rounds with the mode order ALTERNATED
-    per round (the loop keeps warming for dozens of iterations on CPU,
-    so a fixed order books the drift to whichever mode runs first).
-    The acceptance bar is < 2% (ISSUE 8), same contract as
-    blackbox_overhead_pct."""
-    import jax.numpy as jnp
-    import incubator_mxnet_tpu as mx
-    from incubator_mxnet_tpu import autograd, gluon
-    from incubator_mxnet_tpu.telemetry import lens
-
-    rs = np.random.RandomState(0)
-    ps = []
-    for k in range(n_params):
-        p = gluon.Parameter("lob%d" % k, shape=shape)
-        p.initialize(ctx=mx.cpu())
-        p.data()._write(jnp.asarray(rs.randn(*shape).astype(np.float32)))
-        ps.append(p)
-    trainer = gluon.Trainer(ps, "sgd", {"learning_rate": 0.01},
-                            kvstore=mx.kv.create("local"))
-
-    def loop():
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            with autograd.record():
-                loss = None
-                for p in ps:
-                    y = (p.data() * p.data()).sum()
-                    loss = y if loss is None else loss + y
-            loss.backward()
-            trainer.step(1)
-        ps[-1].data().asnumpy()
-        return time.perf_counter() - t0
-
-    for _ in range(3):
-        loop()                                   # warm compiles + plan
-    best = {True: float("inf"), False: float("inf")}
-    prev = lens._enabled_override
-    try:
-        for r in range(repeats):
-            order = (False, True) if r % 2 == 0 else (True, False)
-            for state in order:
-                lens.set_enabled(state)
-                best[state] = min(best[state], loop())
-    finally:
-        lens.set_enabled(prev)
-    pct = (best[True] - best[False]) / best[False] * 100.0
-    return {
-        "lens_on_step_ms": round(best[True] / iters * 1e3, 3),
-        "lens_off_step_ms": round(best[False] / iters * 1e3, 3),
-        "lens_overhead_pct": round(pct, 2),
-    }
-
-
-def _pulse_overhead_bench(iters=50, repeats=6, n_params=8, shape=(16, 16)):
-    """graftpulse async-ledger cost on a real bulked ASYNC train loop
-    (flush-boundary reaper enqueues + mem-timeline probes firing — the
-    graftpulse dispatch-site surface): the same loop timed with the
-    pulse ledger ON (the default) vs forced OFF, lens on throughout,
-    interleaved min-of-rounds with alternating mode order like the lens
-    bench.  Each timed round drains the reaper INSIDE its window so the
-    on-mode pays its full cost (a pending queue crossing into the off
-    round would book the on-mode's work to the off-mode's clock).  The
-    acceptance bar is < 2% (ISSUE 12)."""
-    import jax.numpy as jnp
-    import incubator_mxnet_tpu as mx
-    from incubator_mxnet_tpu import autograd, gluon
-    from incubator_mxnet_tpu.telemetry import lens
-
-    rs = np.random.RandomState(0)
-    ps = []
-    for k in range(n_params):
-        p = gluon.Parameter("pob%d" % k, shape=shape)
-        p.initialize(ctx=mx.cpu())
-        p.data()._write(jnp.asarray(rs.randn(*shape).astype(np.float32)))
-        ps.append(p)
-    trainer = gluon.Trainer(ps, "sgd", {"learning_rate": 0.01},
-                            kvstore=mx.kv.create("local"))
-
-    def loop():
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            with mx.engine.bulk(64):
-                with autograd.record():
-                    loss = None
-                    for p in ps:
-                        y = (p.data() * p.data()).sum()
-                        loss = y if loss is None else loss + y
-                loss.backward()
-            trainer.step(1)
-        ps[-1].data().asnumpy()
-        lens.pulse_drain(10.0)
-        return time.perf_counter() - t0
-
-    prev_lens = lens._enabled_override
-    prev_pulse = lens._pulse_override
-    lens.set_enabled(True)
-    try:
-        for _ in range(3):
-            loop()                               # warm compiles + plan
-        best = {True: float("inf"), False: float("inf")}
-        for r in range(repeats):
-            order = (False, True) if r % 2 == 0 else (True, False)
-            for state in order:
-                lens.set_pulse(state)
-                best[state] = min(best[state], loop())
-    finally:
-        lens.set_pulse(prev_pulse)
-        lens.set_enabled(prev_lens)
-        lens.reset()
-    pct = (best[True] - best[False]) / best[False] * 100.0
-    return {
-        "pulse_on_step_ms": round(best[True] / iters * 1e3, 3),
-        "pulse_off_step_ms": round(best[False] / iters * 1e3, 3),
-        "pulse_overhead_pct": round(pct, 2),
-    }
-
-
 def _tsan_overhead_bench(iters=20, repeats=4, n_params=8, shape=(16, 16)):
     """grafttsan enabled-mode cost on a real overlapped train loop —
     async reduce handles (issue/settle + value registry), scheduler
     regions, and the NDArray._write hook all firing.  Interleaved
-    min-of-rounds with alternating mode order, like the lens bench.
+    min-of-rounds with alternating mode order.
     Default-off means the bar is informational (<10% when enabled)."""
     import jax.numpy as jnp
     import incubator_mxnet_tpu as mx
@@ -1120,8 +989,6 @@ def smoke():
         "ZeRO-1 shard fraction %.3f not ~1/N" \
         % res["zero_state_shard_fraction"]
     res.update(_blackbox_overhead_bench(iters=10, repeats=3))
-    res.update(_lens_overhead_bench(iters=10, repeats=3))
-    res.update(_pulse_overhead_bench(iters=10, repeats=3))
     res.update(_tsan_overhead_bench(iters=8, repeats=2))
     res.update(_armor_overhead_bench(iters=25, repeats=2))
     res.update(_compile_check_overhead_bench(iters=50, repeats=9))
@@ -1297,12 +1164,6 @@ def main():
     # -- graftwatch: flight-recorder overhead on the same 64-op chain ----
     blackbox_overhead = _blackbox_overhead_bench()
 
-    # -- graftlens: attribution overhead on a real train loop (round 8) --
-    lens_overhead = _lens_overhead_bench()
-
-    # -- graftpulse: async device-ledger overhead (round 12) -------------
-    pulse_overhead = _pulse_overhead_bench()
-
     # -- grafttsan: race-detector overhead, enabled mode (round 10) ------
     tsan_overhead = _tsan_overhead_bench()
 
@@ -1317,8 +1178,6 @@ def main():
         **quant,
         **zero,
         **blackbox_overhead,
-        **lens_overhead,
-        **pulse_overhead,
         **tsan_overhead,
         **elastic_overhead,
         "metric": "eager_small_op_dispatch",

@@ -137,7 +137,7 @@ GUARD_COMPONENTS = ("input-sig", "input-fmt", "param-set", "param-meta",
 
 
 # ---------------------------------------------------------------------------
-# switches (lens/pulse convention: memoized on the RAW env string so tests
+# switches (memoized on the RAW env string so tests
 # and live sessions flipping the var mid-process still take effect)
 # ---------------------------------------------------------------------------
 

@@ -114,24 +114,9 @@ def _scope(recording=None, training=None):
         s.recording, s.training = prev_r, prev_t
 
 
-@contextmanager
 def record(train_mode=True):
-    """ref: autograd.py:93 record scope.
-
-    graftlens: the time spent inside a record scope is the training
-    loop's *forward* build — it feeds the per-step ``forward`` component
-    (Module's ``fwd`` phase span covers the symbolic path; overlapping
-    reports union in the lens sweep, so double instrumentation cannot
-    double-count)."""
-    import time as _time
-    from ..telemetry import lens as _lens
-    t0 = _time.perf_counter() if _lens.enabled() else None
-    with _scope(recording=True, training=train_mode):
-        try:
-            yield
-        finally:
-            if t0 is not None:
-                _lens.interval("forward", t0, _time.perf_counter())
+    """ref: autograd.py:93 record scope."""
+    return _scope(recording=True, training=train_mode)
 
 
 def pause(train_mode=False):

@@ -92,7 +92,6 @@ from .analysis.engine_check import (EngineHazardError,
 from .analysis import tsan as _tsan
 from . import profiler as _profiler
 from .telemetry import blackbox as _blackbox
-from .telemetry import lens as _lens
 from .telemetry import metrics as _tmetrics
 from .telemetry import tracing as _ttracing
 
@@ -795,9 +794,7 @@ def flush(state=None, cause="read"):
                     _blackbox.in_flight("engine_flush",
                                         {"segment": seg_id, "cause": cause,
                                          "nodes": len(instrs)}):
-                t_dispatch = time.perf_counter()
                 results = fn(ext)
-                t_dispatched = time.perf_counter()
                 if st.check and results:
                     # EH104 — the fusion-equivalence oracle: replay the
                     # segment UNFUSED (the same replay closure outside jit
@@ -819,7 +816,6 @@ def flush(state=None, cause="read"):
         if err is not None:
             fields["error"] = repr(err)
         _blackbox.record("engine_flush", **fields)
-    sync_booked = False
     if prof_on or flow_marks:
         # the segment span is where op cost actually lands: with
         # profiler.sync the dispatch blocks until ready, so the span IS
@@ -829,41 +825,12 @@ def flush(state=None, cause="read"):
         # a dangling arrow would fail the trace validator
         device_time = _profiler.want_sync()
         if device_time and results:
-            sync_booked = True
-            # device-time lens: under sync mode dispatch→ready is the
-            # segment's device latency.  Booked as dispatch + residual
-            # wait, EXCLUDING any window between them (the EH104 oracle's
-            # host-side unfused replay under GRAFT_ENGINE_CHECK) — an
-            # undercount when the device was still busy during it, never
-            # an overcount of host work as device time.  Cache-miss
-            # spans still include XLA compile (marked cache:"miss").
-            _lens.device(t_dispatch, t_dispatched)
-            t_block = time.perf_counter()
             jax.block_until_ready(results)
-            _lens.device(t_block, time.perf_counter())
         begin = span_begin if prof_on else _profiler._now_us()
         _ttracing.segment_flush_span(
             seg_id, cause, begin, _profiler._now_us(),
             flow_marks, len(instrs), len(live), cache_hit,
             recorded, device_time, error=err is not None)
-    if err is None and results and not sync_booked \
-            and _lens.pulse_active():
-        # graftpulse: no sync mode blocked this dispatch, so hand the
-        # result arrays to the 1-thread reaper — it block-until-readies
-        # OFF this thread and books dispatch→device-done into this
-        # thread's window, filling the device ledger on ordinary async
-        # train loops (the sync path above books directly; sync_booked
-        # gates the enqueue so the two can never double-book one span).
-        # Under GRAFT_ENGINE_CHECK the EH104 oracle ran a FULL host-side
-        # unfused replay after the dispatch: start the span now instead
-        # — an undercount of device time at worst, never host work
-        # booked as device (the sync path's exact invariant)
-        _lens.device_async(results,
-                           time.perf_counter() if st.check else t_dispatch)
-    # graftpulse memory timeline: the flush boundary is the allocation
-    # watermark sample point (one allocator-counter read; auto-disabled
-    # on backends that report none)
-    _lens.mem_sample("flush:%s" % cause)
     if err is not None:
         raise err
     for i, v in zip(live, results):

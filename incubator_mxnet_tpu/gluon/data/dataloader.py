@@ -51,9 +51,7 @@ def prefetch_depth_default():
     batches the pooled pipeline keeps in flight beyond what the worker
     count implies.  2 is classic double-buffering; deeper absorbs
     per-batch build-time variance (one slow batch no longer stalls the
-    consumer) at the cost of that many batches resident on host.  The
-    graftpulse autotuner grows a loader's LIVE depth past this default
-    when worker growth alone can't close a ``data_wait`` signal."""
+    consumer) at the cost of that many batches resident on host."""
     try:
         v = int(os.environ.get("GRAFT_PREFETCH_DEPTH", "2"))
     except ValueError:
@@ -91,41 +89,10 @@ class DataLoader(object):
         self._num_workers = num_workers
         self._prefetch_depth = None     # None = GRAFT_PREFETCH_DEPTH
         self._pool = None       # lazily-created per-loader worker pool
-        self._blocked_wait_s = 0.0      # cumulative consumer-blocked wait
-        #                                 (the autotuner ranks loaders by
-        #                                 its growth to grow the one that
-        #                                 actually starves the loop)
         if batchify_fn is None:
             self._batchify_fn = default_batchify_fn
         else:
             self._batchify_fn = batchify_fn
-        # graftpulse: the loader is a worker-growth target for the
-        # lens-driven autotuner (weak registration; a no-op path when
-        # GRAFT_AUTOTUNE is off — the default)
-        from ...telemetry import autotune as _autotune
-        _autotune.register_loader(self)
-
-    def set_num_workers(self, n):
-        """Re-tune the worker count LIVE (the graftpulse autotuner's
-        knob).  Growth takes effect mid-epoch: the pool's thread cap is
-        raised in place and the open epoch iterator tops its lookahead
-        up on the next batch — a synchronous (``num_workers=0``) open
-        iterator switches to the pooled pipeline on its next batch;
-        shrinking only lowers the target for the next epoch (running
-        threads idle out — never torn down under an in-flight batch)."""
-        n = max(0, int(n))
-        self._num_workers = n
-        pool = self._pool
-        if pool is not None \
-                and isinstance(getattr(pool, "_max_workers", None), int) \
-                and n > pool._max_workers:
-            # ThreadPoolExecutor spawns lazily up to _max_workers on
-            # submit; raising the cap grows it without a restart.  The
-            # attribute is stdlib-private — the getattr/type guard means
-            # a CPython that renames it degrades to deeper lookahead on
-            # the existing threads (full growth after close() rebuilds
-            # the pool) instead of silently "growing" a dead attribute
-            pool._max_workers = n
 
     def prefetch_depth(self):
         """Effective lookahead depth: the live per-loader override when
@@ -135,8 +102,7 @@ class DataLoader(object):
         return prefetch_depth_default() if d is None else d
 
     def set_prefetch_depth(self, n):
-        """Re-tune the lookahead depth LIVE (the graftpulse autotuner's
-        second data knob).  Like ``set_num_workers``, an open epoch
+        """Override the lookahead depth for this loader.  An open epoch
         iterator re-reads the depth on its next batch, so growth deepens
         the pipeline mid-epoch; shrinking drains naturally (in-flight
         futures complete, top-up just stops earlier)."""
@@ -168,33 +134,13 @@ class DataLoader(object):
             pass                # interpreter teardown: nothing to save
 
     def __iter__(self):
-        import time as _time
-        from ...telemetry import lens as _lens
         prefetch = device_prefetch_enabled(self._prefetch_device)
         it = iter(self._batch_sampler)
         if self._num_workers == 0 and not prefetch:
-            switched = False
             for batch in it:
-                # synchronous batch production IS the consumer's wait:
-                # the whole load+batchify lands on graftlens' data_wait
-                t0 = _time.perf_counter()
-                out = self._batchify_fn(
+                yield self._batchify_fn(
                     [self._dataset[idx] for idx in batch])
-                t1 = _time.perf_counter()
-                self._blocked_wait_s += t1 - t0
-                _lens.io_wait(t0, t1)
-                yield out
-                if self._num_workers > 0:
-                    # a live set_num_workers (the autotuner's grow)
-                    # landed mid-epoch: without this re-check the open
-                    # sync generator never consults the knob again —
-                    # the controller would walk it to the cap on zero
-                    # feedback.  Remaining batches flow through the
-                    # pooled pipeline below
-                    switched = True
-                    break
-            if not switched:
-                return
+            return
         # thread-pool pipeline with one-batch lookahead (double
         # buffering); num_workers=0 + device prefetch runs the same
         # pipeline on ONE thread — batches stay sequential and ordered,
@@ -218,9 +164,7 @@ class DataLoader(object):
 
         def top_up():
             # lookahead depth is re-read each batch so a live
-            # set_num_workers / set_prefetch_depth (the autotuner's
-            # grows) deepens the pipeline mid-epoch instead of waiting
-            # for the next one
+            # set_prefetch_depth deepens the pipeline mid-epoch
             want = max(self.prefetch_depth(), self._num_workers)
             try:
                 while len(futures) < want:
@@ -230,15 +174,7 @@ class DataLoader(object):
         try:
             top_up()
             while futures:
-                # only the blocked .result() counts as data_wait — a
-                # lookahead batch that is already done costs ~0 here,
-                # which is exactly the attribution the double-buffering
-                # claim needs to be auditable
-                t0 = _time.perf_counter()
                 out = futures.pop(0).result()
-                t1 = _time.perf_counter()
-                self._blocked_wait_s += t1 - t0
-                _lens.io_wait(t0, t1)
                 top_up()
                 yield out
         finally:

@@ -3,7 +3,8 @@
 Same API surface as the reference (Conv1D/2D/3D, Conv*DTranspose,
 Max/Avg/GlobalMax/GlobalAvg pooling); compute lowers to the Convolution /
 Deconvolution / Pooling registry ops, i.e. XLA convolutions tiling straight
-onto the MXU (no im2col, no cuDNN algorithm selection — XLA autotunes).
+onto the MXU (no im2col, no cuDNN algorithm selection — XLA picks the
+algorithm).
 """
 from __future__ import annotations
 

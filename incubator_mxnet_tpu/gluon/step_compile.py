@@ -57,16 +57,9 @@ inside the single program, and the boundary reduce issues immediately
 after program A with no host work in between.  Fallback steps re-enter
 ``Trainer.step`` and keep their normal overlap behavior.
 
-**Telemetry.**  A compiled step books a conservation-exact lens window:
-the program dispatch is booked through ``lens.device_async`` (ONE device
-span per program via the pulse reaper), host time lands on the
-``fwd``/``kvstore``/``update`` phase spans, ``data_wait`` keeps flowing
-from the DataLoader, and ``host_gap`` stays the residual — the six
-components still sum exactly to the step wall.  The step journal and
-lens record carry ``compiled=True``.  Because parameters are donated,
-``graft_mem_peak_bytes`` no longer includes the transient
-old-weights+new-weights double residency (docs/observability.md,
-"Whole-step compilation").
+**Telemetry.**  Host time lands on the ``fwd``/``kvstore``/``update``
+phase spans and the step journal carries ``compiled=True``
+(docs/observability.md, "Whole-step compilation").
 
 Per-param gradient buffers are NOT materialized on compiled steps
 (``param.grad()`` holds stale values): the gradients live only inside
@@ -80,7 +73,6 @@ against the bucketed-eager twin.
 from __future__ import annotations
 
 import os
-import time
 
 import numpy as np
 
@@ -94,7 +86,6 @@ from ..analysis import compile_safety as _csafety
 from .. import random_state
 from ..ndarray import NDArray
 from ..telemetry import blackbox as _blackbox
-from ..telemetry import lens as _lens
 from ..telemetry import metrics as _tmetrics
 from ..telemetry import tracing as _ttracing
 from ..telemetry import xray as _xray
@@ -238,8 +229,8 @@ class CompiledStep(object):
             return self._fallback(args, batch_size, entry.reason)
         plan_sig = self._plan_sig()
         if plan_sig != entry["plan_sig"]:
-            # the bucket plan moved under us (autotuned target, state
-            # arity flip): treat as a guard miss and rebuild
+            # the bucket plan moved under us (GRAFT_BUCKET_BYTES moved,
+            # state arity flip): treat as a guard miss and rebuild
             self._entries[key] = None
             return self._miss(args, batch_size, "plan-change")
         return self._dispatch(entry, args, batch_size)
@@ -332,7 +323,7 @@ class CompiledStep(object):
 
     def _plan_sig(self):
         """Structural signature of the trainer's CURRENT bucket plan —
-        compared against the entry's so an autotuner bucket move or a
+        compared against the entry's so a bucket-target move or a
         state-arity flip re-traces instead of running a stale program."""
         plan = self._trainer._fused_plan()
         if plan is None:
@@ -837,10 +828,7 @@ class CompiledStep(object):
                             cargs = (train_vals, state_vals, frozen_vals,
                                      input_vals, rng, lrs, wds, rescale)
                             one_c = self._aot(entry, "one", cargs)
-                            t0 = time.perf_counter()
                             outs, aux, new_w, new_s = one_c(*cargs)
-                            _lens.device_async(
-                                [new_w[-1] if new_w else outs[0]], t0)
                             if ref is not None:
                                 aud.check_parity(
                                     "one", (outs, aux, new_w, new_s),
@@ -859,15 +847,12 @@ class CompiledStep(object):
                                 cargs = (train_vals, frozen_vals,
                                          input_vals, rng, res_vals)
                             fb_c = self._aot(entry, "fwd_bwd", cargs)
-                            t0 = time.perf_counter()
                             fb_out = fb_c(*cargs)
                             if qcfg is None:
                                 outs, aux, flats = fb_out
-                                _lens.device_async([flats[-1]], t0)
                             else:
                                 (outs, aux, qcodes, qscales,
                                  new_res) = fb_out
-                                _lens.device_async([qscales[-1]], t0)
                                 # EF residual write-back NOW — it is
                                 # this step's local quantization error,
                                 # independent of the wire reduce; same
@@ -916,17 +901,12 @@ class CompiledStep(object):
                             cargs = (train_vals, state_vals, reduced,
                                      lrs, wds, rescale)
                             up_c = self._aot(entry, "update", cargs)
-                            t1 = time.perf_counter()
                             new_w, new_s = up_c(*cargs)
-                            _lens.device_async(
-                                [new_w[-1] if new_w else reduced[-1]],
-                                t1)
                             if ref_u is not None:
                                 aud.check_parity("update",
                                                  (new_w, new_s), ref_u)
                             self._write_back(entry, new_w, new_s,
                                              state_nds, frozen_nds, aux)
-                    _lens.mem_sample("compiled_step")
         finally:
             if aud is not None:
                 aud.sweep()

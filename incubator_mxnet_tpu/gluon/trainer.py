@@ -111,10 +111,6 @@ class Trainer(object):
         # (two empty attributes) unless GRAFT_ELASTIC wires them up
         self._membership = None
         self._membership_cbs = []
-        # graftpulse: the trainer is a bucket-bytes / bucket-order
-        # target for the lens-driven autotuner (weak registration)
-        from ..telemetry import autotune as _autotune
-        _autotune.register_trainer(self)
 
     def _check_contexts(self):
         contexts = None
@@ -844,7 +840,6 @@ class Trainer(object):
         _overlap.publish_pull_round(self._pull_scheduler)
         all_keys = [i for b in buckets for i in b.indices]
         overlap = self._pull_overlap_ok(all_keys, pull_stale)
-        from ..telemetry import lens as _lens
         for b in buckets:
             flat = reduced[id(b)]
             shapes = [self._params[i].shape for i in b.indices]
@@ -853,9 +848,6 @@ class Trainer(object):
                 list(b.indices),
                 [NDArray(piece, ctx=self._contexts[0])
                  for piece in pieces])
-            # graftpulse memory timeline: each bucket's store-side apply
-            # is an allocation-watermark sample point
-            _lens.mem_sample(self._sched_label(b))
             if overlap:
                 # THIS bucket's weights go back on the wire before the
                 # next bucket updates — the full-duplex stream
@@ -879,7 +871,6 @@ class Trainer(object):
         is sharded: each rank/context runs the fused update — and holds
         optimizer state — only for its contiguous shard, then broadcasts
         the updated weights (byte-identical to the unsharded step)."""
-        from ..telemetry import lens as _lens
         shard = self._zero_spec()
         if shard is not None and plan[0]:
             return self._bucketed_update_sharded(plan, reduced, shard,
@@ -917,9 +908,6 @@ class Trainer(object):
                 opt.fused_bucket_update(optimizer, self._updaters[j],
                                         b.indices, weights, grads,
                                         lrs[j], wds[j], flat_grad=fg)
-            # graftpulse memory timeline: per-bucket watermark after the
-            # fused update dispatch (the future memory planner's signal)
-            _lens.mem_sample(self._sched_label(b))
         for i in leftover:
             param = self._params[i]
             for upd, arr, grad in zip(self._updaters, param.list_data(),
@@ -952,7 +940,6 @@ class Trainer(object):
         """
         from ..ndarray import NDArray
         from ..parallel import quant as _quant
-        from ..telemetry import lens as _lens
         from ..telemetry import metrics as _tmetrics
         buckets, leftover = plan
         kv = self._kvstore_obj
@@ -995,7 +982,6 @@ class Trainer(object):
                 opt.fused_bucket_update(optimizer, self._updaters[j],
                                         b.indices, weights, grads,
                                         lrs, wds, flat_grad=fg)
-            _lens.mem_sample(self._sched_label(b))
             if by_ctx:
                 kv.apply_reduced(
                     list(b.indices),
@@ -1045,7 +1031,6 @@ class Trainer(object):
         # for "state bytes ~1/N" reads this
         _tmetrics.trainer_state_shard_bytes(self._state_shard_nbytes(),
                                             shard["n"])
-        _lens.mem_sample("zero_shard")
 
     def save_states(self, fname):
         """ref: trainer.py:202 save_states."""

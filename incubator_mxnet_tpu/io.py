@@ -141,15 +141,10 @@ class DataIter(object):
         raise StopIteration
 
     def __next__(self):
-        import time as _time
-        t0 = _time.perf_counter()
         batch = self.next()
         # pipeline throughput telemetry: batches_total counter +
-        # batches/sec EWMA gauge per iterator class (graftscope), and
-        # the blocked span feeds graftlens' per-step data_wait component
-        from .telemetry import lens as _lens
+        # batches/sec EWMA gauge per iterator class (graftscope)
         from .telemetry import metrics as _tmetrics
-        _lens.io_wait(t0, _time.perf_counter())
         _tmetrics.io_batch(type(self).__name__)
         return batch
 

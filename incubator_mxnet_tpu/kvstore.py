@@ -35,7 +35,6 @@ from .ndarray import NDArray
 from .ndarray import ndarray as _nd
 from . import optimizer as opt
 from .telemetry import blackbox as _blackbox
-from .telemetry import lens as _lens
 from .telemetry import metrics as _tmetrics
 from .telemetry import tracing as _ttracing
 
@@ -130,12 +129,11 @@ class _AsyncHandle(object):
 
     def wait(self):
         """Block until the in-flight values are ready; returns them.
-        Idempotent — later calls are free.  graftlens books the blocked
-        span as exposed communication and the issue→wait-return span as
-        in-flight communication — an upper bound on the wire time the
-        overlap hid (a handle whose wait queues behind earlier handles
-        books their wait time too, the same convention as
-        ``graft_trainer_overlap_ratio``)."""
+        Idempotent — later calls are free.  ``blocked_s`` is the span
+        blocked here and ``inflight_s`` the issue→wait-return span — an
+        upper bound on the wire time the overlap hid (a handle whose
+        wait queues behind earlier handles books their wait time too,
+        the same convention as ``graft_trainer_overlap_ratio``)."""
         if not self._done:
             self._done = True
             if _tsan._ACTIVE[0]:
@@ -162,10 +160,6 @@ class _AsyncHandle(object):
                 t1 = time.perf_counter()
                 self.blocked_s = t1 - t0
                 self.inflight_s = t1 - self.issued_at
-                if self.values:
-                    # an empty handle never hit the wire: booking its
-                    # issue->wait gap would fake hidden communication
-                    _lens.comm(t0, t1, inflight=t1 - self.issued_at)
                 self._close()
                 _tsan.handle_settle(self)
         return self.values

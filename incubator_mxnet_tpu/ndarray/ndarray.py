@@ -17,7 +17,6 @@ TPU-native rebirth of include/mxnet/ndarray.h + src/ndarray/ndarray.cc:
 """
 from __future__ import annotations
 
-import time as _time
 import weakref
 
 import numpy as np
@@ -32,7 +31,6 @@ from .. import random_state
 from .. import config as _config
 from ..analysis import tsan as _tsan
 from ..analysis import compile_safety as _csafety
-from ..telemetry import lens as _lens
 
 # MXTPU_ENGINE_TYPE=NaiveEngine → block after every dispatch (the
 # reference's synchronous debug engine, src/engine/naive_engine.cc);
@@ -774,8 +772,6 @@ def invoke(op: Operator, inputs, params, out=None):
                               args={"device_time": _profiler.want_sync()})
     if _span is not None:
         _span.__enter__()
-    _pulse = _lens.pulse_active()
-    _t_dispatch = None
     try:
         if recording:
             fn = op.bind(params, is_train)
@@ -784,16 +780,9 @@ def invoke(op: Operator, inputs, params, out=None):
                 wrapped = lambda *xs: fn(*xs, rng=rng)
             else:
                 wrapped = fn
-            # jax.vjp interleaves host linearization tracing with the
-            # execution — no clean dispatch instant exists, so the
-            # device ledger books only the residual wait below (an
-            # undercount, never host tracing booked as device time)
             out_vals, vjp_fn = jax.vjp(wrapped, *vals)
         else:
             fn = op.bind(params, is_train)
-            if _span is not None or _pulse:
-                _t_dispatch = _time.perf_counter()  # after bind: the
-                #                                     executing call only
             out_vals = fn(*vals, **kw)
             vjp_fn = None
     except Exception as exc:
@@ -802,30 +791,13 @@ def invoke(op: Operator, inputs, params, out=None):
         if _span is not None:
             _span.__exit__(type(exc), exc, None)
         raise
-    _sync_booked = False
     if _span is not None:
         if _profiler.want_sync():
-            # device-time lens: under sync mode dispatch→ready IS this
-            # op's device latency — same ledger the sync-mode bulk
-            # flushes feed, so eager (unbulked) steps decompose too.
-            # Recorded ops book the blocking wait only (_t_dispatch is
-            # None there); cache-miss calls still include jit compile
-            _sync_booked = True
-            _t_block = _time.perf_counter()
+            # sync mode: the span closes when the device is done, so its
+            # duration is this op's device latency (cache-miss calls
+            # still include jit compile)
             jax.block_until_ready(out_vals)
-            _lens.device(_t_dispatch if _t_dispatch is not None
-                         else _t_block, _time.perf_counter())
         _span.__exit__()
-    if _pulse and not _sync_booked:
-        # graftpulse: async eager dispatch — hand the results to the
-        # reaper so dispatch→device-done books into this thread's device
-        # ledger without blocking here.  Recorded ops carry no clean
-        # dispatch instant (host tracing above): the post-call instant
-        # starts their span — an undercount, never host work booked as
-        # device time.  The sync path above books directly and skips
-        # the enqueue (no-double-booking contract).
-        _lens.device_async(out_vals, _t_dispatch if _t_dispatch is not None
-                           else _time.perf_counter())
     if _NAIVE_ENGINE:
         jax.block_until_ready(out_vals)
     first = out_vals[0] if isinstance(out_vals, tuple) else out_vals

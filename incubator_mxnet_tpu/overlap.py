@@ -402,10 +402,6 @@ class BucketScheduler(object):
                     [flat], label=host._sched_label(b))
         self.issue_log.append((b.indices, self._fire_count))
         self.issued_total += 1
-        # graftpulse memory timeline: the mid-backward issue is where a
-        # bucket's flat buffer peaks — sample the watermark per bucket
-        from .telemetry import lens as _lens
-        _lens.mem_sample(host._sched_label(b))
 
     # -- consuming (the host's step) ----------------------------------------
     def take(self, plan):

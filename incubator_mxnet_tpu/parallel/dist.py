@@ -19,7 +19,6 @@ from __future__ import annotations
 
 import logging
 import os
-import threading
 import time
 
 import numpy as np
@@ -128,37 +127,6 @@ def _global_sum(flat):
 
 
 _ps_counter = [0]   # SPMD-identical creation index → rendezvous key
-
-
-# -- graftpulse rank-consistent knob mailbox --------------------------------
-#
-# The autotuner must never let ranks act on their own local signals — a
-# rank-divergent GRAFT_BUCKET_BYTES changes each rank's bucket plan and
-# therefore its collective SEQUENCE, which the lockstep auditor would
-# (rightly) flag just before the wire deadlocks.  Instead rank 0 parks
-# its decision here and the next heartbeat broadcasts it in one extra
-# int32 slot of the existing skew allreduce (zero additional
-# collectives); every rank — including rank 0 — applies the knob only
-# when the broadcast LANDS, so the plan flips on the same step
-# everywhere.
-
-_knob_lock = threading.Lock()
-_bucket_proposal = [0]
-
-
-def propose_bucket_bytes(nbytes):
-    """Park rank 0's bucket-bytes decision for the next heartbeat
-    broadcast.  Called by the autotuner on rank 0 only; other ranks'
-    tuners stay observation-only under multi-rank."""
-    with _knob_lock:
-        _bucket_proposal[0] = int(nbytes)
-
-
-def _take_bucket_proposal():
-    with _knob_lock:
-        v = _bucket_proposal[0]
-        _bucket_proposal[0] = 0
-        return v
 
 
 class _PSPullHandle(PullHandle):
@@ -826,16 +794,13 @@ class DistKVStore(KVStore):
         now_ms = int(time.time() * 1000) % (1 << 31)
         audit = _lockstep.enabled()
         elastic = _elastic.enabled()
-        # +1 trailing slot: the graftpulse knob broadcast (rank 0's
-        # bucket-bytes proposal; 0 = nothing pending).  Same collective,
-        # same shape on every rank — the lockstep hash stays in step.
         base_slots = (6 if audit else 2) * W
-        # graftelastic: W MORE per-rank slots after the proposal carry
-        # each rank's membership epoch, so a survivor that fenced a
-        # change names the laggards on the very next heartbeat.  The
+        # graftelastic: W MORE per-rank slots carry each rank's
+        # membership epoch, so a survivor that fenced a change names
+        # the laggards on the very next heartbeat.  The
         # SHAPE depends on GRAFT_ELASTIC — set it IDENTICALLY on every
         # rank, exactly like the audit knob above.
-        vec = np.zeros((base_slots + 1 + (W if elastic else 0),), np.int32)
+        vec = np.zeros((base_slots + (W if elastic else 0),), np.int32)
         vec[rank()] = now_ms
         vec[W + rank()] = self._hb_step % (1 << 31)
         if audit:
@@ -845,28 +810,17 @@ class DistKVStore(KVStore):
             vec[4 * W + rank()] = lag_hash
             vec[5 * W + rank()] = lag_fold % (1 << 31)
         if elastic:
-            vec[base_slots + 1 + rank()] = _lockstep.epoch() % (1 << 31)
-        if rank() == 0:
-            vec[base_slots] = _take_bucket_proposal() % (1 << 31)
+            vec[base_slots + rank()] = _lockstep.epoch() % (1 << 31)
         out = np.asarray(_global_sum(jnp.asarray(vec))).astype(np.int64)
         ts_ms, steps = out[:W], out[W:2 * W]
         if audit:
             hashes, folds_by_rank = out[2 * W:3 * W], out[3 * W:4 * W]
             lag_hashes, lag_folds = out[4 * W:5 * W], out[5 * W:6 * W]
-        prop = int(out[base_slots])
-        if prop > 0:
-            # every rank applies on the SAME heartbeat (rank 0 included:
-            # it too deferred its own decision to the broadcast landing)
-            try:
-                from ..telemetry import autotune as _autotune
-                _autotune.apply_bucket_bytes_broadcast(prop)
-            except Exception:
-                pass
             _lockstep.observe({r: (int(folds_by_rank[r]), int(hashes[r]),
                                    int(lag_folds[r]), int(lag_hashes[r]))
                                for r in range(W)}, my_rank=rank())
         if elastic:
-            epochs = out[base_slots + 1:base_slots + 1 + W]
+            epochs = out[base_slots:base_slots + W]
             mine = int(epochs[rank()])
             ahead = int(epochs.max())
             if ahead > mine:

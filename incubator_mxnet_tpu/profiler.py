@@ -238,14 +238,6 @@ def device_memory():
                         "num_allocs": 0, "source": "live_arrays"})
     fallback = {m["device"]: m for m in out if m["source"] == "live_arrays"}
     if fallback:
-        # settle the pulse reaper's transient result-array refs before
-        # the live-arrays walk: they are ledger bookkeeping, not
-        # workload memory — counting them makes this accounting flicker
-        # by reap latency.  Only the fallback path pays (briefly):
-        # allocator-stats devices skip it, so a metrics scrape on a
-        # busy production job never stalls here
-        from .telemetry import lens as _lens
-        _lens.pulse_drain(0.25)
         for arr in jax.live_arrays():
             try:
                 shards = arr.addressable_shards

@@ -29,8 +29,7 @@ Every dispatch runs inside a ``serve_batch`` flight-recorder bracket
 naming (batch id, model, version, size, bucket) — a stuck batch is
 tripped BY NAME by the graftwatch watchdog and shows as the in-flight
 batch in crash dumps — and lands a ``serve_batch`` journal event with
-the batch's latency split.  Device time of the dispatch is booked on
-the graftlens device ledger.
+the batch's latency split.
 """
 from __future__ import annotations
 
@@ -44,7 +43,6 @@ import numpy as np
 
 from ..analysis import tsan as _tsan
 from ..telemetry import blackbox as _blackbox
-from ..telemetry import lens as _lens
 from ..telemetry import metrics as _tmetrics
 from . import slo as _slo
 
@@ -212,8 +210,6 @@ class DynamicBatcher(object):
         wait_ms = serve_max_wait_ms() if max_wait_ms is None \
             else max(float(max_wait_ms), 0.0)
         self._max_wait = wait_ms / 1e3
-        self._wait_ms_base = wait_ms    # the configured value the
-        #                                 autotuner relaxes back toward
         self._lock = threading.Lock()
         self._cv = threading.Condition(self._lock)
         self._queues = OrderedDict()    # key -> deque[_Request]
@@ -225,39 +221,6 @@ class DynamicBatcher(object):
         self._batch_seq = itertools.count(1)
         self.batches_total = 0
         self.requests_total = 0
-        # graftpulse: the batcher's max-batch / max-wait become live
-        # autotuner targets (weak registration; ~free when GRAFT_AUTOTUNE
-        # is off — the controller's observer returns immediately)
-        try:
-            from ..telemetry import autotune as _autotune
-            _autotune.register_batcher(self)
-        except Exception:
-            pass
-
-    # -- graftpulse live knobs ----------------------------------------------
-    def max_batch(self):
-        return self._max_batch
-
-    def set_max_batch(self, n):
-        """Live resize: takes effect on the next pick — bucket padding
-        follows automatically (``_bucket_for`` caps at the new max, so
-        a grown batch compiles at most one new bucket size)."""
-        with self._cv:
-            self._max_batch = max(int(n), 1)
-            self._cv.notify()
-
-    def max_wait_ms(self):
-        return self._max_wait * 1e3
-
-    def configured_max_wait_ms(self):
-        """The construction-time max-wait — the ceiling the autotuner
-        relaxes a squeezed wait back toward."""
-        return self._wait_ms_base
-
-    def set_max_wait_ms(self, ms):
-        with self._cv:
-            self._max_wait = max(float(ms), 0.0) / 1e3
-            self._cv.notify()
 
     # -- submission ----------------------------------------------------------
     def submit(self, model, x, deadline_ms=None):
@@ -454,7 +417,6 @@ class DynamicBatcher(object):
             t_computed = time.perf_counter()
             for r in reqs:
                 r.t_computed = t_computed
-            _lens.device(t_built, t_computed)   # the device-ledger view
             if self._maybe_probe(model, sig, bucket, entry, params,
                                  xvals, outs):
                 # probe mismatch: discard the batched result and re-run
@@ -478,14 +440,9 @@ class DynamicBatcher(object):
                 r.future._resolve(value, rec)
             self.batches_total += 1
             _slo.record_batch(model, n, bucket)
-            if _lens.enabled():
-                # one lens window per batch cycle on the dispatcher
-                # thread: the device ledger (booked above) lands in a
-                # ring record with origin "serve_batch", so serving's
-                # device_compute is visible in the SAME per-step
-                # attribution stream training uses
-                _lens.step_end("serve_batch",
-                               extra={"batch_size": n, "model": model})
+            # a batch is the dispatcher thread's step: spans and events
+            # from here on carry the next id
+            _blackbox.advance_step()
             _blackbox.record(
                 "serve_batch", batch=bid, model=model, version=version,
                 size=n, bucket=bucket, demoted=demoted,

@@ -1,16 +1,12 @@
-"""graftserve SLO telemetry — per-request latency decomposition in
-graftlens style.
+"""graftserve SLO telemetry — per-request latency decomposition.
 
 Every request's end-to-end wall time decomposes into FOUR components
-that sum EXACTLY to the request wall (the same conservation contract
-``telemetry/lens.py`` keeps per training step):
+that sum EXACTLY to the request wall:
 
 * ``queue_wait``      — enqueue → picked into a batch by the dispatcher,
 * ``batch_assembly``  — pick → padded batch tensor built and on device,
 * ``device_compute``  — dispatch → ``block_until_ready`` (ONE compiled
-                        device call per batch; also booked on the
-                        graftlens DEVICE ledger, so serving compute is
-                        measured on the device, not just host wall),
+                        device call per batch),
 * ``host_io``         — the residual: output rows sliced/converted and
                         the response delivered.
 
@@ -115,9 +111,8 @@ def quantiles(records=None):
 
 def component_quantile(component, q=0.99, records=None):
     """Quantile of ONE latency component over the ring's ok requests —
-    e.g. ``component_quantile("queue_wait", 0.99)`` is the signal the
-    graftpulse serving knob steers on (telemetry/autotune.py).  None on
-    an empty ring or unknown component."""
+    e.g. ``component_quantile("queue_wait", 0.99)``.  None on an empty
+    ring or unknown component."""
     if component not in COMPONENTS:
         return None
     if records is None:

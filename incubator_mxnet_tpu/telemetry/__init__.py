@@ -20,11 +20,6 @@ Four quarters (see docs/observability.md for the full guide):
   thread that trips when an engine flush / dist collective / phase stays
   in flight past ``GRAFT_WATCHDOG_TIMEOUT``, writing the dump + thread
   stacks (and aborting under ``GRAFT_WATCHDOG_ABORT``).
-* :mod:`~incubator_mxnet_tpu.telemetry.lens` — graftlens per-step
-  wall-time attribution (data_wait/forward/backward_compute/
-  exposed_comm/optimizer_update/host_gap, conserving the step wall
-  clock), kept in a ring of the last ``GRAFT_LENS_RING`` steps and
-  printable every ``GRAFT_STEP_REPORT`` steps.
 * :mod:`~incubator_mxnet_tpu.telemetry.aggregate` — cross-rank trace
   merging: N per-rank chrome traces / blackbox dumps → ONE merged trace
   with per-rank tracks, cross-rank flow links per collective, and a
@@ -46,7 +41,6 @@ CLI::
 
     python -m incubator_mxnet_tpu.telemetry --summary [--json]
     python -m incubator_mxnet_tpu.telemetry --blackbox PATH [--json]
-    python -m incubator_mxnet_tpu.telemetry --steps [--json]
     python -m incubator_mxnet_tpu.telemetry --analyze R0.json R1.json \
         [--json | --merged OUT.json]
 
@@ -61,7 +55,6 @@ from __future__ import annotations
 import os as _os
 
 from . import metrics
-from . import lens
 from . import tracing
 from . import blackbox
 from . import watchdog
@@ -74,7 +67,7 @@ from .tracing import phase_span
 from .blackbox import spans
 from .xray import programs
 
-__all__ = ["metrics", "lens", "tracing", "blackbox", "watchdog",
+__all__ = ["metrics", "tracing", "blackbox", "watchdog",
            "aggregate", "xray",
            "Counter", "Gauge", "Histogram", "MetricsRegistry",
            "registry", "enabled", "set_enabled", "parse_prometheus_text",

@@ -1,17 +1,10 @@
-"""graftscope + graftwatch + graftlens CLI.
+"""graftscope + graftwatch CLI.
 
     python -m incubator_mxnet_tpu.telemetry --summary [--json]
         Run one bulked training step (gluon Trainer on CPU, a kvstore
         attached) with segment tracing on, then render the top-k segment
         flushes by device time and the metrics snapshot (flush causes,
         kvstore bytes, device-memory gauges) FROM THAT RUN.
-
-    python -m incubator_mxnet_tpu.telemetry --steps [--json]
-        graftlens live-ring demo: run a short gluon training loop (io
-        iterator -> record/backward -> Trainer.step on a kvstore) and
-        render the per-step wall-time attribution ring — each step's
-        data_wait/forward/backward/exposed_comm/update/host_gap
-        breakdown plus the mean fractions.
 
     python -m incubator_mxnet_tpu.telemetry --analyze R0.json R1.json...
         [--json | --merged OUT.json]
@@ -315,85 +308,8 @@ def _render_blackbox_text(report):
     return "\n".join(lines)
 
 
-def _demo_lens_steps(n_steps=6):
-    """A short real training loop with every lens source lit: io
-    iterator (data_wait), record scope (forward), backward, a local
-    kvstore (exposed_comm) and the fused update — fills the lens ring."""
-    import numpy as np
-    import incubator_mxnet_tpu as mx
-    from incubator_mxnet_tpu import autograd, engine, gluon, io
-    from incubator_mxnet_tpu.telemetry import lens
-
-    prev = lens._enabled_override
-    lens.set_enabled(True)      # the demo must work under GRAFT_LENS=0
-    try:
-        lens.reset()
-        net = gluon.nn.Dense(8)
-        net.initialize()
-        rs = np.random.RandomState(0)
-        x = rs.rand(4 * n_steps, 16).astype(np.float32)
-        y = np.zeros((4 * n_steps, 8), np.float32)
-        net(mx.nd.array(x[:4])).asnumpy()      # param init outside
-        trainer = gluon.Trainer(net.collect_params(), "sgd",
-                                {"learning_rate": 0.1},
-                                kvstore=mx.kv.create("local"))
-        it = io.NDArrayIter(data=x, label=y, batch_size=4)
-        for batch in it:
-            data = batch.data[0]
-            with engine.bulk(64):       # flush boundaries light the
-                #                         pulse + memory-timeline sites
-                with autograd.record():
-                    out = net(data)
-                    loss = (out * out).mean()
-                loss.backward()
-            trainer.step(batch_size=data.shape[0])
-            loss.asnumpy()
-        lens.pulse_drain(2.0)           # settle async ledger bookings
-        return lens.steps()
-    finally:
-        lens.set_enabled(prev)
-
-
-def _render_lens_text(records, agg):
-    from incubator_mxnet_tpu.telemetry.lens import ABBREV, COMPONENTS
-    short = dict(ABBREV)
-    lines = ["graftlens step attribution (%d steps in ring)"
-             % len(records), "=" * 72]
-    lines.append("%-5s %-8s %9s  %s" % (
-        "step", "origin", "wall(ms)",
-        " ".join("%7s" % short[c] for c in COMPONENTS)))
-    for r in records:
-        lines.append("%-5d %-8s %9.2f  %s" % (
-            r["step"], r["origin"], r["wall_s"] * 1e3,
-            " ".join("%7.2f" % (r["components"][c] * 1e3)
-                     for c in COMPONENTS)))
-    if agg.get("steps"):
-        fr = agg["fractions"]
-        lines.append("")
-        lines.append("mean %.2fms/step | %s" % (
-            agg["mean_step_ms"],
-            " ".join("%s %d%%" % (short[c], round(fr[c] * 100))
-                     for c in COMPONENTS)))
-        lines.append("comm blocked %.2fms / in-flight %.2fms over the ring"
-                     % (agg["comm_blocked_s"] * 1e3,
-                        agg["comm_inflight_s"] * 1e3))
-    return "\n".join(lines)
-
-
-def run_steps(as_json):
-    from incubator_mxnet_tpu.telemetry import lens
-    records = _demo_lens_steps()
-    agg = lens.summary(records)
-    if as_json:
-        print(json.dumps({"steps": records, "summary": agg}, indent=2,
-                         sort_keys=True, default=str))
-    else:
-        print(_render_lens_text(records, agg))
-    return 0 if records else 1
-
-
 def _render_analyze_text(report):
-    lines = ["graftlens cross-rank analysis", "=" * 72]
+    lines = ["cross-rank analysis", "=" * 72]
     for r in sorted(report["ranks"], key=int):
         info = report["ranks"][r]
         lines.append("rank %-3s %-40s collectives %-5d heartbeats %d"
@@ -473,7 +389,7 @@ def _render_analyze_text(report):
 
 
 def _render_ingest_text(report):
-    lines = ["graftpulse device-ledger ingestion", "=" * 60]
+    lines = ["device-ledger ingestion", "=" * 60]
     lines.append("device-busy spans: %d" % report["device_events"])
     lines.append("%-8s %10s %10s %10s %7s %6s"
                  % ("step", "wall(ms)", "busy(ms)", "idle(ms)", "busy%",
@@ -503,55 +419,6 @@ def run_ingest(path, as_json):
     return 1 if report["problems"] else 0
 
 
-def _demo_mem_steps():
-    """The --steps demo loop with the exact live-arrays memory sampler
-    installed (host CPU reports no allocator counters, so the default
-    per-flush sampler would auto-disable)."""
-    from incubator_mxnet_tpu.telemetry import lens
-    lens.set_mem_sampler(lens.live_arrays_sampler)
-    try:
-        records = _demo_lens_steps()
-    finally:
-        lens.set_mem_sampler(None)
-    return records, lens.mem_summary()
-
-
-def _render_mem_text(records, sites):
-    lines = ["graftpulse memory timeline (per-site allocation watermarks)",
-             "=" * 72]
-    lines.append("%-32s %8s %14s %14s"
-                 % ("site", "samples", "peak(bytes)", "last-in-use"))
-    for site in sorted(sites, key=lambda s: -sites[s]["peak_bytes"]):
-        s = sites[site]
-        lines.append("%-32s %8d %14d %14d"
-                     % (site[:32], s["samples"], s["peak_bytes"],
-                        s["last_in_use"]))
-    lines.append("")
-    lines.append("per-step window peaks:")
-    lines.append("%-5s %-8s %9s %14s %6s" % ("step", "origin", "wall(ms)",
-                                             "mem-peak(bytes)", "sites"))
-    for r in records:
-        mem = r.get("mem") or {}
-        lines.append("%-5d %-8s %9.2f %14s %6d"
-                     % (r["step"], r["origin"], r["wall_s"] * 1e3,
-                        mem.get("peak_bytes", "-"),
-                        len(mem.get("sites", ()))))
-    return "\n".join(lines)
-
-
-def run_mem(as_json):
-    records, sites = _demo_mem_steps()
-    if as_json:
-        print(json.dumps({"sites": sites,
-                          "steps": [{"step": r["step"],
-                                     "mem": r.get("mem")}
-                                    for r in records]},
-                         indent=2, sort_keys=True, default=str))
-    else:
-        print(_render_mem_text(records, sites))
-    return 0 if sites else 1
-
-
 def run_analyze(paths, merged_out, as_json):
     from incubator_mxnet_tpu.telemetry import aggregate
     report, _trace = aggregate.analyze(paths, merged_out=merged_out)
@@ -567,10 +434,10 @@ def analyze_selftest():
     problems = aggregate.selftest()
     if problems:
         for p in problems:
-            print("graftlens analyze selftest FAIL: %s" % p,
+            print("analyze selftest FAIL: %s" % p,
                   file=sys.stderr)
         return 1
-    print("graftlens analyze selftest OK (merged trace valid, straggler "
+    print("analyze selftest OK (merged trace valid, straggler "
           "table blames the delayed rank)")
     return 0
 
@@ -608,8 +475,8 @@ def main(argv=None):
     ap = argparse.ArgumentParser(
         prog="python -m incubator_mxnet_tpu.telemetry",
         description="graftscope: segment-aware tracing + metrics summary; "
-                    "graftwatch: flight-recorder post-mortems; graftlens: "
-                    "per-step attribution + cross-rank straggler analysis")
+                    "graftwatch: flight-recorder post-mortems + "
+                    "cross-rank straggler analysis")
     ap.add_argument("--summary", action="store_true",
                     help="run (or load) a traced workload and report")
     ap.add_argument("--json", action="store_true",
@@ -629,18 +496,9 @@ def main(argv=None):
     ap.add_argument("--merged", metavar="OUT",
                     help="with --analyze: write the merged chrome trace "
                          "here")
-    ap.add_argument("--steps", action="store_true",
-                    help="run a short training loop and render the "
-                         "graftlens per-step attribution ring")
-    ap.add_argument("--mem", action="store_true",
-                    help="run the demo loop with the exact memory "
-                         "sampler and render the graftpulse per-site "
-                         "allocation-watermark timeline")
     ap.add_argument("--ingest-xla", metavar="TRACE", dest="ingest_xla",
                     help="rebuild the per-step device ledger offline "
-                         "from a chrome trace (the async-ledger "
-                         "fallback when pulse callbacks were "
-                         "unavailable)")
+                         "from a sync-mode chrome trace")
     ap.add_argument("--top", type=int,
                     default=int(os.environ.get("GRAFT_TELEMETRY_TOPK",
                                                "10")),
@@ -659,12 +517,6 @@ def main(argv=None):
 
     if args.ingest_xla:
         return run_ingest(args.ingest_xla, args.json)
-
-    if args.steps:
-        return run_steps(args.json)
-
-    if args.mem:
-        return run_mem(args.json)
 
     if args.blackbox is not None:
         if args.selftest:

@@ -1,8 +1,8 @@
-"""graftlens cross-rank trace aggregation + straggler analytics.
+"""Cross-rank trace aggregation + straggler analytics.
 
-One rank's trace answers *where did my step time go* (telemetry/lens.py);
-it cannot answer the second question that dominates distributed step
-time (EQuARX, arXiv:2506.17615): **which rank made everyone wait?**  A
+One rank's trace answers *where did my step time go*; it cannot answer
+the second question that dominates distributed step time (EQuARX,
+arXiv:2506.17615): **which rank made everyone wait?**  A
 sync collective exits everywhere at once, so the rank that *entered*
 last paid nothing and billed its lateness to every peer — visible only
 by putting all ranks' timelines side by side.
@@ -588,7 +588,7 @@ def analyze(paths, merged_out=None):
 
 
 # ---------------------------------------------------------------------------
-# graftpulse: profiler-trace ingestion (the async-ledger fallback)
+# profiler-trace ingestion
 # ---------------------------------------------------------------------------
 
 # the trace-parsing core (interval union, device-event detection, the
@@ -607,16 +607,14 @@ def _is_device_event(ev, device_pids):
 
 
 def ingest_xla(path_or_doc):
-    """Rebuild the per-step device ledger OFFLINE from a chrome trace —
-    the fallback for runs where the pulse done-callbacks were
-    unavailable (``GRAFT_PULSE=0``, external XLA profiler captures).
+    """Rebuild the per-step device ledger OFFLINE from a chrome trace
+    (``mx.profiler`` sync mode, external XLA profiler captures).
 
     Device-busy spans are unioned per step (``args.step`` stamps, the
-    same id graftlens threads onto flush spans; unstamped device spans
-    pool into one unattributed window).  Step windows follow the live
-    lens convention — previous step's window end to this step's — so
-    ``busy_s + idle_s == wall_s`` holds exactly per row, same contract
-    as the online ledger.  The grouping, the union and the row
+    step id flush spans carry; unstamped device spans pool into one
+    unattributed window).  Step windows run from the previous step's
+    window end to this step's, so ``busy_s + idle_s == wall_s`` holds
+    exactly per row.  The grouping, the union and the row
     convention are the graftxray shared core (``xray.step_spans`` /
     ``xray.step_rows``) — the online capture parser and this offline
     CLI cannot drift apart.  Returns the report dict (``steps`` rows +
@@ -705,12 +703,12 @@ def selftest():
     try:
         for rank in (0, 1):
             fd, p = tempfile.mkstemp(suffix=".json",
-                                     prefix="graftlens_self_r%d_" % rank)
+                                     prefix="graft_analyze_self_r%d_" % rank)
             with os.fdopen(fd, "w") as f:
                 json.dump(_synthetic_dump(rank, delay, buckets=buckets), f)
             paths.append(p)
         fd, merged_path = tempfile.mkstemp(suffix=".json",
-                                           prefix="graftlens_self_merged_")
+                                           prefix="graft_analyze_self_merged_")
         os.close(fd)
         paths.append(merged_path)
         report, trace = analyze(paths[:2], merged_out=merged_path)

@@ -40,14 +40,15 @@ import threading
 import time
 import traceback
 from collections import deque
+from contextlib import contextmanager as _contextmanager
 from contextlib import nullcontext as _nullcontext
 
-from . import lens as _lens
 from ..analysis import lockstep as _lockstep
 
 __all__ = ["enabled", "set_enabled", "record", "events", "stats",
            "in_flight", "inflight_entries", "progress", "last_progress",
            "collective", "phase_begin", "phase_end", "spans", "step_journal",
+           "current_step", "advance_step",
            "workers_seen", "set_rank", "set_clock_offset", "dump",
            "snapshot", "default_path", "validate_dump", "summarize_dump",
            "install_hooks", "configure", "selftest", "SCHEMA",
@@ -125,15 +126,11 @@ def set_clock_offset(seconds):
 def record(kind, **fields):
     """Append one structured event.  THE hot path: a disabled recorder
     costs one env lookup; an enabled one adds one tuple + deque append.
-    graftlens threads its step id through: every event recorded from a
-    thread with lens activity carries ``step`` — the join key the
-    cross-rank aggregator uses."""
+    Every event carries the recording thread's ``step`` id — the join
+    key the cross-rank aggregator uses."""
     if not enabled():
         return
-    if "step" not in fields:
-        step = _lens.current_step()
-        if step is not None:
-            fields["step"] = step
+    fields.setdefault("step", current_step())
     _stats[0] += 1
     _ring.append((time.time(), kind, fields))
 
@@ -278,15 +275,12 @@ class _Collective(object):
         self.fields = fields
         self.entry = None
         self._bb = bb           # False: recorder off, bracket kept alive
-        #                         only for graftlens + chrome spans
+        #                         only for chrome spans
 
     def __enter__(self):
         self._t0 = time.perf_counter()
         seq = next(_collective_seq)
-        fields = dict(self.fields, seq=seq)
-        step = _lens.current_step()
-        if step is not None:
-            fields["step"] = step
+        fields = dict(self.fields, seq=seq, step=current_step())
         self.fields = fields
         # lockstep divergence auditor: fold this collective's identity
         # into the rank's rolling stream hash at the moment its seq is
@@ -312,13 +306,6 @@ class _Collective(object):
             if err is not None:
                 fields["error"] = err
             record("collective", **fields)
-        # graftlens: a sync bracket blocks the host for its whole span —
-        # blocked == in-flight.  Async issues (reduce_many_async) are
-        # excluded: their bracket stays open across healthy overlap and
-        # the REAL blocked/in-flight split is reported by
-        # ReduceHandle.wait on the consumer side.
-        if self.path not in _NO_STRAGGLER_PATHS:
-            _lens.comm(self._t0, self._t0 + dt)
         self._trace_span(dt)
         if self._bb and err is None:
             self._straggler_check(dt)
@@ -371,12 +358,12 @@ def collective(path, **fields):
     """Bracket one kvstore collective (push/pull/reduce_many/ps_*):
     records a ``collective`` ring event with latency + key/byte counts,
     feeds the straggler EWMA, and shows up in-flight while running.
-    With the recorder off, graftlens' comm accounting and the profiler's
-    chrome collective spans must survive — the bracket then runs in
-    light mode (no ring/in-flight/EWMA, same seq/step stamping)."""
+    With the recorder off, the profiler's chrome collective spans must
+    survive — the bracket then runs in light mode (no ring/in-flight/
+    EWMA, same seq/step stamping)."""
     if enabled():
         return _Collective(path, fields)
-    if _lens.enabled() or _profiler_active():
+    if _profiler_active():
         return _Collective(path, fields, bb=False)
     return _NULL
 
@@ -392,6 +379,21 @@ def _profiler_active():
 
 _tls = threading.local()
 _step_counters = {}
+
+
+def current_step():
+    """Id of the step in progress on the calling thread: one more than
+    the steps it has closed.  Spans, ring events, flush spans and
+    collective spans carry it — the key the cross-rank aggregator and
+    the benchmark's span readers join on."""
+    return getattr(_tls, "steps_closed", 0) + 1
+
+
+def advance_step():
+    """Close the calling thread's step (a step journal's exit, a served
+    batch); returns the id it had."""
+    _tls.steps_closed = closed = getattr(_tls, "steps_closed", 0) + 1
+    return closed
 
 
 def phase_begin(phase):
@@ -482,54 +484,31 @@ class _StepJournal(object):
             fields["error_phase"] = self.journal["error_phase"]
         if err is not None:
             fields["error"] = err
-        # graftlens: the journal boundary IS the step-window boundary —
-        # finalize the attribution window and fold the component
-        # breakdown into this ring event (the step event's `step` field
-        # then matches the id stamped on the window's flushes/collectives)
-        lens_rec = _lens.step_end(self.origin, extra=_lens_extra(self.fields))
-        if lens_rec is not None:
-            fields["step"] = lens_rec["step"]
-            fields["lens"] = _lens.compact(lens_rec)
+        # the journal boundary IS the step boundary: the step event's
+        # `step` matches the id stamped on the step's flushes/collectives
+        fields["step"] = advance_step()
         record("step", **fields)
         return False
 
 
-def _lens_extra(fields):
-    extra = {k: fields[k]
-             for k in ("overlapped", "fused", "batch_size", "compiled")
-             if k in fields}
-    return extra or None
-
-
-class _LensOnlyStep(object):
-    """Step boundary for graftlens when the flight recorder is off: the
-    lens window must still close at step end (components would otherwise
-    pile into one endless first step)."""
-
-    __slots__ = ("origin", "fields")
-
-    def __init__(self, origin, fields):
-        self.origin = origin
-        self.fields = fields
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, et, ev, tb):
-        _lens.step_end(self.origin, extra=_lens_extra(self.fields))
-        return False
+@_contextmanager
+def _count_only_step():
+    """Step boundary while the flight recorder is off: the step id that
+    chrome spans carry must still advance at step end."""
+    try:
+        yield
+    finally:
+        advance_step()
 
 
 def step_journal(origin, **fields):
     """Bracket one optimizer step (gluon ``Trainer.step`` /
     ``Module.update``): phase latencies recorded inside land on ONE
     ``step`` ring event with the device-memory highwater — and the
-    journal exit closes the graftlens attribution window (which keeps
-    working when the recorder itself is disabled)."""
+    journal exit advances the thread's step id (also when the recorder
+    itself is disabled)."""
     if not enabled():
-        if _lens.enabled():
-            return _LensOnlyStep(origin, fields)
-        return _NULL
+        return _count_only_step()
     return _StepJournal(origin, fields)
 
 

@@ -774,94 +774,6 @@ def step_retrace_storm():
                       "auditor").inc()
 
 
-# -- graftlens: per-step wall-time attribution --------------------------------
-
-
-def lens_step(rec):
-    """One finalized lens step window (telemetry/lens.py): per-component
-    seconds histogram, last-step fraction gauges, and the hidden-comm
-    ratio (1 - blocked/inflight collective time — the overlap view)."""
-    if not enabled():
-        return
-    r = _REGISTRY
-    r.counter("graft_lens_steps_total",
-              "Training steps attributed by graftlens").inc()
-    h = r.histogram("graft_lens_component_seconds",
-                    "Per-step wall time by lens component", ("component",),
-                    buckets=_PHASE_BUCKETS)
-    g = r.gauge("graft_lens_component_fraction",
-                "Last step's wall-time fraction by lens component",
-                ("component",))
-    wall = rec["wall_s"]
-    for c, v in rec["components"].items():
-        h.observe(v, component=c)
-        g.set(v / wall if wall > 0 else 0.0, component=c)
-    r.histogram("graft_lens_step_seconds",
-                "Attributed step wall time (window end to end)", (),
-                buckets=_PHASE_BUCKETS).observe(wall)
-    if rec["comm_inflight_s"] > 0:
-        r.gauge("graft_lens_comm_hidden_ratio",
-                "1 - blocked/in-flight collective time of the last "
-                "COMM-BEARING step (holds its value across comm-free "
-                "steps; how much comm the overlap hid)").set(
-            max(0.0, min(1.0, 1.0 - rec["comm_blocked_s"]
-                         / rec["comm_inflight_s"])))
-    dev = rec.get("device")
-    if dev is not None:
-        # device-time lens (PR 8 carry-forward): sync-mode flush spans /
-        # serving batch dispatches book true device latency per window
-        r.histogram("graft_lens_device_busy_seconds",
-                    "Per-step device-busy time (profiler sync-mode "
-                    "flushes + serving batch dispatches)", (),
-                    buckets=_PHASE_BUCKETS).observe(dev["busy_s"])
-        r.gauge("graft_lens_device_busy_fraction",
-                "Last device-bearing step's device-busy fraction of "
-                "wall (busy + idle == wall exactly)").set(
-            dev["busy_s"] / wall if wall > 0 else 0.0)
-
-
-# -- graftpulse: memory timeline + autotuner ---------------------------------
-
-
-def mem_sample(site, in_use, peak):
-    """One device-memory watermark sample at an attribution site
-    (telemetry/lens.py ``mem_sample``: engine flush boundaries, fused/
-    duplex buckets, serving batches)."""
-    if not enabled():
-        return
-    r = _REGISTRY
-    r.gauge("graft_mem_peak_bytes",
-            "Live device-bytes watermark by attribution site (window-"
-            "local; the allocator's lifetime peak would tie every site)",
-            ("site",)).set(peak, site=site)
-    r.gauge("graft_mem_bytes_in_use",
-            "Device bytes in use at the last memory-timeline sample"
-            ).set(in_use)
-
-
-def autotune_decision(signal, target, old, new):
-    """One autotuner control decision (telemetry/autotune.py) — the
-    controller is itself observable: every decision counts here and
-    journals as a blackbox ``autotune_decision`` event."""
-    if not enabled():
-        return
-    _REGISTRY.counter("graft_autotune_decisions_total",
-                      "Autotuner control decisions by signal",
-                      ("signal",)).inc(signal=signal)
-    _REGISTRY.gauge("graft_autotune_setting",
-                    "Current value of each autotuned knob",
-                    ("target",)).set(float(new), target=target)
-
-
-def autotune_signal(name, value):
-    """The controller's view of its input signals (window means)."""
-    if not enabled():
-        return
-    _REGISTRY.gauge("graft_autotune_signal",
-                    "Autotuner input signal (window mean)",
-                    ("signal",)).set(float(value), signal=name)
-
-
 # -- graftwatch: watchdog + dist liveness ------------------------------------
 
 _SKEW_BUCKETS = (1e-4, 1e-3, 1e-2, 0.1, 0.5, 1.0, 5.0, 30.0)

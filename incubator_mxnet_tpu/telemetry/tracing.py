@@ -36,10 +36,9 @@ import time
 import jax
 
 from . import blackbox as _blackbox
-from . import lens as _lens
 from . import metrics as _metrics
 
-__all__ = ["phase_span", "next_segment_id", "record_active",
+__all__ = ["phase_span", "current_step", "next_segment_id", "record_active",
            "deferred_op_event", "segment_flush_span",
            "segment_summary", "validate_chrome_trace",
            "process_metadata_events", "trace_header"]
@@ -49,6 +48,8 @@ _segment_ids = itertools.count(1)
 FLOW_NAME = "bulk"
 FLOW_CAT = "engine.flow"
 SEGMENT_SPAN = "bulk_segment_flush"
+
+current_step = _blackbox.current_step
 
 
 def next_segment_id():
@@ -95,10 +96,8 @@ def segment_flush_span(segment, cause, begin_us, end_us, flow_indices,
             "live_outputs": live_outputs,
             "cache": "hit" if cache_hit else "miss",
             "recorded": bool(recorded),
-            "device_time": bool(device_time)}
-    step = _lens.current_step()
-    if step is not None:
-        args["step"] = step      # graftlens: flush → step attribution key
+            "device_time": bool(device_time),
+            "step": current_step()}
     if error:
         args["error"] = True
     p.record_event(SEGMENT_SPAN, begin_us, end_us, cat="engine", args=args)
@@ -122,7 +121,7 @@ class _PhaseSpan(object):
     (name, start, end, parent, step id, on ``time.perf_counter()``) goes
     to the flight recorder (``telemetry.spans()``).  It also emits a
     chrome event (cat "phase") when ``mx.profiler`` runs and feeds
-    graft_phase_seconds and the lens.  The span closes on the exception
+    graft_phase_seconds.  The span closes on the exception
     path too: the chrome event (marked ``error``), the histogram
     observation AND the flight-recorder phase bracket all land, so a
     crash mid-phase leaves a well-formed trace and a dump that names the
@@ -145,9 +144,9 @@ class _PhaseSpan(object):
         self.parent = outer.phase if outer is not None else None
         if self.step is None:
             # a span belongs to its parent's step; one opened outside any
-            # other, to the step window the lens has open on this thread
+            # other, to the step in progress on this thread
             self.step = (outer.step if outer is not None
-                         else _lens.current_step())
+                         else current_step())
         stack.append(self)
         self._begin = _prof()._now_us()
         self._bb = _blackbox.phase_begin(self.phase)
@@ -175,7 +174,6 @@ class _PhaseSpan(object):
             p.record_event(self.phase, self._begin, p._now_us(),
                            cat="phase", args=args)
         _metrics.phase(self.phase, t1 - self._t0)
-        _lens.phase(self.phase, self._t0, t1)
         _blackbox.phase_end(self._bb, self.phase, self._t0, t1,
                             parent=self.parent, step=self.step,
                             error=exc_type is not None)
@@ -199,10 +197,9 @@ def phase_span(phase, args=None, step=None):
     """Context manager for one program span (``_PhaseSpan``).  ``args``
     (strings and numbers) ride the profiler events; ``step`` is given by
     a span that starts a step, and inherited by those opened in it.  Free
-    when the profiler, telemetry, the flight recorder AND the lens are
-    all off."""
+    when the profiler, telemetry AND the flight recorder are all off."""
     if not _metrics.enabled() and not _prof()._P.active() \
-            and not _blackbox.enabled() and not _lens.enabled():
+            and not _blackbox.enabled():
         return _NULL
     return _PhaseSpan(phase, args, step)
 

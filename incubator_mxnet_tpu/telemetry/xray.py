@@ -156,7 +156,7 @@ def step_rows(by_step):
     """The device-ledger row convention shared by ``--ingest-xla`` and
     :func:`attribute`: per-step busy unions, step windows
     chained previous-end → this-end (so ``busy_s + idle_s == wall_s``
-    holds exactly per row, the live-lens contract), and a UNION total
+    holds exactly per row), and a UNION total
     (not a sum — the pooled unattributed row's window overlaps the
     stamped rows').  Returns ``(rows, nonmono, total)``."""
     nonmono = []

@@ -1,15 +1,11 @@
-"""graftlens tests: per-step attribution conservation, overlap-aware
-comm accounting, step-id threading, the cross-rank aggregator +
-straggler table, metadata/flow trace validation, the rank-suffixed dump
-path, and the 2-proc dist harness with a deliberately delayed rank.
+"""Step-id threading through the flight recorder, the cross-rank
+aggregator + straggler table, metadata/flow trace validation, the
+rank-suffixed dump path, and the 2-proc dist harness with a deliberately
+delayed rank.
 
-Covers the ISSUE-8 acceptance surface: the six lens components must sum
-to the measured step wall time (including an overlapped PR-7 step where
-``exposed_comm`` < total collective time and a serial step where they
-are equal), and ``--analyze`` over two ranks' artifacts must produce a
-schema-valid merged chrome trace with per-rank tracks, cross-rank flow
-links per reduced bucket, and a straggler table naming the delayed
-rank.
+``--analyze`` over two ranks' artifacts must produce a schema-valid
+merged chrome trace with per-rank tracks, cross-rank flow links per
+reduced bucket, and a straggler table naming the delayed rank.
 """
 import json
 import os
@@ -24,19 +20,9 @@ import pytest
 
 import incubator_mxnet_tpu as mx
 from incubator_mxnet_tpu import autograd, gluon
-from incubator_mxnet_tpu.telemetry import aggregate, blackbox, lens
+from incubator_mxnet_tpu.telemetry import aggregate, blackbox
 from incubator_mxnet_tpu.telemetry import tracing as ttracing
 from incubator_mxnet_tpu.telemetry.__main__ import main as telemetry_main
-
-
-@pytest.fixture
-def fresh_lens():
-    """A clean, force-enabled lens for one test."""
-    lens.set_enabled(True)
-    lens.reset()
-    yield lens
-    lens.reset()
-    lens.set_enabled(None)
 
 
 def _build_params(n, shape=(8, 8), prefix="lp", seed=0):
@@ -62,199 +48,15 @@ def _train_steps(ps, trainer, n):
     ps[-1].data().asnumpy()
 
 
-def _assert_conserved(rec):
-    total = sum(rec["components"].values())
-    assert total == pytest.approx(rec["wall_s"], abs=1e-6), \
-        (rec["components"], rec["wall_s"])
-    for v in rec["components"].values():
-        assert v >= 0.0
-
-
-# ---------------------------------------------------------------------------
-# attribution conservation
-# ---------------------------------------------------------------------------
-
-def test_components_sum_to_step_wall_time(fresh_lens):
-    """The conservation contract over a full training loop with every
-    source lit: io iterator, record scope, backward, a local kvstore,
-    the fused update."""
-    from incubator_mxnet_tpu import io
-    net = gluon.nn.Dense(4)
-    net.initialize()
-    rs = np.random.RandomState(0)
-    x = rs.rand(24, 8).astype(np.float32)
-    y = np.zeros((24, 4), np.float32)
-    net(mx.nd.array(x[:4])).asnumpy()
-    trainer = gluon.Trainer(net.collect_params(), "sgd",
-                            {"learning_rate": 0.1},
-                            kvstore=mx.kv.create("local"))
-    it = io.NDArrayIter(data=x, label=y, batch_size=4)
-    for batch in it:
-        with autograd.record():
-            out = net(batch.data[0])
-            loss = (out * out).mean()
-        loss.backward()
-        trainer.step(4)
-        loss.asnumpy()
-    recs = lens.steps()
-    assert len(recs) == 6
-    for rec in recs:
-        _assert_conserved(rec)
-    # steady-state steps exercise every component source
-    steady = recs[-1]
-    assert steady["components"]["forward"] > 0
-    assert steady["components"]["backward_compute"] > 0
-    assert steady["components"]["optimizer_update"] > 0
-    assert steady["components"]["exposed_comm"] > 0   # kv push/pull
-    assert any(r["components"]["data_wait"] > 0 for r in recs)
-    assert steady["io_waits"] >= 1 and steady["collectives"] >= 1
-
-
-def test_overlapped_step_hides_comm_serial_step_does_not(fresh_lens):
-    """ISSUE-8 conservation satellite: on the overlapped (PR 7) path
-    ``exposed_comm`` (blocked) < total collective in-flight time; with
-    GRAFT_OVERLAP off the two book EQUAL by construction.  Conservation
-    holds on both."""
-    def run(overlap, prefix):
-        lens.reset()
-        ps = _build_params(8, prefix=prefix)
-        t = gluon.Trainer(ps, "sgd", {"learning_rate": 0.01},
-                          kvstore=mx.kv.create("dist_sync"))
-        t._bucket_bytes_override = 1024
-        t._overlap_override = overlap
-        _train_steps(ps, t, 4)
-        return lens.steps()
-
-    serial = run(False, "ls")
-    for rec in serial:
-        _assert_conserved(rec)
-        # sync brackets book blocked == in-flight identically
-        assert rec["comm_blocked_s"] == rec["comm_inflight_s"]
-
-    overlapped = run(True, "lo")
-    for rec in overlapped:
-        _assert_conserved(rec)
-    last = overlapped[-1]
-    assert last.get("overlapped") is True
-    # the reduce was issued mid-backward: its in-flight span covers the
-    # rest of the walk, while step() only paid the wait
-    assert last["comm_blocked_s"] < last["comm_inflight_s"]
-
-
-def test_lens_survives_disabled_blackbox(fresh_lens):
-    """Step windows must close (via _LensOnlyStep) AND collective
-    brackets must keep feeding comm accounting (light-mode bracket)
-    when the flight recorder is off."""
-    prev = blackbox._enabled_override
-    blackbox.set_enabled(False)
-    before = len(blackbox.events())
-    try:
-        ps = _build_params(2, prefix="lb")
-        t = gluon.Trainer(ps, "sgd", {"learning_rate": 0.01},
-                          kvstore=mx.kv.create("local"))
-        _train_steps(ps, t, 3)
-        assert len(blackbox.events()) == before    # recorder really off
-    finally:
-        blackbox.set_enabled(prev)
-    recs = lens.steps()
-    assert len(recs) == 3
-    for rec in recs:
-        _assert_conserved(rec)
-    # the kvstore reduce still booked as exposed communication
-    assert recs[-1]["collectives"] >= 1
-    assert recs[-1]["comm_blocked_s"] > 0
-    assert recs[-1]["components"]["exposed_comm"] > 0
-
-
-def test_disabled_lens_is_a_noop():
-    lens.set_enabled(False)
-    try:
-        lens.reset()
-        lens.interval("forward", 0.0, 1.0)
-        lens.io_wait(0.0, 1.0)
-        lens.comm(0.0, 1.0)
-        assert lens.step_end("t") is None
-        assert lens.steps() == []
-        assert lens.current_step() is None
-    finally:
-        lens.set_enabled(None)
-        lens.reset()
-
-
-def test_open_window_is_bounded_without_step_boundaries(fresh_lens):
-    """A serving/eval loop (hooks fire, step_end never does) must not
-    grow the open window without bound."""
-    for i in range(3 * lens._MAX_OPEN_INTERVALS):
-        lens.io_wait(float(i), float(i) + 0.5)
-    st = lens._state()
-    assert len(st.intervals) <= lens._MAX_OPEN_INTERVALS
-    rec = lens.step_end("eval")        # a late step still conserves
-    _assert_conserved(rec)
-
-
-def test_toggle_does_not_book_ghost_step(fresh_lens):
-    """A window left open across a disabled period must be dropped on
-    re-enable, not billed as one giant host_gap step."""
-    ps = _build_params(2, prefix="lg")
-    t = gluon.Trainer(ps, "sgd", {"learning_rate": 0.01}, kvstore=None)
-    _train_steps(ps, t, 1)
-    lens.set_enabled(False)
-    time.sleep(0.2)                        # "trains" with the lens off
-    lens.set_enabled(True)
-    _train_steps(ps, t, 1)
-    recs = lens.steps()
-    assert len(recs) == 2
-    _assert_conserved(recs[-1])
-    # the disabled 0.2s must NOT appear in the re-enabled step's window
-    assert recs[-1]["wall_s"] < 0.15, recs[-1]
-
-
-def test_priority_sweep_never_double_counts(fresh_lens):
-    """Overlapping intervals of different categories attribute each
-    elementary slice exactly once, highest priority first."""
-    # forward covers [0, 10]; bwd [4, 8] nested; comm [6, 12] overlaps
-    intervals = [("forward", 0.0, 10.0),
-                 ("backward_compute", 4.0, 8.0),
-                 ("exposed_comm", 6.0, 12.0)]
-    comp, attributed = lens._attribute(intervals, 0.0, 20.0)
-    assert comp["forward"] == pytest.approx(4.0)           # [0,4]
-    assert comp["backward_compute"] == pytest.approx(2.0)  # [4,6]
-    assert comp["exposed_comm"] == pytest.approx(6.0)      # [6,12]
-    assert attributed == pytest.approx(12.0)
-    # clipping to the window
-    comp, attributed = lens._attribute(intervals, 5.0, 11.0)
-    assert comp["forward"] == pytest.approx(0.0)
-    assert comp["backward_compute"] == pytest.approx(1.0)  # [5,6]
-    assert comp["exposed_comm"] == pytest.approx(5.0)      # [6,11]
-    assert attributed == pytest.approx(6.0)
-
-
-def test_ring_bound_and_report(fresh_lens, capfd, monkeypatch):
-    monkeypatch.setenv("GRAFT_LENS_RING", "4")
-    monkeypatch.setenv("GRAFT_STEP_REPORT", "2")
-    lens.configure()
-    try:
-        ps = _build_params(2, prefix="lr")
-        t = gluon.Trainer(ps, "sgd", {"learning_rate": 0.01}, kvstore=None)
-        _train_steps(ps, t, 6)
-        recs = lens.steps()
-        assert len(recs) == 4                  # ring bound
-        assert recs[-1]["step"] == 6
-        err = capfd.readouterr().err
-        assert "graftlens step 2" in err and "graftlens step 6" in err
-        assert "graftlens step 3" not in err   # off-cadence steps silent
-    finally:
-        monkeypatch.delenv("GRAFT_LENS_RING")
-        lens.configure()
-
-
 # ---------------------------------------------------------------------------
 # step-id threading (flushes + collectives + journals share the key)
 # ---------------------------------------------------------------------------
 
-def test_step_id_threaded_through_ring_events(fresh_lens):
+def test_step_id_threaded_through_ring_events():
     blackbox.set_enabled(True)
     blackbox._ring.clear()
+    blackbox._tls.steps_closed = 0      # this thread's ids start over
+    assert ttracing.current_step() == 1
     try:
         ps = _build_params(4, prefix="lt")
         t = gluon.Trainer(ps, "sgd", {"learning_rate": 0.01},
@@ -263,19 +65,36 @@ def test_step_id_threaded_through_ring_events(fresh_lens):
         evs = blackbox.events()
         steps = [e["data"] for e in evs if e["kind"] == "step"]
         assert [s["step"] for s in steps] == [1, 2, 3]
-        assert all("lens" in s for s in steps)
+        assert not any("lens" in s for s in steps)
         # collectives carry the step they ran under plus a lockstep seq
         colls = [e["data"] for e in evs if e["kind"] == "collective"]
         assert colls
         assert all("seq" in c for c in colls)
         seqs = [c["seq"] for c in colls]
         assert seqs == sorted(seqs) and len(set(seqs)) == len(seqs)
-        coll_steps = {c["step"] for c in colls if "step" in c}
-        assert coll_steps and coll_steps <= {1, 2, 3}
-        # the journal's lens fold conserves too (ms view)
-        fold = steps[-1]["lens"]
-        parts = sum(fold[c + "_ms"] for c in lens.COMPONENTS)
-        assert parts == pytest.approx(fold["wall_ms"], abs=0.01)
+        assert {c["step"] for c in colls} <= {1, 2, 3}
+        # every span of a step carries the id its journal closed with
+        by_step = {}
+        for name, _t0, _t1, _parent, step in blackbox.spans():
+            by_step.setdefault(step, set()).add(name)
+        assert all({"bwd", "kvstore", "update"} <= by_step[k]
+                   for k in (1, 2, 3)), by_step
+        assert ttracing.current_step() == 4
+    finally:
+        blackbox.set_enabled(None)
+
+
+def test_step_id_advances_with_the_recorder_off():
+    """Chrome spans still carry step ids under GRAFT_BLACKBOX=0: the
+    journal's place is taken by a boundary that only counts."""
+    blackbox.set_enabled(False)
+    try:
+        ps = _build_params(2, prefix="lo")
+        t = gluon.Trainer(ps, "sgd", {"learning_rate": 0.01},
+                          kvstore=mx.kv.create("local"))
+        before = ttracing.current_step()
+        _train_steps(ps, t, 2)
+        assert ttracing.current_step() == before + 2
     finally:
         blackbox.set_enabled(None)
 
@@ -433,7 +252,7 @@ def test_async_collectives_never_corrupt_clock_or_exit_blame(tmp_path):
         assert r["enter_spread_s"] == pytest.approx(0.0, abs=1e-6)
 
 
-def test_aggregate_mixed_trace_and_dump(tmp_path, fresh_lens):
+def test_aggregate_mixed_trace_and_dump(tmp_path):
     """A real profiler trace of this process merges with a synthetic
     peer dump: collective chrome spans carry seq/step so the join works
     across artifact kinds."""
@@ -518,17 +337,6 @@ def test_cli_analyze_and_steps(tmp_path, capsys):
     assert "straggler table" in out and "worst rank: 1" in out
 
 
-def test_cli_steps_renders_live_ring(capsys):
-    rc = telemetry_main(["--steps", "--json"])
-    out = capsys.readouterr().out
-    assert rc == 0
-    doc = json.loads(out)
-    assert doc["summary"]["steps"] == len(doc["steps"]) > 0
-    for rec in doc["steps"]:
-        total = sum(rec["components"].values())
-        assert total == pytest.approx(rec["wall_s"], abs=1e-6)
-
-
 # ---------------------------------------------------------------------------
 # the 2-proc dist harness: a deliberately delayed rank must be named
 # ---------------------------------------------------------------------------
@@ -560,7 +368,7 @@ def _skipwrap(body):
 _LENS_WORKER = """
     import time
     from incubator_mxnet_tpu import autograd, gluon
-    from incubator_mxnet_tpu.telemetry import blackbox, lens
+    from incubator_mxnet_tpu.telemetry import blackbox
 
     kv = mx.kv.create("dist_sync")
     rank, nw = kv.rank, kv.num_workers
@@ -588,13 +396,6 @@ _LENS_WORKER = """
         t.step(1)
     ps[-1].data().asnumpy()
 
-    # in-worker conservation check over the whole dist loop
-    recs = lens.steps()
-    assert len(recs) >= 4, recs
-    for r in recs:
-        total = sum(r["components"].values())
-        assert abs(total - r["wall_s"]) < 1e-6, (r["components"],
-                                                 r["wall_s"])
     out = blackbox.dump(path=r"%(dir)s/lens_bb.rank%%d.json" %% rank,
                         reason="manual")
     assert out, "dump failed"

@@ -7,8 +7,11 @@ Every program here is read from the optimized HLO of a fresh compile: JAX's
 persistent cache leaves op metadata out of its key, so a cached executable
 may carry the scopes of an older build of the same program.
 """
+import contextlib
 import glob
 import re
+import threading
+import time
 
 import numpy as np
 import pytest
@@ -381,3 +384,141 @@ def test_spans_land_in_the_jax_profilers_trace(tmp_path):
              if plane.name.startswith("/host:")
              for line in plane.lines for ev in line.events}
     assert "mx:update" in names
+
+
+# ---------------------------------------------------------------------------
+# C. one way to watch a step: the loops leave spans and a journal, and no
+#    thread of the package's behind them
+# ---------------------------------------------------------------------------
+
+def _square_params(prefix, n=3):
+    rs = np.random.RandomState(0)
+    ps = []
+    for k in range(n):
+        p = gluon.Parameter("%s%d" % (prefix, k), shape=(8, 8))
+        p.initialize(ctx=mx.cpu())
+        p.data()._write(rs.randn(8, 8).astype(np.float32))
+        ps.append(p)
+    return ps
+
+
+def _param_loop(prefix, n, bulk):
+    ps = _square_params(prefix)
+    trainer = gluon.Trainer(ps, "sgd", {"learning_rate": 0.01},
+                            kvstore=mx.kv.create("local"))
+    for _ in range(n):
+        with engine.bulk(64) if bulk else contextlib.nullcontext():
+            with autograd.record():
+                loss = sum((p.data() * p.data()).sum() for p in ps)
+            loss.backward()
+        trainer.step(1)
+    ps[-1].data().asnumpy()
+
+
+def _loop_deferred_bulk(n):
+    _param_loop("wt_bulk", n, bulk=True)
+    return "step", {"bwd", "kvstore", "update"}
+
+
+def _loop_unbulked_eager(n):
+    _param_loop("wt_eager", n, bulk=False)
+    return "step", {"bwd", "kvstore", "update"}
+
+
+def _loop_trainer_local_kvstore(n):
+    net = _mlp("wt_gluon_")
+    net.hybridize()
+    x, y = _batch()
+    trainer = gluon.Trainer(net.collect_params(), "sgd",
+                            {"learning_rate": 0.1},
+                            kvstore=mx.kv.create("local"))
+    loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
+    for _ in range(n):
+        with autograd.record():
+            loss = loss_fn(net(mx.nd.array(x)), mx.nd.array(y))
+        loss.backward()
+        trainer.step(x.shape[0])
+    loss.asnumpy()
+    return "step", {"fwd", "bwd", "kvstore", "update"}
+
+
+def _loop_module_update(n):
+    data = mx.sym.Variable("data")
+    fc = mx.sym.FullyConnected(data, num_hidden=4, name="wt_fc")
+    m = mx.mod.Module(mx.sym.SoftmaxOutput(fc, name="softmax"),
+                      context=mx.cpu())
+    m.bind(data_shapes=[("data", (6, 10))],
+           label_shapes=[("softmax_label", (6,))])
+    m.init_params(initializer=mx.init.Xavier())
+    m.init_optimizer(optimizer="sgd")
+    rs = np.random.RandomState(0)
+    batch = mx.io.DataBatch(
+        data=[mx.nd.array(rs.rand(6, 10).astype(np.float32))],
+        label=[mx.nd.array(rs.randint(0, 4, (6,)).astype(np.float32))])
+    for _ in range(n):
+        m.forward_backward(batch)
+        m.update()
+    m.get_outputs()[0].asnumpy()
+    return "step", {"fwd", "bwd", "update"}
+
+
+def _loop_compile_step(n):
+    net = _mlp("wt_compiled_")
+    x, y = _batch()
+    trainer = gluon.Trainer(net.collect_params(), "sgd",
+                            {"learning_rate": 0.1}, kvstore=None)
+    cstep = trainer.compile_step(
+        net, loss=gluon.loss.SoftmaxCrossEntropyLoss(), enabled=True)
+    for _ in range(n):
+        out = cstep(mx.nd.array(x), mx.nd.array(y), batch_size=x.shape[0])
+    out.asnumpy()
+    assert cstep.compiled_steps >= n - 2
+    return "step", {"update"}
+
+
+def _loop_serving_batches(n):
+    from incubator_mxnet_tpu import serving
+    net = _mlp("wt_serve_")
+    net.hybridize()
+    x, _y = _batch()
+    with serving.Server(max_batch=4, max_wait_ms=1) as srv:
+        srv.load("m", block=net, example=mx.nd.array(x[:1]))
+        for _ in range(n):
+            srv.submit("m", x[0]).get(timeout=60.0)
+    return "serve_batch", set()
+
+
+def _package_threads():
+    return {t for t in threading.enumerate()
+            if t.is_alive() and t.name.startswith("graft")}
+
+
+@pytest.mark.parametrize("loop", [
+    _loop_deferred_bulk, _loop_unbulked_eager, _loop_trainer_local_kvstore,
+    _loop_module_update, _loop_compile_step, _loop_serving_batches],
+    ids=lambda f: f.__name__[len("_loop_"):])
+def test_loop_leaves_spans_and_no_watcher_thread(loop):
+    n = 4
+    before = _package_threads()
+    blackbox._ring.clear()
+    mark = time.perf_counter()
+    kind, names = loop(n)
+    # the journal: one event a step, stamped with ids that rise by one,
+    # and none of them carries a second decomposition of the step
+    closed = [e["data"] for e in blackbox.events() if e["kind"] == kind]
+    assert len(closed) == n
+    ids = [e["step"] for e in closed]
+    assert ids == list(range(ids[0], ids[0] + n))
+    assert not any("lens" in e for e in closed)
+    # the spans: every step's carry the id its journal closed with
+    by_step = {}
+    for name, _t0, _t1, parent, step in telemetry.spans(since=mark):
+        if parent is None:
+            by_step.setdefault(step, set()).add(name)
+    for step in ids:
+        assert names <= by_step.get(step, set()), (step, by_step)
+    # the threads: whatever the loop started has ended with it
+    deadline = time.monotonic() + 5.0
+    while _package_threads() - before and time.monotonic() < deadline:
+        time.sleep(0.01)
+    assert not {t.name for t in _package_threads() - before}

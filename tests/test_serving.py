@@ -2,7 +2,7 @@
 shape buckets and dtypes, max-wait timeout flush, LRU eviction under a
 tight budget, mid-traffic hot-swap with no torn weights, watchdog-named
 stalled batches, per-request SLO conservation, the parity-probe demotion
-rail, the in-memory C-predict loader, the device-time lens, and
+rail, the in-memory C-predict loader, and
 GRAFT_TSAN coverage of the serving threads + KVStore._store."""
 import json
 import subprocess
@@ -16,7 +16,7 @@ import pytest
 import incubator_mxnet_tpu as mx
 from incubator_mxnet_tpu import gluon, serving
 from incubator_mxnet_tpu.analysis import tsan
-from incubator_mxnet_tpu.telemetry import blackbox, lens, watchdog
+from incubator_mxnet_tpu.telemetry import blackbox, watchdog
 
 DIN, DHID, DOUT = 12, 16, 4
 
@@ -730,55 +730,6 @@ def test_watchdog_names_stalled_batch(monkeypatch, tmp_path):
         assert wdinfo["tripped_detail"]["size"] == 1
     finally:
         blackbox.set_enabled(None)
-
-
-# ---------------------------------------------------------------------------
-# device-time lens
-# ---------------------------------------------------------------------------
-
-def test_device_lens_books_sync_flush():
-    """Under profiler sync mode an engine flush books device latency on
-    the lens device ledger; busy + idle == wall exactly."""
-    from incubator_mxnet_tpu import profiler, engine
-    lens.set_enabled(True)
-    lens.reset()
-    profiler.set_config(profile_all=True, sync=True)
-    profiler.set_state("run")
-    try:
-        # unique shape: other suites rely on (a*a)+a replay-cache MISSES
-        # for their own shapes — do not pre-populate theirs
-        a = mx.nd.array(np.ones((6, 9), np.float32))
-        with engine.bulk(8):
-            ((a * a) + a).asnumpy()
-        rec = lens.step_end("test_device")
-    finally:
-        profiler.set_state("stop")
-        profiler.dumps(reset=True)      # drain the event buffer so the
-        lens.set_enabled(None)          # next profiled test starts clean
-        lens.reset()
-    assert rec is not None and "device" in rec
-    dev = rec["device"]
-    assert dev["busy_s"] > 0 and dev["spans"] >= 1
-    assert dev["busy_s"] + dev["idle_s"] == rec["wall_s"]
-
-
-def test_serving_batches_land_on_lens_device_ledger():
-    lens.set_enabled(True)
-    lens.reset()
-    try:
-        net = _mlp()
-        _serve(net, n_req=6)
-        recs = [r for r in lens.steps() if r["origin"] == "serve_batch"]
-        assert recs
-        assert any("device" in r and r["device"]["busy_s"] > 0
-                   for r in recs)
-        for r in recs:
-            if "device" in r:
-                d = r["device"]
-                assert d["busy_s"] + d["idle_s"] == r["wall_s"]
-    finally:
-        lens.set_enabled(None)
-        lens.reset()
 
 
 # ---------------------------------------------------------------------------

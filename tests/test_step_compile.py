@@ -18,12 +18,9 @@ The contract under test (gluon/step_compile.py):
 * **Boundary** — behind a store the cross-worker reduce stays at the
   program boundary via the existing ``reduce_many`` wire (labeled
   ``compiled_step`` in the flight recorder).
-* **Telemetry** — a compiled step books a conservation-exact lens
-  window carrying ``compiled: True``.
 * **Satellites** — first-touch pull ordering
   (``Trainer.note_first_touch_order`` / ``GRAFT_BUCKET_ORDER=touch``),
-  the ``GRAFT_PREFETCH_DEPTH`` DataLoader knob, and the autotuner's
-  worker→prefetch escalation.
+  and the ``GRAFT_PREFETCH_DEPTH`` DataLoader knob.
 """
 import os
 import time
@@ -36,7 +33,7 @@ from incubator_mxnet_tpu import autograd, gluon
 from incubator_mxnet_tpu import optimizer as opt
 from incubator_mxnet_tpu.gluon.step_compile import (
     CompiledStep, max_ulp_diff, step_compile_enabled)
-from incubator_mxnet_tpu.telemetry import autotune, blackbox, lens
+from incubator_mxnet_tpu.telemetry import blackbox
 
 import jax.numpy as jnp
 
@@ -358,45 +355,6 @@ def test_kill_switch_and_recording_guard(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# telemetry: lens conservation + compiled flag
-# ---------------------------------------------------------------------------
-
-def test_compiled_step_books_conserved_lens_window():
-    lens.set_enabled(True)
-    lens.reset()
-    try:
-        _net_e, _tr_e, net_c, tr_c, cstep = make_pair(
-            "sgd", {"learning_rate": 0.05, "momentum": 0.9})
-        rng = np.random.RandomState(23)
-        for _ in range(4):
-            cstep(xbatch(rng))
-        net_c.collect_params()[
-            sorted(net_c.collect_params())[-1]].data().asnumpy()
-        lens.pulse_drain(5.0)
-        recs = lens.steps()
-        assert len(recs) == 4
-        for rec in recs:
-            total = sum(rec["components"].values())
-            assert total == pytest.approx(rec["wall_s"], abs=1e-6), \
-                (rec["components"], rec["wall_s"])
-            for v in rec["components"].values():
-                assert v >= 0.0
-        steady = recs[-1]
-        assert steady.get("compiled") is True
-        assert recs[0].get("compiled") is None      # the eager fallback
-        # the programs were booked through the pulse ledger: some device
-        # time must have landed inside the window
-        assert steady["components"]["optimizer_update"] > 0 \
-            or steady["device_busy_s"] >= 0.0
-        # the compiled flag survives into the compact stream
-        assert lens.compact(steady).get("compiled") is True
-    finally:
-        lens.pulse_drain(5.0)
-        lens.reset()
-        lens.set_enabled(None)
-
-
-# ---------------------------------------------------------------------------
 # satellite: first-touch pull ordering
 # ---------------------------------------------------------------------------
 
@@ -448,7 +406,7 @@ def test_bucket_order_touch_mode(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# satellite: GRAFT_PREFETCH_DEPTH + autotuner escalation
+# satellite: GRAFT_PREFETCH_DEPTH
 # ---------------------------------------------------------------------------
 
 def test_prefetch_depth_knob(monkeypatch):
@@ -474,74 +432,6 @@ def test_prefetch_depth_knob(monkeypatch):
         out = [b for b in loader]
         assert len(out) == 8                # depth never changes content
     finally:
-        loader.close()
-
-
-def _fake_rec(step, wall=0.1, data_wait=0.06):
-    comp = {c: 0.0 for c in lens.COMPONENTS}
-    comp["data_wait"] = data_wait
-    comp["host_gap"] = wall - data_wait
-    return {"step": step, "origin": "trainer", "wall_s": wall,
-            "components": comp, "comm_blocked_s": 0.0,
-            "comm_inflight_s": 0.0, "collectives": 0, "io_waits": 0}
-
-
-def test_autotune_escalates_to_prefetch_when_workers_capped():
-    """Workers grow first; once the starved loader is at the worker cap,
-    the SAME data_wait signal doubles its prefetch depth instead —
-    journaled, cooldown'd, capped at max_prefetch."""
-    from incubator_mxnet_tpu.gluon.data import DataLoader
-    from incubator_mxnet_tpu.gluon.data.dataset import ArrayDataset
-    ds = ArrayDataset(mx.nd.array(np.arange(16, dtype=np.float32)))
-    loader = DataLoader(ds, batch_size=2, num_workers=2,
-                        prefetch_device=False)
-    loader._blocked_wait_s = 1.0            # looks starved to the ranker
-    autotune.set_enabled(True)
-    ctrl = autotune.Autotuner(interval=1, cooldown=0, data_wait_bound=0.2,
-                              max_workers=2, max_prefetch=8)
-    try:
-        ctrl.attach_loader(loader)
-        marker = time.time()
-        ctrl.on_step(_fake_rec(0))
-        # workers were already at the cap → the prefetch knob moved
-        assert loader._num_workers == 2
-        assert loader.prefetch_depth() == 4
-        ctrl.on_step(_fake_rec(1))
-        assert loader.prefetch_depth() == 8
-        ctrl.on_step(_fake_rec(2))
-        assert loader.prefetch_depth() == 8  # max_prefetch cap holds
-        grows = [d for d in ctrl.decisions()
-                 if d["target"] == "prefetch_depth"]
-        assert [(d["old"], d["new"]) for d in grows] == [(2, 4), (4, 8)]
-        evs = [e for e in blackbox.events()
-               if e.get("kind") == "autotune_decision"
-               and e.get("ts", 0) >= marker
-               and e.get("data", {}).get("target") == "prefetch_depth"]
-        assert len(evs) == 2
-    finally:
-        autotune.set_enabled(None)
-        loader.close()
-
-
-def test_autotune_worker_growth_still_first():
-    """A loader below the worker cap grows workers, NOT prefetch —
-    escalation only fires when worker growth is exhausted."""
-    from incubator_mxnet_tpu.gluon.data import DataLoader
-    from incubator_mxnet_tpu.gluon.data.dataset import ArrayDataset
-    ds = ArrayDataset(mx.nd.array(np.arange(16, dtype=np.float32)))
-    loader = DataLoader(ds, batch_size=2, num_workers=1,
-                        prefetch_device=False)
-    loader._blocked_wait_s = 1.0
-    autotune.set_enabled(True)
-    ctrl = autotune.Autotuner(interval=1, cooldown=0, data_wait_bound=0.2,
-                              max_workers=4, max_prefetch=8)
-    try:
-        ctrl.attach_loader(loader)
-        ctrl.on_step(_fake_rec(0))
-        assert loader._num_workers == 2
-        assert loader.prefetch_depth() == 2  # untouched
-    finally:
-        autotune.set_enabled(None)
         loader.close()
 
 

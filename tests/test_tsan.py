@@ -920,7 +920,7 @@ _DIVERGENCE_WORKER = textwrap.dedent("""
                if e["kind"] == "lockstep_divergence"]
         assert evs, "no flight-recorder divergence event"
         print("WORKER %d DIVERGENCE seq=%d peers=%s OK"
-              % (rank, div["first_divergent_seq"], peers), flush=True)
+              % (rank, div["first_divergent_fold"], peers), flush=True)
     except Exception:
         tb = traceback.format_exc()
         if "Multiprocess computations aren't implemented" in tb:
@@ -942,3 +942,72 @@ def test_two_process_forced_divergence(tmp_path):
     assert "WORKER 0 DIVERGENCE" in out and "WORKER 1 DIVERGENCE" in out, \
         out[-3000:]
     assert "WATCHDOG TRIP" not in out, out[-3000:]
+
+
+# ---------------------------------------------------------------------------
+# lockstep online bisection
+# ---------------------------------------------------------------------------
+
+def test_lockstep_pins_skipped_collective_online():
+    """A rank that SKIPS one mid-stream collective is not just named —
+    the lagged-prefix points bracket the divergence to adjacent folds
+    and the report pins the exact collective from the local table."""
+    from incubator_mxnet_tpu.analysis import lockstep as ls
+    ls.reset()
+    ls.set_enabled(True)
+    try:
+        def digest(i):
+            return ls._crc("reduce_many|1|%d|%d"
+                           % (4096 + i, ls.keys_digest(["k%d" % i])))
+
+        for i in range(1, 11):
+            ls.fold(i, "reduce_many", n_keys=1, nbytes=4096 + i,
+                    keys=["k%d" % i])
+        # simulate the peer's stream: identical minus collective #5
+        rolling, foldn, points = 0, 0, []
+        for i in [1, 2, 3, 4, 6, 7, 8, 9, 10]:
+            foldn += 1
+            rolling = (rolling * 1000003 + digest(i) + foldn) & 0x7fffffff
+            points.append((foldn, rolling))
+        report = None
+        for k, head in enumerate(points):
+            lagp = points[k - 2] if k >= 2 else (0, 0)
+            report = ls.observe({1: (head[0], head[1],
+                                     lagp[0], lagp[1])}, my_rank=0)
+            if report:
+                break
+        assert report is not None
+        assert report["pinned"] is True
+        assert report["first_divergent_fold"] == 5
+        assert report["last_matching_fold"] == 4
+        assert report["divergent_ranks"] == [1]
+        c = report["divergent_collective"]
+        assert c["path"] == "reduce_many" and c["nbytes"] == 4096 + 5
+        # latched: later heartbeats do not re-report
+        assert ls.observe({1: points[-1] + (0, 0)}, my_rank=0) is None
+        assert ls.divergence()["pinned"] is True
+    finally:
+        ls.reset()
+        ls.set_enabled(None)
+
+
+def test_lockstep_state_lagged_pairs():
+    from incubator_mxnet_tpu.analysis import lockstep as ls
+    ls.reset()
+    ls.set_enabled(True)
+    try:
+        # shorter than the lag: lag half ships (0, 0)
+        ls.fold(1, "reduce_many", n_keys=1, nbytes=1, keys=["a"])
+        f, h, lf, lh = ls.state_lagged()
+        assert (f, lf, lh) == (1, 0, 0) and h != 0
+        for i in range(2, 12):
+            ls.fold(i, "reduce_many", n_keys=1, nbytes=i, keys=["a"])
+        f, h, lf, lh = ls.state_lagged()
+        assert f == 11 and lf == 11 - ls.lag()
+        rows = {r["fold"]: r["rolling"] for r in ls.table()}
+        assert lh == rows[lf]
+        # a healthy laggard (peer = our own lagged prefix) never reports
+        assert ls.observe({1: (lf, lh)}, my_rank=0) is None
+    finally:
+        ls.reset()
+        ls.set_enabled(None)

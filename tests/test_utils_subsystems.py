@@ -3,7 +3,9 @@
 Parity models: python/mxnet/visualization.py, docs/faq/env_var.md,
 tools/im2rec.py.
 """
+import glob
 import os
+import re
 import subprocess
 import sys
 
@@ -55,6 +57,37 @@ def test_config_env_layer(monkeypatch):
     # generated doc is committed
     here = os.path.join(os.path.dirname(__file__), "..", "docs", "env_var.md")
     assert os.path.exists(here)
+
+
+_REPO = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
+
+
+def _switches_read():
+    """Every ``GRAFT_*`` name the package looks up: the quoted names in
+    its source (a docstring's mention is not a lookup)."""
+    names = set()
+    for path in glob.glob(os.path.join(_REPO, "incubator_mxnet_tpu", "**",
+                                       "*.py"), recursive=True):
+        with open(path) as f:
+            names.update(re.findall(r'''["'](GRAFT_[A-Z0-9_]+)["']''',
+                                    f.read()))
+    return sorted(names)
+
+
+def _switches_documented():
+    """The ``GRAFT_*`` names that head a section of docs/env_var.md."""
+    with open(os.path.join(_REPO, "docs", "env_var.md")) as f:
+        return {name for line in f if line.startswith("## ")
+                for name in re.findall(r"GRAFT_[A-Z0-9_]+", line)}
+
+
+@pytest.mark.parametrize("switch", _switches_read())
+def test_switch_is_documented(switch):
+    assert switch in _switches_documented()
+
+
+def test_no_documented_switch_is_dead():
+    assert _switches_documented() <= set(_switches_read())
 
 
 def test_im2rec_list_and_pack(tmp_path):

@@ -303,3 +303,117 @@ def test_eh301_storm_report_names_cost_growth(fresh_xray):
     msg = str(storm[-1].message)
     assert "cost growth since previous trace" in msg
     assert "gstep_one" in msg and "flops" in msg
+
+
+# ---------------------------------------------------------------------------
+# profiler-trace ingestion (telemetry --ingest-xla)
+# ---------------------------------------------------------------------------
+
+def test_ingest_xla_unions_overlapping_device_spans(tmp_path):
+    """Synthetic chrome trace: overlapping device spans must UNION per
+    step (never sum), busy + idle == wall per row, unstamped device
+    spans pool separately, host spans are ignored."""
+    us = 1e6
+    events = [
+        {"ph": "M", "name": "process_name", "pid": "d0",
+         "args": {"name": "TPU:0 device stream"}},
+        # step 1: two overlapping spans 0-10ms and 5-15ms -> 15ms busy
+        {"ph": "X", "name": "op", "pid": "d0", "tid": 1,
+         "ts": 0.000 * us, "dur": 0.010 * us, "args": {"step": 1}},
+        {"ph": "X", "name": "op", "pid": "d0", "tid": 1,
+         "ts": 0.005 * us, "dur": 0.010 * us, "args": {"step": 1}},
+        # step 2: one span 20-25ms; window = prev end (15ms) -> 25ms
+        {"ph": "X", "name": "op", "pid": "d0", "tid": 1,
+         "ts": 0.020 * us, "dur": 0.005 * us, "args": {"step": 2}},
+        # our own sync-mode flush span (host pid, device_time arg)
+        {"ph": "X", "name": "bulk_segment_flush", "pid": 77, "tid": 2,
+         "ts": 0.030 * us, "dur": 0.002 * us,
+         "args": {"device_time": True}},
+        # host span: ignored
+        {"ph": "X", "name": "host", "pid": 77, "tid": 2,
+         "ts": 0.000 * us, "dur": 0.050 * us, "args": {}},
+    ]
+    path = tmp_path / "trace.json"
+    path.write_text(json.dumps({"traceEvents": events}))
+    report = aggregate.ingest_xla(str(path))
+    assert report["problems"] == []
+    assert report["device_events"] == 4
+    rows = {r["step"]: r for r in report["steps"]}
+    assert rows[1]["busy_s"] == pytest.approx(0.015)
+    assert rows[1]["wall_s"] == pytest.approx(0.015)
+    assert rows[2]["busy_s"] == pytest.approx(0.005)
+    assert rows[2]["wall_s"] == pytest.approx(0.010)   # 15ms -> 25ms
+    for r in report["steps"]:
+        assert r["busy_s"] + r["idle_s"] == pytest.approx(r["wall_s"])
+    assert rows[None]["spans"] == 1                    # the flush span
+
+
+def test_ingest_xla_total_is_span_union_not_row_sum(tmp_path):
+    """Unstamped spans pool into a None row whose window OVERLAPS the
+    stamped rows' chained windows: the total must be the union over all
+    device spans, not the sum of row walls (which would double the wall
+    and halve the headline busy_fraction)."""
+    us = 1e6
+    path = tmp_path / "u.json"
+    path.write_text(json.dumps({"traceEvents": [
+        {"ph": "X", "name": "op", "pid": "/device:TPU:0", "tid": 1,
+         "ts": 0.000 * us, "dur": 0.010 * us, "args": {"step": 1}},
+        {"ph": "X", "name": "op", "pid": "/device:TPU:0", "tid": 1,
+         "ts": 0.020 * us, "dur": 0.005 * us, "args": {"step": 2}},
+        # unstamped span covering the WHOLE capture
+        {"ph": "X", "name": "op", "pid": "/device:TPU:0", "tid": 1,
+         "ts": 0.000 * us, "dur": 0.030 * us, "args": {}}]}))
+    report = aggregate.ingest_xla(str(path))
+    assert report["total"]["wall_s"] == pytest.approx(0.030)
+    assert report["total"]["busy_s"] == pytest.approx(0.030)
+    assert report["total"]["busy_fraction"] == pytest.approx(1.0)
+
+
+def test_ingest_xla_flags_non_monotonic_step_ids(tmp_path):
+    """A restarted step counter (or merged captures) puts a low step id
+    LATE in time: id-order window chaining clamps its successors' wall
+    to 0 — the report must say so in problems[], not zero silently."""
+    us = 1e6
+    path = tmp_path / "nm.json"
+    path.write_text(json.dumps({"traceEvents": [
+        # step 5 runs first in time, step 1 (restarted counter) after —
+        # id order chains step 5's window start past its own spans
+        {"ph": "X", "name": "op", "pid": "/device:TPU:0", "tid": 1,
+         "ts": 0.000 * us, "dur": 0.010 * us, "args": {"step": 5}},
+        {"ph": "X", "name": "op", "pid": "/device:TPU:0", "tid": 1,
+         "ts": 0.100 * us, "dur": 0.010 * us, "args": {"step": 1}}]}))
+    report = aggregate.ingest_xla(str(path))
+    rows = {r["step"]: r for r in report["steps"]}
+    assert rows[5]["wall_s"] == 0.0                 # the clamped row
+    assert any("not time-monotonic" in p for p in report["problems"])
+
+
+def test_ingest_xla_cli(tmp_path, capsys):
+    from incubator_mxnet_tpu.telemetry.__main__ import main as tmain
+    us = 1e6
+    path = tmp_path / "t.json"
+    path.write_text(json.dumps({"traceEvents": [
+        {"ph": "X", "name": "op", "pid": "/device:TPU:0", "tid": 1,
+         "ts": 0, "dur": 0.004 * us, "args": {"step": 1}}]}))
+    rc = tmain(["--ingest-xla", str(path)])
+    out = capsys.readouterr().out
+    assert rc == 0
+    assert "device-ledger ingestion" in out
+    assert "1" in out
+    # external traces stamp steps as strings: "2" must pool with 2 and
+    # a non-numeric stamp must sort, not TypeError against ints
+    path3 = tmp_path / "m.json"
+    path3.write_text(json.dumps({"traceEvents": [
+        {"ph": "X", "name": "op", "pid": "/device:TPU:0", "tid": 1,
+         "ts": 0, "dur": 1000, "args": {"step": 2}},
+        {"ph": "X", "name": "op", "pid": "/device:TPU:0", "tid": 1,
+         "ts": 2000, "dur": 1000, "args": {"step": "2"}},
+        {"ph": "X", "name": "op", "pid": "/device:TPU:0", "tid": 1,
+         "ts": 4000, "dur": 1000, "args": {"step": "warmup"}}]}))
+    report = aggregate.ingest_xla(str(path3))
+    assert [r["step"] for r in report["steps"]] == [2, "warmup"]
+    assert report["steps"][0]["spans"] == 2
+    # empty trace: rc 1 + a problem line
+    path2 = tmp_path / "e.json"
+    path2.write_text(json.dumps({"traceEvents": []}))
+    assert tmain(["--ingest-xla", str(path2)]) == 1

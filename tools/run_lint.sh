@@ -21,12 +21,10 @@
 # 4. graftwatch smoke — telemetry --blackbox --selftest exercises the
 #    flight recorder end-to-end (engine flushes, kvstore collectives, a
 #    step journal, an in-flight bracket) and validates the dump schema.
-# 5. graftlens smoke — telemetry --analyze --selftest merges two
-#    synthetic rank dumps (rank 1 deliberately delayed) and requires a
-#    schema-valid merged trace with cross-rank flow links per reduced
-#    bucket plus a straggler table blaming rank 1; bench_eager --smoke
-#    (tier 3) additionally reports lens_overhead_pct against its < 2%
-#    budget (tracked in BENCH JSON, like blackbox_overhead_pct).
+# 5. cross-rank analysis smoke — telemetry --analyze --selftest merges
+#    two synthetic rank dumps (rank 1 deliberately delayed) and requires
+#    a schema-valid merged trace with cross-rank flow links per reduced
+#    bucket plus a straggler table blaming rank 1.
 # 6. grafttsan smoke — analysis.tsan --selftest forces one race per
 #    EH2xx rule through the real instrumented paths (handles, scheduler
 #    regions, bulk segments, tracked arrays), requires the exact
@@ -48,13 +46,7 @@
 #    typed error naming the dead rank; bench_eager --smoke (tier 3)
 #    additionally reports armor_overhead_pct (retry plumbing with zero
 #    faults armed) against its < 2% budget in BENCH JSON.
-# 9. graftpulse smoke — telemetry.autotune --selftest runs the synthetic
-#    starved-DataLoader scenario end-to-end: the lens-driven controller
-#    must grow the loader's workers until the data_wait fraction drops
-#    below the bound within a bounded number of steps, with every
-#    decision journaled to the flight recorder; bench_eager --smoke
-#    (tier 3) additionally reports pulse_overhead_pct (the async device
-#    ledger's cost) against its < 2% budget in BENCH JSON.
+# 9. (the autotuner's selftest went with the autotuner, PR 28.)
 # 10. graftstep smoke — gluon.step_compile --selftest drives the
 #    whole-step compiled training path: one lazy trace on a static-shape
 #    loop (zero retraces after step 2), a set_learning_rate that must
@@ -126,9 +118,6 @@ JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python bench_serving.py --smoke \
     || exit $?
 JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
     python -m incubator_mxnet_tpu.armor --selftest \
-    || exit $?
-JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
-    python -m incubator_mxnet_tpu.telemetry.autotune --selftest \
     || exit $?
 JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
     python -m incubator_mxnet_tpu.gluon.step_compile --selftest \
