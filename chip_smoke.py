@@ -426,8 +426,9 @@ def phase_dropout(mx, jax, shape=(4, 2048, 4096), p=0.1):
     check(abs(share - (1 - p)) < 4 * sigma,
           "dropout kept %.6f of the elements, not %.2f" % (share, 1 - p))
 
-    # the Gluon loop: cachedop_backward runs the forward again with the
-    # step's key and must meet cachedop_forward's mask
+    # the Gluon loop: cachedop_backward draws the mask again from the step's
+    # key (a draw is no residual of the forward's) and must meet
+    # cachedop_forward's mask
     net = nn.HybridSequential()
     net.add(nn.Dropout(p))
     net.initialize()

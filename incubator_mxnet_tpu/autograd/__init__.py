@@ -132,21 +132,25 @@ def predict_mode():
 
 
 class TapeNode:
-    __slots__ = ("op", "inputs", "outputs", "vjp", "fn", "used")
+    __slots__ = ("op", "inputs", "outputs", "vjp", "fn", "release", "used")
 
-    def __init__(self, op, inputs, outputs, vjp, fn=None):
+    def __init__(self, op, inputs, outputs, vjp, fn=None, release=None):
         self.op = op
         self.inputs = inputs      # list[NDArray] (strong refs keep tape valid)
         self.outputs = outputs    # list[NDArray]
         self.vjp = vjp
         self.fn = fn              # pure fn of inputs (higher-order replay)
+        # drops what vjp holds of the forward (a CachedOp's residuals):
+        # called after the pass that is this node's last, i.e. one that
+        # does not retain the graph; the outputs may outlive the node's use
+        self.release = release
         self.used = False
 
 
-def _record(op, inputs, outputs, vjp_fn, fn=None):
+def _record(op, inputs, outputs, vjp_fn, fn=None, release=None):
     """Called by ndarray.invoke under recording (RecordOp, imperative.cc:182)."""
     s = _st()
-    node = TapeNode(op, inputs, outputs, vjp_fn, fn)
+    node = TapeNode(op, inputs, outputs, vjp_fn, fn, release)
     for i, o in enumerate(outputs):
         o._tape_ref = (node, i)
     s.tape.append(node)
@@ -270,6 +274,8 @@ def _run_backward(heads, head_grads, retain_graph, train_mode, variables=None,
                 _accum(id(inp), g)
             if not retain_graph:
                 node.used = True
+                if node.release is not None:
+                    node.release()
         for arr in final_at.pop(k, ()):
             # final for this pass: deliver now and tell the scheduler —
             # last-layer grads (high tape indices) fire first, giving the

@@ -15,9 +15,16 @@ TPU-native rebirth of python/mxnet/gluon/block.py:
       are detected via the NDArray version counter and returned as extra
       outputs, then written back eagerly — MXNet's mutable aux-state
       semantics preserved over functional XLA.
-* Under autograd recording, one tape node is recorded for the whole
-  CachedOp with its jax.vjp — mirroring ``_CachedOp``'s fused backward
-  (src/imperative/cached_op.cc:434).
+* Under autograd recording the forward program takes the ``jax.vjp`` once
+  and returns, beside its outputs, what the pullback needs that only the
+  forward can give: the outputs of the matrix products (convolutions,
+  ``dot_general``, the Pallas kernels) and of the reductions (``_kept``).
+  One tape node is recorded for the whole CachedOp; its backward program
+  starts from those residuals, makes the elementwise values between them
+  again and runs no forward convolution — ``_CachedOp`` keeps its forward's
+  activations for its fused backward the same way
+  (src/imperative/cached_op.cc:434), and frees them after it, as the tape
+  node does here.  Outside ``record()`` no residual is made.
 """
 from __future__ import annotations
 
@@ -34,6 +41,7 @@ from .. import ndarray as _nd
 from ..ops.registry import Operator
 from .. import autograd
 from .. import random_state
+from ..telemetry import metrics as _tmetrics
 from ..telemetry import tracing as _ttracing
 from ..telemetry import xray as _xray
 from .parameter import Parameter, ParameterDict, DeferredInitializationError
@@ -253,6 +261,28 @@ def _indent(s_, num_spaces):
     return "\n".join([first] + lines)
 
 
+# What a recorded forward keeps for its backward is decided by the primitive
+# that made a value, never by a model's or a layer's name: the matrix
+# products and the kernels that stand for them (a ``custom_vjp``'s forward
+# saves its kernel's outputs, so what it chose to save stays saved), and the
+# reductions (BatchNorm's statistics, pooling, a softmax's sums).  Every
+# other value (elementwise work on those, a gather, a dropout's draw from
+# the step's key) the backward makes again, fused into its consumers where
+# XLA can.
+_KEPT_PRIMITIVES = frozenset((
+    "conv_general_dilated", "dot_general", "ragged_dot",
+    "ragged_dot_general", "pallas_call",
+    "reduce_sum", "reduce_max", "reduce_min", "reduce_prod", "reduce_and",
+    "reduce_or", "reduce_xor", "argmax", "argmin",
+    "reduce_window", "reduce_window_sum", "reduce_window_max",
+    "reduce_window_min"))
+
+
+def _kept(prim, *_avals, **_params):
+    """``jax.checkpoint`` policy of the recorded forward."""
+    return prim.name in _KEPT_PRIMITIVES
+
+
 class _TraceParam(object):
     """Shadow for a Parameter during CachedOp tracing: .data() returns the
     tracer-backed NDArray; writes land on the shadow and are harvested."""
@@ -281,7 +311,7 @@ class CachedOp(object):
         # _clear_cached_op (→ a fresh CachedOp); cache the walk here
         self._params = block._active_params
         self._param_names = sorted(self._params.keys())
-        self._noted = None      # the cache entry the program registry has
+        self._noted = None      # (entry, recorded or not) the registry has
         # forward-use order of the params, recorded by first-touch hooks
         # on the first trace (graftstep pull priority; empty until then)
         self.touch_order = []
@@ -344,33 +374,36 @@ class CachedOp(object):
         entry = self._cache.get(key)
         if entry is None:
             raw = self._make_fn(param_names, len(input_vals), in_fmt, train)
-
-            def cachedop_backward(pv, iv, rng_, cts):
-                # forward rematerializes inside the compiled backward — the
-                # whole fwd+bwd is one XLA program, no Python re-trace per
-                # step (rng_ is the same key, so dropout masks match)
-                _, vjp_fn = jax.vjp(lambda p, i: raw(p, i, rng_)[0], pv, iv)
-                return vjp_fn(cts)
-
+            recorded, backward = _make_recorded(raw)
             entry = {"raw": raw, "jit": jax.jit(raw),
-                     "vjp": jax.jit(cachedop_backward)}
+                     "record": jax.jit(recorded),
+                     "backward": jax.jit(backward)}
             self._cache[key] = entry
 
         rng = random_state.next_key()
+        args = (param_vals, input_vals, rng)
         with _ttracing.phase_span("fwd"):
-            out_vals, aux_updates = entry["jit"](param_vals, input_vals, rng)
-        if entry is not self._noted:
-            # the two programs go to the registry once, by shape alone
-            self._noted = entry
-            args = (param_vals, input_vals, rng)
-            _xray.register_program("cachedop_forward", entry["jit"], args,
-                                   phase="forward")
-            _xray.register_program("cachedop_backward", entry["vjp"],
-                                   args + (tuple(out_vals),),
-                                   phase="backward")
+            if recording:
+                out_vals, aux_updates, residuals, pullback = \
+                    entry["record"](*args)
+            else:
+                out_vals, aux_updates = entry["jit"](*args)
+        if (id(entry), recording) != self._noted:
+            # the programs that just ran go to the registry, by shape alone
+            self._noted = (id(entry), recording)
+            _xray.register_program(
+                "cachedop_forward", entry["record" if recording else "jit"],
+                args, phase="forward")
+            if recording:
+                _xray.register_program(
+                    "cachedop_backward", entry["backward"],
+                    (residuals, pullback) + args + (tuple(out_vals),),
+                    phase="backward")
         if "out_fmt" not in entry:
             # fn ran (traced) at least once for this entry, setting the fmt
             entry["out_fmt"] = self._last_out_fmt
+        if recording and "residual_bytes" not in entry:
+            entry["residual_bytes"] = sum(r.nbytes for r in residuals)
 
         ctx = flat_args[0]._ctx if flat_args else current_context()
         out_arrays = [NDArray(v, ctx=ctx) for v in out_vals]
@@ -380,13 +413,23 @@ class CachedOp(object):
             params[name].data()._write(val)
 
         if recording:
+            _tmetrics.cachedop_recorded(entry["residual_bytes"])
             real_idx = [i for i, a in enumerate(flat_args) if a is not None]
             tape_inputs = [params[n].data() for n in param_names] + \
                 [flat_args[i] for i in real_idx]
+            # the one reference to this call's residuals: the tape drops it
+            # after a pass that does not retain the graph, so they are freed
+            # when the backward is done, as MXNet frees its activations
+            held = [residuals]
 
             def tape_vjp(ct):
+                if not held:
+                    raise RuntimeError(
+                        "graph already backpropagated: this block's "
+                        "residuals were freed by a pass without "
+                        "retain_graph=True")
                 cts = ct if isinstance(ct, tuple) else (ct,)
-                pv_g, iv_g = entry["vjp"](param_vals, input_vals, rng, cts)
+                pv_g, iv_g = entry["backward"](held[0], pullback, *args, cts)
                 return tuple(pv_g[n] for n in param_names) + \
                     tuple(iv_g[i] for i in real_idx)
 
@@ -396,7 +439,9 @@ class CachedOp(object):
             def tape_fn(*vals):
                 # replayable pure function of the tape inputs — lets
                 # autograd's create_graph build grad-of-grad through the
-                # whole compiled block (same rng → same dropout masks)
+                # whole compiled block (same rng → same dropout masks): the
+                # only place left where a recorded forward is traced again
+                _tmetrics.cachedop_replay()
                 pv = dict(zip(param_names, vals[:n_par]))
                 iv = list(input_vals)
                 for j, idx in enumerate(real_idx):
@@ -408,10 +453,66 @@ class CachedOp(object):
                           num_inputs=len(tape_inputs),
                           num_outputs=len(out_arrays))
             autograd._record(op, tape_inputs, out_arrays, tape_vjp,
-                             fn=tape_fn)
+                             fn=tape_fn, release=held.clear)
 
         out, _ = _regroup(out_arrays, entry["out_fmt"])
         return out
+
+
+def _make_recorded(raw):
+    """The two programs of a recorded call of ``raw``, the functionalized
+    forward: the forward that takes the vjp once, and the backward that is
+    its pullback.
+
+    The pullback is a pytree.  Those of its leaves that are the program's
+    own arguments (parameters, inputs, the key: the backward takes them
+    again as arguments) stay where they are; the others are the residuals,
+    and only they leave the forward program.  ``pullback`` says, leaf by
+    leaf, which is which: it is static, the forward's fourth output."""
+
+    def cachedop_forward(param_vals, input_vals, rng):
+        out_vals, vjp_fn, aux_updates = jax.vjp(
+            # the two passes are two programs: XLA has nothing to merge
+            # them by, so no barrier is asked for (prevent_cse)
+            jax.checkpoint(lambda pv, iv: raw(pv, iv, rng), policy=_kept,
+                           prevent_cse=False),
+            param_vals, input_vals, has_aux=True)
+        leaves, treedef = jax.tree.flatten(vjp_fn)
+        given = {id(leaf): i for i, leaf in enumerate(
+            jax.tree.leaves((param_vals, input_vals, rng)))}
+        sources = tuple(given.get(id(leaf)) for leaf in leaves)
+        residuals = [leaf for leaf, src in zip(leaves, sources)
+                     if src is None]
+        return out_vals, aux_updates, residuals, _Static((treedef, sources))
+
+    def cachedop_backward(residuals, pullback, param_vals, input_vals, rng,
+                          cts):
+        treedef, sources = pullback.value
+        given = jax.tree.leaves((param_vals, input_vals, rng))
+        kept = iter(residuals)
+        vjp_fn = jax.tree.unflatten(
+            treedef, [next(kept) if src is None else given[src]
+                      for src in sources])
+        return vjp_fn(cts)
+
+    return cachedop_forward, cachedop_backward
+
+
+@jax.tree_util.register_static
+class _Static(object):
+    """A hashable value that crosses ``jax.jit`` as structure, not data."""
+
+    __slots__ = ("value", "_hash")
+
+    def __init__(self, value):
+        self.value = value
+        self._hash = hash(value)    # asked for at every dispatch
+
+    def __hash__(self):
+        return self._hash
+
+    def __eq__(self, other):
+        return isinstance(other, _Static) and self.value == other.value
 
 
 def _fmt_key(fmt):

@@ -720,6 +720,33 @@ def dropout_mask_trace(op):
                       ("op",)).inc(op=op)
 
 
+def cachedop_recorded(residual_bytes):
+    """One forward of a hybridized Block under ``autograd.record()``: it
+    took the vjp once and kept, for its backward, ``residual_bytes`` of
+    residuals beyond its parameters and inputs (from the shapes of the
+    entry's leaves, reckoned once an entry; nothing is read back)."""
+    if not enabled():
+        return
+    _REGISTRY.counter("graft_cachedop_recorded_calls_total",
+                      "Recorded CachedOp forwards that made residuals for "
+                      "their backward").inc()
+    _REGISTRY.gauge("graft_cachedop_residual_bytes",
+                    "Bytes the last recorded CachedOp forward kept for its "
+                    "backward, beyond parameters and inputs"
+                    ).set(residual_bytes)
+
+
+def cachedop_replay():
+    """One re-trace of a CachedOp's forward for ``create_graph``: the only
+    place left where a recorded forward is traced again (a training loop
+    reads 0)."""
+    if not enabled():
+        return
+    _REGISTRY.counter("graft_cachedop_replays_total",
+                      "CachedOp forwards re-traced for higher-order "
+                      "gradients (create_graph)").inc()
+
+
 def moe_assignments(load, assignments):
     """One eager ``grouped`` call: ``load`` is the assignments each held
     expert got, ``assignments`` all the (token, expert) pairs the router

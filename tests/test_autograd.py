@@ -158,3 +158,42 @@ def test_numeric_gradient_harness():
     from incubator_mxnet_tpu.test_utils import check_numeric_gradient
     x = mx.nd.array(np.random.rand(3, 3).astype(np.float32) + 0.5)
     check_numeric_gradient(lambda a: mx.nd.log(a * a + 1.0), [x])
+
+
+def _node_with_release(calls):
+    """y = 3 x recorded as one hand-made tape node that says when the tape
+    lets go of what its vjp holds."""
+    from incubator_mxnet_tpu.ops.registry import Operator
+    x = mx.nd.array([1.0, 2.0])
+    x.attach_grad()
+    y = mx.nd.array([3.0, 6.0])
+    op = Operator("_triple", lambda a: a, num_inputs=1, num_outputs=1)
+    ag._record(op, [x], [y], lambda ct: (3.0 * ct,),
+               release=lambda: calls.append("released"))
+    return x, y
+
+
+@pytest.mark.parametrize("retain", [False, True])
+def test_tape_releases_a_node_after_its_last_pass_only(retain):
+    calls = []
+    with ag.record():
+        x, y = _node_with_release(calls)
+    y.backward(retain_graph=retain)
+    assert_almost_equal(x.grad.asnumpy(), np.array([3.0, 3.0]))
+    assert calls == ([] if retain else ["released"])
+    if retain:
+        y.backward()
+        assert calls == ["released"]
+        assert_almost_equal(x.grad.asnumpy(), np.array([3.0, 3.0]))
+
+
+def test_a_node_the_pass_does_not_reach_is_not_released():
+    calls = []
+    with ag.record():
+        _x, _y = _node_with_release(calls)
+        z = mx.nd.array([1.0])
+        z.attach_grad()
+        w = (z * 2).sum()
+    w.backward()
+    assert calls == []
+    assert_almost_equal(z.grad.asnumpy(), np.array([2.0]))

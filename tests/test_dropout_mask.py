@@ -5,7 +5,7 @@ The mask itself: the share kept, the kept values bit for bit, ``p == 0``,
 inference mode, ``mode="always"`` and ``axes`` as before, and no correlation
 between two sites of one trace, two steps of a trainer, or neighbours along
 an axis.  What the callers rely on: one key gives one mask in every program
-(eager, ``jit``, ``cachedop_forward`` and the forward inside
+(eager, ``jit``, ``cachedop_forward`` and the draw made again inside
 ``cachedop_backward``, the fused step's ``vjp``), shards of a ``dp`` mesh
 draw bits of their own, and the fused step of a toy OPT stages one
 ``rng_bit_generator`` a Dropout site and no threefry of a mask's size.
@@ -196,8 +196,9 @@ def test_one_key_gives_one_mask_eagerly_and_under_jit():
 
 
 def test_cachedop_backward_meets_the_forward_programs_mask():
-    """The Gluon loop: ``cachedop_backward`` runs the forward again with
-    the step's key, in a program of its own."""
+    """The Gluon loop: ``cachedop_backward`` draws the mask again from the
+    step's key, in a program of its own (the forward hands it the matrix
+    products' outputs, not the draws)."""
     net = nn.HybridSequential()
     with net.name_scope():
         net.add(nn.Dropout(0.5))

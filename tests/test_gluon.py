@@ -390,3 +390,369 @@ def test_functionalize_threads_rng():
     np.testing.assert_array_equal(a, b)
     assert (a != c).any(), "different keys must give different masks"
     assert ((a == 0).mean() > 0.2), "dropout inactive in train trace"
+
+
+# ---------------------------------------------------------------------------
+# The recorded CachedOp: the forward takes the vjp once and hands its
+# backward the outputs of the matrix products and of the reductions
+# ---------------------------------------------------------------------------
+
+class _Residual(gluon.HybridBlock):
+    """relu(x + BN(conv(relu(BN(conv(x))))))."""
+
+    def __init__(self, channels, **kwargs):
+        super().__init__(**kwargs)
+        with self.name_scope():
+            self.body = nn.HybridSequential(prefix="")
+            self.body.add(nn.Conv2D(channels, 3, padding=1, use_bias=False,
+                                    in_channels=channels),
+                          nn.BatchNorm(in_channels=channels),
+                          nn.Activation("relu"),
+                          nn.Conv2D(channels, 3, padding=1, use_bias=False,
+                                    in_channels=channels),
+                          nn.BatchNorm(in_channels=channels))
+
+    def hybrid_forward(self, F, x):
+        return F.Activation(x + self.body(x), act_type="relu")
+
+
+def _conv_net():
+    net = nn.HybridSequential(prefix="rc_conv_")
+    with net.name_scope():
+        net.add(nn.Conv2D(8, 3, padding=1, in_channels=3),
+                nn.BatchNorm(in_channels=8), nn.Activation("relu"),
+                _Residual(8), _Residual(8),
+                nn.GlobalAvgPool2D(), nn.Flatten(), nn.Dense(5, in_units=8))
+    return net, (4, 3, 12, 12)
+
+
+def _dropout_net():
+    net = nn.HybridSequential(prefix="rc_drop_")
+    with net.name_scope():
+        net.add(nn.Dense(16, activation="relu", in_units=12),
+                nn.Dropout(0.5), nn.Dense(5, in_units=16))
+    return net, (8, 12)
+
+
+def _attention_net():
+    net = nn.HybridSequential(prefix="rc_attn_")
+    with net.name_scope():
+        net.add(nn.Dense(16, flatten=False, in_units=6),
+                nn.MultiHeadAttention(16, 4, causal=True),
+                nn.Dense(5, flatten=False, in_units=16))
+    return net, (2, 8, 6)
+
+
+_RECORDED_NETS = {"conv_bn_relu_residual": _conv_net,
+                  "dense_dropout": _dropout_net,
+                  "attention": _attention_net}
+_N_CONVOLUTIONS = 5         # of _conv_net
+
+
+@pytest.fixture(params=sorted(_RECORDED_NETS))
+def recorded_net(request):
+    """(net, x, the parameters it was initialized with), not hybridized."""
+    mx.random.seed(29)
+    net, shape = _RECORDED_NETS[request.param]()
+    net.initialize(mx.init.Xavier())
+    x = mx.nd.array(np.random.RandomState(29).randn(*shape)
+                    .astype(np.float32))
+    x.attach_grad()
+    net(x)          # the shapes a layer left open are inferred here
+    start = {k: p.data().asnumpy().copy()
+             for k, p in net.collect_params().items()}
+    return net, x, start
+
+
+def _restart(net, start):
+    for k, p in net.collect_params().items():
+        p.set_data(mx.nd.array(start[k]))
+
+
+def _loss(net, x):
+    out = net(x)
+    return out, (out * out).sum()
+
+
+def _grads(net, x):
+    grads = {k: p.grad().asnumpy().copy()
+             for k, p in net.collect_params().items() if p.grad_req != "null"}
+    grads["x"] = x.grad.asnumpy().copy()
+    return grads
+
+
+def _assert_same(got, want, rtol=1e-4, atol=1e-5):
+    assert set(got) == set(want)
+    for k in want:
+        np.testing.assert_allclose(got[k], want[k], rtol=rtol, atol=atol,
+                                   err_msg=k)
+
+
+def _counters():
+    from incubator_mxnet_tpu.telemetry import metrics
+    snap = metrics.registry().snapshot(collect=False)
+
+    def read(name):
+        samples = snap.get(name, {}).get("samples", [])
+        return samples[0]["value"] if samples else 0
+    return {name: read("graft_cachedop_" + name)
+            for name in ("recorded_calls_total", "residual_bytes",
+                         "replays_total")}
+
+
+def _reference_grads(net, x, seed):
+    """What the un-hybridized eager tape gives; where the net draws random
+    numbers (the eager ops draw a key each, the CachedOp one for its whole
+    program), ``jax.vjp`` over the functionalized forward under the key the
+    CachedOp will draw."""
+    import jax
+    from incubator_mxnet_tpu import random_state
+    from incubator_mxnet_tpu.gluon.block import functionalize
+    if not any(isinstance(c, nn.Dropout) for c in net._children):
+        with autograd.record():
+            _, loss = _loss(net, x)
+        loss.backward()
+        return _grads(net, x)
+    fn, params = functionalize(net, x, train=True)
+    mx.random.seed(seed)
+    key = random_state.next_key()
+    out, pullback = jax.vjp(lambda p, v: fn(p, v, rng=key), params,
+                            x._read())
+    p_g, x_g = pullback(2 * out)
+    grads = {k: np.asarray(v) for k, v in p_g.items()
+             if net.collect_params()[k].grad_req != "null"}
+    grads["x"] = np.asarray(x_g)
+    return grads
+
+
+def test_recorded_gradients_equal_the_eager_tapes(recorded_net):
+    net, x, start = recorded_net
+    want = _reference_grads(net, x, seed=3)
+    _restart(net, start)
+    net.hybridize()
+    mx.random.seed(3)
+    with autograd.record():
+        _, loss = _loss(net, x)
+    loss.backward()
+    _assert_same(_grads(net, x), want)
+
+
+def test_moving_statistics_move_once_a_recorded_step():
+    mx.random.seed(29)
+    net, shape = _conv_net()
+    net.initialize(mx.init.Xavier())
+    x = mx.nd.array(np.random.RandomState(2).randn(*shape)
+                    .astype(np.float32) * 2 + 1)
+    start = {k: p.data().asnumpy().copy()
+             for k, p in net.collect_params().items()}
+    after = []
+    for hybrid in (False, True):
+        _restart(net, start)
+        if hybrid:
+            net.hybridize()
+        with autograd.record():
+            _, loss = _loss(net, x)
+        loss.backward()
+        after.append({k: p.data().asnumpy().copy()
+                      for k, p in net.collect_params().items()
+                      if "running" in k})
+    assert len(after[0]) == 10
+    for k, eager in after[0].items():
+        assert not np.allclose(eager, start[k]), k
+        np.testing.assert_allclose(after[1][k], eager, rtol=1e-5, atol=1e-6,
+                                   err_msg=k)
+
+
+def test_retained_graph_gives_the_same_gradients_twice(recorded_net):
+    net, x, _ = recorded_net
+    net.hybridize()
+    with autograd.record():
+        out, loss = _loss(net, x)
+    node = out._tape_ref[0]
+    loss.backward(retain_graph=True)
+    first = _grads(net, x)
+    assert node.release.__self__, "a retained pass freed the residuals"
+    loss.backward(retain_graph=True)
+    second = _grads(net, x)
+    for k in first:
+        np.testing.assert_array_equal(first[k], second[k], err_msg=k)
+    loss.backward()
+    for k, g in _grads(net, x).items():
+        np.testing.assert_array_equal(first[k], g, err_msg=k)
+    # that pass was the node's last: the residuals are gone with it, and
+    # the node has left the tape, so one more backward walks nothing
+    assert node.used and node.release.__self__ == []
+    with pytest.raises(RuntimeError, match="already backpropagated"):
+        node.vjp(out._read())
+    loss.backward()
+    for k, g in _grads(net, x).items():
+        np.testing.assert_array_equal(first[k], g, err_msg=k)
+
+
+def test_two_recorded_calls_before_one_backward(recorded_net):
+    net, x, start = recorded_net
+    x2 = mx.nd.array(x.asnumpy()[::-1].copy() * 0.5)
+    x2.attach_grad()
+    if any(isinstance(c, nn.Dropout) for c in net._children):
+        # an eager Dropout draws a key of its own: the reference is the
+        # hybridized net, one call and one backward at a time
+        net.hybridize()
+    want = None
+    for arr, seed in ((x, 5), (x2, 6)):
+        mx.random.seed(seed)
+        with autograd.record():
+            _, loss = _loss(net, arr)
+        loss.backward()
+        g = _grads(net, arr)
+        g.pop("x")
+        want = g if want is None else {k: want[k] + g[k] for k in g}
+    want_x, want_x2 = x.grad.asnumpy().copy(), x2.grad.asnumpy().copy()
+    _restart(net, start)
+    net.hybridize()
+    mx.random.seed(5)
+    with autograd.record():
+        _, first = _loss(net, x)
+        mx.random.seed(6)
+        _, second = _loss(net, x2)
+        loss = first + second
+    loss.backward()
+    got = _grads(net, x)
+    got.pop("x")
+    _assert_same(got, want)
+    np.testing.assert_allclose(x.grad.asnumpy(), want_x, rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(x2.grad.asnumpy(), want_x2, rtol=1e-4,
+                               atol=1e-5)
+
+
+def test_create_graph_through_a_hybridized_block_counts_one_replay(
+        recorded_net):
+    net, x, _ = recorded_net
+    net.hybridize()
+    mx.random.seed(8)
+    with autograd.record():
+        _, loss = _loss(net, x)
+    loss.backward(retain_graph=True)
+    want = x.grad.asnumpy().copy()
+    before = _counters()
+    g, = autograd.grad([loss], [x], create_graph=True, retain_graph=True)
+    np.testing.assert_allclose(g.asnumpy(), want, rtol=1e-4, atol=1e-5)
+    after = _counters()
+    assert after["replays_total"] - before["replays_total"] == 1
+    assert after["recorded_calls_total"] == before["recorded_calls_total"]
+    # and the gradient is itself on the tape: its own gradient flows to x
+    (g * g).sum().backward()
+    assert np.isfinite(x.grad.asnumpy()).all()
+    assert np.abs(x.grad.asnumpy()).sum() > 0
+
+
+def _made_by(jaxpr, var, outer=()):
+    """The primitive that made ``var`` in ``jaxpr``, through the calls that
+    only wrap it (an operator's own ``jit``, also where it hands an argument
+    of its own on) and the marker JAX puts on a saved value (a
+    ``reduce_precision`` to the value's own precision).  ``outer``: the
+    (jaxpr, call) pairs around ``jaxpr``."""
+    for eqn in jaxpr.eqns:
+        if var not in eqn.outvars:
+            continue
+        if eqn.primitive.name == "reduce_precision":
+            return _made_by(jaxpr, eqn.invars[0], outer)
+        inner = eqn.params.get("jaxpr")
+        if inner is not None:
+            inner = getattr(inner, "jaxpr", inner)
+            return _made_by(inner, inner.outvars[eqn.outvars.index(var)],
+                            outer + ((jaxpr, eqn),))
+        return eqn.primitive.name
+    if outer and var in jaxpr.invars:
+        around, call = outer[-1]
+        return _made_by(around, call.invars[jaxpr.invars.index(var)],
+                        outer[:-1])
+    return "an argument"
+
+
+def test_backward_program_runs_no_forward_convolution(recorded_net):
+    import re
+    import jax
+    from incubator_mxnet_tpu import telemetry
+    from incubator_mxnet_tpu.gluon import block as block_module
+    from jax.experimental.compilation_cache import compilation_cache
+    net, x, _ = recorded_net
+    net.hybridize()
+    with autograd.record():
+        _, loss = _loss(net, x)
+    loss.backward()
+    (entry,) = net._cached_op._cache.values()
+    params = {k: p.data()._read() for k, p in net.collect_params().items()}
+    args = (params, [x._read()], jax.random.PRNGKey(0))
+    out_vals, _aux, residuals, pullback = jax.eval_shape(entry["record"],
+                                                         *args)
+    # the optimized HLO of this build, not of a cached one
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        hlo = {"forward": entry["record"].lower(*args).compile().as_text(),
+               "backward": entry["backward"].lower(
+                   residuals, pullback, *args,
+                   tuple(out_vals)).compile().as_text()}
+        made_again = [path for path
+                      in telemetry.programs()["cachedop_backward"].ops.values()
+                      if "rematted_computation" in path]
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    convolutions = {k: len(re.findall(r" convolution\(", text))
+                    for k, text in hlo.items()}
+    n = _N_CONVOLUTIONS if "conv" in net.prefix else 0
+    # a convolution's backward is two (its input's and its weights'
+    # gradient); the parent's program held a third, the forward's own
+    assert convolutions == {"forward": n, "backward": 2 * n}
+    # what the backward makes again is elementwise
+    assert made_again
+    assert not [path for path in made_again
+                if path.endswith(("conv_general_dilated", "dot_general"))]
+
+    # every residual was made by a matrix product or a reduction, and none
+    # is a parameter or an input (those are the backward's own arguments)
+    jaxpr = jax.make_jaxpr(entry["record"])(*args).jaxpr
+    (call,) = jaxpr.eqns
+    inner = call.params["jaxpr"].jaxpr
+    assert len(residuals) > 0
+    made = [_made_by(inner, v) for v in inner.outvars[-len(residuals):]]
+    assert set(made) <= block_module._KEPT_PRIMITIVES, made
+    assert ("conv_general_dilated" if n else "dot_general") in made
+    assert entry["residual_bytes"] == _counters()["residual_bytes"] == sum(
+        int(np.prod(r.shape)) * r.dtype.itemsize for r in residuals)
+
+
+def test_outside_record_no_residual_is_made(recorded_net):
+    net, x, _ = recorded_net
+    net.hybridize()
+    before = _counters()
+    eager = net(x)
+    with autograd.predict_mode():
+        net(x)
+    with autograd.train_mode():
+        net(x)
+    assert _counters() == before
+    assert eager._tape_ref is None
+    entries = net._cached_op._cache.values()
+    assert len(entries) == 2        # inference and training mode
+    assert all("residual_bytes" not in entry for entry in entries)
+
+
+def test_counters_of_three_recorded_loop_steps(recorded_net):
+    net, x, _ = recorded_net
+    net.hybridize()
+    trainer = gluon.Trainer(net.collect_params(), "sgd",
+                            {"learning_rate": 0.01})
+    before = _counters()
+    for _ in range(3):
+        with autograd.record():
+            _, loss = _loss(net, x)
+        loss.backward()
+        trainer.step(x.shape[0])
+    after = _counters()
+    assert after["recorded_calls_total"] - before["recorded_calls_total"] == 3
+    assert after["replays_total"] == before["replays_total"]
+    (entry,) = net._cached_op._cache.values()
+    assert after["residual_bytes"] == entry["residual_bytes"] > 0

@@ -203,7 +203,7 @@ def test_flash_backward_scope_reaches_the_scans_body(fresh_compiles):
     assert "flash_attention_reference" in fwd
 
 
-def test_gluon_loop_programs_are_named_and_whole_phase():
+def test_gluon_loop_programs_are_named_and_whole_phase(fresh_compiles):
     net = _mlp("pt_gluon_")
     net.hybridize()
     x, y = _batch()
