@@ -10,6 +10,7 @@ review: ring attention existed only as a raw jax function).
 """
 from __future__ import annotations
 
+import jax
 import numpy as np
 
 from ..block import HybridBlock
@@ -26,7 +27,7 @@ class MultiHeadAttention(HybridBlock):
     units : int
         Total embedding width E (split across heads).
     num_heads : int
-        Head count H; head dim D = E // H.
+        Head count H; head dim D = E // H unless ``head_dim`` says otherwise.
     causal : bool
         Autoregressive masking.
     seq_axis : str or None
@@ -61,21 +62,46 @@ class MultiHeadAttention(HybridBlock):
         learned ones, as the OPT cells do).
     qk_norm_epsilon : float
         The epsilon of ``qk_norm``'s RMSNorm.
+    head_dim : int or None
+        The width D of a head where it is not ``units // num_heads``: q is
+        then ``num_heads * head_dim`` wide, k and v ``num_kv_heads *
+        head_dim``, ``proj_out`` maps the ``num_heads * head_dim`` channels
+        back to ``units`` and the scores are scaled by ``1 / sqrt(head_dim)``
+        (Mellum2: 32 heads of 128 over a model width of 2304).
+    window : int or None
+        Sliding-window attention (needs ``causal``): a query sees its own
+        key and the ``window - 1`` before it, as ``sliding_window`` counts
+        in HF configs.  None: every key up to its own.  Not with
+        ``seq_axis``: the ring has no window.
+    rotary_scaling : mapping or None
+        YaRN's parameters for the rotary positions (needs ``rotary_base``):
+        ``factor``, ``original_max_position`` and optionally ``beta_fast``,
+        ``beta_slow``, ``attention_factor``, as ``_contrib_RotaryEmbedding``
+        takes them under ``scaling``.  None: the plain frequencies.
 
-    With the last four left at their defaults the layer stages the program
-    it staged before they existed (the chip benchmark's ``opt6b7_fused_s2048``
-    and ``opt6b7_fused_adam`` run it so); ``lfm2moe_fused_s8192`` runs 32
-    query heads over 8 K/V heads of 64 with ``qk_norm`` and base 1e6.
+    With everything after ``fused_qkv`` left at its default the layer stages
+    the program it staged before those arguments existed (the chip
+    benchmark's ``opt6b7_fused_s2048`` and ``opt6b7_fused_adam`` run it so);
+    ``lfm2moe_fused_s8192`` runs 32 query heads over 8 K/V heads of 64 with
+    ``qk_norm`` and base 1e6; ``mellum2_fused_s8192`` 32 over 4 of 128 at
+    width 2304, three layers with ``window=1024`` to one with YaRN.
     """
 
     def __init__(self, units, num_heads, causal=False, seq_axis=None,
                  use_bias=True, fused_qkv=False, weight_initializer=None,
                  num_kv_heads=None, qk_norm=False, rotary_base=None,
-                 qk_norm_epsilon=1e-5, **kwargs):
+                 qk_norm_epsilon=1e-5, head_dim=None, window=None,
+                 rotary_scaling=None, **kwargs):
         super().__init__(**kwargs)
-        if units % num_heads:
+        if head_dim is None and units % num_heads:
             raise ValueError("units (%d) must be divisible by num_heads (%d)"
                              % (units, num_heads))
+        if window is not None and (not causal or seq_axis is not None):
+            raise ValueError("window counts back from the query's own key: "
+                             "it needs causal=True and seq_axis=None")
+        if rotary_scaling is not None and rotary_base is None:
+            raise ValueError("rotary_scaling scales rotary positions: it "
+                             "needs rotary_base")
         num_kv_heads = num_heads if num_kv_heads is None else num_kv_heads
         if num_heads % num_kv_heads:
             raise ValueError("num_heads (%d) must be a multiple of "
@@ -87,18 +113,23 @@ class MultiHeadAttention(HybridBlock):
         self._num_heads = num_heads
         self._num_kv_heads = num_kv_heads
         self._rotary_base = rotary_base
-        kv_units = units // num_heads * num_kv_heads
+        self._rotary_scaling = (None if rotary_scaling is None
+                                else dict(rotary_scaling))
+        self._window = window
+        self._head_dim = head_dim = (units // num_heads if head_dim is None
+                                     else int(head_dim))
+        q_units, kv_units = num_heads * head_dim, num_kv_heads * head_dim
         self._causal = bool(causal)
         self._seq_axis = seq_axis
         self._fused_qkv = bool(fused_qkv)
         with self.name_scope():
             if self._fused_qkv:
-                self.proj_qkv = Dense(3 * units, flatten=False,
+                self.proj_qkv = Dense(3 * q_units, flatten=False,
                                       use_bias=use_bias,
                                       weight_initializer=weight_initializer,
                                       prefix="qkv_")
             else:
-                self.proj_q = Dense(units, flatten=False, use_bias=use_bias,
+                self.proj_q = Dense(q_units, flatten=False, use_bias=use_bias,
                                     weight_initializer=weight_initializer,
                                     prefix="q_")
                 self.proj_k = Dense(kv_units, flatten=False,
@@ -112,7 +143,6 @@ class MultiHeadAttention(HybridBlock):
             self.proj_out = Dense(units, flatten=False, use_bias=use_bias,
                                   weight_initializer=weight_initializer,
                                   prefix="out_")
-            head_dim = units // num_heads
             self.q_norm = self.k_norm = None
             if qk_norm:
                 self.q_norm = RMSNorm(epsilon=qk_norm_epsilon,
@@ -129,7 +159,10 @@ class MultiHeadAttention(HybridBlock):
             x = norm(x)
         x = F.transpose(x, axes=(0, 2, 1, 3))
         if positions and self._rotary_base is not None:
-            x = F._contrib_RotaryEmbedding(x, base=self._rotary_base)
+            scaled = ({} if self._rotary_scaling is None
+                      else {"scaling": self._rotary_scaling})
+            x = F._contrib_RotaryEmbedding(x, base=self._rotary_base,
+                                           **scaled)
         return x
 
     def hybrid_forward(self, F, query, key=None, value=None):
@@ -142,7 +175,7 @@ class MultiHeadAttention(HybridBlock):
         Sk = key.shape[1]
         if self._fused_qkv:
             qkv = self.proj_qkv(query)                   # (B, S, 3E)
-            E = self._units
+            E = self._num_heads * self._head_dim
             q = self._split_heads(
                 F, F.slice_axis(qkv, axis=-1, begin=0, end=E), B, S,
                 norm=self.q_norm, positions=True)
@@ -162,13 +195,19 @@ class MultiHeadAttention(HybridBlock):
                 # each K/V head serves a run of consecutive query heads
                 k, v = (F.repeat(t, repeats=self._num_heads // kv, axis=1)
                         for t in (k, v))
-        scale = 1.0 / float(np.sqrt(self._units // self._num_heads))
+        scale = 1.0 / float(np.sqrt(self._head_dim))
         if self._seq_axis is None:
-            out = F._contrib_FlashAttention(q, k, v, causal=self._causal,
-                                            scale=scale)
+            windowed = ({} if self._window is None
+                        else {"window": self._window})
+            # the layer's kind for a trace's reader, inside the Block's name
+            with jax.named_scope("attn_full" if self._window is None
+                                 else "attn_window"):
+                out = F._contrib_FlashAttention(
+                    q, k, v, causal=self._causal, scale=scale, **windowed)
         else:
             out = F._contrib_RingAttention(q, k, v, seq_axis=self._seq_axis,
                                            causal=self._causal, scale=scale)
         out = F.transpose(out, axes=(0, 2, 1, 3))
-        out = F.reshape(out, shape=(B, S, self._units))
+        out = F.reshape(out, shape=(B, S,
+                                    self._num_heads * self._head_dim))
         return self.proj_out(out)

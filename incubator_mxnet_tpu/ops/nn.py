@@ -13,6 +13,7 @@ layout assignment re-tiles internally, so user code ports unchanged.
 from __future__ import annotations
 
 import functools
+import math
 
 import numpy as np
 import jax
@@ -378,22 +379,92 @@ def _rms_norm(data, gamma, eps=1e-5):
     return (y * gamma.astype(jnp.float32)).astype(data.dtype)
 
 
+_YARN_KEYS = ("factor", "original_max_position", "beta_fast", "beta_slow",
+              "attention_factor")
+
+
+def yarn_inverse_frequencies(dim, base, factor, original_max_position,
+                             beta_fast=32.0, beta_slow=1.0):
+    """YaRN's D/2 inverse frequencies (Peng et al., arXiv:2309.00071, the
+    "NTK-by-parts" blend as HF ``transformers`` computes it): channel pair
+    i turns by ``f_i = base^(-2i/D)`` a position where it makes more than
+    ``beta_fast`` turns over the original context (kept, "extrapolated"),
+    by ``f_i / factor`` where it makes fewer than ``beta_slow``
+    ("interpolated"), and by a linear blend between the two pairs ``low``
+    and ``high`` at which it makes exactly those.  On the host, float64."""
+    pair = np.arange(dim // 2, dtype=np.float64)
+    extrapolated = float(base) ** (-2.0 * pair / dim)
+
+    def pair_of(turns):
+        return (dim * math.log(original_max_position / (turns * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low = max(math.floor(pair_of(beta_fast)), 0)
+    high = min(math.ceil(pair_of(beta_slow)), dim - 1)
+    ramp = np.clip((pair - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return extrapolated / factor * ramp + extrapolated * (1.0 - ramp)
+
+
+def _host_table(seq, inv, split=64):
+    """cos and sin of ``t * inv`` for t < seq, (seq, len(inv)) float32,
+    exact to float32's rounding at any position: position t = split * a + b
+    turns by the sum of two angles whose cosines and sines are taken on the
+    host in float64 ((seq / split + split) rows of constants, not seq), and
+    the device only adds the angles: cos(A + B) = cos A cos B - sin A sin B."""
+    inv = np.asarray(inv, np.float64)
+    coarse = np.arange(0, seq, split, dtype=np.float64)[:, None] * inv
+    fine = np.arange(split, dtype=np.float64)[:, None] * inv
+    ca, sa = (jnp.asarray(f(coarse), jnp.float32)[:, None] for f in
+              (np.cos, np.sin))
+    cb, sb = (jnp.asarray(f(fine), jnp.float32)[None] for f in
+              (np.cos, np.sin))
+    return tuple(t.reshape(-1, inv.size)[:seq]
+                 for t in (ca * cb - sa * sb, sa * cb + ca * sb))
+
+
 @register("_contrib_RotaryEmbedding", num_inputs=1)
-def _rotary_embedding(data, base=10000.0):
+def _rotary_embedding(data, base=10000.0, scaling=None):
     """Rotary positions (Su et al., arXiv:2104.09864) over the whole last
     axis of (B, H, S, D), rotate-half convention: channel i pairs with
     channel i + D/2, position t (row t of S) turns the pair by
     t * base^(-2i/D).  Angles and the rotation in float32; the result has
-    the data's type."""
+    the data's type.
+
+    ``scaling``: None, or YaRN's parameters as a mapping with the keys
+    ``factor``, ``original_max_position`` and optionally ``beta_fast``
+    (32), ``beta_slow`` (1) and ``attention_factor`` (0.1 ln(factor) + 1):
+    the pairs turn by ``yarn_inverse_frequencies`` and the cosines and
+    sines are multiplied by ``attention_factor``, at every position (the
+    scaling is static, not by the sequence's length); ``factor`` 1 is the
+    plain frequencies.  With a scaling the cosines and sines come from the
+    host (``_host_table``): a TPU's float32 cosine of an angle of thousands
+    of radians is good to a hundredth of a radian and no better (PERF.md,
+    PR 30: two compilations of the same formula parted by 1e-2 at 8192
+    positions), which a layer that attends far back shows."""
     d, seq = data.shape[-1], data.shape[-2]
     if d % 2:
         raise ValueError("rotary positions need an even head dimension, "
                          "got %d" % d)
     with jax.named_scope("rope"):
-        inv = 1.0 / (base ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-        ang = jnp.arange(seq, dtype=jnp.float32)[:, None] * inv
-        cos = jnp.concatenate([jnp.cos(ang)] * 2, axis=-1)
-        sin = jnp.concatenate([jnp.sin(ang)] * 2, axis=-1)
+        if scaling is None:
+            inv = 1.0 / (base ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+            ang = jnp.arange(seq, dtype=jnp.float32)[:, None] * inv
+            cos = jnp.concatenate([jnp.cos(ang)] * 2, axis=-1)
+            sin = jnp.concatenate([jnp.sin(ang)] * 2, axis=-1)
+        else:
+            scaling = dict(scaling)
+            unknown = set(scaling) - set(_YARN_KEYS)
+            if unknown or not {"factor", "original_max_position"} <= set(
+                    scaling):
+                raise ValueError(
+                    "scaling takes the keys %s (the first two required), "
+                    "got %s" % (", ".join(_YARN_KEYS), sorted(scaling)))
+            factor = scaling.pop("attention_factor", None)
+            if factor is None:
+                factor = 0.1 * math.log(scaling["factor"]) + 1.0
+            cos, sin = (jnp.concatenate([t * factor] * 2, axis=-1)
+                        for t in _host_table(seq, yarn_inverse_frequencies(
+                            d, base, **scaling)))
         x = data.astype(jnp.float32)
         turned = jnp.concatenate([-x[..., d // 2:], x[..., :d // 2]], axis=-1)
         return (x * cos + turned * sin).astype(data.dtype)

@@ -40,7 +40,8 @@ balancing), ``norm_topk`` divides the chosen experts' scores by their sum
 experts are W2 (silu(W1 x) ⊙ W3 x); otherwise relu(x W1) W2.
 
 What has run on the chip: ``grouped`` alone, in ``lfm2moe_fused_s8192``
-(PERF.md).  ``dense`` and ``capacity`` are held by the CPU tests
+and, at top-8 of 64 by softmax with experts 0-7 held, in
+``mellum2_fused_s8192`` (PERF.md).  ``dense`` and ``capacity`` are held by the CPU tests
 (``tests/test_model_parallel.py``, ``tests/test_moe_capacity.py``); no
 benchmark cell runs them, and ``capacity`` needs a mesh with an ``ep`` axis.
 On eager calls the layer counts itself: ``graft_moe_dispatch_traces_total

@@ -674,9 +674,10 @@ def trainer_compiled_fallback(reason):
                       ("reason",)).inc(reason=reason)
 
 
-def flash_attention_trace(path):
+def flash_attention_trace(path, window=None):
     """One trace of ``ops.attention.flash_attention``, forward or backward,
-    labeled by the path it took.  Forward: ``pallas`` /
+    labeled by the path it took and by its window (``"none"`` or the
+    width in keys).  Forward: ``pallas`` /
     ``reference_off_tpu`` (concrete operands, chosen by where they live),
     ``lowering_platform`` (traced operands: Pallas when the enclosing
     program is lowered for a TPU, jnp otherwise) or ``reference_unaligned``
@@ -687,8 +688,26 @@ def flash_attention_trace(path):
     if not enabled():
         return
     _REGISTRY.counter("graft_flash_attention_traces_total",
-                      "flash_attention traces by execution path",
-                      ("path",)).inc(path=path)
+                      "flash_attention traces by execution path and window",
+                      ("path", "window")).inc(
+        path=path, window="none" if window is None else str(window))
+
+
+def flash_blocks(visited, causal, window=None):
+    """The (query block, key block) pairs in which the Pallas forward of
+    the causal ``flash_attention`` call just traced runs a product, over
+    all of its (batch, head) slices, and those of the causal triangle alone:
+    two gauges, by ``kind`` (``window`` / ``full``), set when the call is
+    traced.  Static counts from shapes; nothing is read back in a step."""
+    if not enabled():
+        return
+    kind = "full" if window is None else "window"
+    for name, value, what in (
+            ("graft_flash_blocks_visited", visited, "run a product in"),
+            ("graft_flash_blocks_causal", causal, "of the causal triangle")):
+        _REGISTRY.gauge(name, "Block pairs of the last traced causal flash "
+                        "forward %s, by kind of layer" % what,
+                        ("kind",)).set(float(value), kind=kind)
 
 
 def moe_dispatch_trace(path):

@@ -184,3 +184,78 @@ def test_grad_compiles_to_three_kernels_at_the_opt_cells_shape(
               if "flash_attention_bwd" in line]
     assert scoped and not any(re.search(r"\bwhile\(", s) for s in scoped)
     assert not re.search(r"f32\[[\d,]*2048,1024\]", text)
+
+
+def _loss_of(window):
+    def loss(q, k, v):
+        return A.flash_attention(q, k, v, True, None, window).astype(
+            jnp.float32).sum()
+    return jax.grad(loss, argnums=(0, 1, 2))
+
+
+def test_window_grad_compiles_to_three_named_kernels_at_the_mellum_cells_shape(
+        topo, no_compile_cache):
+    """(1, 32, 8192, 128) bf16 under a window of 1024: Mosaic takes the three
+    banded kernels, and a trace tells them from the full layers' by name."""
+    from jax.sharding import SingleDeviceSharding
+    spec = jax.ShapeDtypeStruct(
+        (1, 32, 8192, 128), jnp.bfloat16,
+        sharding=SingleDeviceSharding(topo.devices[0]))
+    text = jax.jit(_loss_of(1024)).lower(spec, spec, spec).compile().as_text()
+    names = [re.match(r"\s*(?:ROOT )?%(\S+) =", line).group(1)
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert sorted(n.split(".")[0] for n in names) == [
+        "flash_window_bwd_dkv", "flash_window_bwd_dq", "flash_window_pallas"]
+    assert "flash_attention_pallas" not in " ".join(names)
+
+
+# sha256 of each kernel's Mosaic module printed without debug locations, as
+# commit 0112fb9 (the parent of the PR that brought windows) lowered
+# ``grad(flash_attention(q, k, v, causal=True))`` for a v5e, in the order
+# forward, dK/dV, dQ.  A PR that changes the causal kernels on purpose
+# records them anew; one that adds an option beside them may not move them.
+KERNELS_WITHOUT_A_WINDOW = {
+    (4, 32, 2048, 128): ["70a2c89c474f497a", "904bd27c9616a081",
+                         "37cf7cdab58b8d03"],
+    (1, 32, 8192, 64): ["709ed1065112820b", "3706ba7af9bf260f",
+                        "71b2bad4e5eb6fb8"],
+}
+
+
+def _mosaic_modules(lowered_text):
+    """The Mosaic modules of a lowering's ``tpu_custom_call``s as text with
+    no debug locations (a kernel's line numbers are no part of it)."""
+    import base64
+    import json
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+    found = []
+    for m in re.finditer(r'backend_config = "((?:[^"\\]|\\.)*)"',
+                         lowered_text):
+        raw = (m.group(1).replace("\\22", '"').replace("\\5C", "\\")
+               .replace("\\0A", " "))
+        body = base64.b64decode(
+            json.loads(raw)["custom_call_config"]["body"])
+        ctx = jax_mlir.make_ir_context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            found.append(ir.Module.parse(body).operation.get_asm(
+                enable_debug_info=False))
+    return found
+
+
+@pytest.mark.parametrize("shape", sorted(KERNELS_WITHOUT_A_WINDOW))
+def test_without_a_window_the_kernels_are_the_programs_they_were(
+        topo, no_compile_cache, shape):
+    """``window=None`` (and a window that holds every key) lower to the
+    kernels the OPT and LFM2 cells ran before the window existed."""
+    import hashlib
+    from jax.sharding import SingleDeviceSharding
+    spec = jax.ShapeDtypeStruct(
+        shape, jnp.bfloat16, sharding=SingleDeviceSharding(topo.devices[0]))
+    for window in (None, shape[2]):
+        text = jax.jit(_loss_of(window)).lower(spec, spec, spec).as_text()
+        digests = [hashlib.sha256(asm.encode()).hexdigest()[:16]
+                   for asm in _mosaic_modules(text)]
+        assert digests == KERNELS_WITHOUT_A_WINDOW[shape], window

@@ -532,29 +532,36 @@ def no_compile_cache():
     cc.reset_cache()
 
 
+@pytest.mark.parametrize("config, router, shape, buffer_rows", [
+    (CONFIG, "sigmoid", (8192, 2048, 1792, 8, 32), 32768),
+    # PR 30: top-8 of 64 by softmax, a buffer of tokens x 8 rows
+    ("mellum2_12b_a2b5_ep8_l4", "softmax", (8192, 2304, 896, 8, 64), 65536)])
 def test_routed_layer_compiles_for_the_chip_at_the_cells_shape(
-        bench_catalog, one_chip, no_compile_cache):
-    """Forward and backward of the grouped dispatch over 8192 tokens, top-4
-    of 32 with experts 0-7 held, in bf16: the products are the Pallas
-    grouped matmul (``gmm``, ``tgmm``), not XLA's expansion of
-    ``ragged_dot``, and no row moves by a scatter."""
+        bench_catalog, one_chip, no_compile_cache, config, router, shape,
+        buffer_rows):
+    """Forward and backward of the grouped dispatch over 8192 tokens with
+    experts 0-7 held, in bf16 (LFM2: top-4 of 32; Mellum2: top-8 of 64):
+    the products are the Pallas grouped matmul (``gmm``, ``tgmm``), not
+    XLA's expansion of ``ragged_dot``, and no row moves by a scatter."""
     import jax
     import jax.numpy as jnp
     from incubator_mxnet_tpu.parallel import moe
-    sizes, _ = bench_catalog.config(CONFIG)
+    sizes, _ = bench_catalog.config(config)
     traffic = bench_catalog.traffic("fused_s8192")
     tokens = traffic["batch_per_chip"] * traffic["seq_len"]
     d, h = sizes["hidden_size"], sizes["moe_intermediate_size"]
     held, experts = sizes["num_experts"], sizes["num_experts_published"]
-    assert (tokens, d, h, held, experts) == (8192, 2048, 1792, 8, 32)
+    assert (tokens, d, h, held, experts) == shape
+    assert tokens * sizes["num_experts_per_tok"] == buffer_rows
 
     def spec(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     def loss(x, gate, w1, w3, w2, bias):
         _, chosen, weights = moe._route(
-            x, gate, bias, top_k=sizes["num_experts_per_tok"],
-            router="sigmoid", norm_topk=True, scaling=1.0)
+            x, gate, bias if router == "sigmoid" else None,
+            top_k=sizes["num_experts_per_tok"],
+            router=router, norm_topk=True, scaling=1.0)
         out, _ = moe.grouped_moe_apply(x, chosen, weights, w1, w3, w2, 0)
         return jnp.sum(jnp.square(out.astype(jnp.float32)))
 
@@ -571,7 +578,8 @@ def test_routed_layer_compiles_for_the_chip_at_the_cells_shape(
     # three products forward, three for the rows and three for the weights
     assert names.count("gmm") == 6 and names.count("tgmm") == 3, names
     big_scatters = [line for line in text.splitlines()
-                    if " scatter(" in line and "[32768,2048]" in line]
+                    if " scatter(" in line
+                    and "[%d,%d]" % (buffer_rows, d) in line]
     assert not big_scatters
 
 
