@@ -25,7 +25,11 @@ Three dispatch modes behind one module interface:
   experts behind them in no group; JAX's Pallas grouped matmul on the TPU,
   ``jax.lax.ragged_dot`` off it).  The buffer has a row for every
   assignment (tokens × top_k), so its shapes are static and nothing can
-  overflow.  What absent experts would have added is left out: on the
+  overflow; the rows behind the groups are written by no kernel and read
+  by nothing: the two gathers that move rows between the tokens' order and
+  the buffer's send every assignment that lies in no group to a row of
+  zeros (``_to_buffer``, ``_to_tokens``), so the buffer is never masked
+  whole.  What absent experts would have added is left out: on the
   mesh of a deployment their chips add it through the exchange; on one
   chip the layer computes its share (the chip benchmark's cell
   ``lfm2moe_fused_s8192``: experts 0–7 of 32, top-4).  Compute ∝ the
@@ -282,44 +286,74 @@ grouped_dot.defvjp(_grouped_dot_fwd, _grouped_dot_bwd)
 def _experts(xs, w1, w3, w2, group_sizes, in_group):
     """Rows ``xs`` (A, d), sorted by expert, through the experts whose
     stacked weights are given.  ``in_group`` (A, 1) marks the rows that lie
-    in a group; every product's rows behind them are set to zero at once:
-    they are whatever the kernel's buffer held, a NaN among it, and
-    zero times that is no zero in the products of the backward pass.
-    Products accumulate in float32 and leave in xs's dtype, as a Dense
-    layer's do; the activation is taken in float32."""
+    in a group; the hidden products' rows behind them are set to zero at
+    once: they are whatever the kernel's buffer held, a NaN among it, and
+    zero times that is no zero in the products of the backward pass.  The
+    last product's rows behind the groups stay as the kernel left them:
+    the caller reads none of them (``_to_tokens``).  Products accumulate in
+    float32 and leave in xs's dtype, as a Dense layer's do; the activation
+    is taken in float32."""
     def dot(rows, w):
         return jnp.where(in_group, grouped_dot(rows, w, group_sizes),
                          jnp.zeros((), rows.dtype))
     h = dot(xs, w1).astype(jnp.float32)
     h = (jax.nn.relu(h) if w3 is None
          else jax.nn.silu(h) * dot(xs, w3).astype(jnp.float32))
-    return dot(h.astype(xs.dtype), w2)
+    return grouped_dot(h.astype(xs.dtype), w2, group_sizes)
+
+
+# Rows move between the tokens' order and the buffer's by two gathers that
+# are each other's transpose: ``order`` (A,) names the assignment, token x
+# top_k + choice, that each row of the buffer holds, and ``slot`` (A,) the
+# row that holds each assignment, or A for an assignment that lies in no
+# group.  That one reads zeros, so what no kernel wrote behind the groups
+# (unspecified, a NaN among it) is never read and needs no mask: a pass
+# over the whole buffer less for every array that crosses.  A gather both
+# ways, where the transpose of a plain gather is a scatter-add that the
+# TPU's compiler sorts and serialises.
+
+def _zero_filled(rows, slot):
+    """``rows[slot]``, zeros where ``slot`` is ``len(rows)``."""
+    return rows.at[slot].get(mode="fill", fill_value=0)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _permute(rows, index, inverse, repeats=1):
-    """``jnp.repeat(rows, repeats, axis=0)[index]`` for a permutation
-    ``index`` whose inverse is ``inverse``.  The cotangent goes back as
-    ``g[inverse]`` (summed over the repeats): a gather both ways, where the
-    transpose of a plain gather is a scatter-add that the TPU's compiler
-    sorts and serialises."""
-    return rows[index // repeats if repeats > 1 else index]
+def _to_buffer(x, order, slot, top_k):
+    """Each token's row of ``x`` (N, d) at the buffer rows that hold its
+    assignments, (A, d).  The cotangent of a token is the float32 sum over
+    its ``top_k`` assignments' rows, those in no group counting zero."""
+    return x[order // top_k]
 
 
-def _permute_fwd(rows, index, inverse, repeats):
-    return _permute(rows, index, inverse, repeats), (index, inverse)
+def _to_buffer_fwd(x, order, slot, top_k):
+    return _to_buffer(x, order, slot, top_k), slot
 
 
-def _permute_bwd(repeats, res, g):
-    _, inverse = res
-    g = g[inverse]
-    if repeats > 1:
-        g = g.reshape(-1, repeats, g.shape[-1]).astype(jnp.float32).sum(
-            axis=1).astype(g.dtype)
-    return g, None, None
+def _to_buffer_bwd(top_k, slot, g):
+    g = _zero_filled(g, slot)
+    return (g.reshape(-1, top_k, g.shape[-1]).astype(jnp.float32).sum(
+        axis=1).astype(g.dtype), None, None)
 
 
-_permute.defvjp(_permute_fwd, _permute_bwd)
+_to_buffer.defvjp(_to_buffer_fwd, _to_buffer_bwd)
+
+
+@jax.custom_vjp
+def _to_tokens(ys, slot, order):
+    """The buffer's rows ``ys`` (A, d) in the order of the assignments,
+    zeros for an assignment in no group."""
+    return _zero_filled(ys, slot)
+
+
+def _to_tokens_fwd(ys, slot, order):
+    return _to_tokens(ys, slot, order), order
+
+
+def _to_tokens_bwd(order, g):
+    return g[order], None, None
+
+
+_to_tokens.defvjp(_to_tokens_fwd, _to_tokens_bwd)
 
 
 def grouped_moe_apply(x, chosen, weights, w1, w3, w2, first):
@@ -330,9 +364,9 @@ def grouped_moe_apply(x, chosen, weights, w1, w3, w2, first):
     held expert got, (count,) int32.
 
     The A = N·k assignments are sorted by held expert, those of absent
-    experts behind them; both ways the rows move by a permutation of A
-    (the sort's, and its inverse), so neither direction of the gradient
-    adds rows serially."""
+    experts behind them; both ways the rows move by a gather over a
+    permutation of A (the sort's, and its inverse), so neither direction
+    of the gradient adds rows serially."""
     n, k = chosen.shape
     count = w1.shape[0]
     with jax.named_scope("moe_dispatch"):
@@ -344,19 +378,18 @@ def grouped_moe_apply(x, chosen, weights, w1, w3, w2, first):
         group_sizes = jnp.bincount(key, length=count + 1)[:count].astype(
             jnp.int32)
         back = jnp.argsort(order)                 # the inverse permutation
-        in_group = (jnp.arange(n * k) < group_sizes.sum())[:, None]
-        # rows behind the groups are computed by no expert: zeros go in, and
-        # coming back the mask keeps their gradient, which no kernel wrote,
-        # out of the tokens'
-        xs = jnp.where(in_group, _permute(x, order, back, k),
-                       jnp.zeros((), x.dtype))
+        in_groups = group_sizes.sum()
+        in_group = (jnp.arange(n * k) < in_groups)[:, None]
+        slot = jnp.where(back < in_groups, back, n * k)
+        xs = _to_buffer(x, order, slot, k)
     with jax.named_scope(_SCOPE):
         ys = _experts(xs, w1, w3, w2, group_sizes, in_group)
     with jax.named_scope("moe_combine"):
         # back to (token, choice) order, then the weighted sum over a
         # token's choices: the weights meet the rows where both lie in the
         # router's order, so no scalar is gathered
-        out = _permute(ys, back, order).reshape(n, k, -1).astype(jnp.float32)
+        out = _to_tokens(ys, slot, order).reshape(n, k, -1).astype(
+            jnp.float32)
         out = (out * weights[:, :, None]).sum(axis=1)
     return out.astype(x.dtype), group_sizes
 

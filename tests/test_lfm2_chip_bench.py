@@ -11,6 +11,7 @@ rehearsals are ``test_chip_bench_rehearsal.py``'s, which find it in
 import importlib.util
 import json
 import pathlib
+import re
 import types
 
 import numpy as np
@@ -542,7 +543,8 @@ def test_routed_layer_compiles_for_the_chip_at_the_cells_shape(
     """Forward and backward of the grouped dispatch over 8192 tokens with
     experts 0-7 held, in bf16 (LFM2: top-4 of 32; Mellum2: top-8 of 64):
     the products are the Pallas grouped matmul (``gmm``, ``tgmm``), not
-    XLA's expansion of ``ragged_dot``, and no row moves by a scatter."""
+    XLA's expansion of ``ragged_dot``, no row moves by a scatter, and no
+    pass masks the buffer at the tokens' width."""
     import jax
     import jax.numpy as jnp
     from incubator_mxnet_tpu.parallel import moe
@@ -581,6 +583,18 @@ def test_routed_layer_compiles_for_the_chip_at_the_cells_shape(
                     if " scatter(" in line
                     and "[%d,%d]" % (buffer_rows, d) in line]
     assert not big_scatters
+    # PR 31: nothing masks the whole buffer at the tokens' width.  What the
+    # program writes of [buffer_rows, d]: the four gathers (in and back,
+    # forward and backward), the kernels' results and the sum of the two
+    # hidden products' row cotangents; the parent also wrote four masked
+    # or converted copies (``select_n`` / ``convert_element_type``)
+    entry = text[text.index("ENTRY "):]
+    wrote = [re.search(r'op_name="[^"]*/([\w\-]+)"', line).group(1)
+             for line in entry.splitlines()
+             if re.match(r"\s*(ROOT )?%%[\w.\-]+ = bf16\[%d,%d\]\S* "
+                         r"(fusion|add|select|convert)\(" % (buffer_rows, d),
+                         line)]
+    assert sorted(wrote) == ["add_any"] + ["gather"] * 4, wrote
 
 
 def test_flash_kernels_compile_at_the_cells_shape(bench_catalog, one_chip,

@@ -386,6 +386,197 @@ def test_grouped_gradients_match_dense_and_spare_the_bias():
                                atol=2e-5)
 
 
+# ---------------------------------------------------------------------------
+# grouped dispatch against the layer that masked its whole buffer (PR 26-30)
+# ---------------------------------------------------------------------------
+
+def _every_row_masked(x, chosen, weights, w1, w3, w2, first):
+    """``grouped_moe_apply`` as it stood until PR 31: the rows gathered in
+    and every product's rows are masked with ``in_group`` over the whole
+    buffer, and the rows move by plain indexing with the sort's
+    permutation and its inverse.  The plain reference today's layer, which
+    masks nothing it does not read, is held to."""
+    from incubator_mxnet_tpu.parallel.moe import grouped_dot
+    n, k = chosen.shape
+    count = w1.shape[0]
+    local = chosen.reshape(-1) - first
+    held = (local >= 0) & (local < count)
+    key = jnp.where(held, local, count).astype(jnp.int32)
+    order = jnp.argsort(key, stable=True)
+    group_sizes = jnp.bincount(key, length=count + 1)[:count].astype(
+        jnp.int32)
+    back = jnp.argsort(order)
+    in_group = (jnp.arange(n * k) < group_sizes.sum())[:, None]
+
+    def dot(rows, w):
+        return jnp.where(in_group, grouped_dot(rows, w, group_sizes),
+                         jnp.zeros((), rows.dtype))
+    xs = jnp.where(in_group, jnp.repeat(x, k, axis=0)[order],
+                   jnp.zeros((), x.dtype))
+    h = jax.nn.silu(dot(xs, w1).astype(jnp.float32)) * dot(xs, w3).astype(
+        jnp.float32)
+    ys = dot(h.astype(xs.dtype), w2)
+    out = ys[back].reshape(n, k, -1).astype(jnp.float32)
+    return (out * weights[:, :, None]).sum(axis=1).astype(x.dtype)
+
+
+def _dense_share(x, chosen, weights, w1, w3, w2, first):
+    """``dispatch="dense"``'s equations over the held experts alone: every
+    held expert for every token, weighted by ``combine``."""
+    count = w1.shape[0]
+    combine = (jax.nn.one_hot(chosen - first, count, dtype=x.dtype)
+               * weights[:, :, None]).sum(1)                  # (N, count)
+    h = jax.nn.silu(jnp.einsum("nd,edh->neh", x, w1)) * jnp.einsum(
+        "nd,edh->neh", x, w3)
+    return jnp.einsum("ne,ned->nd", combine, jnp.einsum("neh,ehd->ned", h, w2))
+
+
+# a product of under 64 rows takes another route through the CPU's compiler
+TOKENS, WIDTH, HIDDEN = 72, 12, 20
+SHARES = [(4, 8, 32), (8, 8, 64), (2, 2, 8), (2, 8, 8)]   # top_k, count, E
+
+
+def _choices(router, top_k, count, experts):
+    """(N, k) experts a token, distinct within a token, experts 0 ..
+    count - 1 held: ``balanced`` sends the share count / experts;
+    ``all_held`` every assignment (the routed cells' trained steps);
+    ``none_held`` no assignment; ``one_token`` a single token's single
+    choice.  A layer that holds every expert has only ``all_held``."""
+    step = np.arange(top_k)
+    on_held = (np.arange(TOKENS)[:, None] + step) % count
+    if router == "all_held" or count == experts:
+        return on_held
+    if router == "balanced":
+        return (np.arange(TOKENS)[:, None] * top_k + step) % experts
+    chosen = count + (np.arange(TOKENS)[:, None] + step) % (experts - count)
+    if router == "one_token":
+        chosen[TOKENS // 2, top_k - 1] = count - 1
+    return chosen
+
+
+def _layer_inputs(top_k, count, experts, router, seed=14):
+    rs = np.random.RandomState(seed)
+    x = jnp.asarray(rs.randn(TOKENS, WIDTH), jnp.float32)
+    gate = jnp.asarray(rs.randn(WIDTH, experts) * 0.5, jnp.float32)
+    w1, w3 = (jnp.asarray(rs.randn(count, WIDTH, HIDDEN) * 0.3, jnp.float32)
+              for _ in range(2))
+    w2 = jnp.asarray(rs.randn(count, HIDDEN, WIDTH) * 0.3, jnp.float32)
+    chosen = jnp.asarray(_choices(router, top_k, count, experts), jnp.int32)
+    return chosen, (x, gate, w1, w3, w2)
+
+
+def _through(apply, chosen):
+    """``apply`` behind a router whose choice is given and whose weights
+    are the gate's sigmoid scores at the chosen, renormalised: the gate is
+    differentiated through the weights, as in ``_route``."""
+    def fn(x, gate, w1, w3, w2):
+        scores = jax.nn.sigmoid(x @ gate)
+        weights = jnp.take_along_axis(scores, chosen, axis=1)
+        weights = weights / (weights.sum(-1, keepdims=True) + 1e-6)
+        out = apply(x, chosen, weights, w1, w3, w2, 0)
+        return out[0] if isinstance(out, tuple) else out
+    return fn
+
+
+def _value_and_gradients(apply, chosen, args):
+    out, pullback = jax.vjp(_through(apply, chosen), *args)
+    return (out,) + pullback(jnp.cos(out))
+
+
+@pytest.mark.parametrize("router", ["balanced", "all_held", "none_held",
+                                    "one_token"])
+@pytest.mark.parametrize("top_k,count,experts", SHARES)
+def test_grouped_equals_the_layer_that_masked_every_row(top_k, count,
+                                                        experts, router):
+    """Value and gradients (x, gate, w1, w3, w2) against the layer that
+    masked its whole buffer, to the last bit in float32 (the sums are the
+    same sums in the same order), and against the dense equations."""
+    from incubator_mxnet_tpu.parallel import moe
+    chosen, args = _layer_inputs(top_k, count, experts, router)
+    held = int((np.asarray(chosen) < count).sum())
+    assert held == {"balanced": TOKENS * top_k * count // experts,
+                    "all_held": TOKENS * top_k, "none_held": 0,
+                    "one_token": 1}[
+        "all_held" if count == experts else router]
+    got, want, dense = (_value_and_gradients(apply, chosen, args)
+                        for apply in (moe.grouped_moe_apply,
+                                      _every_row_masked, _dense_share))
+    for g, w, d in zip(got, want, dense):
+        assert np.array_equal(np.asarray(g), np.asarray(w))
+        np.testing.assert_allclose(np.asarray(g), np.asarray(d), rtol=2e-4,
+                                   atol=2e-5)
+
+
+@pytest.mark.parametrize("router", ["balanced", "none_held", "one_token"])
+def test_grouped_reads_no_row_that_no_kernel_wrote(monkeypatch, router):
+    """The Pallas grouped matmul leaves the rows behind the groups as its
+    buffer held them; ``lax.ragged_dot`` on the CPU zeroes them and would
+    hide a reader.  With a grouped product that writes NaN there, forward
+    (its result) and backward (the rows' cotangent), the layer's value and
+    gradients are what they are without: nothing reads those rows."""
+    from incubator_mxnet_tpu.parallel import moe
+    clean = moe.grouped_dot
+
+    def poison(rows, group_sizes):
+        behind = jnp.arange(rows.shape[0])[:, None] >= group_sizes.sum()
+        return jnp.where(behind, jnp.nan, rows)
+
+    @jax.custom_vjp
+    def dirty(lhs, rhs, group_sizes):
+        return poison(clean(lhs, rhs, group_sizes), group_sizes)
+
+    def forward(lhs, rhs, group_sizes):
+        return dirty(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
+
+    def backward(res, g):
+        lhs, rhs, group_sizes = res
+        d_lhs, d_rhs = jax.vjp(lambda a, b: clean(a, b, group_sizes), lhs,
+                               rhs)[1](g)
+        return poison(d_lhs, group_sizes), d_rhs, None
+
+    dirty.defvjp(forward, backward)
+    chosen, args = _layer_inputs(4, 8, 32, router)
+    want = _value_and_gradients(moe.grouped_moe_apply, chosen, args)
+    monkeypatch.setattr(moe, "grouped_dot", dirty)
+    got = _value_and_gradients(moe.grouped_moe_apply, chosen, args)
+    for g, w in zip(got, want):
+        assert np.isfinite(np.asarray(g)).all()
+        assert np.array_equal(np.asarray(g), np.asarray(w))
+
+
+def test_grouped_masks_no_row_of_the_tokens_width():
+    """What crosses between the tokens' order and the buffer's is not
+    masked over the whole buffer: in the layer's jaxpr, forward and
+    backward, no ``select_n`` writes (A, d) (the two gathers fill the rows
+    of assignments in no group themselves, ``_zero_filled``), and the
+    hidden products' masks, two forward and their two transposes, are
+    (A, h)."""
+    from incubator_mxnet_tpu.parallel import moe
+    chosen, args = _layer_inputs(4, 8, 32, "balanced")
+    jaxpr = jax.make_jaxpr(lambda *a: _value_and_gradients(
+        moe.grouped_moe_apply, chosen, a))(*args)
+    assignments = TOKENS * 4
+    selects = [aval.shape for primitive, aval in _outputs(jaxpr.jaxpr)
+               if primitive == "select_n" and aval.ndim == 2
+               and aval.shape[0] == assignments]
+    assert sorted(selects) == [(assignments, HIDDEN)] * 4, selects
+    masked = jax.make_jaxpr(lambda *a: _value_and_gradients(
+        _every_row_masked, chosen, a))(*args)
+    assert [aval.shape for primitive, aval in _outputs(masked.jaxpr)
+            if primitive == "select_n"].count((assignments, WIDTH)) == 4
+
+
+def _outputs(jaxpr):
+    """(primitive, type) of every equation's outputs, sub-jaxprs
+    included."""
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            if hasattr(v.aval, "shape"):
+                yield eqn.primitive.name, v.aval
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _outputs(sub)
+
+
 def test_grouped_counters_and_arguments():
     rs = np.random.RandomState(10)
     x = nd.array(rs.randn(16, 10).astype(np.float32))
