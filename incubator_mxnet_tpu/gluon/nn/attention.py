@@ -16,7 +16,7 @@ import numpy as np
 from ..block import HybridBlock
 from .basic_layers import Dense, RMSNorm
 
-__all__ = ["MultiHeadAttention"]
+__all__ = ["MultiHeadAttention", "LatentAttention"]
 
 
 class MultiHeadAttention(HybridBlock):
@@ -84,7 +84,9 @@ class MultiHeadAttention(HybridBlock):
     benchmark's ``opt6b7_fused_s2048`` and ``opt6b7_fused_adam`` run it so);
     ``lfm2moe_fused_s8192`` runs 32 query heads over 8 K/V heads of 64 with
     ``qk_norm`` and base 1e6; ``mellum2_fused_s8192`` 32 over 4 of 128 at
-    width 2304, three layers with ``window=1024`` to one with YaRN.
+    width 2304, three layers with ``window=1024`` to one with YaRN;
+    ``kimivl_mla_fused_1row`` runs ``LatentAttention`` below, through the
+    same kernel call.
     """
 
     def __init__(self, units, num_heads, causal=False, seq_axis=None,
@@ -210,4 +212,126 @@ class MultiHeadAttention(HybridBlock):
         out = F.transpose(out, axes=(0, 2, 1, 3))
         out = F.reshape(out, shape=(B, S,
                                     self._num_heads * self._head_dim))
+        return self.proj_out(out)
+
+
+class LatentAttention(HybridBlock):
+    """Multi-head latent self-attention (DeepSeek-V2, arXiv:2405.04434;
+    without a query latent, as Moonlight and Kimi-VL run it), causal,
+    (B, S, E) -> (B, S, E), no bias.
+
+    Keys and values come from one narrow latent a token and the positions
+    from a rotary part beside the head's own channels::
+
+        q = W_q x                      H heads of nope_dim + rope_dim
+        [c ; k_pe] = W_kva x           kv_latent_dim + rope_dim: ONE rotary key
+        [k_nope ; v] = W_kvb RMSNorm(c)   H heads of nope_dim + v_head_dim
+        q_h = [q_nope_h ; rope(q_pe_h)],  k_h = [k_nope_h ; rope(k_pe)]
+        o_h = softmax(q_h k_h^T / sqrt(nope_dim + rope_dim) + causal) v_h
+        out = W_o [o_1 ; ... ; o_H]
+
+    so a head's keys are ``nope_dim + rope_dim`` wide and its values
+    ``v_head_dim`` (Kimi-VL: 192 over 128), the rotary key is shared by all
+    heads, and positions touch ``rope_dim`` channels of a head only.
+
+    Parameters
+    ----------
+    units : int
+        The model width E.
+    num_heads : int
+        Heads H.
+    kv_latent_dim : int
+        Width of the K/V latent ``c`` (HF ``kv_lora_rank``).
+    nope_dim, rope_dim : int
+        A head's channels without positions and its rotary channels (HF
+        ``qk_nope_head_dim``, ``qk_rope_head_dim``).
+    v_head_dim : int
+        A head's value width, which is its output's.
+    rotary_base : float
+        Base of the rotary positions, rotate-half over the ``rope_dim``
+        channels (HF lays the pairs out adjacent: the same scores, with the
+        rotary columns of ``W_q`` and ``W_kva`` permuted).
+    latent_norm_epsilon : float
+        Epsilon of the RMSNorm on the latent.
+    rotary_scaling : mapping or None
+        As ``MultiHeadAttention``'s: handed to ``_contrib_RotaryEmbedding``
+        under ``scaling``.
+
+    A class beside ``MultiHeadAttention`` and not arguments of it: the two
+    share no projection (one down-projection, a norm and an up-projection
+    stand where k and v had a Dense each), and every other argument of that
+    layer (``seq_axis``, ``fused_qkv``, ``num_kv_heads``, ``qk_norm``,
+    ``window``, cross attention) would have to be refused here.  What they
+    share is the kernel call, ``F._contrib_FlashAttention(q, k, v)``, which
+    takes v at a width of its own: wrapped in the scope ``attn_latent``,
+    everything before it in ``attn_latent_proj``.  The rotary key is
+    repeated to H heads before the kernel, as grouped K/V heads are.  The
+    chip benchmark's ``kimivl_mla_fused_1row`` runs it at 16 heads, a latent
+    of 512 and 128 + 64 over 128.
+    """
+
+    def __init__(self, units, num_heads, kv_latent_dim, nope_dim, rope_dim,
+                 v_head_dim, rotary_base=10000.0, latent_norm_epsilon=1e-6,
+                 rotary_scaling=None, **kwargs):
+        super().__init__(**kwargs)
+        if rope_dim % 2:
+            raise ValueError("rotary positions need an even rope_dim, got %d"
+                             % rope_dim)
+        self._num_heads = num_heads
+        self._latent, self._nope, self._rope = kv_latent_dim, nope_dim, rope_dim
+        self._v_dim = v_head_dim
+        self._rotary = {"base": rotary_base}
+        if rotary_scaling is not None:
+            self._rotary["scaling"] = dict(rotary_scaling)
+
+        def dense(out, inp, prefix):
+            return Dense(out, flatten=False, use_bias=False, in_units=inp,
+                         prefix=prefix)
+
+        with self.name_scope():
+            self.proj_q = dense(num_heads * (nope_dim + rope_dim), units, "q_")
+            self.proj_kv_a = dense(kv_latent_dim + rope_dim, units, "kv_a_")
+            self.kv_norm = RMSNorm(epsilon=latent_norm_epsilon,
+                                   in_channels=kv_latent_dim,
+                                   prefix="kv_norm_")
+            self.proj_kv_b = dense(num_heads * (nope_dim + v_head_dim),
+                                   kv_latent_dim, "kv_b_")
+            self.proj_out = dense(units, num_heads * v_head_dim, "out_")
+
+    def _heads(self, F, x, B, S):
+        """(B, S, H * D) -> (B, H, S, D)."""
+        return F.transpose(
+            F.reshape(x, shape=(B, S, self._num_heads, -1)),
+            axes=(0, 2, 1, 3))
+
+    def hybrid_forward(self, F, x):
+        B, S = x.shape[0], x.shape[1]
+        H, nope, rope = self._num_heads, self._nope, self._rope
+
+        def part(t, begin, end):
+            return F.slice_axis(t, axis=-1, begin=begin, end=end)
+
+        def turned(t):
+            return F._contrib_RotaryEmbedding(t, **self._rotary)
+
+        with jax.named_scope("attn_latent_proj"):
+            q = self._heads(F, self.proj_q(x), B, S)
+            q = F.concat(part(q, 0, nope), turned(part(q, nope, nope + rope)),
+                         dim=-1)
+            down = self.proj_kv_a(x)                    # (B, S, latent + rope)
+            latent = self.kv_norm(part(down, 0, self._latent))
+            kv = self._heads(F, self.proj_kv_b(latent), B, S)
+            # one rotary key a token, the same for every head
+            k_pe = turned(F.reshape(part(down, self._latent,
+                                         self._latent + rope),
+                                    shape=(B, 1, S, rope)))
+            k = F.concat(part(kv, 0, nope), F.repeat(k_pe, repeats=H, axis=1),
+                         dim=-1)
+            v = part(kv, nope, nope + self._v_dim)
+        with jax.named_scope("attn_latent"):
+            out = F._contrib_FlashAttention(
+                q, k, v, causal=True,
+                scale=1.0 / float(np.sqrt(nope + rope)))
+        out = F.reshape(F.transpose(out, axes=(0, 2, 1, 3)),
+                        shape=(B, S, H * self._v_dim))
         return self.proj_out(out)

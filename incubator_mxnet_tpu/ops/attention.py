@@ -31,6 +31,13 @@ maps onto the MXU with O(seq) memory.
   where the causal grid has 16), and the calls are named
   ``flash_window_pallas`` / ``flash_window_bwd_dkv`` / ``flash_window_bwd_dq``.
   Without a window the program is the one it was.
+* Two head widths: q and k share one (``Dqk``), v, the output and dO
+  another (``Dv``), as latent attention has them (keys of 192 over values
+  of 128).  Each is padded to the lanes on its own (``_fold``), so the
+  q / k blocks are ``Dqk`` wide and the v / out / dO blocks, the forward's
+  accumulator and the backward's ``delta`` ``Dv`` wide: P·V, dV and the
+  traffic of out and dO are not paid at the key's width.  Equal widths are
+  the program they were.
 * Off the TPU (the CPU test mesh), and for lengths that are not multiples
   of 128, the same math runs as jnp: a dense forward that returns the same
   statistics and a chunked scan backward (f32, HIGHEST) that consumes
@@ -64,9 +71,10 @@ _LANES = 128
 
 def _attention_reference_stats(q, k, v, causal=False, scale=None,
                                window=None):
-    """(B, H, Sq, D), (B, H, Sk, D) → out (B, H, Sq, D) and the softmax's
-    row statistics (B, H, Sq) in f32: the maximum ``m`` of the masked,
-    scaled scores and the sum ``l`` of ``exp(score - m)``."""
+    """q (B, H, Sq, Dqk), k (B, H, Sk, Dqk), v (B, H, Sk, Dv) → out
+    (B, H, Sq, Dv) and the softmax's row statistics (B, H, Sq) in f32: the
+    maximum ``m`` of the masked, scaled scores and the sum ``l`` of
+    ``exp(score - m)``."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     logits = jnp.einsum("bhqd,bhkd->bhqk", q, k,
@@ -88,7 +96,7 @@ def _attention_reference_stats(q, k, v, causal=False, scale=None,
 
 
 def _attention_reference(q, k, v, causal=False, scale=None, window=None):
-    """(B, H, Sq, D), (B, H, Sk, D) → (B, H, Sq, D)."""
+    """(B, H, Sq, Dqk), (B, H, Sk, Dqk), (B, H, Sk, Dv) → (B, H, Sq, Dv)."""
     return _attention_reference_stats(q, k, v, causal, scale, window)[0]
 
 
@@ -232,7 +240,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
                       band=None):
     from jax.experimental import pallas as pl
     qi, j, ki, inside = _grid_ids(band)
-    (bq, d), bk = q_ref.shape, k_ref.shape[0]
+    bq, bk, d = q_ref.shape[0], k_ref.shape[0], v_ref.shape[1]
 
     @pl.when(j == 0)
     def _():
@@ -350,9 +358,12 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, g_ref, m_ref, linv_ref, delta_ref,
 
 def _fold(x):
     """(B, H, S, D) → (B*H, S, Dp).  MXU lanes want D in multiples of 128;
-    typical head dims (64, 96) get zero-padded — padded Q/K columns
+    typical head dims (64, 96, 192) get zero-padded — padded Q/K columns
     contribute nothing to QKᵀ and padded V (or dO) columns produce output
-    (or gradient) columns that ``_unfold`` slices off."""
+    (or gradient) columns that ``_unfold`` slices off.  Each operand is
+    padded from its own width: q and k share one (and dq, dk with them), v,
+    the output and dO share the other (and dv), so a key of 192 over a value
+    of 128 runs at 256 and 128 lanes, not 256 and 256."""
     b, h, s, d = x.shape
     dp = -(-d // _LANES) * _LANES
     if dp != d:
@@ -385,12 +396,15 @@ def _pallas_call(kernel, name, grid, in_specs, out_specs, out_shape, scratch,
     )(*operands)
 
 
-def _specs(bq, bk, dp, off, causal, q_major, band=None):
-    """Block specs of a (batch*heads, ·, ·) grid whose second axis runs over
-    query blocks (``q_major``: forward, dQ) or key blocks (dK/dV).  The
-    index of a block the causal mask skips is clamped to the nearest one it
-    visits, so a skipped grid step fetches nothing; a ``band``'s steps are
-    its own blocks (``_Band.index``)."""
+def _specs(bq, bk, off, causal, q_major, band=None):
+    """``(q_like, kv_like, stat_spec)`` of a (batch*heads, ·, ·) grid whose
+    second axis runs over query blocks (``q_major``: forward, dQ) or key
+    blocks (dK/dV): the block spec of an operand that lies along the queries
+    (q, out, dO, dq) or along the keys (k, v, dk, dv) at the lane width it
+    is called with, and that of a row statistic.  The index of a block the
+    causal mask skips is clamped to the nearest one it visits, so a skipped
+    grid step fetches nothing; a ``band``'s steps are its own blocks
+    (``_Band.index``)."""
     from jax.experimental import pallas as pl
 
     clamp = causal and off >= 0
@@ -420,8 +434,8 @@ def _specs(bq, bk, dp, off, causal, q_major, band=None):
     def stat_at(b, i, j):
         return b, 0, q_of(i, j)
 
-    return (pl.BlockSpec((None, bq, dp), q_at),
-            pl.BlockSpec((None, bk, dp), kv_at),
+    return (lambda lanes: pl.BlockSpec((None, bq, lanes), q_at),
+            lambda lanes: pl.BlockSpec((None, bk, lanes), kv_at),
             pl.BlockSpec((None, 1, bq), stat_at))
 
 
@@ -450,26 +464,27 @@ def blocks_visited(sq, sk, window=None, block_q=None, block_k=None):
 
 def _flash_forward_pallas(q, k, v, causal, scale, block_q=None, block_k=None,
                           interpret=False, window=None):
-    """out (B, H, Sq, D) and the row statistics m, l (B, H, Sq) in f32."""
+    """out (B, H, Sq, Dv) and the row statistics m, l (B, H, Sq) in f32."""
     from jax.experimental.pallas import tpu as pltpu
     Sq, Sk = q.shape[2], k.shape[2]
     bq, bk, off, band = _geometry(q, k, block_q, block_k, window, True)
     qf, kf, vf = _fold(q), _fold(k), _fold(v)
-    BH, _, Dp = qf.shape
-    q_spec, kv_spec, stat_spec = _specs(bq, bk, Dp, off, causal, True, band)
+    (BH, _, Dp), Dv = qf.shape, vf.shape[2]
+    q_like, kv_like, stat_spec = _specs(bq, bk, off, causal, True, band)
     stat = jax.ShapeDtypeStruct((BH, 1, Sq), jnp.float32)
     out, m, l = _pallas_call(
         functools.partial(_flash_fwd_kernel, causal=causal, scale=scale,
                           off=off, **_windowed(window, band)),
         "flash_attention_pallas" if window is None else "flash_window_pallas",
         (BH, Sq // bq, band.steps if band else Sk // bk),
-        [q_spec, kv_spec, kv_spec], [q_spec, stat_spec, stat_spec],
-        [jax.ShapeDtypeStruct(qf.shape, q.dtype), stat, stat],
-        [pltpu.VMEM((bq, Dp), jnp.float32),
+        [q_like(Dp), kv_like(Dp), kv_like(Dv)],
+        [q_like(Dv), stat_spec, stat_spec],
+        [jax.ShapeDtypeStruct((BH, Sq, Dv), q.dtype), stat, stat],
+        [pltpu.VMEM((bq, Dv), jnp.float32),
          pltpu.VMEM((bq, _LANES), jnp.float32),
          pltpu.VMEM((bq, _LANES), jnp.float32)],
-        4 * BH * Sq * Sk * Dp, (qf, kf, vf), interpret)
-    return (_unfold(out, q), m.reshape(q.shape[:3]), l.reshape(q.shape[:3]))
+        2 * BH * Sq * Sk * (Dp + Dv), (qf, kf, vf), interpret)
+    return (_unfold(out, v), m.reshape(q.shape[:3]), l.reshape(q.shape[:3]))
 
 
 def _windowed(window, band):
@@ -484,38 +499,39 @@ def _flash_backward_pallas(q, k, v, out, m, l, g, causal, scale,
     from jax.experimental.pallas import tpu as pltpu
     Sq, Sk = q.shape[2], k.shape[2]
     qf, kf, vf, gf = _fold(q), _fold(k), _fold(v), _fold(g)
-    BH, _, Dp = qf.shape
+    (BH, _, Dp), Dv = qf.shape, vf.shape[2]
     delta = (g.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)
     stats = [x.reshape(BH, 1, Sq) for x in (m, 1.0 / l, delta)]
     operands = (qf, kf, vf, gf, *stats)
-    flops = 2 * BH * Sq * Sk * Dp
+    # one product over the score tile at the key's width, at the value's
+    qk_flops, v_flops = (2 * BH * Sq * Sk * lanes for lanes in (Dp, Dv))
     names = (("flash_attention_bwd_dkv", "flash_attention_bwd_dq")
              if window is None else
              ("flash_window_bwd_dkv", "flash_window_bwd_dq"))
 
     bq, bk, off, band = _geometry(q, k, block_q, block_k, window, False)
-    q_spec, kv_spec, stat_spec = _specs(bq, bk, Dp, off, causal, False, band)
+    q_like, kv_like, stat_spec = _specs(bq, bk, off, causal, False, band)
     dk, dv = _pallas_call(
         functools.partial(_flash_dkv_kernel, causal=causal, scale=scale,
                           off=off, **_windowed(window, band)),
         names[0], (BH, Sk // bk, band.steps if band else Sq // bq),
-        [q_spec, kv_spec, kv_spec, q_spec] + [stat_spec] * 3,
-        [kv_spec, kv_spec],
+        [q_like(Dp), kv_like(Dp), kv_like(Dv), q_like(Dv)] + [stat_spec] * 3,
+        [kv_like(Dp), kv_like(Dv)],
         [jax.ShapeDtypeStruct(kf.shape, k.dtype),
          jax.ShapeDtypeStruct(vf.shape, v.dtype)],
-        [pltpu.VMEM((bk, Dp), jnp.float32)] * 2,
-        4 * flops, operands, interpret)
+        [pltpu.VMEM((bk, Dp), jnp.float32), pltpu.VMEM((bk, Dv), jnp.float32)],
+        2 * qk_flops + 2 * v_flops, operands, interpret)      # s, dK; dP, dV
 
     bq, bk, off, band = _geometry(q, k, block_q, block_k, window, True)
-    q_spec, kv_spec, stat_spec = _specs(bq, bk, Dp, off, causal, True, band)
+    q_like, kv_like, stat_spec = _specs(bq, bk, off, causal, True, band)
     dq = _pallas_call(
         functools.partial(_flash_dq_kernel, causal=causal, scale=scale,
                           off=off, **_windowed(window, band)),
         names[1], (BH, Sq // bq, band.steps if band else Sk // bk),
-        [q_spec, kv_spec, kv_spec, q_spec] + [stat_spec] * 3, q_spec,
-        jax.ShapeDtypeStruct(qf.shape, q.dtype),
+        [q_like(Dp), kv_like(Dp), kv_like(Dv), q_like(Dv)] + [stat_spec] * 3,
+        q_like(Dp), jax.ShapeDtypeStruct(qf.shape, q.dtype),
         [pltpu.VMEM((bq, Dp), jnp.float32)],
-        3 * flops, operands, interpret)
+        2 * qk_flops + v_flops, operands, interpret)            # s, dQ; dP
     return _unfold(dq, q), _unfold(dk, k), _unfold(dv, v)
 
 
@@ -557,6 +573,7 @@ def _flash_forward(q, k, v, causal, scale, window=None):
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     Sq, Sk = q.shape[2], k.shape[2]
+    _metrics.flash_head_dim(q.shape[-1], v.shape[-1])
     if causal and not (Sq % 128 or Sk % 128):
         visited, triangle = blocks_visited(Sq, Sk, window)
         slices = q.shape[0] * q.shape[1]
@@ -590,7 +607,10 @@ def _window_of(window, causal, sk):
 
 
 def flash_attention(q, k, v, causal=False, scale=None, window=None):
-    """softmax(QKᵀ·scale + mask)·V with O(seq) memory, (B, H, S, D).
+    """softmax(QKᵀ·scale + mask)·V with O(seq) memory: q (B, H, Sq, Dqk),
+    k (B, H, Sk, Dqk), v (B, H, Sk, Dv) → (B, H, Sq, Dv).  ``Dv`` may differ
+    from ``Dqk`` (latent attention: keys of 192 over values of 128); the
+    default ``scale`` is ``1 / sqrt(Dqk)``.
 
     ``causal``: query r sees the keys up to its own (the diagonal anchored
     at the end of the key axis, so Sq != Sk keeps its meaning).  ``window``
@@ -700,7 +720,8 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 @register("_contrib_FlashAttention", num_inputs=3,
           aliases=("flash_attention", "_contrib_DotProductAttention"))
 def _flash_attention_op(q, k, v, causal=False, scale=None, window=None):
-    """Registered op wrapper — (B, H, S, D) inputs.  ``window`` (with
+    """Registered op wrapper — (B, H, S, D) inputs, v's D its own (the
+    output's).  ``window`` (with
     ``causal``): each query sees its own key and the ``window - 1`` before
     it; None: every key up to its own (``ops.attention.flash_attention``)."""
     return flash_attention(q, k, v, causal, scale, window)

@@ -42,10 +42,16 @@ not trained to the scores *for the choice only* (auxiliary-loss-free load
 balancing), ``norm_topk`` divides the chosen experts' scores by their sum
 (over all chosen, held or not), ``scaling`` multiplies them.  ``gated``
 experts are W2 (silu(W1 x) ⊙ W3 x); otherwise relu(x W1) W2.
+``shared_hidden_size`` adds a shared expert (DeepSeekMoE, arXiv:2401.06066):
+one ``GatedMLP`` of that width that every token passes, unweighted, added to
+the routed sum under the scope ``moe_shared``.  It is no part of the share:
+outside ``experts_held``, not sharded over ``ep`` (every chip computes it
+alike), so where shares are added up it counts once.
 
 What has run on the chip: ``grouped`` alone, in ``lfm2moe_fused_s8192``
 and, at top-8 of 64 by softmax with experts 0-7 held, in
-``mellum2_fused_s8192`` (PERF.md).  ``dense`` and ``capacity`` are held by the CPU tests
+``mellum2_fused_s8192``, and at top-6 of 64 by sigmoid with ``scaling``
+2.446 beside a shared expert in ``kimivl_mla_fused_1row`` (PERF.md).  ``dense`` and ``capacity`` are held by the CPU tests
 (``tests/test_model_parallel.py``, ``tests/test_moe_capacity.py``); no
 benchmark cell runs them, and ``capacity`` needs a mesh with an ``ep`` axis.
 On eager calls the layer counts itself: ``graft_moe_dispatch_traces_total
@@ -419,18 +425,25 @@ class ExpertParallelMoE(HybridBlock):
     gated : SwiGLU experts.
     in_units : the tokens' width d; 0 (the default) leaves it to the first
         input.
+    shared_hidden_size : None, or the width of a shared expert: a child
+        ``GatedMLP`` (``shared_experts_``) that every token passes, added
+        to the routed sum unweighted; replicated under an ``ep`` mesh and
+        outside ``experts_held``.  Needs ``in_units``.  Counted by
+        ``graft_moe_shared_traces_total``.
 
     The defaults are the layer as it was (softmax, ReLU experts, dense);
     the chip benchmark's ``lfm2moe_fused_s8192`` runs ``grouped`` with
     ``experts_held=(0, 8)`` of 32, top-4, sigmoid scores, a selection bias,
-    renormalised, gated.
+    renormalised, gated; ``mellum2_fused_s8192`` top-8 of 64 by softmax;
+    ``kimivl_mla_fused_1row`` top-6 of 64 by sigmoid, ``scaling`` 2.446,
+    with ``shared_hidden_size`` 2816.
     """
 
     def __init__(self, hidden_size, num_experts, top_k=1, ep_axis="ep",
                  dispatch="dense", capacity_factor=1.25, experts_held=None,
                  router="softmax", selection_bias=False, norm_topk=None,
-                 scaling=1.0, gated=False, in_units=0, prefix=None,
-                 params=None, **kwargs):
+                 scaling=1.0, gated=False, in_units=0,
+                 shared_hidden_size=None, prefix=None, params=None, **kwargs):
         super().__init__(prefix=prefix, params=params, **kwargs)
         self._hidden = hidden_size
         self._num_experts = num_experts
@@ -484,6 +497,15 @@ class ExpertParallelMoE(HybridBlock):
                 "expert_bias", shape=(num_experts,), grad_req="null",
                 init="zeros" if selection_bias is True else selection_bias
             ) if selection_bias else None
+            self.shared_experts = None
+            if shared_hidden_size is not None:
+                if not in_units:
+                    raise ValueError("a shared expert is built with its "
+                                     "widths: shared_hidden_size needs "
+                                     "in_units")
+                from ..gluon.nn.basic_layers import GatedMLP
+                self.shared_experts = GatedMLP(in_units, shared_hidden_size,
+                                               prefix="shared_experts_")
         # shard the expert dimension over "ep": each device owns E/ep
         # experts' weights and their compute.  ``ep_axis=None``: the layer
         # runs on a mesh without that axis (one chip's share, held whole)
@@ -513,8 +535,18 @@ class ExpertParallelMoE(HybridBlock):
 
         if self._dispatch == "capacity":
             out = self._capacity_forward(xv, gw, w1, w2)
-            return NDArray(out) if isinstance(x, NDArray) else out
+        else:
+            out = self._routed(xv, gw, w1, w2, w3, bias)
+        if self.shared_experts is not None:
+            _metrics.moe_shared_trace()
+            with jax.named_scope("moe_shared"):
+                shared = self.shared_experts(x)
+            out = out + (shared._read() if isinstance(shared, NDArray)
+                         else shared)
+        return NDArray(out) if isinstance(x, NDArray) else out
 
+    def _routed(self, xv, gw, w1, w2, w3, bias):
+        """The routed sum of ``dense`` and ``grouped``."""
         scores, chosen, weights = _route(xv, gw, bias, **self._routing)
         _metrics.moe_dispatch_trace(self._dispatch)
         if self._dispatch == "grouped":
@@ -535,7 +567,7 @@ class ExpertParallelMoE(HybridBlock):
             y = jnp.einsum("neh,ehd->ned", h, w2)
             out = jnp.einsum("ne,ned->nd", combine.astype(xv.dtype), y)
         self._store_aux(chosen, scores)
-        return NDArray(out) if isinstance(x, NDArray) else out
+        return out
 
     @property
     def last_aux_loss(self):

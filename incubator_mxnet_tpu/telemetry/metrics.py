@@ -710,6 +710,31 @@ def flash_blocks(visited, causal, window=None):
                         ("kind",)).set(float(value), kind=kind)
 
 
+def flash_head_dim(qk, v):
+    """The head widths of the ``flash_attention`` call just traced: q's and
+    k's (``part="qk"``) and v's, which is the output's (``part="v"``).  One
+    gauge, set from shapes when the call is traced: a reader tells a latent
+    layer's kernels (192 over 128) from a layer of one width by it."""
+    if not enabled():
+        return
+    gauge = _REGISTRY.gauge("graft_flash_head_dim",
+                            "Head widths of the last traced flash_attention "
+                            "call: q and k, and v", ("part",))
+    gauge.set(float(qk), part="qk")
+    gauge.set(float(v), part="v")
+
+
+def moe_shared_trace():
+    """One trace of an ``ExpertParallelMoE`` forward that has a shared
+    expert (``shared_hidden_size``): the always-on ``GatedMLP`` added to the
+    routed sum."""
+    if not enabled():
+        return
+    _REGISTRY.counter("graft_moe_shared_traces_total",
+                      "ExpertParallelMoE forward traces with a shared "
+                      "expert").inc()
+
+
 def moe_dispatch_trace(path):
     """One trace of ``parallel.moe.ExpertParallelMoE``'s routed forward,
     labeled by its dispatch mode (``dense`` / ``grouped``): beside

@@ -21,10 +21,12 @@ from incubator_mxnet_tpu.ops import attention as A
 TOLERANCE = {"float32": 1e-5, "bfloat16": 2e-2}
 
 
-def _problem(dtype, sq, sk, d, seed=0):
+def _problem(dtype, sq, sk, d, seed=0, dv=None):
+    """q, k, v, dO: keys ``d`` wide, values (and dO) ``dv``, or ``d``."""
     rs = np.random.RandomState(seed)
-    return tuple(jnp.asarray(rs.randn(1, 2, s, d), dtype)
-                 for s in (sq, sk, sk, sq))
+    dv = d if dv is None else dv
+    return tuple(jnp.asarray(rs.randn(1, 2, s, w), dtype)
+                 for s, w in ((sq, d), (sk, d), (sk, dv), (sq, dv)))
 
 
 def _reference(q, k, v, g, causal, scale):
@@ -74,6 +76,58 @@ def test_kernels_match_autodiff_of_the_reference(dtype, causal, sq, sk, d):
                                   scale=scale)
     for name, a, b in zip(("dq", "dk", "dv"), scan, want[3:]):
         assert _error(a, b) <= TOLERANCE[dtype], name
+
+
+# keys over values: latent attention's 192 over 128 (the key padded to 256
+# lanes, the value not), both whole lanes, both padded, a value wider than
+# the key
+TWO_WIDTHS = [(192, 128), (256, 128), (48, 16), (128, 256)]
+
+
+@pytest.mark.parametrize("dqk,dv", TWO_WIDTHS)
+@pytest.mark.parametrize("window", [None, 100])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_kernels_take_a_value_width_of_its_own(dtype, window, dqk, dv):
+    """q and k ``dqk`` wide, v, the output and dO ``dv`` wide: forward, dQ,
+    dK and dV of the kernels, and of the jnp scan fed the kernel's
+    statistics, against autodiff of the dense reference; every result has
+    its operand's own width."""
+    q, k, v, g = _problem(jnp.dtype(dtype), 256, 256, dqk, seed=3, dv=dv)
+    scale = dqk ** -0.5
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    with jax.default_matmul_precision("highest"):
+        want, vjp = jax.vjp(lambda a, b, c: A._attention_reference(
+            a, b, c, True, scale, window), *f32)
+        want = (want, *vjp(g.astype(jnp.float32)))
+    out, m, l = A._flash_forward_pallas(q, k, v, True, scale, 128, 128,
+                                        interpret=True, window=window)
+    got = (out, *A._flash_backward_pallas(
+        q, k, v, out, m, l, g, True, scale, 128, 128, interpret=True,
+        window=window))
+    assert [x.shape[-1] for x in got] == [dv, dqk, dqk, dv]
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        assert _error(a, b) <= TOLERANCE[dtype], name
+    scan = A._flash_backward_scan(q, k, v, out, m, l, g, causal=True,
+                                  scale=scale, window=window)
+    for name, a, b in zip(("dq", "dk", "dv"), scan, want[1:]):
+        assert _error(a, b) <= TOLERANCE[dtype], name
+
+
+def test_the_op_differentiates_at_two_widths_and_gauges_them():
+    """``flash_attention`` off the TPU (the reference forward, the scan
+    backward) at keys of 24 over values of 8, with the default scale
+    1 / sqrt(24); the gauge says which widths the call was traced at."""
+    q, k, v, g = _problem(jnp.float32, 128, 128, 24, seed=4, dv=8)
+    out, vjp = jax.vjp(lambda a, b, c: A.flash_attention(a, b, c, True),
+                       q, k, v)
+    want, want_vjp = jax.vjp(lambda a, b, c: A._attention_reference(
+        a, b, c, True, 24 ** -0.5), q, k, v)
+    assert out.shape == (1, 2, 128, 8)
+    for a, b in zip((out, *vjp(g)), (want, *want_vjp(g))):
+        assert _error(a, b) <= TOLERANCE["float32"]
+    gauge = telemetry.registry().snapshot()["graft_flash_head_dim"]
+    assert {s["labels"]["part"]: s["value"] for s in gauge["samples"]} == {
+        "qk": 24.0, "v": 8.0}
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
@@ -213,13 +267,33 @@ def test_window_grad_compiles_to_three_named_kernels_at_the_mellum_cells_shape(
 # sha256 of each kernel's Mosaic module printed without debug locations, as
 # commit 0112fb9 (the parent of the PR that brought windows) lowered
 # ``grad(flash_attention(q, k, v, causal=True))`` for a v5e, in the order
-# forward, dK/dV, dQ.  A PR that changes the causal kernels on purpose
-# records them anew; one that adds an option beside them may not move them.
+# forward, dK/dV, dQ: the OPT cells' shape and LFM2's.  A PR that changes the
+# causal kernels on purpose records them anew; one that adds an option beside
+# them may not move them.  Mellum2's two (the full layer's shape here, its
+# window of 1024 below) are as commit f52de4a, the parent of the PR that gave
+# v a width of its own, lowered them.
 KERNELS_WITHOUT_A_WINDOW = {
     (4, 32, 2048, 128): ["70a2c89c474f497a", "904bd27c9616a081",
                          "37cf7cdab58b8d03"],
     (1, 32, 8192, 64): ["709ed1065112820b", "3706ba7af9bf260f",
                         "71b2bad4e5eb6fb8"],
+    (1, 32, 8192, 128): ["a00e90e60e16610c", "dfe140416ee8fb32",
+                         "dc71e49c8d147f3b"],
+}
+KERNELS_UNDER_MELLUMS_WINDOW = {
+    ((1, 32, 8192, 128), 1024): ["c016666f86115bb6", "03e4f20088f8eb17",
+                                 "33ed446b6a779ae9"],
+}
+# what each call tells the compiler of its cost, as the same commits did:
+# (FLOPs, bytes) forward, dK/dV, dQ.  With one width the two-width count is
+# the one-width count.
+COST_ESTIMATES = {
+    (4, 32, 2048, 128): [(274877906944, 270532608),
+                         (549755813888, 405798912),
+                         (412316860416, 338690048)],
+    (1, 32, 8192, 64): [(1099511627776, 270532608),
+                        (2199023255552, 405798912),
+                        (1649267441664, 338690048)],
 }
 
 
@@ -259,3 +333,46 @@ def test_without_a_window_the_kernels_are_the_programs_they_were(
         digests = [hashlib.sha256(asm.encode()).hexdigest()[:16]
                    for asm in _mosaic_modules(text)]
         assert digests == KERNELS_WITHOUT_A_WINDOW[shape], window
+    if shape in COST_ESTIMATES:
+        costs = [(int(f), int(b)) for b, f in re.findall(
+            r'bytes_accessed\\22:(\d+),\\0A\\22flops\\22:(\d+)', text)]
+        assert costs == COST_ESTIMATES[shape]
+
+
+@pytest.mark.parametrize("shape,window", sorted(KERNELS_UNDER_MELLUMS_WINDOW))
+def test_one_width_under_a_window_is_the_program_it_was(
+        topo, no_compile_cache, shape, window):
+    """Mellum2's window layers: q, k and v of one width lower to the banded
+    kernels they lowered to before v could have a width of its own."""
+    import hashlib
+    from jax.sharding import SingleDeviceSharding
+    spec = jax.ShapeDtypeStruct(
+        shape, jnp.bfloat16, sharding=SingleDeviceSharding(topo.devices[0]))
+    text = jax.jit(_loss_of(window)).lower(spec, spec, spec).as_text()
+    digests = [hashlib.sha256(asm.encode()).hexdigest()[:16]
+               for asm in _mosaic_modules(text)]
+    assert digests == KERNELS_UNDER_MELLUMS_WINDOW[shape, window]
+
+
+def test_grad_compiles_at_the_latent_cells_two_widths(topo, no_compile_cache):
+    """(1, 16, 4096, 192) keys over (1, 16, 4096, 128) values in bf16,
+    causal: Mosaic takes the three kernels; the forward writes 128 lanes a
+    head, dK comes at the key's 256 padded lanes and dV at the value's 128:
+    v is not padded to the key's width."""
+    from jax.sharding import SingleDeviceSharding
+    chip = SingleDeviceSharding(topo.devices[0])
+    qk = jax.ShapeDtypeStruct((1, 16, 4096, 192), jnp.bfloat16, sharding=chip)
+    v = jax.ShapeDtypeStruct((1, 16, 4096, 128), jnp.bfloat16, sharding=chip)
+    text = jax.jit(_loss_of(None)).lower(qk, qk, v).compile().as_text()
+    calls = {re.match(r"\s*(?:ROOT )?%(\w+)", line).group(1):
+             line.split(" custom-call(")[0]
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line}
+    assert sorted(calls) == ["flash_attention_bwd_dkv",
+                             "flash_attention_bwd_dq",
+                             "flash_attention_pallas"]
+    assert "bf16[16,4096,128]" in calls["flash_attention_pallas"]
+    assert "bf16[16,4096,256]" not in calls["flash_attention_pallas"]
+    assert ("bf16[16,4096,256]" in calls["flash_attention_bwd_dkv"]
+            and "bf16[16,4096,128]" in calls["flash_attention_bwd_dkv"])
+    assert "bf16[16,4096,256]" in calls["flash_attention_bwd_dq"]

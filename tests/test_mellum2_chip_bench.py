@@ -443,8 +443,11 @@ def test_the_cell_is_declared_as_the_issue_names_it():
     (cell,) = [w for w in spec["workloads"] if w["name"] == CELL]
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         CONFIG, "fused_s8192", 1)
-    assert spec["workloads"][-1] is cell and spec["configs"][-1]["name"] == (
-        CONFIG)
+    # appended behind what was there (later PRs append behind it in turn)
+    cells = [w["name"] for w in spec["workloads"]]
+    assert cells.index(CELL) == cells.index("lfm2moe_fused_s8192") + 1
+    configs = [c["name"] for c in spec["configs"]]
+    assert configs.index(CONFIG) == configs.index("lfm2_8b_a1b_ep4_l5") + 1
     mine = {m["name"] for g in ("end_to_end", "per_layer") for m in spec[g]
             if m.get("workloads") == [CELL]}
     # no end-to-end entry of its own: that list is a benchmark PR's to change
