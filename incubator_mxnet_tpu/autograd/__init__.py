@@ -9,7 +9,9 @@ TPU-native rebirth of src/imperative/imperative.cc (+ python/mxnet/autograd.py):
   ``pass::Gradient`` construction step (imperative.cc:433): XLA already owns
   the per-op backward kernels.
 * ``backward()`` walks the tape in reverse accumulating cotangents
-  (RunGraph over the backward graph, imperative.cc:268).
+  (RunGraph over the backward graph, imperative.cc:268).  A node that
+  can leave cotangents out (a recorded CachedOp) is told which of its
+  inputs the pass has a use for (:func:`_wanted`).
 * ``grad()`` with ``create_graph=True`` re-records each vjp application,
   giving higher-order gradients (parity with autograd.py:270).
 """
@@ -132,9 +134,11 @@ def predict_mode():
 
 
 class TapeNode:
-    __slots__ = ("op", "inputs", "outputs", "vjp", "fn", "release", "used")
+    __slots__ = ("op", "inputs", "outputs", "vjp", "fn", "release",
+                 "selective", "used")
 
-    def __init__(self, op, inputs, outputs, vjp, fn=None, release=None):
+    def __init__(self, op, inputs, outputs, vjp, fn=None, release=None,
+                 selective=False):
         self.op = op
         self.inputs = inputs      # list[NDArray] (strong refs keep tape valid)
         self.outputs = outputs    # list[NDArray]
@@ -144,13 +148,18 @@ class TapeNode:
         # called after the pass that is this node's last, i.e. one that
         # does not retain the graph; the outputs may outlive the node's use
         self.release = release
+        # vjp takes, after the cotangents, one bool an input: whether the
+        # pass has a use for that input's cotangent (a CachedOp's backward
+        # program computes only those); an eager op's vjp takes no such set
+        self.selective = selective
         self.used = False
 
 
-def _record(op, inputs, outputs, vjp_fn, fn=None, release=None):
+def _record(op, inputs, outputs, vjp_fn, fn=None, release=None,
+            selective=False):
     """Called by ndarray.invoke under recording (RecordOp, imperative.cc:182)."""
     s = _st()
-    node = TapeNode(op, inputs, outputs, vjp_fn, fn, release)
+    node = TapeNode(op, inputs, outputs, vjp_fn, fn, release, selective)
     for i, o in enumerate(outputs):
         o._tape_ref = (node, i)
     s.tape.append(node)
@@ -254,6 +263,10 @@ def _run_backward(heads, head_grads, retain_graph, train_mode, variables=None,
                     # hit the wire earliest.
                     inp._tape_pos = k
 
+    # what a selective node is told (see _wanted): made at the first such
+    # node, so a tape of eager ops alone pays nothing
+    on_tape = listed = None
+
     for k in range(len(tape) - 1, -1, -1):
         node = tape[k]
         if any(id(o) in grads for o in node.outputs):
@@ -267,7 +280,13 @@ def _run_backward(heads, head_grads, retain_graph, train_mode, variables=None,
                 in_cts = _recorded_vjp(node, out_cts)
             else:
                 ct = out_cts[0] if len(out_cts) == 1 else out_cts
-                in_cts = node.vjp(ct)
+                if node.selective:
+                    if on_tape is None:
+                        on_tape = {id(n) for n in tape}
+                        listed = {id(v) for v in variables or ()}
+                    in_cts = node.vjp(ct, _wanted(node, on_tape, listed))
+                else:
+                    in_cts = node.vjp(ct)
             for idx, (inp, g) in enumerate(zip(node.inputs, in_cts)):
                 if idx in node.op.nograd_inputs or g is None:
                     continue
@@ -310,6 +329,20 @@ def _run_backward(heads, head_grads, retain_graph, train_mode, variables=None,
     if not retain_graph and not create_graph:
         s.tape = [n for n in s.tape if not n.used]
     return results
+
+
+def _wanted(node, on_tape, listed):
+    """One bool an input of ``node``: whether this pass has a use for the
+    input's cotangent.  It has when the cotangent will be delivered (a grad
+    buffer whose request is not ``"null"``, :func:`_deliver`'s own test),
+    returned (the caller listed the input among ``grad``'s ``variables``)
+    or handed on (the input is the output of a node still on the tape).
+    Any other cotangent the pass would drop at its end."""
+    return tuple(
+        (inp._grad is not None and inp._grad_req != "null")
+        or id(inp) in listed
+        or (inp._tape_ref is not None and id(inp._tape_ref[0]) in on_tape)
+        for inp in node.inputs)
 
 
 def _fire_ready_hook(arr):

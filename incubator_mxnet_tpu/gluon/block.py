@@ -24,7 +24,11 @@ TPU-native rebirth of python/mxnet/gluon/block.py:
   again and runs no forward convolution — ``_CachedOp`` keeps its forward's
   activations for its fused backward the same way
   (src/imperative/cached_op.cc:434), and frees them after it, as the tape
-  node does here.  Outside ``record()`` no residual is made.
+  node does here.  Outside ``record()`` no residual is made.  The reverse
+  pass tells the node which of its inputs' cotangents it has a use for
+  (``autograd._wanted``) and the backward program, compiled once a set,
+  returns those alone: the gradient of a batch nobody marked, or of a
+  ``grad_req="null"`` parameter, is not computed.
 """
 from __future__ import annotations
 
@@ -312,6 +316,7 @@ class CachedOp(object):
         self._params = block._active_params
         self._param_names = sorted(self._params.keys())
         self._noted = None      # (entry, recorded or not) the registry has
+        self._noted_backward = None     # (entry, cotangents left out) it has
         # forward-use order of the params, recorded by first-touch hooks
         # on the first trace (graftstep pull priority; empty until then)
         self.touch_order = []
@@ -394,11 +399,6 @@ class CachedOp(object):
             _xray.register_program(
                 "cachedop_forward", entry["record" if recording else "jit"],
                 args, phase="forward")
-            if recording:
-                _xray.register_program(
-                    "cachedop_backward", entry["backward"],
-                    (residuals, pullback) + args + (tuple(out_vals),),
-                    phase="backward")
         if "out_fmt" not in entry:
             # fn ran (traced) at least once for this entry, setting the fmt
             entry["out_fmt"] = self._last_out_fmt
@@ -422,19 +422,39 @@ class CachedOp(object):
             # when the backward is done, as MXNet frees its activations
             held = [residuals]
 
-            def tape_vjp(ct):
+            n_par = len(param_names)
+
+            def tape_vjp(ct, wanted=None):
+                # ``wanted``: one bool a tape input, from the pass
+                # (autograd._wanted); without it every cotangent is computed
                 if not held:
                     raise RuntimeError(
                         "graph already backpropagated: this block's "
                         "residuals were freed by a pass without "
                         "retain_graph=True")
                 cts = ct if isinstance(ct, tuple) else (ct,)
-                pv_g, iv_g = entry["backward"](held[0], pullback, *args, cts)
-                return tuple(pv_g[n] for n in param_names) + \
+                if wanted is None:
+                    wanted = (True,) * len(tape_inputs)
+                # the cotangents left out, as the program knows them: the
+                # parameters by name, the inputs by place
+                left_out = (
+                    tuple(n for n, w in zip(param_names, wanted) if not w),
+                    tuple(i for i, w in zip(real_idx, wanted[n_par:])
+                          if not w))
+                bwd_args = (held[0], pullback) + args + (
+                    cts, _Static(left_out))
+                pv_g, iv_g = entry["backward"](*bwd_args)
+                if (id(entry), left_out) != self._noted_backward:
+                    # the backward this pass ran goes to the registry
+                    self._noted_backward = (id(entry), left_out)
+                    _xray.register_program(
+                        "cachedop_backward", entry["backward"], bwd_args,
+                        phase="backward")
+                _tmetrics.cachedop_cotangents_skipped(*map(len, left_out))
+                return tuple(pv_g.get(n) for n in param_names) + \
                     tuple(iv_g[i] for i in real_idx)
 
             raw = entry["raw"]
-            n_par = len(param_names)
 
             def tape_fn(*vals):
                 # replayable pure function of the tape inputs — lets
@@ -453,7 +473,7 @@ class CachedOp(object):
                           num_inputs=len(tape_inputs),
                           num_outputs=len(out_arrays))
             autograd._record(op, tape_inputs, out_arrays, tape_vjp,
-                             fn=tape_fn, release=held.clear)
+                             fn=tape_fn, release=held.clear, selective=True)
 
         out, _ = _regroup(out_arrays, entry["out_fmt"])
         return out
@@ -486,14 +506,19 @@ def _make_recorded(raw):
         return out_vals, aux_updates, residuals, _Static((treedef, sources))
 
     def cachedop_backward(residuals, pullback, param_vals, input_vals, rng,
-                          cts):
+                          cts, left_out=_NOTHING_LEFT_OUT):
         treedef, sources = pullback.value
         given = jax.tree.leaves((param_vals, input_vals, rng))
         kept = iter(residuals)
         vjp_fn = jax.tree.unflatten(
             treedef, [next(kept) if src is None else given[src]
                       for src in sources])
-        return vjp_fn(cts)
+        pv_g, iv_g = vjp_fn(cts)
+        # the cotangents the pass has no use for do not leave the program,
+        # and XLA drops what only they needed
+        names, places = left_out.value
+        return ({name: g for name, g in pv_g.items() if name not in names},
+                [None if i in places else g for i, g in enumerate(iv_g)])
 
     return cachedop_forward, cachedop_backward
 
@@ -513,6 +538,9 @@ class _Static(object):
 
     def __eq__(self, other):
         return isinstance(other, _Static) and self.value == other.value
+
+
+_NOTHING_LEFT_OUT = _Static(((), ()))
 
 
 def _fmt_key(fmt):

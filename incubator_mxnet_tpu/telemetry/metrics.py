@@ -780,6 +780,23 @@ def cachedop_recorded(residual_bytes):
                     ).set(residual_bytes)
 
 
+def cachedop_cotangents_skipped(parameters, inputs):
+    """One backward call of a recorded CachedOp node: of the node's inputs,
+    how many cotangents its backward program did not compute because the
+    pass had no use for them (``autograd._wanted``), parameters (a
+    ``grad_req`` of ``"null"``) and inputs (no grad buffer, no history on
+    the tape, not asked of ``autograd.grad``) apart.  Both kinds are there
+    from the first call on, at 0 where everything was wanted."""
+    if not enabled():
+        return
+    counter = _REGISTRY.counter(
+        "graft_cachedop_cotangents_skipped_total",
+        "Cotangents a CachedOp's backward program left out because the "
+        "pass had no use for them", ("kind",))
+    counter.inc(parameters, kind="parameter")
+    counter.inc(inputs, kind="input")
+
+
 def cachedop_replay():
     """One re-trace of a CachedOp's forward for ``create_graph``: the only
     place left where a recorded forward is traced again (a training loop
