@@ -334,7 +334,10 @@ class CachedOp(object):
             args, _ = _regroup(nd_in, in_fmt)
             if not isinstance(args, list):
                 args = [args]
-            with random_state.use_key(rng):
+            # this trace's counts (telemetry.step_counter) are dropped, and
+            # kept from a fused step's collection that may be open around it
+            with _ttracing.collect_step_counters(), \
+                    random_state.use_key(rng):
                 with autograd._scope(recording=False, training=train):
                     with block._trace_params(shadows):
                         out = block.hybrid_forward_dispatch(*args)
@@ -461,7 +464,6 @@ class CachedOp(object):
                 # autograd's create_graph build grad-of-grad through the
                 # whole compiled block (same rng → same dropout masks): the
                 # only place left where a recorded forward is traced again
-                _tmetrics.cachedop_replay()
                 pv = dict(zip(param_names, vals[:n_par]))
                 iv = list(input_vals)
                 for j, idx in enumerate(real_idx):

@@ -27,8 +27,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..ndarray import NDArray
 from .. import autograd, random_state
 from ..ops.registry import get_op
-from ..telemetry import xray as _xray
-from ..telemetry.tracing import phase_span
+from ..telemetry import blackbox as _blackbox, xray as _xray
+from ..telemetry.tracing import (collect_step_counters, phase_span,
+                                 stack_step_counters)
 from .mesh import data_parallel_mesh
 
 __all__ = ["DataParallelTrainer", "pure_optimizer"]
@@ -138,6 +139,7 @@ class DataParallelTrainer(object):
         self._jit_cache = {}
         self._steps = 0            # step id of the program spans
         self._noted = None         # the program the registry last got
+        self._count_labels = {}    # labels of the step's counters, by trace
 
     # -- parameter plumbing ------------------------------------------------
     def _gather_params(self, example_x):
@@ -246,7 +248,8 @@ class DataParallelTrainer(object):
                     x = x.astype(jnp.float32)
             shadows = {n: NDArray(v) for n, v in all_vals.items()}
             ndx, ndy = NDArray(x), NDArray(y)
-            with random_state.use_key(rng):
+            with collect_step_counters() as found, \
+                    random_state.use_key(rng):
                 with autograd._scope(recording=False, training=train):
                     with block._trace_params(shadows):
                         out = block.hybrid_forward_dispatch(ndx)
@@ -254,7 +257,11 @@ class DataParallelTrainer(object):
                         out = NDArray(out._read().astype(jnp.float32))
                     per_sample = loss_blk(out, ndy)
             aux = {n: s._read() for n, s in shadows.items() if s._version > 0}
-            return jnp.mean(per_sample._read()), aux
+            # what the layers counted (telemetry.step_counter) leaves beside
+            # the aux states, outside the differentiated value; a model that
+            # counts nothing adds no result to the program
+            counts, self._count_labels = stack_step_counters(found)
+            return jnp.mean(per_sample._read()), (aux, counts)
 
         def dp_train_step(params, opt_state, rng_key, x, y, lr):
             # rng key lives on device across steps: split here, return the
@@ -267,7 +274,7 @@ class DataParallelTrainer(object):
             # starts with its phase): jax.vjp and not value_and_grad, which
             # gives the pullback no call site to put a scope around
             with jax.named_scope("xray:forward"):
-                loss_val, pullback, aux = jax.vjp(
+                loss_val, pullback, (aux, counts) = jax.vjp(
                     lambda t: forward_loss(t, fvals, x, y, rng), tvals,
                     has_aux=True)
             with jax.named_scope("xray:backward"):
@@ -283,7 +290,7 @@ class DataParallelTrainer(object):
                 for n, v in aux.items():
                     if n not in tvals:
                         new_params[n] = v.astype(new_params[n].dtype)
-            return new_params, new_opt, next_key, loss_val
+            return new_params, new_opt, next_key, loss_val, counts
 
         return dp_train_step
 
@@ -307,7 +314,7 @@ class DataParallelTrainer(object):
             self._jit_cache[key] = jax.jit(
                 step,
                 in_shardings=(ptree, otree, repl, batch, batch, repl),
-                out_shardings=(ptree, otree, repl, repl),
+                out_shardings=(ptree, otree, repl, repl, repl),
                 donate_argnums=(0, 1, 2) if self._donate else ())
         return self._jit_cache[key]
 
@@ -328,17 +335,18 @@ class DataParallelTrainer(object):
                 def body(carry, xy):
                     p, s, k = carry
                     x, y = xy
-                    p, s, k, loss = step(p, s, k, x, y, lr)
-                    return (p, s, k), loss
+                    p, s, k, loss, counts = step(p, s, k, x, y, lr)
+                    return (p, s, k), (loss, counts)
 
-                (params, opt_state, rng_key), losses = jax.lax.scan(
-                    body, (params, opt_state, rng_key), (xs, ys))
-                return params, opt_state, rng_key, losses[-1]
+                (params, opt_state, rng_key), (losses, counts) = \
+                    jax.lax.scan(body, (params, opt_state, rng_key),
+                                 (xs, ys))
+                return params, opt_state, rng_key, losses[-1], counts
 
             self._jit_cache[key] = jax.jit(
                 dp_train_multi_step,
                 in_shardings=(ptree, otree, repl, batch, batch, repl),
-                out_shardings=(ptree, otree, repl, repl),
+                out_shardings=(ptree, otree, repl, repl, repl),
                 donate_argnums=(0, 1, 2) if self._donate else ())
         return self._jit_cache[key]
 
@@ -388,7 +396,8 @@ class DataParallelTrainer(object):
         """One launch of a jitted step, under the program spans ``step``
         (which carries the step id) > ``place``, ``dispatch``; the program
         is handed to the registry (``telemetry.programs()``) the first
-        time it runs."""
+        time it runs, and what its layers counted to the flight recorder
+        under the step's id, unread (``telemetry.step_counters()``)."""
         from .mesh import use_mesh
         self._steps += 1
         with use_mesh(self.mesh), phase_span("step", step=self._steps):
@@ -404,8 +413,10 @@ class DataParallelTrainer(object):
                 _xray.register_program(name, fn, args)
                 self._noted = fn
             with phase_span("dispatch"):
-                self._params, self._opt_state, self._rng_key, loss_val = \
-                    fn(*args)
+                (self._params, self._opt_state, self._rng_key, loss_val,
+                 counts) = fn(*args)
+        if counts:
+            _blackbox.step_counts(self._steps, counts, self._count_labels)
         return loss_val
 
     def step_multi(self, datas, labels):

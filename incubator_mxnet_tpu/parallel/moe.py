@@ -58,7 +58,10 @@ On eager calls the layer counts itself: ``graft_moe_dispatch_traces_total
 {path}`` every routed forward traced, and for ``grouped``
 ``graft_moe_assignments_total{held}`` and the gauge
 ``graft_moe_expert_load_max_over_mean`` (``last_expert_load`` has the
-counts); inside a traced step nothing is read back.
+counts).  Inside a compiled step the same counts go out with the step's
+results (``telemetry.step_counter``: ``moe_held_rows``, ``moe_assignments``,
+and ``moe_dropped_rows`` of ``capacity``), a row a layer in call order,
+read back by step id with ``telemetry.step_counters()``.
 """
 from __future__ import annotations
 
@@ -75,6 +78,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..gluon.block import HybridBlock
 from ..ndarray import NDArray
 from ..telemetry import metrics as _metrics
+from ..telemetry.tracing import step_counter
 
 __all__ = ["ExpertParallelMoE"]
 
@@ -598,8 +602,11 @@ class ExpertParallelMoE(HybridBlock):
         (``last_expert_load``), both device arrays, and the counters
         ``graft_moe_assignments_total{held}`` and gauge
         ``graft_moe_expert_load_max_over_mean``, which read the load back.
-        Nothing inside a traced step, which must not sync."""
-        if isinstance(load, jax.core.Tracer):
+        A traced step must not sync: its load and the assignments made go
+        out with the step's results, ``moe_held_rows`` (count,) and
+        ``moe_assignments``."""
+        if step_counter("moe_held_rows", load, layer=self.name):
+            step_counter("moe_assignments", chosen.size, layer=self.name)
             return
         self.last_chosen, self.last_expert_load = chosen, load
         _metrics.moe_assignments(np.asarray(load), int(chosen.size))
@@ -608,7 +615,8 @@ class ExpertParallelMoE(HybridBlock):
         """Switch all-to-all dispatch over the scoped mesh's ep axis.
         Eager calls place operands on the mesh, run, and gather the output
         home (storing ``last_drop_fraction``); inside an enclosing jit the
-        caller's shardings flow through and stats stay on device."""
+        caller's shardings flow through and the rows dropped go out with a
+        compiled step's results (``moe_dropped_rows``)."""
         from .mesh import current_mesh, dispatch_on_mesh, gather_home
         mesh = current_mesh(required=True)
         if self._ep_axis not in mesh.axis_names:
@@ -619,12 +627,11 @@ class ExpertParallelMoE(HybridBlock):
             lambda a, b, c, d: switch_moe_apply(a, b, c, d, mesh, ep,
                                                 self._capacity_factor),
             mesh, (P(ep), P(), P(ep), P(ep)), xv, gw, w1, w2)
-        if eager:
-            if not isinstance(drops, jax.core.Tracer):
-                # concrete eager call; under the eager tape's vjp trace
-                # drops is a tracer — stats stay at their last value
-                self.last_drop_fraction = float(
-                    np.mean(jax.device_get(drops)))
-                self.last_aux_loss = float(np.mean(jax.device_get(aux)))
-            return gather_home(out, mesh)
-        return out
+        # a device's share of the tokens times the share it dropped
+        dropped = drops.sum() * (xv.shape[0] // mesh.shape[ep])
+        if not step_counter("moe_dropped_rows", dropped, layer=self.name):
+            # concrete eager call; under the eager tape's vjp trace drops
+            # is a tracer and the stats stay at their last value
+            self.last_drop_fraction = float(np.mean(jax.device_get(drops)))
+            self.last_aux_loss = float(np.mean(jax.device_get(aux)))
+        return gather_home(out, mesh) if eager else out

@@ -32,6 +32,9 @@ What the program marks in itself, for a reader of a profiler trace
   profiler's trace beside the device events, and a record (name, start,
   end, parent, step id, on ``time.perf_counter()``) that :func:`spans`
   hands out, oldest first.
+* :func:`step_counter` — a count made inside a compiled step (the rows a
+  routed layer's held experts got): it leaves the fused step beside the
+  loss and :func:`step_counters` hands it out by the ``step`` span's id.
 * :func:`programs` — the jitted programs of the train paths by the name
   the trace's ``XLA Modules`` line gives them, each with its phase or its
   ops' ``op_name`` paths (``xray:forward|backward|update`` scopes, Block
@@ -63,8 +66,8 @@ from . import xray
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       compact_snapshot, enabled, parse_prometheus_text,
                       registry, set_enabled, write_snapshot)
-from .tracing import phase_span
-from .blackbox import spans
+from .tracing import phase_span, step_counter
+from .blackbox import spans, step_counters
 from .xray import programs
 
 __all__ = ["metrics", "tracing", "blackbox", "watchdog",
@@ -72,7 +75,7 @@ __all__ = ["metrics", "tracing", "blackbox", "watchdog",
            "Counter", "Gauge", "Histogram", "MetricsRegistry",
            "registry", "enabled", "set_enabled", "parse_prometheus_text",
            "compact_snapshot", "write_snapshot", "phase_span", "spans",
-           "programs"]
+           "step_counter", "step_counters", "programs"]
 
 _snapshot_path = _os.environ.get("GRAFT_TELEMETRY_SNAPSHOT")
 if _snapshot_path:

@@ -48,6 +48,7 @@ from ..analysis import lockstep as _lockstep
 __all__ = ["enabled", "set_enabled", "record", "events", "stats",
            "in_flight", "inflight_entries", "progress", "last_progress",
            "collective", "phase_begin", "phase_end", "spans", "step_journal",
+           "step_counts", "step_counters",
            "current_step", "advance_step",
            "workers_seen", "set_rank", "set_clock_offset", "dump",
            "snapshot", "default_path", "validate_dump", "summarize_dump",
@@ -93,6 +94,9 @@ _stats = [0]                    # events recorded ever (dropped = _stats[0]
 # the ring's switch and size: a loop that flushes 40 times a step would
 # otherwise push a step's spans out of the ring within seconds
 _spans = deque(maxlen=_ring_size())
+# what the compiled steps counted (tracing.step_counter), oldest first:
+# (step id, {name: device array}, {name: [labels of each row]}), unread
+_counts = deque(maxlen=_ring_size())
 _rank = [0]
 _clock_offset = [None]          # latest heartbeat clock/arrival offset
 #                                 estimate vs the freshest-arriving rank
@@ -104,11 +108,12 @@ _started_at = time.time()
 
 def configure(size=None):
     """Re-size the ring (tests / live re-tuning).  Keeps newest events."""
-    global _ring, _spans
+    global _ring, _spans, _counts
     if size is not None:
         os.environ["GRAFT_BLACKBOX_SIZE"] = str(int(size))
     _ring = deque(_ring, maxlen=_ring_size())
     _spans = deque(_spans, maxlen=_ring_size())
+    _counts = deque(_counts, maxlen=_ring_size())
 
 
 def set_rank(rank):
@@ -433,6 +438,37 @@ def spans(since=None):
     return [s for s in held if s[1] >= since]
 
 
+def step_counts(step, counts, labels):
+    """Keep what the compiled step ``step`` (the id its ``step`` span
+    carries) counted: device arrays, kept unread, so nothing here waits for
+    the device."""
+    if enabled():
+        _counts.append((step, counts, labels))
+
+
+def step_counters(since_step=None):
+    """``[(step id, {name: numpy array})]`` of the compiled steps' counts
+    still held, oldest first; with ``since_step``, of the steps from that id
+    on.  Reading is what waits for a step: the reader's time."""
+    import jax
+    held = [(step, counts) for step, counts, _ in list(_counts)
+            if since_step is None or step >= since_step]
+    # one device_get: every copy is started before the first is waited for
+    return list(zip([step for step, _ in held],
+                    jax.device_get([counts for _, counts in held])))
+
+
+def _newest_step_counts():
+    """The newest record whose step is done (a dump must not wait for a
+    device that hangs), as plain lists with each row's labels."""
+    for step, counts, labels in reversed(list(_counts)):
+        if all(v.is_ready() for v in counts.values()):
+            return {"step": step, "counts": {
+                n: {"labels": labels.get(n), "values": v.tolist()}
+                for n, v in counts.items()}}
+    return None
+
+
 def _device_mem_peak():
     """Cheap device-memory highwater: allocator counters only (the
     live_arrays fallback walk is too slow for a per-step journal)."""
@@ -601,6 +637,7 @@ def snapshot(reason="manual", extra=None):
         # [name, start, end, parent, step] on the perf_counter clock, which
         # perf_anchor ties to the events' wall clock
         "spans": [list(sp) for sp in spans()],
+        "step_counters": _newest_step_counts(),
         "perf_anchor": {"perf_s": time.perf_counter(), "wall_s": now},
         "threads": _thread_stacks(),
     }

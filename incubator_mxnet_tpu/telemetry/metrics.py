@@ -771,9 +771,6 @@ def cachedop_recorded(residual_bytes):
     entry's leaves, reckoned once an entry; nothing is read back)."""
     if not enabled():
         return
-    _REGISTRY.counter("graft_cachedop_recorded_calls_total",
-                      "Recorded CachedOp forwards that made residuals for "
-                      "their backward").inc()
     _REGISTRY.gauge("graft_cachedop_residual_bytes",
                     "Bytes the last recorded CachedOp forward kept for its "
                     "backward, beyond parameters and inputs"
@@ -795,17 +792,6 @@ def cachedop_cotangents_skipped(parameters, inputs):
         "pass had no use for them", ("kind",))
     counter.inc(parameters, kind="parameter")
     counter.inc(inputs, kind="input")
-
-
-def cachedop_replay():
-    """One re-trace of a CachedOp's forward for ``create_graph``: the only
-    place left where a recorded forward is traced again (a training loop
-    reads 0)."""
-    if not enabled():
-        return
-    _REGISTRY.counter("graft_cachedop_replays_total",
-                      "CachedOp forwards re-traced for higher-order "
-                      "gradients (create_graph)").inc()
 
 
 def moe_assignments(load, assignments):

@@ -25,10 +25,12 @@ step/place/dispatch, engine flushes.  Each span lands in the JAX
 profiler's trace as ``mx:<name>`` (beside the device events, on their
 clock), in the flight recorder's span record (``telemetry.spans()``), in
 the chrome trace (cat ``phase``) and in the ``graft_phase_seconds``
-histogram.
+histogram.  Beside it :func:`step_counter`, the one primitive for a count
+made *inside* a compiled step, where no span can look.
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 import threading
 import time
@@ -38,7 +40,9 @@ import jax
 from . import blackbox as _blackbox
 from . import metrics as _metrics
 
-__all__ = ["phase_span", "current_step", "next_segment_id", "record_active",
+__all__ = ["phase_span", "step_counter", "collect_step_counters",
+           "stack_step_counters", "current_step", "next_segment_id",
+           "record_active",
            "deferred_op_event", "segment_flush_span",
            "segment_summary", "validate_chrome_trace",
            "process_metadata_events", "trace_header"]
@@ -202,6 +206,51 @@ def phase_span(phase, args=None, step=None):
             and not _blackbox.enabled():
         return _NULL
     return _PhaseSpan(phase, args, step)
+
+
+_counting = threading.local()    # .open: the collection of the trace in hand
+
+
+@contextlib.contextmanager
+def collect_step_counters():
+    """Open this thread's collection for :func:`step_counter` (the pattern
+    of ``random_state.use_key``) and yield it: ``(name, labels, value)`` in
+    call order.  Opened by whoever compiles a step, around the forward it
+    traces (``DataParallelTrainer``).  It shadows one that is open: a nested
+    ``jax.jit``'s tracers must not reach the outer step's results, so
+    ``CachedOp``'s trace opens one and drops it."""
+    outer = getattr(_counting, "open", None)
+    _counting.open = found = []
+    try:
+        yield found
+    finally:
+        _counting.open = outer
+
+
+def step_counter(name, value, **labels):
+    """A count made inside a compiled step, at a layer boundary: a sum or a
+    count, a scalar or a short vector, integer or float32 (a static number
+    counts too).  With a collection open it leaves the step with its
+    results and ``telemetry.step_counters()`` hands it out by step id; with
+    none a traced value is dropped.  False for a concrete value with no
+    collection open: an eager call, whose host counters are the caller's
+    to write."""
+    found = getattr(_counting, "open", None)
+    if found is not None:
+        found.append((name, labels, value))
+        return True
+    return isinstance(value, jax.core.Tracer)
+
+
+def stack_step_counters(found):
+    """``({name: its values stacked in call order}, {name: [labels of each
+    row]})`` of a collection; two empty dicts of an empty one."""
+    rows = {}
+    for name, labels, value in found:
+        rows.setdefault(name, []).append((labels, value))
+    return ({n: jax.numpy.stack([jax.numpy.asarray(v) for _, v in r])
+             for n, r in rows.items()},
+            {n: [labels for labels, _ in r] for n, r in rows.items()})
 
 
 # ---------------------------------------------------------------------------

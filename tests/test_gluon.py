@@ -491,13 +491,30 @@ def _assert_same(got, want, rtol=1e-4, atol=1e-5):
 def _counters():
     from incubator_mxnet_tpu.telemetry import metrics
     snap = metrics.registry().snapshot(collect=False)
+    samples = snap.get("graft_cachedop_residual_bytes", {}).get("samples", [])
+    return {"residual_bytes": samples[0]["value"] if samples else 0}
 
-    def read(name):
-        samples = snap.get(name, {}).get("samples", [])
-        return samples[0]["value"] if samples else 0
-    return {name: read("graft_cachedop_" + name)
-            for name in ("recorded_calls_total", "residual_bytes",
-                         "replays_total")}
+
+@pytest.fixture
+def raw_calls(monkeypatch):
+    """Calls of every CachedOp's functionalized forward made from here on:
+    one when a program of an entry is traced, one a replay for
+    ``create_graph``; a recorded step that hits its entry makes none."""
+    import functools
+    from incubator_mxnet_tpu.gluon.block import CachedOp
+    calls = []
+    make = CachedOp._make_fn
+
+    def counting(self, *args, **kwargs):
+        raw = make(self, *args, **kwargs)
+
+        @functools.wraps(raw)
+        def cachedop_forward(*vals):
+            calls.append(1)
+            return raw(*vals)
+        return cachedop_forward
+    monkeypatch.setattr(CachedOp, "_make_fn", counting)
+    return calls
 
 
 def _reference_grads(net, x, seed):
@@ -625,7 +642,7 @@ def test_two_recorded_calls_before_one_backward(recorded_net):
 
 
 def test_create_graph_through_a_hybridized_block_counts_one_replay(
-        recorded_net):
+        recorded_net, raw_calls):
     net, x, _ = recorded_net
     net.hybridize()
     mx.random.seed(8)
@@ -633,12 +650,10 @@ def test_create_graph_through_a_hybridized_block_counts_one_replay(
         _, loss = _loss(net, x)
     loss.backward(retain_graph=True)
     want = x.grad.asnumpy().copy()
-    before = _counters()
+    before = len(raw_calls)         # the recorded forward's one trace
     g, = autograd.grad([loss], [x], create_graph=True, retain_graph=True)
     np.testing.assert_allclose(g.asnumpy(), want, rtol=1e-4, atol=1e-5)
-    after = _counters()
-    assert after["replays_total"] - before["replays_total"] == 1
-    assert after["recorded_calls_total"] == before["recorded_calls_total"]
+    assert (before, len(raw_calls)) == (1, 2)
     # and the gradient is itself on the tape: its own gradient flows to x
     (g * g).sum().backward()
     assert np.isfinite(x.grad.asnumpy()).all()
@@ -740,19 +755,24 @@ def test_outside_record_no_residual_is_made(recorded_net):
     assert all("residual_bytes" not in entry for entry in entries)
 
 
-def test_counters_of_three_recorded_loop_steps(recorded_net):
+def test_counters_of_three_recorded_loop_steps(recorded_net, raw_calls):
+    import time
+    from incubator_mxnet_tpu import telemetry
     net, x, _ = recorded_net
     net.hybridize()
     trainer = gluon.Trainer(net.collect_params(), "sgd",
                             {"learning_rate": 0.01})
-    before = _counters()
+    since = time.perf_counter()
     for _ in range(3):
         with autograd.record():
             _, loss = _loss(net, x)
         loss.backward()
         trainer.step(x.shape[0])
     after = _counters()
-    assert after["recorded_calls_total"] - before["recorded_calls_total"] == 3
-    assert after["replays_total"] == before["replays_total"]
+    # three recorded forwards, three backwards, and the forward traced once:
+    # no step replayed it
+    spans = [s[0] for s in telemetry.spans(since=since)]
+    assert (spans.count("fwd"), spans.count("bwd")) == (3, 3)
+    assert len(raw_calls) == 1
     (entry,) = net._cached_op._cache.values()
     assert after["residual_bytes"] == entry["residual_bytes"] > 0

@@ -482,10 +482,13 @@ def test_the_cell_is_declared_as_the_issue_names_it():
     for m in spec["per_layer"]:
         if m["name"] in mine:
             assert m["moves"] == "samples_per_s_per_chip", m["name"]
-    # no list that was there names the cell
+    # no list that was there names the cell; PR 34's four metrics of the
+    # routed layer (``moe_step.py``) came later, with the three routed
+    # cells in one list each
     for group in ("end_to_end", "per_layer"):
         for m in spec[group]:
-            if CELL in m.get("workloads", ()):
+            if CELL in m.get("workloads", ()) and not m["name"].startswith(
+                    ("moe_step_", "moe_experts_rows_")):
                 assert m["workloads"] == [CELL], m["name"]
 
 

@@ -465,10 +465,13 @@ def test_the_cell_is_declared_as_the_issue_names_it():
     # flash_fwd.py, flash_bwd.py and gqa_flash.py take the head as
     # hidden_size // num_attention_heads (72 here): not declared
     assert not {m for m in mine if m.startswith(("flash_", "gqa_flash"))}
-    # no list that was there names the cell
+    # no list that was there names the cell; PR 34's four metrics of the
+    # routed layer (``moe_step.py``) came later, with the three routed
+    # cells in one list each
     for group in ("end_to_end", "per_layer"):
         for m in spec[group]:
-            if CELL in m.get("workloads", ()):
+            if CELL in m.get("workloads", ()) and not m["name"].startswith(
+                    ("moe_step_", "moe_experts_rows_")):
                 assert m["workloads"] == [CELL], m["name"]
 
 
