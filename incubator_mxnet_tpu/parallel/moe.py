@@ -29,11 +29,12 @@ Three dispatch modes behind one module interface:
   by nothing: the two gathers that move rows between the tokens' order and
   the buffer's send every assignment that lies in no group to a row of
   zeros (``_to_buffer``, ``_to_tokens``), so the buffer is never masked
-  whole.  What absent experts would have added is left out: on the
-  mesh of a deployment their chips add it through the exchange; on one
-  chip the layer computes its share (the chip benchmark's cell
-  ``lfm2moe_fused_s8192``: experts 0–7 of 32, top-4).  Compute ∝ the
-  assignments held, independent of num_experts.
+  whole.  The assignments are numbered choice x N + token, so the buffer
+  seen by choice, (k, N, d), is the same bytes for any top_k.  What absent
+  experts would have added is left out: on the mesh of a deployment their
+  chips add it through the exchange; on one chip the layer computes its
+  share (the chip benchmark's cell ``lfm2moe_fused_s8192``: experts 0–7 of
+  32, top-4).  Compute ∝ the assignments held, independent of num_experts.
 
 Routing, shared by ``dense`` and ``grouped`` (``_route``): ``router`` says
 how scores are made of the gate's logits (``"softmax"``, or ``"sigmoid"``
@@ -313,18 +314,34 @@ def _experts(xs, w1, w3, w2, group_sizes, in_group):
 
 
 # Rows move between the tokens' order and the buffer's by two gathers that
-# are each other's transpose: ``order`` (A,) names the assignment, token x
-# top_k + choice, that each row of the buffer holds, and ``slot`` (A,) the
-# row that holds each assignment, or A for an assignment that lies in no
-# group.  That one reads zeros, so what no kernel wrote behind the groups
-# (unspecified, a NaN among it) is never read and needs no mask: a pass
-# over the whole buffer less for every array that crosses.  A gather both
-# ways, where the transpose of a plain gather is a scatter-add that the
-# TPU's compiler sorts and serialises.
+# are each other's transpose: ``order`` (A,) names the assignment, choice x N
+# + token, that each row of the buffer holds, and ``slot`` (A,) the row that
+# holds each assignment, or A for an assignment that lies in no group.  That
+# one reads zeros, so what no kernel wrote behind the groups (unspecified, a
+# NaN among it) is never read and needs no mask: a pass over the whole
+# buffer less for every array that crosses.  A gather both ways, where the
+# transpose of a plain gather is a scatter-add that the TPU's compiler sorts
+# and serialises.
+#
+# Choice-major, because of which dimension a view of the assignments splits:
+# (A, d) seen as (k, N, d) splits the leading dimension and is the same
+# bytes for every k (N a multiple of the sublane tile), while (N, k, d)
+# splits the sublanes, and where k is no whole tile of them (top-4, top-6)
+# the TPU's compiler writes the view out as a relayout, three passes over
+# the buffer a layer and one of them in float32.  The weighted sum over a
+# token's choices has its own rule (``_to_tokens``) for the same reason: its
+# transpose writes the rows' cotangent a choice slab at a time, so the
+# token cotangent's broadcast over the choices is never an array.
 
 def _zero_filled(rows, slot):
     """``rows[slot]``, zeros where ``slot`` is ``len(rows)``."""
     return rows.at[slot].get(mode="fill", fill_value=0)
+
+
+def _slabs(rows, top_k):
+    """The assignments' rows (A, d) as ``top_k`` slabs (N, d), a choice
+    each."""
+    return rows.reshape(top_k, -1, rows.shape[-1])
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -332,7 +349,7 @@ def _to_buffer(x, order, slot, top_k):
     """Each token's row of ``x`` (N, d) at the buffer rows that hold its
     assignments, (A, d).  The cotangent of a token is the float32 sum over
     its ``top_k`` assignments' rows, those in no group counting zero."""
-    return x[order // top_k]
+    return x[order % x.shape[0]]
 
 
 def _to_buffer_fwd(x, order, slot, top_k):
@@ -340,27 +357,46 @@ def _to_buffer_fwd(x, order, slot, top_k):
 
 
 def _to_buffer_bwd(top_k, slot, g):
-    g = _zero_filled(g, slot)
-    return (g.reshape(-1, top_k, g.shape[-1]).astype(jnp.float32).sum(
-        axis=1).astype(g.dtype), None, None)
+    g = _slabs(_zero_filled(g, slot), top_k)
+    return g.astype(jnp.float32).sum(axis=0).astype(g.dtype), None, None
 
 
 _to_buffer.defvjp(_to_buffer_fwd, _to_buffer_bwd)
 
 
 @jax.custom_vjp
-def _to_tokens(ys, slot, order):
-    """The buffer's rows ``ys`` (A, d) in the order of the assignments,
-    zeros for an assignment in no group."""
-    return _zero_filled(ys, slot)
+def _to_tokens(ys, weights, slot, order):
+    """The buffer's rows ``ys`` (A, d) back in the order of the assignments,
+    zeros for an assignment in no group, and summed over each token's
+    choices with ``weights`` (k, N): (N, d) in float32, the products and
+    the sums in float32, choice 0 first."""
+    return _to_tokens_fwd(ys, weights, slot, order)[0]
 
 
-def _to_tokens_fwd(ys, slot, order):
-    return _to_tokens(ys, slot, order), order
+def _filled(rows, slot, top_k):
+    """``rows`` (A, d), gathered with ``slot`` clipped, by choice, (k, N, d),
+    with the rows of assignments in no group set to zero: the fill of
+    ``_zero_filled``, taken where the rows are read, so that what is kept
+    between the passes is the gather's own result."""
+    held = (slot < len(slot)).reshape(top_k, -1, 1)
+    return jnp.where(held, _slabs(rows, top_k), jnp.zeros((), rows.dtype))
 
 
-def _to_tokens_bwd(order, g):
-    return g[order], None, None
+def _to_tokens_fwd(ys, weights, slot, order):
+    rows = ys.at[slot].get(mode="clip")
+    filled = _filled(rows, slot, len(weights)).astype(jnp.float32)
+    out = (filled * weights[:, :, None]).sum(axis=0)
+    return out, (rows, weights, slot, order)
+
+
+def _to_tokens_bwd(res, g):
+    rows, weights, slot, order = res
+    # a slab a choice: written as one broadcast of ``g`` over the choices,
+    # the compiler materialises that broadcast in float32
+    d_rows = jnp.stack([(g * w[:, None]).astype(rows.dtype) for w in weights])
+    filled = _filled(rows, slot, len(weights)).astype(jnp.float32)
+    return (d_rows.reshape(rows.shape)[order], (g * filled).sum(axis=-1),
+            None, None)
 
 
 _to_tokens.defvjp(_to_tokens_fwd, _to_tokens_bwd)
@@ -373,14 +409,14 @@ def grouped_moe_apply(x, chosen, weights, w1, w3, w2, first):
     among each token's chosen ones, in x's dtype, and the assignments each
     held expert got, (count,) int32.
 
-    The A = N·k assignments are sorted by held expert, those of absent
-    experts behind them; both ways the rows move by a gather over a
-    permutation of A (the sort's, and its inverse), so neither direction
-    of the gradient adds rows serially."""
+    The A = N·k assignments, numbered choice × N + token, are sorted by
+    held expert, those of absent experts behind them; both ways the rows
+    move by a gather over a permutation of A (the sort's, and its inverse),
+    so neither direction of the gradient adds rows serially."""
     n, k = chosen.shape
     count = w1.shape[0]
     with jax.named_scope("moe_dispatch"):
-        local = chosen.reshape(-1) - first                    # (A,)
+        local = chosen.T.reshape(-1) - first                  # (A,)
         held = (local >= 0) & (local < count)
         # absent experts sort behind every held one, as group ``count``
         key = jnp.where(held, local, count).astype(jnp.int32)
@@ -395,12 +431,10 @@ def grouped_moe_apply(x, chosen, weights, w1, w3, w2, first):
     with jax.named_scope(_SCOPE):
         ys = _experts(xs, w1, w3, w2, group_sizes, in_group)
     with jax.named_scope("moe_combine"):
-        # back to (token, choice) order, then the weighted sum over a
+        # back to (choice, token) order, then the weighted sum over a
         # token's choices: the weights meet the rows where both lie in the
         # router's order, so no scalar is gathered
-        out = _to_tokens(ys, slot, order).reshape(n, k, -1).astype(
-            jnp.float32)
-        out = (out * weights[:, :, None]).sum(axis=1)
+        out = _to_tokens(ys, weights.T, slot, order)
     return out.astype(x.dtype), group_sizes
 
 

@@ -533,23 +533,28 @@ def no_compile_cache():
     cc.reset_cache()
 
 
-@pytest.mark.parametrize("config, router, shape, buffer_rows", [
-    (CONFIG, "sigmoid", (8192, 2048, 1792, 8, 32), 32768),
+@pytest.mark.parametrize("config, traffic, router, shape, buffer_rows", [
+    (CONFIG, "fused_s8192", "sigmoid", (8192, 2048, 1792, 8, 32), 32768),
     # PR 30: top-8 of 64 by softmax, a buffer of tokens x 8 rows
-    ("mellum2_12b_a2b5_ep8_l4", "softmax", (8192, 2304, 896, 8, 64), 65536)])
+    ("mellum2_12b_a2b5_ep8_l4", "fused_s8192", "softmax",
+     (8192, 2304, 896, 8, 64), 65536),
+    # PR 32's cell: top-6 of 64 by sigmoid over a row of 4096
+    ("kimi_vl_a3b_ep8_l5", "fused_s4096", "sigmoid",
+     (4096, 2048, 1408, 8, 64), 24576)])
 def test_routed_layer_compiles_for_the_chip_at_the_cells_shape(
-        bench_catalog, one_chip, no_compile_cache, config, router, shape,
-        buffer_rows):
-    """Forward and backward of the grouped dispatch over 8192 tokens with
-    experts 0-7 held, in bf16 (LFM2: top-4 of 32; Mellum2: top-8 of 64):
-    the products are the Pallas grouped matmul (``gmm``, ``tgmm``), not
-    XLA's expansion of ``ragged_dot``, no row moves by a scatter, and no
-    pass masks the buffer at the tokens' width."""
+        bench_catalog, one_chip, no_compile_cache, config, traffic, router,
+        shape, buffer_rows):
+    """Forward and backward of the grouped dispatch over a cell's row of
+    tokens with experts 0-7 held, in bf16 (LFM2: top-4 of 32; Mellum2:
+    top-8 of 64; Kimi-VL: top-6 of 64): the products are the Pallas grouped
+    matmul (``gmm``, ``tgmm``), not XLA's expansion of ``ragged_dot``, no
+    row moves by a scatter, no pass masks the buffer at the tokens' width,
+    and no view of the buffer by choice is written out."""
     import jax
     import jax.numpy as jnp
     from incubator_mxnet_tpu.parallel import moe
     sizes, _ = bench_catalog.config(config)
-    traffic = bench_catalog.traffic("fused_s8192")
+    traffic = bench_catalog.traffic(traffic)
     tokens = traffic["batch_per_chip"] * traffic["seq_len"]
     d, h = sizes["hidden_size"], sizes["moe_intermediate_size"]
     held, experts = sizes["num_experts"], sizes["num_experts_published"]
@@ -563,7 +568,8 @@ def test_routed_layer_compiles_for_the_chip_at_the_cells_shape(
         _, chosen, weights = moe._route(
             x, gate, bias if router == "sigmoid" else None,
             top_k=sizes["num_experts_per_tok"],
-            router=router, norm_topk=True, scaling=1.0)
+            router=router, norm_topk=True,
+            scaling=float(sizes.get("routed_scaling_factor", 1.0)))
         out, _ = moe.grouped_moe_apply(x, chosen, weights, w1, w3, w2, 0)
         return jnp.sum(jnp.square(out.astype(jnp.float32)))
 
@@ -595,6 +601,18 @@ def test_routed_layer_compiles_for_the_chip_at_the_cells_shape(
                          r"(fusion|add|select|convert)\(" % (buffer_rows, d),
                          line)]
     assert sorted(wrote) == ["add_any"] + ["gather"] * 4, wrote
+    # PR 35: the assignments lie choice-major, so a view of the buffer by
+    # choice splits its leading dimension and is a bitcast; token-major,
+    # at top-4 and top-6 (no whole sublane tile), the program wrote each
+    # (N, k, d) view out as a ``reshape``, one of them in float32, and the
+    # weighted sum's cotangent as a float32 ``broadcast`` beside them
+    relayouts = [
+        line.strip()[:100] for line in entry.splitlines()
+        for found in [re.match(r"\s*(ROOT )?%[\w.\-]+ = \w+\[([\d,]+)\]\S* "
+                               r"(reshape|broadcast|copy|transpose)\(", line)]
+        if found and np.prod([int(v) for v in found.group(2).split(",")])
+        >= buffer_rows * d]
+    assert not relayouts, relayouts
 
 
 def test_flash_kernels_compile_at_the_cells_shape(bench_catalog, one_chip,

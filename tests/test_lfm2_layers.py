@@ -390,16 +390,20 @@ def test_grouped_gradients_match_dense_and_spare_the_bias():
 # grouped dispatch against the layer that masked its whole buffer (PR 26-30)
 # ---------------------------------------------------------------------------
 
-def _every_row_masked(x, chosen, weights, w1, w3, w2, first):
+def _every_row_masked(x, chosen, weights, w1, w3, w2, first,
+                      token_major=False):
     """``grouped_moe_apply`` as it stood until PR 31: the rows gathered in
     and every product's rows are masked with ``in_group`` over the whole
     buffer, and the rows move by plain indexing with the sort's
     permutation and its inverse.  The plain reference today's layer, which
-    masks nothing it does not read, is held to."""
+    masks nothing it does not read, is held to.  It numbers its assignments
+    as the layer does since PR 35, choice x N + token; ``token_major`` is
+    the numbering until then, token x k + choice, which puts the rows of a
+    group in another order and so the sums of the weights' gradients."""
     from incubator_mxnet_tpu.parallel.moe import grouped_dot
     n, k = chosen.shape
     count = w1.shape[0]
-    local = chosen.reshape(-1) - first
+    local = (chosen if token_major else chosen.T).reshape(-1) - first
     held = (local >= 0) & (local < count)
     key = jnp.where(held, local, count).astype(jnp.int32)
     order = jnp.argsort(key, stable=True)
@@ -411,13 +415,16 @@ def _every_row_masked(x, chosen, weights, w1, w3, w2, first):
     def dot(rows, w):
         return jnp.where(in_group, grouped_dot(rows, w, group_sizes),
                          jnp.zeros((), rows.dtype))
-    xs = jnp.where(in_group, jnp.repeat(x, k, axis=0)[order],
-                   jnp.zeros((), x.dtype))
+    rows = jnp.repeat(x, k, axis=0) if token_major else jnp.tile(x, (k, 1))
+    xs = jnp.where(in_group, rows[order], jnp.zeros((), x.dtype))
     h = jax.nn.silu(dot(xs, w1).astype(jnp.float32)) * dot(xs, w3).astype(
         jnp.float32)
-    ys = dot(h.astype(xs.dtype), w2)
-    out = ys[back].reshape(n, k, -1).astype(jnp.float32)
-    return (out * weights[:, :, None]).sum(axis=1).astype(x.dtype)
+    ys = dot(h.astype(xs.dtype), w2)[back].astype(jnp.float32)
+    if token_major:
+        out = (ys.reshape(n, k, -1) * weights[:, :, None]).sum(axis=1)
+    else:
+        out = (ys.reshape(k, n, -1) * weights.T[:, :, None]).sum(axis=0)
+    return out.astype(x.dtype)
 
 
 def _dense_share(x, chosen, weights, w1, w3, w2, first):
@@ -505,6 +512,27 @@ def test_grouped_equals_the_layer_that_masked_every_row(top_k, count,
         assert np.array_equal(np.asarray(g), np.asarray(w))
         np.testing.assert_allclose(np.asarray(g), np.asarray(d), rtol=2e-4,
                                    atol=2e-5)
+
+
+@pytest.mark.parametrize("router", ["balanced", "all_held", "none_held",
+                                    "one_token"])
+@pytest.mark.parametrize("top_k,count,experts", SHARES)
+def test_grouped_order_of_assignments_is_only_rounding(top_k, count, experts,
+                                                       router):
+    """The same layer against the reference that numbers its assignments
+    token x k + choice, as the layer did until PR 35: value and gradients
+    agree within float32 rounding, so the order of the assignment axis
+    changes the order of sums (the rows of a group, for the weights'
+    gradients) and nothing else."""
+    import functools
+    from incubator_mxnet_tpu.parallel import moe
+    chosen, args = _layer_inputs(top_k, count, experts, router)
+    got, want = (_value_and_gradients(apply, chosen, args)
+                 for apply in (moe.grouped_moe_apply, functools.partial(
+                     _every_row_masked, token_major=True)))
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-5,
+                                   atol=1e-5)
 
 
 @pytest.mark.parametrize("router", ["balanced", "none_held", "one_token"])
