@@ -3,3 +3,4 @@ from ..block import Block, HybridBlock, SymbolBlock
 from .basic_layers import *
 from .conv_layers import *
 from .attention import *
+from .ssm import *

@@ -14,9 +14,11 @@ import jax
 import numpy as np
 
 from ..block import HybridBlock
+from ... import initializer
+from ...telemetry import metrics as _metrics
 from .basic_layers import Dense, RMSNorm
 
-__all__ = ["MultiHeadAttention", "LatentAttention"]
+__all__ = ["MultiHeadAttention", "LatentAttention", "DifferentialAttention"]
 
 
 class MultiHeadAttention(HybridBlock):
@@ -335,3 +337,136 @@ class LatentAttention(HybridBlock):
         out = F.reshape(F.transpose(out, axes=(0, 2, 1, 3)),
                         shape=(B, S, H * self._v_dim))
         return self.proj_out(out)
+
+
+class DifferentialAttention(HybridBlock):
+    """Differential attention (Ye et al., arXiv:2410.05258, laid out as the
+    Diff Transformer's own code and Phi-4-mini-flash's attention have it),
+    causal, (B, S, E) -> (B, S, E), with biases::
+
+        [q ; k ; v] = W_qkv x + b        E + 2 * num_kv_heads * D,  D = E / H
+        q -> (H/2, 2, D): q1_i, q2_i     consecutive heads pair up
+        k -> (G/2, 2, D): k1_j, k2_j     v -> (G/2, 2 D): a pair's two value
+                                         heads side by side
+        o_i = softmax(q1_i k1_j^T / sqrt(D) + M) v_j
+              - lam * softmax(q2_i k2_j^T / sqrt(D) + M) v_j,   j = i // (H/G)
+        lam = exp(lq1 . lk1) - exp(lq2 . lk2) + lam_init
+        lam_init = 0.8 - 0.6 exp(-0.3 depth)
+        out = W_o [(1 - lam_init) RMSNorm_2D(o_i)]_i + b_o
+
+    ``H = num_heads`` query heads over ``G = num_kv_heads`` K/V heads of D,
+    i.e. H / 2 differential heads 2 D wide; four learned vectors of D and one
+    norm gain of 2 D a layer; ``depth`` is the layer's index in the published
+    model, which sets ``lam_init``.  ``M`` is causal, or with ``window`` the
+    query's own key and the ``window - 1`` before it.
+
+    On the kernels it is ONE call, ``F._contrib_FlashAttention(q, k, v)`` at
+    keys of D over values of 2 D: the H query heads in their order
+    (head 2 i + s is q1 for s = 0, q2 for s = 1), the values repeated to H
+    heads, and the keys laid out so that query head 2 i + s meets key head
+    2 j + s (the (G/2, 2) key heads broadcast over a pair's query groups,
+    which a plain ``repeat`` is not: it would hand q2 the key k1).  The
+    output (H, 2 D) read as (H/2, 2, 2 D) gives the two softmaxes' results.
+
+    ``cross=True``: the layer projects q only (``W_q``, bias) and attends
+    onto the ``(k, v)`` another layer made, handed in as ``kv``: k
+    (B, G, S, D) and v (B, G/2, S, 2 D) as ``return_kv=True`` makes a layer
+    return them beside its output.  No rotary positions.
+
+    A class beside ``MultiHeadAttention`` and not arguments of it (as
+    ``LatentAttention`` is): the pairing, the two value heads side by side,
+    the four vectors, the norm after the kernel and the handed-over k, v
+    would each be an argument that every other combination of that layer's
+    arguments has to refuse.  What they share is the kernel call, here under
+    the scope ``attn_diff`` (with ``attn_window`` / ``attn_full`` /
+    ``attn_cross`` inside it); the subtraction, the norm and the factor are
+    staged under ``attn_diff_combine``.  The chip benchmark's
+    ``phi4flash_sambay_fused_1row`` runs it at 40 heads over 20 of 64, with
+    ``window=512``, without, and as a cross layer.
+    """
+
+    def __init__(self, units, num_heads, num_kv_heads, depth, window=None,
+                 cross=False, return_kv=False, epsilon=1e-5, **kwargs):
+        super().__init__(**kwargs)
+        if units % num_heads or num_heads % 2 or num_kv_heads % 2 \
+                or num_heads % num_kv_heads:
+            raise ValueError(
+                "differential heads pair up: units (%d) a multiple of an "
+                "even num_heads (%d), itself a multiple of an even "
+                "num_kv_heads (%d)" % (units, num_heads, num_kv_heads))
+        if cross and (window is not None or return_kv):
+            raise ValueError("a cross layer attends over the whole row of "
+                             "another layer's k, v and has none to return")
+        self._units, self._heads, self._kv_heads = units, num_heads, num_kv_heads
+        self._dim = dim = units // num_heads
+        self._window, self._cross, self._return_kv = window, cross, return_kv
+        self._epsilon = epsilon
+        self._lambda_init = 0.8 - 0.6 * float(np.exp(-0.3 * depth))
+        with self.name_scope():
+            if cross:
+                self.proj_q = Dense(units, flatten=False, in_units=units,
+                                    prefix="q_")
+            else:
+                self.proj_qkv = Dense(units + 2 * num_kv_heads * dim,
+                                      flatten=False, in_units=units,
+                                      prefix="qkv_")
+            self.proj_out = Dense(units, flatten=False, in_units=units,
+                                  prefix="out_")
+            for name in ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2"):
+                setattr(self, name, self.params.get(
+                    name, shape=(dim,), init=initializer.Normal(0.1)))
+            self.subln = self.params.get("subln_gamma", shape=(2 * dim,),
+                                         init=initializer.One())
+
+    def _heads_of(self, F, t, heads):
+        """(B, S, heads * D') -> (B, heads, S, D')."""
+        B, S = t.shape[0], t.shape[1]
+        return F.transpose(F.reshape(t, shape=(B, S, heads, -1)),
+                           axes=(0, 2, 1, 3))
+
+    def hybrid_forward(self, F, x, kv=None, lambda_q1=None, lambda_k1=None,
+                       lambda_q2=None, lambda_k2=None, subln=None):
+        B, S = x.shape[0], x.shape[1]
+        H, G, D = self._heads, self._kv_heads, self._dim
+        if self._cross:
+            if kv is None:
+                raise ValueError("a cross layer takes the (k, v) of the "
+                                 "layer that made them")
+            _metrics.shared_kv_read()
+            q = self._heads_of(F, self.proj_q(x), H)
+            k, v = kv
+        else:
+            qkv = self.proj_qkv(x)
+            q = self._heads_of(F, F.slice_axis(
+                qkv, axis=-1, begin=0, end=H * D), H)
+            k = self._heads_of(F, F.slice_axis(
+                qkv, axis=-1, begin=H * D, end=(H + G) * D), G)
+            v = self._heads_of(F, F.slice_axis(
+                qkv, axis=-1, begin=(H + G) * D, end=(H + 2 * G) * D), G // 2)
+        Sk = k.shape[2]
+        # query heads 2 i + s, i = fold * j .. fold * j + fold - 1, meet key
+        # head 2 j + s: the pair (k1_j, k2_j) once for each of them
+        fold = H // G
+        keys = F.reshape(
+            F.repeat(F.reshape(k, shape=(B, G // 2, 1, 2, Sk, D)),
+                     repeats=fold, axis=2), shape=(B, H, Sk, D))
+        values = F.repeat(v, repeats=2 * fold, axis=1)
+        windowed = {} if self._window is None else {"window": self._window}
+        kind = ("attn_cross" if self._cross else
+                "attn_full" if self._window is None else "attn_window")
+        with jax.named_scope("attn_diff"), jax.named_scope(kind):
+            out = F._contrib_FlashAttention(
+                q, keys, values, causal=True, scale=1.0 / float(np.sqrt(D)),
+                **windowed)
+        with jax.named_scope("attn_diff_combine"):
+            lam = (F.exp(F.sum(lambda_q1 * lambda_k1))
+                   - F.exp(F.sum(lambda_q2 * lambda_k2)) + self._lambda_init)
+            both = F.reshape(out, shape=(B, H // 2, 2, S, 2 * D))
+            first, second = (F.reshape(
+                F.slice_axis(both, axis=2, begin=s, end=s + 1),
+                shape=(B, H // 2, S, 2 * D)) for s in (0, 1))
+            o = F.RMSNorm(first - lam * second, subln, eps=self._epsilon)
+            o = o * (1.0 - self._lambda_init)
+        o = F.reshape(F.transpose(o, axes=(0, 2, 1, 3)), shape=(B, S, H * D))
+        o = self.proj_out(o)
+        return (o, (k, v)) if self._return_kv else o

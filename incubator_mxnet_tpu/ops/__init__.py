@@ -17,6 +17,7 @@ from . import optimizer_ops  # noqa: F401  fused updates
 from . import rnn         # noqa: F401  fused RNN + CTC
 from . import vision      # noqa: F401  detection/sampling (SSD/RCNN/STN)
 from . import attention   # noqa: F401  flash attention
+from . import ssm         # noqa: F401  selective scan (state-space layers)
 from . import linalg      # noqa: F401  LAPACK la_op family + FFT/count_sketch
 from . import quantization  # noqa: F401  INT8 quantize/dequantize/quantized_*
 

@@ -609,7 +609,9 @@ def _window_of(window, causal, sk):
 def flash_attention(q, k, v, causal=False, scale=None, window=None):
     """softmax(QKᵀ·scale + mask)·V with O(seq) memory: q (B, H, Sq, Dqk),
     k (B, H, Sk, Dqk), v (B, H, Sk, Dv) → (B, H, Sq, Dv).  ``Dv`` may differ
-    from ``Dqk`` (latent attention: keys of 192 over values of 128); the
+    from ``Dqk`` (latent attention: keys of 192 over values of 128;
+    differential attention: keys of 64 over values of 128, under a window
+    too, the chip benchmark's ``phi4flash_sambay_fused_1row``); the
     default ``scale`` is ``1 / sqrt(Dqk)``.
 
     ``causal``: query r sees the keys up to its own (the diagonal anchored

@@ -724,6 +724,43 @@ def flash_head_dim(qk, v):
     gauge.set(float(v), part="v")
 
 
+def ssm_scan_trace(path):
+    """One trace of ``ops.ssm.selective_scan``, forward or backward, labeled
+    by the form it took: ``pallas`` (concrete operands on a TPU),
+    ``lowering_platform`` (traced operands: the Mosaic kernels when the
+    enclosing program is lowered for a TPU, the ``jnp`` form otherwise) or
+    ``jnp`` (off the TPU, or a shape the kernels do not take)."""
+    if not enabled():
+        return
+    _REGISTRY.counter("graft_ssm_scan_traces_total",
+                      "selective_scan traces by form", ("path",)).inc(
+        path=path)
+
+
+def ssm_scan_shape(chunk, state_elems):
+    """The chunk (time steps between two saved states) and the state's size
+    (channels x states) of the ``selective_scan`` call just traced: two
+    gauges, set from shapes."""
+    if not enabled():
+        return
+    _REGISTRY.gauge("graft_ssm_scan_chunk",
+                    "Time steps between two saved states of the last traced "
+                    "selective_scan call").set(float(chunk))
+    _REGISTRY.gauge("graft_ssm_state_elems",
+                    "Channels x states of the last traced selective_scan "
+                    "call").set(float(state_elems))
+
+
+def shared_kv_read():
+    """One trace of an attention layer that projects no k, v of its own and
+    attends onto another layer's (``DifferentialAttention(cross=True)``)."""
+    if not enabled():
+        return
+    _REGISTRY.counter("graft_shared_kv_reads_total",
+                      "Cross-attention layer traces that read another "
+                      "layer's k, v").inc()
+
+
 def moe_shared_trace():
     """One trace of an ``ExpertParallelMoE`` forward that has a shared
     expert (``shared_hidden_size``): the always-on ``GatedMLP`` added to the

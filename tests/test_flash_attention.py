@@ -80,8 +80,9 @@ def test_kernels_match_autodiff_of_the_reference(dtype, causal, sq, sk, d):
 
 # keys over values: latent attention's 192 over 128 (the key padded to 256
 # lanes, the value not), both whole lanes, both padded, a value wider than
-# the key
-TWO_WIDTHS = [(192, 128), (256, 128), (48, 16), (128, 256)]
+# the key, differential attention's 64 over 128 (a pair's two value heads
+# side by side: the key padded to 128 lanes)
+TWO_WIDTHS = [(192, 128), (256, 128), (48, 16), (128, 256), (64, 128)]
 
 
 @pytest.mark.parametrize("dqk,dv", TWO_WIDTHS)
@@ -376,3 +377,63 @@ def test_grad_compiles_at_the_latent_cells_two_widths(topo, no_compile_cache):
     assert ("bf16[16,4096,256]" in calls["flash_attention_bwd_dkv"]
             and "bf16[16,4096,128]" in calls["flash_attention_bwd_dkv"])
     assert "bf16[16,4096,256]" in calls["flash_attention_bwd_dq"]
+
+
+@pytest.mark.parametrize("window", [512, None])
+def test_grad_compiles_at_the_differential_cells_shape(topo, no_compile_cache,
+                                                       window):
+    """(1, 40, 4096, 64) keys over (1, 40, 4096, 128) values in bf16, under
+    a window of 512 and without: the first shape that uses the banded grid
+    and two head widths in one call (``phi4flash_sambay_fused_1row``).
+    Mosaic takes the three kernels of the kind; q, k run at 128 padded lanes
+    and v, out at their own 128."""
+    from jax.sharding import SingleDeviceSharding
+    chip = SingleDeviceSharding(topo.devices[0])
+    qk = jax.ShapeDtypeStruct((1, 40, 4096, 64), jnp.bfloat16, sharding=chip)
+    v = jax.ShapeDtypeStruct((1, 40, 4096, 128), jnp.bfloat16, sharding=chip)
+    text = jax.jit(_loss_of(window)).lower(qk, qk, v).compile().as_text()
+    names = sorted(re.match(r"\s*(?:ROOT )?%(\w+?)(?:\.\d+)? =", line).group(1)
+                   for line in text.splitlines()
+                   if 'custom_call_target="tpu_custom_call"' in line)
+    stem = "flash_attention" if window is None else "flash_window"
+    assert names == [stem + "_bwd_dkv", stem + "_bwd_dq", stem + "_pallas"]
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_scan_kernels_compile_at_the_state_space_cells_shape(
+        topo, no_compile_cache, dtype):
+    """``ops/ssm.py``'s two kernels at one row of 4096 steps, 5120 channels
+    of 16 states (``phi4flash_sambay_fused_1row``; float32 is the forward
+    check's): Mosaic takes both, and nothing of size L x 5120 x 16 is among
+    the program's temporaries (1.34 GB in float32)."""
+    from jax.sharding import SingleDeviceSharding
+    from incubator_mxnet_tpu.ops import ssm
+    chip = SingleDeviceSharding(topo.devices[0])
+    length, channels, states = 4096, 5120, 16
+
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=chip)
+
+    ops = (spec(1, length, channels), spec(1, length, channels),
+           spec(channels, states), spec(1, length, states),
+           spec(1, length, states), spec(channels))
+    assert ssm._kernels_take(ops[0], ops[2], ssm.CHUNK)
+
+    def loss(*a):
+        # the kernels themselves: under ``jit`` alone ``_choose`` would leave
+        # the choice to the lowering, which is this test's too
+        return ssm.selective_scan(*a).astype(jnp.float32).sum()
+
+    # as ``run.py::reference_check`` calls the Block: Mosaic refuses a bf16
+    # product at float32 precision, which the context would hand the
+    # kernels' one-hot products (found on the chip, PR 38)
+    with jax.default_matmul_precision("highest"):
+        compiled = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(
+            *ops).compile()
+    text = compiled.as_text()
+    names = sorted(re.match(r"\s*(?:ROOT )?%(\w+?)(?:\.\d+)? =", line).group(1)
+                   for line in text.splitlines()
+                   if 'custom_call_target="tpu_custom_call"' in line)
+    assert names == ["selective_scan_bwd", "selective_scan_pallas"]
+    every_state = 4 * length * channels * states
+    assert compiled.memory_analysis().temp_size_in_bytes < every_state // 4
