@@ -7,11 +7,14 @@ XLA), so these lower to pure lax reshapes/slices/gathers.
 """
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..telemetry import metrics as _metrics
 from .registry import register
 
 
@@ -245,6 +248,111 @@ def _pick(data, index, axis=1, keepdims=False):
     return out
 
 
+# -- Embedding: the lookup, and a backward rule of its own --------------------
+#
+# The table's gradient is the transpose of the lookup, XLA's ``scatter-add``
+# of the cotangent's rows.  On a TPU the compiler sorts the ids and adds the
+# rows one after another, and what a row costs goes with how it tiles a row
+# of that width, not with the work: at most 0.40 us a row at every width that
+# is a power of two (512 to 4096, six tables) and 0.62 at every other width
+# under 2560 elements, but 2.4 at 2560, 1.25 at 3584 and 10.7 at 5120 over a
+# table of 25088 rows, in bf16 and float32 alike (PERF.md section 6, PR 39).
+# A quarter of the row at a time, 512 to 1024 elements wide, read 0.02-0.10.
+# So a row of 2560 elements or more whose width is no power of two is handed
+# over in four column blocks, where the three more passes over the table that
+# the blocks' concatenation costs are worth it.
+
+_WIDE = 2560            # elements: no narrower row read over 0.62 us
+_PARTS = 4
+_WORST_ROW_S = 2.6e-6   # the scatter's worst row inside a train step
+_HBM_BYTES_S = 8e11     # what a pass over the table moves on a v5e
+_MEASURED = (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float16))  # 2-byte rows
+
+
+# A branch of ``platform_dependent`` puts ``branch_0_fun`` on the name stack;
+# the scope is set inside each form, so that a reader of the ops' ``op_name``
+# paths finds it innermost whichever way the form was reached.
+_SCOPE = "embed_grad"
+
+
+def _table_grad_scatter(idx, g, rows):
+    """The transpose of the clipped take as JAX writes it: one
+    ``scatter-add`` of whole rows."""
+    table = jax.ShapeDtypeStruct((rows,) + g.shape[idx.ndim:], g.dtype)
+    take = lambda w: jnp.take(w, idx, axis=0, mode="clip")
+    with jax.named_scope(_SCOPE):
+        return jax.linear_transpose(take, table)(g)[0]
+
+
+def _table_grad_column_parts(idx, g, rows):
+    """The same sum, ``_PARTS`` column blocks of the table one after another:
+    each its own ``scatter-add`` of the same ids."""
+    with jax.named_scope(_SCOPE):
+        ids = jnp.clip(idx.reshape(-1), 0, rows - 1)
+        flat = g.reshape(ids.shape[0], -1)
+        width = flat.shape[1] // _PARTS
+        blocks = [jnp.zeros((rows, width), g.dtype).at[ids].add(
+                      flat[:, lo:lo + width])
+                  for lo in range(0, flat.shape[1], width)]
+        return jnp.concatenate(blocks, axis=1).reshape(
+            (rows,) + g.shape[idx.ndim:])
+
+
+def embedding_grad_form(n, rows, dim, dtype, platform):
+    """``"column_parts"`` or ``"scatter"``: how the gradient of a ``rows`` x
+    ``dim`` table of ``dtype`` is summed from ``n`` looked-up rows on
+    ``platform``.  Two modelled costs decide a wide row: the blocks' three
+    more passes over the table, and half of what ``n`` rows cost where the
+    whole-row scatter tiles badly, since nothing says whether it will."""
+    dtype = jnp.dtype(dtype)
+    trusted = dim < _WIDE or dim & (dim - 1) == 0 or dim % _PARTS != 0
+    if platform != "tpu" or trusted or dtype not in _MEASURED:
+        # a float32 table's scatter reads the same at 2560 columns; its
+        # blocks were not timed (PERF.md section 7)
+        return "scatter"
+    passes = 3 * rows * dim * dtype.itemsize / _HBM_BYTES_S
+    return "column_parts" if passes <= n * _WORST_ROW_S / 2 else "scatter"
+
+
+def _table_grad(idx, g, rows):
+    """The gradient of the table that ``idx`` looked rows up in, from the
+    rows' cotangent ``g``: the form chosen by the operands' devices, or for
+    tracers by the platform the enclosing program is lowered for."""
+    traced = any(isinstance(t, jax.core.Tracer) for t in (idx, g))
+    platform = "tpu" if traced else next(iter(g.devices())).platform
+    dim = g.size // max(idx.size, 1)
+    form = embedding_grad_form(idx.size, rows, dim, g.dtype, platform)
+    scatter = functools.partial(_table_grad_scatter, rows=rows)
+    if form == "scatter":
+        _metrics.embedding_grad_trace("scatter")
+        return scatter(idx, g)
+    parts = functools.partial(_table_grad_column_parts, rows=rows)
+    if traced:
+        _metrics.embedding_grad_trace("column_parts_on_tpu")
+        return lax.platform_dependent(idx, g, tpu=parts, default=scatter)
+    _metrics.embedding_grad_trace("column_parts")
+    return parts(idx, g)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _lookup(rows, weight, idx):
+    # clip, not fill: jnp.take's NaN-fill default turns one rounded-up
+    # index (e.g. a bf16-cast token id at the vocab edge) into a NaN row
+    # that poisons the whole step; the reference clamps too
+    return jnp.take(weight, idx, axis=0, mode="clip")
+
+
+def _lookup_fwd(rows, weight, idx):
+    return _lookup(rows, weight, idx), idx
+
+
+def _lookup_bwd(rows, idx, g):
+    return _table_grad(idx, g, rows), np.zeros(idx.shape, jax.dtypes.float0)
+
+
+_lookup.defvjp(_lookup_fwd, _lookup_bwd)
+
+
 @register("Embedding", num_inputs=2, nograd_inputs=(0,),
           input_names=("data", "weight"),
           finfer_params=lambda ds, p: {"weight": (p.get("input_dim", 0),
@@ -255,11 +363,7 @@ def _embedding(data, weight, input_dim=0, output_dim=0, dtype="float32", sparse_
     On TPU this is a gather from HBM; the rowsparse-gradient variant of the
     reference maps to the sparse module's row-sparse grad path.
     """
-    idx = data.astype(jnp.int32)
-    # clip, not fill: jnp.take's NaN-fill default turns one rounded-up
-    # index (e.g. a bf16-cast token id at the vocab edge) into a NaN row
-    # that poisons the whole step; the reference clamps too
-    return jnp.take(weight, idx, axis=0, mode="clip")
+    return _lookup(weight.shape[0], weight, data.astype(jnp.int32))
 
 
 @register("one_hot", num_inputs=1, differentiable=False)

@@ -801,6 +801,21 @@ def dropout_mask_trace(op):
                       ("op",)).inc(op=op)
 
 
+def embedding_grad_trace(path):
+    """One trace of ``Embedding``'s backward (``ops.tensor._table_grad``),
+    labeled by the form the table's gradient took: ``scatter`` (one
+    ``scatter-add`` of whole rows, the transpose of the lookup),
+    ``column_parts`` (a ``scatter-add`` a quarter of the row at a time,
+    concrete operands on a TPU) or ``column_parts_on_tpu`` (traced operands:
+    the column blocks where the enclosing program is lowered for a TPU, the
+    whole-row scatter elsewhere)."""
+    if not enabled():
+        return
+    _REGISTRY.counter("graft_embedding_grad_traces_total",
+                      "Traces of Embedding's backward by the form of the "
+                      "table's gradient", ("path",)).inc(path=path)
+
+
 def cachedop_recorded(residual_bytes):
     """One forward of a hybridized Block under ``autograd.record()``: it
     took the vjp once and kept, for its backward, ``residual_bytes`` of
