@@ -80,6 +80,15 @@ class MultiHeadAttention(HybridBlock):
         ``factor``, ``original_max_position`` and optionally ``beta_fast``,
         ``beta_slow``, ``attention_factor``, as ``_contrib_RotaryEmbedding``
         takes them under ``scaling``.  None: the plain frequencies.
+    gate : bool
+        Gated attention (Arcee's AFMoE, Trinity-Mini): a fifth projection
+        ``gate_`` of the layer's input, ``units -> num_heads * head_dim``
+        without bias, whose sigmoid multiplies the heads' outputs, laid out
+        (B, S, H * D), before ``proj_out``: ``out = W_o (sigmoid(W_g x) *
+        [o_1 ... o_H])``.  Self-attention only.  Staged under the scope
+        ``attn_gate`` (projection, sigmoid and product) and counted by
+        ``graft_attention_gate_traces_total``.  False (the default): no such
+        parameter and the program the layer staged before.
 
     With everything after ``fused_qkv`` left at its default the layer stages
     the program it staged before those arguments existed (the chip
@@ -87,6 +96,9 @@ class MultiHeadAttention(HybridBlock):
     ``lfm2moe_fused_s8192`` runs 32 query heads over 8 K/V heads of 64 with
     ``qk_norm`` and base 1e6; ``mellum2_fused_s8192`` 32 over 4 of 128 at
     width 2304, three layers with ``window=1024`` to one with YaRN;
+    ``trinitymini_gated_fused_1row`` 32 over 4 of 128 with ``gate`` and
+    ``qk_norm``, four layers with ``window=2048`` and base 1e4 to one with
+    no positions at all (``rotary_base=None``);
     ``kimivl_mla_fused_1row`` runs ``LatentAttention`` below, through the
     same kernel call.
     """
@@ -95,7 +107,7 @@ class MultiHeadAttention(HybridBlock):
                  use_bias=True, fused_qkv=False, weight_initializer=None,
                  num_kv_heads=None, qk_norm=False, rotary_base=None,
                  qk_norm_epsilon=1e-5, head_dim=None, window=None,
-                 rotary_scaling=None, **kwargs):
+                 rotary_scaling=None, gate=False, **kwargs):
         super().__init__(**kwargs)
         if head_dim is None and units % num_heads:
             raise ValueError("units (%d) must be divisible by num_heads (%d)"
@@ -153,6 +165,12 @@ class MultiHeadAttention(HybridBlock):
                                       in_channels=head_dim, prefix="q_norm_")
                 self.k_norm = RMSNorm(epsilon=qk_norm_epsilon,
                                       in_channels=head_dim, prefix="k_norm_")
+            self.proj_gate = None
+            if gate:
+                self.proj_gate = Dense(q_units, flatten=False,
+                                       use_bias=False,
+                                       weight_initializer=weight_initializer,
+                                       prefix="gate_")
 
     def _split_heads(self, F, x, B, S, heads=None, norm=None,
                      positions=False):
@@ -173,6 +191,10 @@ class MultiHeadAttention(HybridBlock):
         if self._fused_qkv and (key is not None or value is not None):
             raise ValueError("fused_qkv supports self-attention only "
                              "(pass just the query)")
+        if self.proj_gate is not None and (key is not None
+                                           or value is not None):
+            raise ValueError("the gate is of the layer's own input: "
+                             "self-attention only (pass just the query)")
         key = query if key is None else key
         value = key if value is None else value
         B, S = query.shape[0], query.shape[1]
@@ -214,6 +236,11 @@ class MultiHeadAttention(HybridBlock):
         out = F.transpose(out, axes=(0, 2, 1, 3))
         out = F.reshape(out, shape=(B, S,
                                     self._num_heads * self._head_dim))
+        if self.proj_gate is not None:
+            _metrics.attention_gate_trace()
+            with jax.named_scope("attn_gate"):
+                out = out * F.Activation(self.proj_gate(query),
+                                         act_type="sigmoid")
         return self.proj_out(out)
 
 

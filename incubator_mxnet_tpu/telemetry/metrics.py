@@ -772,6 +772,17 @@ def moe_shared_trace():
                       "expert").inc()
 
 
+def attention_gate_trace():
+    """One trace of a ``MultiHeadAttention`` forward with ``gate=True``: the
+    sigmoid of a projection of the layer's input multiplied onto the heads'
+    outputs before ``proj_out``."""
+    if not enabled():
+        return
+    _REGISTRY.counter("graft_attention_gate_traces_total",
+                      "MultiHeadAttention forward traces with an output "
+                      "gate").inc()
+
+
 def moe_dispatch_trace(path):
     """One trace of ``parallel.moe.ExpertParallelMoE``'s routed forward,
     labeled by its dispatch mode (``dense`` / ``grouped``): beside

@@ -248,6 +248,16 @@ def _loss_of(window):
     return jax.grad(loss, argnums=(0, 1, 2))
 
 
+def _kernels_of_the_grad(window, spec):
+    """The names of the Mosaic calls, sorted, in the gradient of
+    ``flash_attention`` compiled for q, k, v of ``spec``."""
+    text = jax.jit(_loss_of(window)).lower(spec, spec, spec).compile(
+        ).as_text()
+    return sorted(re.match(r"\s*(?:ROOT )?%(\S+) =", line).group(1).split(
+        ".")[0] for line in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line)
+
+
 def test_window_grad_compiles_to_three_named_kernels_at_the_mellum_cells_shape(
         topo, no_compile_cache):
     """(1, 32, 8192, 128) bf16 under a window of 1024: Mosaic takes the three
@@ -256,13 +266,25 @@ def test_window_grad_compiles_to_three_named_kernels_at_the_mellum_cells_shape(
     spec = jax.ShapeDtypeStruct(
         (1, 32, 8192, 128), jnp.bfloat16,
         sharding=SingleDeviceSharding(topo.devices[0]))
-    text = jax.jit(_loss_of(1024)).lower(spec, spec, spec).compile().as_text()
-    names = [re.match(r"\s*(?:ROOT )?%(\S+) =", line).group(1)
-             for line in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
-    assert sorted(n.split(".")[0] for n in names) == [
+    assert _kernels_of_the_grad(1024, spec) == [
         "flash_window_bwd_dkv", "flash_window_bwd_dq", "flash_window_pallas"]
-    assert "flash_attention_pallas" not in " ".join(names)
+
+
+@pytest.mark.parametrize("window, names", [
+    (2048, ["flash_window_bwd_dkv", "flash_window_bwd_dq",
+            "flash_window_pallas"]),
+    (None, ["flash_attention_bwd_dkv", "flash_attention_bwd_dq",
+            "flash_attention_pallas"])])
+def test_grad_compiles_at_the_gated_cells_shapes(topo, no_compile_cache,
+                                                 window, names):
+    """(1, 32, 4096, 128) bf16, ``trinitymini_gated_fused_1row``'s row: under
+    a window of 2048, half the row (its four sliding layers), and without one
+    (its full layer), Mosaic takes three kernels of the kind's names."""
+    from jax.sharding import SingleDeviceSharding
+    spec = jax.ShapeDtypeStruct(
+        (1, 32, 4096, 128), jnp.bfloat16,
+        sharding=SingleDeviceSharding(topo.devices[0]))
+    assert _kernels_of_the_grad(window, spec) == names
 
 
 # sha256 of each kernel's Mosaic module printed without debug locations, as

@@ -378,3 +378,118 @@ def test_host_table_is_exact_far_out_and_factor_one_is_plain_rope():
         "attention_factor": 1.0})
     np.testing.assert_allclose(np.asarray(one),
                                np.asarray(op(x, base=500000.0)), atol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# the gate (PR 40): off it is the layer the three cells stage, on it is the
+# ungated layer's kernel output under a sigmoid of the layer's input
+# ---------------------------------------------------------------------------
+
+_PLAIN_ROPE = {"factor": 1.0, "original_max_position": 131072,
+               "attention_factor": 1.0}
+# ``MultiHeadAttention`` as the OPT, LFM2 and Mellum2 configurations build it,
+# at their files' rehearsal widths on (2, 128, 64), with the parameters'
+# shapes and the sha256 (16 digits) of the StableHLO text of its forward and
+# of the gradient of its sum as commit e500d6e, the parent of the PR that
+# brought the gate, lowered them on the CPU.  A PR that changes what those
+# layers stage on purpose records the digests anew; one that adds a keyword
+# beside them may not move them.
+STAGED_WITHOUT_A_GATE = {
+    "opt6b7": (dict(units=64, num_heads=2, causal=True, use_bias=True),
+               "26d4f67dea4d089e"),
+    "lfm2moe": (dict(units=64, num_heads=4, causal=True, use_bias=False,
+                     num_kv_heads=2, qk_norm=True, qk_norm_epsilon=1e-5,
+                     rotary_base=1e6), "97196d9c21e49001"),
+    "mellum2_window": (dict(units=64, num_heads=8, causal=True,
+                            use_bias=False, num_kv_heads=1, rotary_base=5e5,
+                            head_dim=16, rotary_scaling=_PLAIN_ROPE,
+                            window=32), "2cef21df3e05d3d1"),
+    "mellum2_full": (dict(units=64, num_heads=8, causal=True, use_bias=False,
+                          num_kv_heads=1, rotary_base=5e5, head_dim=16,
+                          rotary_scaling=MELLUM_YARN), "df65bba18f71148f"),
+}
+
+
+def _staged(**kw):
+    """(the layer, {parameter: shape}, the digest of what it lowers to)."""
+    import hashlib
+    from incubator_mxnet_tpu.gluon.block import functionalize
+    attn = nn.MultiHeadAttention(kw.pop("units"), kw.pop("num_heads"),
+                                 prefix="attn_", **kw)
+    attn.initialize(mx.init.Normal(0.1))
+    x = np.zeros((2, 128, 64), np.float32)
+    fn, params = functionalize(attn, x, train=True)
+    fwd = jax.jit(fn).lower(params, x).as_text()
+    bwd = jax.jit(jax.grad(lambda p, a: fn(p, a).sum())).lower(
+        params, x).as_text()
+    return (attn, {n: tuple(v.shape) for n, v in params.items()},
+            hashlib.sha256((fwd + bwd).encode()).hexdigest()[:16])
+
+
+@pytest.mark.parametrize("cell", sorted(STAGED_WITHOUT_A_GATE))
+def test_without_the_gate_the_layer_stages_the_program_it_staged(cell):
+    kw, digest = STAGED_WITHOUT_A_GATE[cell]
+    attn, shapes, staged = _staged(**kw)
+    assert attn.proj_gate is None
+    assert not [n for n in shapes if "gate" in n]
+    assert _staged(gate=False, **kw)[1:] == (shapes, staged)
+    assert staged == digest
+    # and the gate is one parameter more, another program
+    _, gated, other = _staged(gate=True, **kw)
+    width = kw["num_heads"] * kw.get("head_dim",
+                                     kw["units"] // kw["num_heads"])
+    assert {n: s for n, s in gated.items() if n not in shapes} == {
+        "attn_gate_weight": (width, 64)}
+    assert other != digest
+
+
+@pytest.mark.parametrize("window,base", [(None, None), (24, 10000.0)])
+def test_the_gate_is_a_sigmoid_of_the_input_on_the_kernels_output(window,
+                                                                  base):
+    """8 query heads over 2 K/V heads of 16 at a width of 48 with QK-norm,
+    as Trinity-Mini's full layers (no positions) and sliding ones: forward
+    and every gradient agree with the gate applied by hand to what the
+    ungated layer hands ``proj_out``."""
+    from incubator_mxnet_tpu.gluon.block import functionalize
+    units, heads, kv, dim, seq = 48, 8, 2, 16, 40
+
+    def make(gate):
+        mx.random.seed(11)
+        attn = nn.MultiHeadAttention(
+            units, heads, causal=True, use_bias=False, num_kv_heads=kv,
+            head_dim=dim, qk_norm=True, rotary_base=base, window=window,
+            gate=gate, prefix="a_")
+        attn.initialize(mx.init.Normal(0.3))
+        return attn
+
+    x = np.random.RandomState(2).randn(2, seq, units).astype(np.float32)
+    fn, params = functionalize(make(True), x, train=True)
+    plain_fn, plain = functionalize(make(False), x, train=True)
+    assert set(params) - set(plain) == {"a_gate_weight"}
+    assert params["a_gate_weight"].shape == (heads * dim, units)
+    w_out = params["a_out_weight"]
+
+    def by_hand(p, a):
+        # the ungated layer with an identity in proj_out's place hands over
+        # the heads' outputs, (B, S, H * D)
+        shared = {n: p[n] for n in plain if n != "a_out_weight"}
+        ctx = plain_fn(dict(shared, a_out_weight=jnp.eye(
+            heads * dim, dtype=w_out.dtype)), a)
+        gate = jax.nn.sigmoid(a @ p["a_gate_weight"].T)
+        return (gate * ctx) @ p["a_out_weight"].T
+
+    got, vjp = jax.vjp(fn, params, jnp.asarray(x))
+    want, want_vjp = jax.vjp(by_hand, params, jnp.asarray(x))
+    assert _close(got, want)
+    g = jnp.asarray(np.random.RandomState(3).randn(*got.shape), jnp.float32)
+    (got_p, got_x), (want_p, want_x) = vjp(g), want_vjp(g)
+    assert _close(got_x, want_x, 1e-4)
+    for name in params:
+        assert _close(got_p[name], want_p[name], 1e-4), name
+    # a gate of one half everywhere is half the ungated layer
+    halved = fn(dict(params, a_gate_weight=jnp.zeros_like(
+        params["a_gate_weight"])), jnp.asarray(x))
+    assert _close(halved, 0.5 * plain_fn(
+        {n: params[n] for n in plain}, jnp.asarray(x)))
+    with pytest.raises(ValueError, match="self-attention only"):
+        make(True)(mx.nd.array(x), mx.nd.array(x))
