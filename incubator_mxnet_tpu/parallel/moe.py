@@ -35,6 +35,22 @@ Three dispatch modes behind one module interface:
   chips add it through the exchange; on one chip the layer computes its
   share (the chip benchmark's cell ``lfm2moe_fused_s8192``: experts 0–7 of
   32, top-4).  Compute ∝ the assignments held, independent of num_experts.
+  **The tokens in parts** (PR 41): a row of tokens whose buffer would be
+  larger than ``_PART_BYTES`` (96 MiB) goes through the layer in the fewest
+  equal parts that bring it under, each part the whole dropless layer over
+  its tokens with a buffer of its own (N / p x top_k rows), the outputs
+  concatenated and ``group_sizes`` summed; a token's output does not depend
+  on which tokens share its buffer, so this is the same result, and since a
+  part has a row for each of its assignments nothing can overflow (PR 31's
+  bounded buffer cut the *rows* and ran a tail for the overflow).  Why: the
+  TPU's compiler never gives an array of 128 MiB or more its second memory
+  space (``S(1)`` in a layout), and XLA's gather over a buffer of 134 MB
+  moved a row in 34.5 ns where over Kimi-VL's 100.7 MB it took 6.3-9.1
+  (PERF.md, S16 (a)).  The parts come from the shapes and the rows' dtype
+  alone (``_parts``: Kimi-VL's row of 4096 one, LFM2's and Trinity-Mini's
+  two, Mellum2's four), no argument and no switch; the experts' weights
+  reach the parts in float32 so that their gradient is summed over the
+  parts in float32 and rounded once, as one part's is.
 
 Routing, shared by ``dense`` and ``grouped`` (``_route``): ``router`` says
 how scores are made of the gate's logits (``"softmax"``, or ``"sigmoid"``
@@ -56,7 +72,9 @@ and, at top-8 of 64 by softmax with experts 0-7 held, in
 (``tests/test_model_parallel.py``, ``tests/test_moe_capacity.py``); no
 benchmark cell runs them, and ``capacity`` needs a mesh with an ``ep`` axis.
 On eager calls the layer counts itself: ``graft_moe_dispatch_traces_total
-{path}`` every routed forward traced, and for ``grouped``
+{path}`` every routed forward traced (beside it the gauges
+``graft_moe_buffer_parts`` and ``graft_moe_buffer_part_bytes`` of the last
+``grouped`` call traced), and for ``grouped``
 ``graft_moe_assignments_total{held}`` and the gauge
 ``graft_moe_expert_load_max_over_mean`` (``last_expert_load`` has the
 counts).  Inside a compiled step the same counts go out with the step's
@@ -229,7 +247,7 @@ def _kernel_or_ragged(operands, kernel, ragged):
 
 
 def _ragged(lhs, rhs, group_sizes):
-    return lax.ragged_dot(lhs, rhs, group_sizes,
+    return lax.ragged_dot(lhs, rhs.astype(lhs.dtype), group_sizes,
                           preferred_element_type=lhs.dtype)
 
 
@@ -251,13 +269,19 @@ def _gmm(lhs, rhs, group_sizes):
     megablox = _megablox()
     k, n = rhs.shape[1:]
     with jax.named_scope(_SCOPE):
-        return megablox.gmm(lhs, rhs, group_sizes, lhs.dtype,
+        return megablox.gmm(lhs, rhs.astype(lhs.dtype), group_sizes,
+                            lhs.dtype,
                             (_row_tile(lhs.shape[0]), _whole(k), _tile(n)))
 
 
 def _ragged_bwd(lhs, rhs, group_sizes, g):
-    _, pullback = jax.vjp(lambda a, b: _ragged(a, b, group_sizes), lhs, rhs)
-    return pullback(g)
+    # in ``rhs``'s dtype, the rows widened to it where it is the wider: the
+    # weights' cotangent leaves as it was accumulated (``grouped_dot``)
+    def product(a, b):
+        return lax.ragged_dot(a.astype(b.dtype), b, group_sizes,
+                              preferred_element_type=b.dtype)
+    _, pullback = jax.vjp(product, lhs, rhs)
+    return pullback(g.astype(rhs.dtype))
 
 
 def _gmm_bwd(lhs, rhs, group_sizes, g):
@@ -265,7 +289,7 @@ def _gmm_bwd(lhs, rhs, group_sizes, g):
     k, n = rhs.shape[1:]
     rows = _row_tile(lhs.shape[0])
     with jax.named_scope(_SCOPE):
-        d_lhs = megablox.gmm(g, rhs, group_sizes, lhs.dtype,
+        d_lhs = megablox.gmm(g, rhs.astype(lhs.dtype), group_sizes, lhs.dtype,
                              (rows, _whole(n), _tile(k)), transpose_rhs=True)
         d_rhs = megablox.tgmm(lhs.swapaxes(0, 1), g, group_sizes, rhs.dtype,
                               (rows, _tile(k), _tile(n)))
@@ -278,7 +302,11 @@ def grouped_dot(lhs, rhs, group_sizes):
     the rows of group g: rows [0, sum(group_sizes)) lie in groups, in
     order; what is left in the rows behind them is unspecified (the kernel
     does not visit them), so a caller masks them.  f32 accumulation, the
-    result in lhs's dtype."""
+    result in lhs's dtype.  ``rhs`` may be wider than the rows (float32
+    beside bf16 rows): the products take it in the rows' dtype and its
+    cotangent leaves in its own, so that a caller who multiplies one
+    ``rhs`` with several ``lhs`` gets the sum of their cotangents as it was
+    accumulated, rounded by whoever narrowed ``rhs``, once."""
     return _kernel_or_ragged((lhs, rhs, group_sizes), _gmm, _ragged)
 
 
@@ -402,12 +430,61 @@ def _to_tokens_bwd(res, g):
 _to_tokens.defvjp(_to_tokens_fwd, _to_tokens_bwd)
 
 
+# The largest buffer of assignments a part of the tokens may have (module
+# docstring, "the tokens in parts"): under the 128 MiB from which on the
+# TPU's compiler gives an array no second memory space and XLA's gather
+# moves a row in 34.5 ns for 8-12, and at or above Kimi-VL's 100,663,296 B,
+# whose layer stays one part and its step the program it was.
+_PART_BYTES = 96 << 20
+
+
+def _parts(n, k, d, itemsize):
+    """The fewest equal parts of ``n`` tokens, ``k`` choices each, whose
+    buffer of (n / parts) x k rows of ``d`` elements has at most
+    ``_PART_BYTES`` and still whole row tiles where the buffer of all
+    tokens has them; where no split fits, the finest that keeps them."""
+    tile = _row_tile(n * k) or 1
+    whole = [p for p in range(1, n + 1)
+             if n % p == 0 and (n // p * k) % tile == 0]
+    return next((p for p in whole
+                 if n // p * k * d * itemsize <= _PART_BYTES), whole[-1])
+
+
 def grouped_moe_apply(x, chosen, weights, w1, w3, w2, first):
     """Dropless dispatch of ``x`` (N, d) to the experts ``first ..
     first + w1.shape[0] - 1``: ``chosen`` (N, k) and ``weights`` (N, k)
     from ``_route``.  Returns the weighted sum (N, d) over the held experts
     among each token's chosen ones, in x's dtype, and the assignments each
     held expert got, (count,) int32.
+
+    The tokens go through in ``_parts`` equal parts, by their shapes alone:
+    a token's output does not depend on which tokens share its buffer, so
+    every part is the whole layer over its tokens (``_grouped_part``), no
+    row can overflow and nothing is bounded.  The experts' weights reach
+    the parts in float32, so that their gradient is the parts' float32
+    products summed in float32 and rounded once, as one part's is."""
+    n, k = chosen.shape
+    row_bytes = x.shape[1] * x.dtype.itemsize
+    parts = _parts(n, k, x.shape[1], x.dtype.itemsize)
+    _metrics.moe_buffer_parts(parts, n // parts * k * row_bytes)
+    if parts == 1:
+        out, load = _grouped_part(x, chosen, weights, w1, w3, w2, first)
+        return out.astype(x.dtype), load
+    w1, w3, w2 = (w if w is None else w.astype(jnp.float32)
+                  for w in (w1, w3, w2))
+    outs, loads = zip(*(
+        _grouped_part(*of_part, w1, w3, w2, first)
+        for of_part in zip(*(jnp.split(a, parts)
+                             for a in (x, chosen, weights)))))
+    # joined in float32 and narrowed once, as one part's sum is: with each
+    # part narrowed before the join Trinity-Mini's whole step compiled to
+    # 52 MB more of temporaries than with one part, so to 30 MB fewer
+    return jnp.concatenate(outs).astype(x.dtype), sum(loads)
+
+
+def _grouped_part(x, chosen, weights, w1, w3, w2, first):
+    """``grouped_moe_apply`` over tokens that share one buffer, the
+    weighted sum still in float32.
 
     The A = N·k assignments, numbered choice × N + token, are sorted by
     held expert, those of absent experts behind them; both ways the rows
@@ -435,7 +512,7 @@ def grouped_moe_apply(x, chosen, weights, w1, w3, w2, first):
         # token's choices: the weights meet the rows where both lie in the
         # router's order, so no scalar is gathered
         out = _to_tokens(ys, weights.T, slot, order)
-    return out.astype(x.dtype), group_sizes
+    return out, group_sizes
 
 
 class ExpertParallelMoE(HybridBlock):

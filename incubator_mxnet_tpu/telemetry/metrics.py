@@ -795,6 +795,21 @@ def moe_dispatch_trace(path):
                       ("path",)).inc(path=path)
 
 
+def moe_buffer_parts(parts, part_bytes):
+    """One trace of ``parallel.moe.grouped_moe_apply``: the equal parts its
+    tokens went through the layer in (1: the buffer of all assignments is
+    under the layer's limit) and the bytes of a part's buffer, both from
+    shapes.  The last traced call's, as ``graft_flash_blocks_visited``."""
+    if not enabled():
+        return
+    _REGISTRY.gauge("graft_moe_buffer_parts",
+                    "Equal parts of its tokens the last traced grouped "
+                    "dispatch ran in").set(parts)
+    _REGISTRY.gauge("graft_moe_buffer_part_bytes",
+                    "Bytes of a part's buffer of assignments, last traced "
+                    "grouped dispatch").set(part_bytes)
+
+
 def dropout_mask_trace(op):
     """One trace of ``ops.nn.inverted_dropout``, the function that draws
     every dropout mask, labeled by the operator that asked (``Dropout`` /

@@ -533,23 +533,35 @@ def no_compile_cache():
     cc.reset_cache()
 
 
-@pytest.mark.parametrize("config, traffic, router, shape, buffer_rows", [
-    (CONFIG, "fused_s8192", "sigmoid", (8192, 2048, 1792, 8, 32), 32768),
-    # PR 30: top-8 of 64 by softmax, a buffer of tokens x 8 rows
-    ("mellum2_12b_a2b5_ep8_l4", "fused_s8192", "softmax",
-     (8192, 2304, 896, 8, 64), 65536),
-    # PR 32's cell: top-6 of 64 by sigmoid over a row of 4096
-    ("kimi_vl_a3b_ep8_l5", "fused_s4096", "sigmoid",
-     (4096, 2048, 1408, 8, 64), 24576)])
+@pytest.mark.parametrize(
+    "config, traffic, router, shape, buffer_rows, parts", [
+        (CONFIG, "fused_s8192", "sigmoid", (8192, 2048, 1792, 8, 32), 32768,
+         2),
+        # PR 30: top-8 of 64 by softmax, a buffer of tokens x 8 rows
+        ("mellum2_12b_a2b5_ep8_l4", "fused_s8192", "softmax",
+         (8192, 2304, 896, 8, 64), 65536, 4),
+        # PR 32's cell: top-6 of 64 by sigmoid over a row of 4096; its
+        # buffer of 100.7 MB is under the layer's limit and stays whole
+        ("kimi_vl_a3b_ep8_l5", "fused_s4096", "sigmoid",
+         (4096, 2048, 1408, 8, 64), 24576, 1),
+        # PR 40's cell: top-8 of 128 by sigmoid, LFM2's buffer to the byte
+        # over Kimi-VL's row of 4096
+        ("trinity_mini_ep16_l5", "fused_s4096", "sigmoid",
+         (4096, 2048, 1024, 8, 128), 32768, 2)])
 def test_routed_layer_compiles_for_the_chip_at_the_cells_shape(
         bench_catalog, one_chip, no_compile_cache, config, traffic, router,
-        shape, buffer_rows):
+        shape, buffer_rows, parts):
     """Forward and backward of the grouped dispatch over a cell's row of
     tokens with experts 0-7 held, in bf16 (LFM2: top-4 of 32; Mellum2:
-    top-8 of 64; Kimi-VL: top-6 of 64): the products are the Pallas grouped
-    matmul (``gmm``, ``tgmm``), not XLA's expansion of ``ragged_dot``, no
-    row moves by a scatter, no pass masks the buffer at the tokens' width,
-    and no view of the buffer by choice is written out."""
+    top-8 of 64; Kimi-VL: top-6 of 64; Trinity-Mini: top-8 of 128): the
+    products are the Pallas grouped matmul (``gmm``, ``tgmm``), not XLA's
+    expansion of ``ragged_dot``, no row moves by a scatter, no pass masks
+    the buffer at the tokens' width, and no view of the buffer by choice is
+    written out.  PR 41: a buffer over the layer's limit is worked in
+    ``parts`` parts of the tokens, every count a part, nothing of the whole
+    buffer's shape is written, and the compiler gives kernel results and
+    sums of a part's shape the second memory space (``S(1)`` in a layout)
+    that it gives no array of 128 MiB."""
     import jax
     import jax.numpy as jnp
     from incubator_mxnet_tpu.parallel import moe
@@ -560,6 +572,8 @@ def test_routed_layer_compiles_for_the_chip_at_the_cells_shape(
     held, experts = sizes["num_experts"], sizes["num_experts_published"]
     assert (tokens, d, h, held, experts) == shape
     assert tokens * sizes["num_experts_per_tok"] == buffer_rows
+    assert moe._parts(tokens, sizes["num_experts_per_tok"], d, 2) == parts
+    part_rows = buffer_rows // parts
 
     def spec(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -569,7 +583,8 @@ def test_routed_layer_compiles_for_the_chip_at_the_cells_shape(
             x, gate, bias if router == "sigmoid" else None,
             top_k=sizes["num_experts_per_tok"],
             router=router, norm_topk=True,
-            scaling=float(sizes.get("routed_scaling_factor", 1.0)))
+            scaling=float(sizes.get("routed_scaling_factor",
+                                    sizes.get("route_scale", 1.0))))
         out, _ = moe.grouped_moe_apply(x, chosen, weights, w1, w3, w2, 0)
         return jnp.sum(jnp.square(out.astype(jnp.float32)))
 
@@ -583,24 +598,46 @@ def test_routed_layer_compiles_for_the_chip_at_the_cells_shape(
              if "tpu_custom_call" in line and " custom-call(" in line]
     names = [line.split("=")[0].strip().lstrip("%").split(".")[0]
              for line in calls]
-    # three products forward, three for the rows and three for the weights
-    assert names.count("gmm") == 6 and names.count("tgmm") == 3, names
+    # a part: three products forward, three for the rows and three for the
+    # weights
+    assert (names.count("gmm"), names.count("tgmm")) == (
+        6 * parts, 3 * parts), names
     big_scatters = [line for line in text.splitlines()
                     if " scatter(" in line
-                    and "[%d,%d]" % (buffer_rows, d) in line]
+                    and "[%d,%d]" % (part_rows, d) in line]
     assert not big_scatters
     # PR 31: nothing masks the whole buffer at the tokens' width.  What the
-    # program writes of [buffer_rows, d]: the four gathers (in and back,
-    # forward and backward), the kernels' results and the sum of the two
-    # hidden products' row cotangents; the parent also wrote four masked
-    # or converted copies (``select_n`` / ``convert_element_type``)
+    # program writes of [part_rows, d], a part: the four gathers (in and
+    # back, forward and backward), the kernels' results and the sum of the
+    # two hidden products' row cotangents; the layer until PR 31 also wrote
+    # four masked or converted copies (``select_n`` /
+    # ``convert_element_type``)
     entry = text[text.index("ENTRY "):]
-    wrote = [re.search(r'op_name="[^"]*/([\w\-]+)"', line).group(1)
-             for line in entry.splitlines()
-             if re.match(r"\s*(ROOT )?%%[\w.\-]+ = bf16\[%d,%d\]\S* "
-                         r"(fusion|add|select|convert)\(" % (buffer_rows, d),
-                         line)]
-    assert sorted(wrote) == ["add_any"] + ["gather"] * 4, wrote
+
+    def written(rows, ops):
+        return [((re.findall(r'op_name="[^"]*/([\w\-]+)"', line) or [""])[0],
+                 "S(1)" in line.split(" = ")[1].split(" ")[0])
+                for line in entry.splitlines()
+                if re.match(r"\s*(ROOT )?%%[\w.\-]+ = bf16\[%d,%d\]\S* (%s)\("
+                            % (rows, d, ops), line)]
+    wrote = written(part_rows, "fusion|add|select|convert")
+    assert sorted(op for op, _ in wrote) == (
+        ["add_any"] * parts + ["gather"] * 4 * parts), wrote
+    results = [(op, fast) for op, fast in written(part_rows, "custom-call")
+               if op == "pallas_call"]
+    assert len(results) == 3 * parts            # a part: w2, and d_xs twice
+    # PR 41: with the buffer in parts, a kernel's result and a sum of a
+    # part's shape lie in the second memory space, as Kimi-VL's whole
+    # buffer's always did and no array of LFM2's, Mellum2's or Trinity-
+    # Mini's whole buffer ever did.  A gather's result does in some cells
+    # (Trinity-Mini's two forward ones, Kimi-VL's) and not in LFM2's, and
+    # on the chip the gathers of all of them move a row in 8-12 ns (PERF.md
+    # section 6, PR 41): what the placement witnesses is that the arrays
+    # are under the compiler's limit, which is what the parts are for
+    assert any(fast for _, fast in results), results
+    assert any(fast for op, fast in wrote if op == "add_any"), wrote
+    if parts > 1:
+        assert not re.search(r" = \w+\[%d,%d\]" % (buffer_rows, d), entry)
     # PR 35: the assignments lie choice-major, so a view of the buffer by
     # choice splits its leading dimension and is a bitcast; token-major,
     # at top-4 and top-6 (no whole sublane tile), the program wrote each
@@ -611,7 +648,7 @@ def test_routed_layer_compiles_for_the_chip_at_the_cells_shape(
         for found in [re.match(r"\s*(ROOT )?%[\w.\-]+ = \w+\[([\d,]+)\]\S* "
                                r"(reshape|broadcast|copy|transpose)\(", line)]
         if found and np.prod([int(v) for v in found.group(2).split(",")])
-        >= buffer_rows * d]
+        >= part_rows * d]
     assert not relayouts, relayouts
 
 

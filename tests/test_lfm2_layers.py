@@ -605,6 +605,141 @@ def _outputs(jaxpr):
             yield from _outputs(sub)
 
 
+# ---------------------------------------------------------------------------
+# the tokens in parts (PR 41): a buffer a part, the same result
+# ---------------------------------------------------------------------------
+
+def _skewed(parts, top_k=4, count=8, experts=32):
+    """``balanced`` choices with expert 0 empty in the first of ``parts``
+    equal parts of the tokens and chosen by every token of the last."""
+    chosen = _choices("balanced", top_k, count, experts).copy()
+    of_part = TOKENS // parts
+    chosen[:of_part][chosen[:of_part] == 0] = count + 1        # absent
+    chosen[-of_part:, 0] = 0       # a token's run starts at a multiple of 4
+    assert not (chosen[:of_part] == 0).any()
+    assert (chosen[-of_part:] == 0).sum() == of_part
+    assert all(len(set(row)) == top_k for row in chosen)
+    return jnp.asarray(chosen, jnp.int32)
+
+
+def _limit_for(parts, top_k, dtype):
+    """The limit at which ``TOKENS`` tokens go through in ``parts``."""
+    return TOKENS // parts * top_k * WIDTH * jnp.dtype(dtype).itemsize
+
+
+@pytest.mark.parametrize("parts", [2, 4])
+def test_parted_equals_one_part_in_float32(monkeypatch, parts):
+    """Value, the five cotangents and ``group_sizes`` of the layer run in 2
+    and 4 parts against one part, float32: a token's output does not
+    depend on which tokens share its buffer, and the weights' gradients are
+    the same products summed a part at a time."""
+    from incubator_mxnet_tpu.parallel import moe
+    _, args = _layer_inputs(4, 8, 32, "balanced")
+    chosen = _skewed(parts)
+    assert moe._parts(TOKENS, 4, WIDTH, 4) == 1
+    want = _value_and_gradients(moe.grouped_moe_apply, chosen, args)
+    weights = jnp.full(chosen.shape, 0.25, jnp.float32)
+    _, load = moe.grouped_moe_apply(args[0], chosen, weights, *args[2:], 0)
+    monkeypatch.setattr(moe, "_PART_BYTES", _limit_for(parts, 4, "float32"))
+    assert moe._parts(TOKENS, 4, WIDTH, 4) == parts
+    got = _value_and_gradients(moe.grouped_moe_apply, chosen, args)
+    for g, w in zip(got, want):
+        assert np.asarray(w).any()
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-6,
+                                   atol=1e-6)
+    _, parted_load = moe.grouped_moe_apply(args[0], chosen, weights,
+                                           *args[2:], 0)
+    assert np.array_equal(np.asarray(parted_load), np.asarray(load))
+    assert int(load.sum()) == int((np.asarray(chosen) < 8).sum())
+
+
+def test_parted_weight_gradients_in_bf16_are_one_rounding(monkeypatch):
+    """In bf16 the experts' weight gradients of four parts lie no farther
+    from the float32 layer's than one part's do: the parts' products leave
+    in float32, are summed in float32 and rounded once.  Rounded a part in
+    bf16 and added in bf16 they would lie farther, which the last lines
+    show on the same numbers."""
+    from incubator_mxnet_tpu.parallel import moe
+    _, args = _layer_inputs(4, 8, 32, "balanced")
+    chosen = _skewed(4)
+    exact = _value_and_gradients(moe.grouped_moe_apply, chosen, args)[3:]
+    # the router stays in float32, as ``_route`` scores
+    half = tuple(a if i == 1 else a.astype(jnp.bfloat16)
+                 for i, a in enumerate(args))
+
+    def gap(grads):
+        assert all(g.dtype == jnp.bfloat16 for g in grads)
+        return max(float(np.abs(np.asarray(g, np.float32) - np.asarray(e))
+                         .max() / np.abs(np.asarray(e)).max())
+                   for g, e in zip(grads, exact))
+
+    one = gap(_value_and_gradients(moe.grouped_moe_apply, chosen, half)[3:])
+    monkeypatch.setattr(moe, "_PART_BYTES", _limit_for(4, 4, "bfloat16"))
+    parted = _value_and_gradients(moe.grouped_moe_apply, chosen, half)
+    assert gap(parted[3:]) <= 1.1 * one
+    # every sum of the parts' weight cotangents is a float32 sum
+    jaxpr = jax.make_jaxpr(lambda *a: _value_and_gradients(
+        moe.grouped_moe_apply, chosen, a))(*half)
+    sums = [aval.dtype for primitive, aval in _outputs(jaxpr.jaxpr)
+            if primitive == "add_any" and aval.ndim == 3]
+    assert len(sums) == 3 * 3 and set(sums) == {jnp.dtype("float32")}
+    # ... and what bf16 parts added in bf16 would have given: each part's
+    # rows alone (their cotangent is the whole layer's, cos(out))
+    x = half[0]
+    naive = [jnp.zeros_like(w) for w in half[2:]]
+    monkeypatch.undo()
+    for rows in np.split(np.arange(TOKENS), 4):
+        grads = _value_and_gradients(moe.grouped_moe_apply, chosen[rows],
+                                     (x[rows],) + half[1:])[3:]
+        naive = [n + g for n, g in zip(naive, grads)]
+    assert gap(naive) > gap(parted[3:])
+
+
+@pytest.mark.parametrize("cell,shape,parts", [
+    ("kimivl_mla_fused_1row", (4096, 6, 2048), 1),
+    ("lfm2moe_fused_s8192", (8192, 4, 2048), 2),
+    ("trinitymini_gated_fused_1row", (4096, 8, 2048), 2),
+    ("mellum2_fused_s8192", (8192, 8, 2304), 4)])
+def test_parts_follow_the_buffers_bytes(cell, shape, parts):
+    """``_parts`` from the shapes and the rows' dtype alone, at the routed
+    cells' (N, k, d) in bf16: Kimi-VL's 100,663,296 B stay one part, and
+    every part is under 128 MiB with whole row tiles."""
+    from incubator_mxnet_tpu.parallel import moe
+    n, k, d = shape
+    assert 100663296 <= moe._PART_BYTES < 128 << 20
+    assert moe._parts(n, k, d, 2) == parts
+    rows = n // parts * k
+    assert rows * d * 2 <= moe._PART_BYTES and rows % 256 == 0
+    # the tier-1 shapes lie far under the limit; float32 rows are twice
+    # the bytes; a row of tokens that no split brings under it is split
+    # as finely as its row tiles allow
+    assert moe._parts(TOKENS, 4, WIDTH, 4) == 1
+    assert moe._parts(n, k, d, 4) == 2 * parts
+    assert moe._parts(512, 1, 1 << 20, 2) == 2
+
+
+def test_a_buffer_under_the_limit_is_staged_as_one_part(monkeypatch):
+    """Under the limit the layer stages ``_grouped_part`` and nothing
+    else: no slice of the tokens, no concatenation, the weights as they
+    came.  Over it, a slice of x, chosen and weights a part and one
+    concatenation of the parts' outputs."""
+    from incubator_mxnet_tpu.parallel import moe
+    chosen, (x, _, w1, w3, w2) = _layer_inputs(4, 8, 32, "balanced")
+    weights = jnp.full(chosen.shape, 0.25, jnp.float32)
+    staged, one = (str(jax.make_jaxpr(fn)(x, chosen, weights, w1, w3, w2, 0))
+                   for fn in (moe.grouped_moe_apply, moe._grouped_part))
+    assert staged == one
+    monkeypatch.setattr(moe, "_PART_BYTES", _limit_for(3, 4, "float32"))
+    # a function of its own: ``make_jaxpr`` keeps a function's trace
+    parted = jax.make_jaxpr(lambda *a: moe.grouped_moe_apply(*a, 0))(
+        x, chosen, weights, w1, w3, w2)
+    made = [(primitive, aval.shape) for primitive, aval
+            in _outputs(parted.jaxpr)]
+    assert made.count(("concatenate", (TOKENS, WIDTH))) == 1
+    assert made.count(("split", (TOKENS // 3, WIDTH))) == 3
+    assert made.count(("ragged_dot_general", (TOKENS // 3 * 4, HIDDEN))) == 6
+
+
 def test_grouped_counters_and_arguments():
     rs = np.random.RandomState(10)
     x = nd.array(rs.randn(16, 10).astype(np.float32))
@@ -637,6 +772,31 @@ def test_grouped_counters_and_arguments():
         ExpertParallelMoE(4, 8, dispatch="capacity", router="sigmoid")
     with pytest.raises(ValueError):
         ExpertParallelMoE(4, 8, router="tanh")
+
+
+def test_grouped_buffer_part_gauges(monkeypatch):
+    """``graft_moe_buffer_parts`` / ``graft_moe_buffer_part_bytes``: the
+    parts the last traced grouped call ran in and a part's bytes."""
+    from incubator_mxnet_tpu.parallel import moe
+    rs = np.random.RandomState(10)
+    x = nd.array(rs.randn(16, 10).astype(np.float32))
+    registry = mx.telemetry.registry()
+
+    def gauge(name):
+        (sample,) = registry.snapshot()[name]["samples"]
+        return sample["value"]
+
+    _, layer = _moe_pair("grouped", x, rs)
+    layer(x)
+    assert gauge("graft_moe_buffer_parts") == 1
+    assert gauge("graft_moe_buffer_part_bytes") == 16 * 3 * 10 * 4
+    monkeypatch.setattr(moe, "_PART_BYTES", 16 * 3 * 10 * 4 // 2)
+    want = layer(x).asnumpy()
+    assert gauge("graft_moe_buffer_parts") == 2
+    assert gauge("graft_moe_buffer_part_bytes") == 8 * 3 * 10 * 4
+    monkeypatch.undo()
+    np.testing.assert_allclose(layer(x).asnumpy(), want, rtol=1e-6,
+                               atol=1e-6)
 
 
 def test_grouped_trains_in_the_fused_step_in_bf16():
