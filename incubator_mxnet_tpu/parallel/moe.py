@@ -51,6 +51,18 @@ Three dispatch modes behind one module interface:
   two, Mellum2's four), no argument and no switch; the experts' weights
   reach the parts in float32 so that their gradient is summed over the
   parts in float32 and rounded once, as one part's is.
+  **The products' tiles** (PR 42; ``_tiling``, from the shapes and the
+  dtypes' sizes alone): rows in tiles of 256; the dimension a ``gmm``
+  contracts whole up to 2048 (``_whole``); a weight dimension that a kernel
+  writes in the largest multiple of 128 up to 1024 that divides it, and
+  never under 512 where the dimension is that wide (``_tile``): the
+  kernels' grids put the column tile outermost and fetch the rows again
+  for each.  Kimi-VL's 1408 = 11 x 128 ran in eleven tiles of 128; it now
+  runs whole where the kernel's blocks fit the 16 MiB of VMEM by
+  ``_block_bytes`` (all six of its narrow products do, in bf16), else in
+  two tiles of 768, the second ragged (megablox computes the 128 columns
+  past the end and drops them).  A column tile changes no sum's order:
+  the results are the parent's bit for bit.
 
 Routing, shared by ``dense`` and ``grouped`` (``_route``): ``router`` says
 how scores are made of the gate's logits (``"softmax"``, or ``"sigmoid"``
@@ -68,13 +80,20 @@ alike), so where shares are added up it counts once.
 What has run on the chip: ``grouped`` alone, in ``lfm2moe_fused_s8192``
 and, at top-8 of 64 by softmax with experts 0-7 held, in
 ``mellum2_fused_s8192``, and at top-6 of 64 by sigmoid with ``scaling``
-2.446 beside a shared expert in ``kimivl_mla_fused_1row`` (PERF.md).  ``dense`` and ``capacity`` are held by the CPU tests
+2.446 beside a shared expert in ``kimivl_mla_fused_1row``, and at top-8 of
+128 in ``trinitymini_gated_fused_1row`` (PERF.md).  The weights' widths
+there and their tiles: 1792 in two of 896 (LFM2), 896 whole and 2304 in
+three of 768 (Mellum2), 1024 whole (Trinity-Mini), every 2048 in two of
+1024, and since PR 42 Kimi-VL's 1408 whole for eleven of 128.
+``dense`` and ``capacity`` are held by the CPU tests
 (``tests/test_model_parallel.py``, ``tests/test_moe_capacity.py``); no
 benchmark cell runs them, and ``capacity`` needs a mesh with an ``ep`` axis.
 On eager calls the layer counts itself: ``graft_moe_dispatch_traces_total
 {path}`` every routed forward traced (beside it the gauges
 ``graft_moe_buffer_parts`` and ``graft_moe_buffer_part_bytes`` of the last
-``grouped`` call traced), and for ``grouped``
+``grouped`` call traced, and ``graft_moe_product_tile{product, dim}`` with
+``graft_moe_ragged_tile_traces_total{product}`` of the Pallas kernels
+traced), and for ``grouped``
 ``graft_moe_assignments_total{held}`` and the gauge
 ``graft_moe_expert_load_max_over_mean`` (``last_expert_load`` has the
 counts).  Inside a compiled step the same counts go out with the step's
@@ -220,16 +239,90 @@ def _row_tile(rows):
     return next((t for t in (256, 128) if rows % t == 0), None)
 
 
-def _tile(n):
-    """The tile of a dimension of ``n``: the largest multiple of 128 up to
-    1024 that divides it, else 128 (megablox masks a ragged last tile)."""
+def _dividing(n):
+    """The largest multiple of 128 up to 1024 that divides ``n``, else 128."""
     return next((t for t in range(1024, 0, -128) if n % t == 0), 128)
 
 
+def _tile(n, fits=None):
+    """The tile of a weight dimension that a grouped product *writes* (the
+    columns of ``gmm``'s result, both dimensions of ``tgmm``'s): the largest
+    multiple of 128 up to 1024 that divides ``n``.  Where that is under 512
+    for an ``n`` of 512 or more: all of ``n`` where it is whole lanes and
+    ``fits(n)`` says the kernel's blocks still fit the VMEM, else the
+    multiple of 128 in 512 .. 1024 whose last, ragged tile overhangs ``n``
+    least (the larger of two that overhang alike; megablox computes the
+    overhang and drops it).  Why no tile under 512: megablox's grids put
+    the column tile outermost, so every operand indexed by the row tile is
+    fetched again for each column tile.  Kimi-VL's 1408 = 11 x 128, which
+    no multiple of 128 in between divides, ran in eleven tiles of 128 until
+    PR 42 and six of a layer's nine products read their rows eleven times,
+    bound by the HBM at twice their MXU time: 0.91 ms a ``gmm`` at the
+    cell's shapes and 1.09-1.25 a ``tgmm``, for 0.49 and 0.67-0.72 in two
+    tiles of 768 (1536 columns computed, the rows read twice) and 0.45 and
+    0.58-0.64 whole (v5e, PERF.md section 6, PR 42).  1792 takes 896
+    (LFM2), 896 itself and 2304 768 (Mellum2), 1024 itself (Trinity-Mini),
+    every 2048 1024: the tiles they always had."""
+    tile = _dividing(n)
+    if n < 512 or tile >= 512:
+        return tile
+    if fits is not None and n % 128 == 0 and fits(n):
+        return n
+    return min(range(1024, 511, -128), key=lambda t: -n % t)
+
+
 def _whole(k):
-    """The contracted dimension in one tile where it is at most 2048, so
-    that a row tile's product is finished in one grid step."""
-    return k if k <= 2048 and k % 128 == 0 else _tile(k)
+    """The tile of the dimension a ``gmm`` *contracts*: all of it where it
+    is at most 2048 and whole lanes (Kimi-VL's 1408 among them), so that a
+    row tile's product is finished in one grid step; else a tile that
+    divides it (``_dividing``: Mellum2's 2304 in three of 768), so that
+    the kernel masks no ragged tile inside the accumulation."""
+    return k if k <= 2048 and k % 128 == 0 else _dividing(k)
+
+
+# What a Mosaic kernel may hold in VMEM on the v5e (the compiler's scoped
+# limit), and what one of megablox's holds by this file's reckoning: its
+# operands' and its result's blocks twice, for the pipeline, the float32
+# accumulator, and in ``tgmm`` the float32 copies of both operand blocks
+# that the kernel masks.  Held against the TPU's compiler at Kimi-VL's
+# shapes (PR 42): ``gmm`` at (256, 2048, 1408) in bf16 reckons 15.75 MiB and
+# compiles and runs, at (256, 2048, 1536) 17.0 and is refused; ``tgmm`` at
+# (256, 1024, 1408) 15.75 with a bf16 result and runs, 21.25 with a float32
+# one, (256, 1024, 1152) float32 17.75 and (256, 1024, 1664) bf16 18.25,
+# all three refused.
+_VMEM_BYTES = 16 << 20
+
+
+def _block_bytes(product, tiles, itemsize, out_itemsize):
+    """The bytes of VMEM that the kernel ``product`` holds at ``tiles``."""
+    rows, tk, tn = tiles
+    if product == "tgmm":
+        return ((2 * itemsize + 4) * rows * (tk + tn)
+                + (2 * out_itemsize + 4) * tk * tn)
+    return (2 * itemsize * (rows * tk + tk * tn)
+            + (2 * out_itemsize + 4) * rows * tn)
+
+
+def _tiling(product, rows, k, n, itemsize, out_itemsize):
+    """The tiles (row, k, n), in megablox's order, of one of the three
+    kernels of a grouped product over ``rhs`` (E, k, n): ``"gmm"``, rows
+    (A, k) to (A, n), contracts k; ``"gmm_t"``, the rows' cotangent (A, n)
+    to (A, k), contracts n; ``"tgmm"``, the weights' cotangent (E, k, n),
+    contracts the rows a row tile at a time.  From the shapes and the
+    operands' and the result's ``itemsize`` alone; the gauges
+    ``graft_moe_product_tile`` keep what was staged last."""
+    rows = _row_tile(rows)
+    first, second = (n, k) if product == "gmm_t" else (k, n)
+
+    def fits(tk, tn):
+        return _block_bytes(product, (rows, tk, tn), itemsize,
+                            out_itemsize) <= _VMEM_BYTES
+    # ``tgmm`` writes both: each takes its tile beside the other's
+    tk = (_tile(first, lambda t: fits(t, _tile(second)))
+          if product == "tgmm" else _whole(first))
+    tn = _tile(second, lambda t: fits(tk, t))
+    _metrics.moe_product_tile(product, (first, second), (tk, tn))
+    return rows, tk, tn
 
 
 def _kernel_or_ragged(operands, kernel, ragged):
@@ -268,10 +361,11 @@ _SCOPE = "moe_experts"
 def _gmm(lhs, rhs, group_sizes):
     megablox = _megablox()
     k, n = rhs.shape[1:]
+    size = lhs.dtype.itemsize
     with jax.named_scope(_SCOPE):
         return megablox.gmm(lhs, rhs.astype(lhs.dtype), group_sizes,
                             lhs.dtype,
-                            (_row_tile(lhs.shape[0]), _whole(k), _tile(n)))
+                            _tiling("gmm", lhs.shape[0], k, n, size, size))
 
 
 def _ragged_bwd(lhs, rhs, group_sizes, g):
@@ -287,12 +381,14 @@ def _ragged_bwd(lhs, rhs, group_sizes, g):
 def _gmm_bwd(lhs, rhs, group_sizes, g):
     megablox = _megablox()
     k, n = rhs.shape[1:]
-    rows = _row_tile(lhs.shape[0])
+    rows, size = lhs.shape[0], lhs.dtype.itemsize
     with jax.named_scope(_SCOPE):
         d_lhs = megablox.gmm(g, rhs.astype(lhs.dtype), group_sizes, lhs.dtype,
-                             (rows, _whole(n), _tile(k)), transpose_rhs=True)
+                             _tiling("gmm_t", rows, k, n, size, size),
+                             transpose_rhs=True)
         d_rhs = megablox.tgmm(lhs.swapaxes(0, 1), g, group_sizes, rhs.dtype,
-                              (rows, _tile(k), _tile(n)))
+                              _tiling("tgmm", rows, k, n, size,
+                                      rhs.dtype.itemsize))
     return d_lhs, d_rhs
 
 

@@ -810,6 +810,29 @@ def moe_buffer_parts(parts, part_bytes):
                     "grouped dispatch").set(part_bytes)
 
 
+def moe_product_tile(product, dims, tiles):
+    """One trace of a kernel of ``parallel.moe.grouped_dot`` on its Pallas
+    path (``product``: ``gmm``, the rows' cotangent ``gmm_t``, the weights'
+    ``tgmm``): the tiles its two weight dimensions were staged with,
+    megablox's ``k`` and ``n`` in that order, both from shapes.  The last
+    traced kernel's of each kind, as ``graft_moe_buffer_parts``; a tile
+    that does not divide its dimension (a ragged last tile, which the
+    kernel computes and drops) counts the trace."""
+    if not enabled():
+        return
+    gauge = _REGISTRY.gauge("graft_moe_product_tile",
+                            "Tile of a grouped product's weight dimension, "
+                            "last traced kernel of its kind",
+                            ("product", "dim"))
+    for dim, tile in zip("kn", tiles):
+        gauge.set(tile, product=product, dim=dim)
+    if any(size % tile for size, tile in zip(dims, tiles)):
+        _REGISTRY.counter("graft_moe_ragged_tile_traces_total",
+                          "Grouped-product kernel traces with a tile that "
+                          "does not divide its dimension",
+                          ("product",)).inc(product=product)
+
+
 def dropout_mask_trace(op):
     """One trace of ``ops.nn.inverted_dropout``, the function that draws
     every dropout mask, labeled by the operator that asked (``Dropout`` /

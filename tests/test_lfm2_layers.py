@@ -6,11 +6,15 @@ router's experts (the chip benchmark's cell ``lfm2moe_fused_s8192`` runs
 them all; its whole model is held to its reference in
 ``tests/test_lfm2_chip_bench.py``).
 """
+import contextlib
+import signal
+
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 import incubator_mxnet_tpu as mx
 from incubator_mxnet_tpu import gluon, nd
@@ -797,6 +801,178 @@ def test_grouped_buffer_part_gauges(monkeypatch):
     monkeypatch.undo()
     np.testing.assert_allclose(layer(x).asnumpy(), want, rtol=1e-6,
                                atol=1e-6)
+
+
+def test_grouped_product_tile_gauges():
+    """``graft_moe_product_tile{product, dim}``: the tiles the last traced
+    kernel of each kind took for megablox's k and n, from shapes, and
+    ``graft_moe_ragged_tile_traces_total{product}``, counted when one of
+    them does not divide its dimension.  The kernels are traced, as a
+    step lowered for the TPU traces them; nothing runs."""
+    from incubator_mxnet_tpu.parallel import moe
+    registry = mx.telemetry.registry()
+
+    def samples(name):
+        return {tuple(sorted(s["labels"].items())): s["value"]
+                for s in registry.snapshot().get(name, {"samples": []})[
+                    "samples"]}
+
+    def tiles(product):
+        found = samples("graft_moe_product_tile")
+        return tuple(found[(("dim", dim), ("product", product))]
+                     for dim in "kn")
+
+    def ragged():
+        found = samples("graft_moe_ragged_tile_traces_total")
+        return [found.get((("product", p),), 0)
+                for p in ("gmm", "gmm_t", "tgmm")]
+
+    def spec(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    before = ragged()
+    sizes = spec(2, dtype=jnp.int32)
+    # Kimi-VL's w1, (k, n) = (2048, 1408), in bf16: 1408 whole
+    jax.eval_shape(moe._gmm, spec(256, 2048), spec(2, 2048, 1408), sizes)
+    assert tiles("gmm") == (2048, 1408)
+    jax.eval_shape(moe._gmm_bwd, spec(256, 2048), spec(2, 2048, 1408), sizes,
+                   spec(256, 1408))
+    assert tiles("gmm_t") == (1408, 1024)
+    assert tiles("tgmm") == (1024, 1408)
+    assert ragged() == before
+    # the same weights in float32, as a layer in parts hands them over:
+    # ``tgmm``'s float32 result does not fit whole, two tiles of 768
+    jax.eval_shape(moe._gmm_bwd, spec(256, 2048),
+                   spec(2, 2048, 1408, dtype=jnp.float32), sizes,
+                   spec(256, 1408))
+    assert tiles("tgmm") == (1024, 768)
+    assert ragged() == [before[0], before[1], before[2] + 1]
+    # LFM2's w2: (1792, 2048); every tile divides
+    jax.eval_shape(moe._gmm_bwd, spec(256, 1792), spec(2, 1792, 2048), sizes,
+                   spec(256, 2048))
+    assert tiles("gmm_t") == (2048, 896)
+    assert tiles("tgmm") == (896, 1024)
+    assert ragged() == [before[0], before[1], before[2] + 1]
+
+
+# (k, n) of the routed cells' stacked weights, w1 / w3 then w2, the bytes
+# of an element of ``tgmm``'s result (4 where the tokens go in parts), and
+# the tiles (rows, k, n) in megablox's order of the three kernels over each.
+# LFM2's, Mellum2's and Trinity-Mini's are the literals of the tree before
+# PR 42: the guard that those three steps stay the programs they were
+_CELL_TILES = [
+    ("lfm2moe_fused_s8192", (2048, 1792), 4,
+     ((256, 2048, 896), (256, 1792, 1024), (256, 1024, 896))),
+    ("lfm2moe_fused_s8192", (1792, 2048), 4,
+     ((256, 1792, 1024), (256, 2048, 896), (256, 896, 1024))),
+    ("mellum2_fused_s8192", (2304, 896), 4,
+     ((256, 768, 896), (256, 896, 768), (256, 768, 896))),
+    ("mellum2_fused_s8192", (896, 2304), 4,
+     ((256, 896, 768), (256, 768, 896), (256, 896, 768))),
+    ("trinitymini_gated_fused_1row", (2048, 1024), 4,
+     ((256, 2048, 1024), (256, 1024, 1024), (256, 1024, 1024))),
+    ("trinitymini_gated_fused_1row", (1024, 2048), 4,
+     ((256, 1024, 1024), (256, 2048, 1024), (256, 1024, 1024))),
+    # PR 42: 1408 = 11 x 128 whole (it ran in eleven tiles of 128) ...
+    ("kimivl_mla_fused_1row", (2048, 1408), 2,
+     ((256, 2048, 1408), (256, 1408, 1024), (256, 1024, 1408))),
+    ("kimivl_mla_fused_1row", (1408, 2048), 2,
+     ((256, 1408, 1024), (256, 2048, 1408), (256, 1408, 1024))),
+    # ... and where the whole does not fit the VMEM (``tgmm`` with a
+    # float32 result: no cell today) in two tiles of 768, the second ragged
+    ("kimivl_in_parts", (2048, 1408), 4,
+     ((256, 2048, 1408), (256, 1408, 1024), (256, 1024, 768))),
+    ("kimivl_in_parts", (1408, 2048), 4,
+     ((256, 1408, 1024), (256, 2048, 1408), (256, 768, 1024)))]
+
+
+@pytest.mark.parametrize("cell,kn,out_itemsize,product,tiling", [
+    pytest.param(cell, kn, out_itemsize if product == "tgmm" else 2, product,
+                 tiling,
+                 id="%s-%dx%d-%s" % ((cell.split("_f")[0],) + kn + (product,)))
+    for cell, kn, out_itemsize, tilings in _CELL_TILES
+    for product, tiling in zip(("gmm", "gmm_t", "tgmm"), tilings)])
+def test_product_tiles_by_cell(cell, kn, out_itemsize, product, tiling):
+    """``_tiling`` from shapes and itemsizes alone, at the four routed cells'
+    weights in bf16: no written dimension of 512 or more in tiles under 512,
+    every tile whole lanes, the contracted dimension of a ``gmm`` in tiles
+    that divide it (no masked accumulation), and the kernel's blocks by the
+    layer's own reckoning within the VMEM a kernel gets."""
+    from incubator_mxnet_tpu.parallel import moe
+    assert moe._tiling(product, 24576, *kn, 2, out_itemsize) == tiling
+    rows, tk, tn = tiling
+    k, n = kn[::-1] if product == "gmm_t" else kn
+    for size, tile in ((k, tk), (n, tn)):
+        assert tile % 128 == 0 and tile <= max(size, 128)
+        assert tile >= min(size, 512)
+    assert product == "tgmm" or k % tk == 0
+    assert moe._VMEM_BYTES == 16 << 20
+    assert moe._block_bytes(product, tiling, 2, out_itemsize) <= 16 << 20
+
+
+@contextlib.contextmanager
+def _time_limit(seconds):
+    """Fail the test, and do not hang the run, after ``seconds``."""
+    def late(signum, frame):
+        raise TimeoutError("no result within %d s" % seconds)
+    was = signal.signal(signal.SIGALRM, late)
+    signal.alarm(seconds)
+    try:
+        yield
+    finally:
+        signal.alarm(0)
+        signal.signal(signal.SIGALRM, was)
+
+
+def test_a_ragged_column_tile_is_the_product_tile_for_tile():
+    """Megablox's three kernels in the interpreter at a tile that does not
+    divide its dimension, 1408 in two tiles of ``_tile(1408)``: over rows
+    in three uneven groups and an empty one they give ``lax.ragged_dot``
+    and its ``vjp`` to float32's rounding, and, bit for bit, what the same
+    kernels give in eleven tiles of 128: a column tile changes neither the
+    order of a sum nor a dtype, so PR 42's step is its parent's to the
+    bit."""
+    import importlib
+    from incubator_mxnet_tpu.parallel import moe
+    megablox = importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+    wide, rows, k, n = moe._tile(1408), 384, 256, 1408
+    assert 1408 % wide and wide >= 512
+    rs = np.random.RandomState(42)
+    sizes = jnp.asarray([100, 0, 180, 60], jnp.int32)
+    held = int(sizes.sum())
+    in_group = (jnp.arange(rows) < held)[:, None]
+    x = jnp.asarray(rs.randn(rows, k), jnp.float32)
+    w = jnp.asarray(rs.randn(4, k, n) / 16, jnp.float32)
+    g = jnp.asarray(rs.randn(rows, n), jnp.float32)
+
+    def ragged(x, w):
+        return lax.ragged_dot(x, w, sizes, precision="highest")
+
+    want, pullback = jax.vjp(ragged, x, w)
+    _, want_w = pullback(jnp.where(in_group, g, 0))
+
+    def kernels(tile):
+        run = dict(interpret=True)
+        return (megablox.gmm(x, w, sizes, x.dtype, (128, k, tile), **run),
+                megablox.gmm(g[:, :k], w.swapaxes(1, 2), sizes, x.dtype,
+                             (128, k, tile), transpose_rhs=True, **run),
+                megablox.tgmm(x.T, g, sizes, x.dtype, (128, k, tile), **run),
+                megablox.tgmm(g.T, x, sizes, x.dtype, (128, tile, k), **run))
+
+    with _time_limit(240):
+        got, narrow = kernels(wide), kernels(128)
+    for a, b in zip(got, narrow):
+        a, b = (v[:held] if v.ndim == 2 else v for v in (a, b))
+        assert np.isfinite(a).all()
+        np.testing.assert_array_equal(a, b)
+    # the rows' cotangent through a weight whose *rows* are the 1408
+    _, pullback_t = jax.vjp(
+        lambda h: ragged(h, w.swapaxes(1, 2)), jnp.zeros((rows, n)))
+    for a, b in ((got[0][:held], want[:held]),
+                 (got[1][:held], pullback_t(g[:, :k])[0][:held]),
+                 (got[2], want_w), (got[3], want_w.swapaxes(1, 2))):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
 
 
 def test_grouped_trains_in_the_fused_step_in_bf16():
