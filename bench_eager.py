@@ -51,12 +51,6 @@ at < 2% overhead) and, via an 8-device child process, the ZeRO-1
 sharded update (``GRAFT_SHARD_OPTIMIZER=1`` — byte-parity with the
 unsharded ctx-0 replica, per-shard optimizer-state bytes ~1/N).
 
-Round 17 (graftguard) adds ``compile_check_overhead_pct``: the compiled
-whole-step path (graftstep) timed with the EH3xx runtime auditor armed
-(guard-key bookkeeping, bake-hash recheck, donated-buffer poisoning and
-sweep — but NO sentinel replay) vs off.  Same < 2% bar; the off mode
-additionally asserts the hot-path flag is a cached list-index load.
-
 Round 20 (graftelastic) adds ``elastic_overhead_pct``: the enabled-idle
 membership fence (GRAFT_ELASTIC=1, Membership attached, no change ever
 queued).  The fence's gate — one memoized env read + an empty-deque
@@ -314,120 +308,6 @@ def _duplex_step_bench(iters=12, repeats=3, n_params=FUSED_N_PARAMS,
             pull_exposed.get("duplex", 0.0), 6),
         "duplex_pull_overlap_ratio": round(float(snap.get(
             "graft_trainer_pull_overlap_ratio", 0.0)), 4),
-    }
-
-
-def _compiled_step_bench(iters=12, repeats=3, n_params=FUSED_N_PARAMS,
-                         shape=FUSED_SHAPE, ulp_tol=16):
-    """graftstep: the whole bucketed-eager training iteration
-    (record → forward → backward → Trainer.step, dispatched as many
-    programs plus the host tape walk) vs the SAME iteration as the
-    compiled whole-step program pair (fwd+bwd → ``reduce_many`` →
-    donated fused update) over the 64-param dist_sync model the other
-    trainer benches use.  The whole iteration is timed — the compiled
-    step's claim is that the HOST work between programs (eager op
-    dispatch, tape bookkeeping, 64 per-param python hops) disappears,
-    not that any one program gets faster.  Params+states parity is
-    asserted under the documented ULP tolerance (lr rides as a traced
-    operand in the compiled program — ~1 ULP fma drift per step), and
-    the static-shape loop must show exactly ONE trace (zero retraces
-    after step 2)."""
-    import jax
-    import incubator_mxnet_tpu as mx
-    from incubator_mxnet_tpu import autograd, gluon
-    from incubator_mxnet_tpu.gluon.step_compile import max_ulp_diff
-
-    class Net(gluon.HybridBlock):
-        def __init__(self, **kw):
-            super().__init__(**kw)
-            with self.name_scope():
-                for k in range(n_params):
-                    setattr(self, "w%d" % k,
-                            self.params.get("w%d" % k, shape=shape))
-
-        def hybrid_forward(self, F, x, **ps):
-            acc = None
-            for k in range(n_params):
-                y = (ps["w%d" % k] * ps["w%d" % k] * x).sum()
-                acc = y if acc is None else acc + y
-            return acc
-
-    def build(prefix):
-        net = Net(prefix=prefix)
-        net.initialize(ctx=mx.cpu())
-        rs = np.random.RandomState(0)
-        for name in sorted(net.collect_params()):
-            p = net.collect_params()[name]
-            p.set_data(mx.nd.array(
-                rs.randn(*p.shape).astype(np.float32)))
-        tr = gluon.Trainer(net.collect_params(), "sgd",
-                           {"learning_rate": 0.01, "momentum": 0.9},
-                           kvstore=mx.kv.create("dist_sync"))
-        return net, tr
-
-    x = mx.nd.array(
-        np.random.RandomState(1).rand(*shape).astype(np.float32))
-    net_e, tr_e = build("cse")
-    net_c, tr_c = build("csc")
-    cstep = tr_c.compile_step(net_c, enabled=True)
-
-    def eager_iter():
-        with autograd.record():
-            out = net_e(x)
-        out.backward()
-        tr_e.step(1)
-
-    def compiled_iter():
-        cstep(x, batch_size=1)
-
-    # warmup: the eager arm compiles its per-op/per-bucket programs and
-    # builds its plan; the compiled arm's first call falls back eager
-    # and traces lazily, the second dispatches the compiled pair
-    for _ in range(2):
-        eager_iter()
-        compiled_iter()
-    net_e.collect_params()[sorted(net_e.collect_params())[0]] \
-        .data().asnumpy()
-    best = {"eager": float("inf"), "compiled": float("inf")}
-    for _ in range(repeats):
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            eager_iter()
-        net_e.collect_params()[sorted(net_e.collect_params())[-1]] \
-            .data().asnumpy()                    # sync
-        best["eager"] = min(best["eager"],
-                            (time.perf_counter() - t0) / iters)
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            compiled_iter()
-        net_c.collect_params()[sorted(net_c.collect_params())[-1]] \
-            .data().asnumpy()
-        best["compiled"] = min(best["compiled"],
-                               (time.perf_counter() - t0) / iters)
-    worst_ulp = 0
-    for ne, nc in zip(sorted(net_e.collect_params()),
-                      sorted(net_c.collect_params())):
-        ulp = max_ulp_diff(net_e.collect_params()[ne].data()._read(),
-                           net_c.collect_params()[nc].data()._read())
-        worst_ulp = max(worst_ulp, ulp)
-    assert worst_ulp <= ulp_tol, \
-        "compiled step diverged from bucketed-eager by %s ULP" % worst_ulp
-    assert cstep.retraces == 1, \
-        "static-shape loop retraced the compiled step (%d traces)" \
-        % cstep.retraces
-    return {
-        "compiled_step_params": n_params,
-        "compiled_step_eager_ms": round(best["eager"] * 1e3, 3),
-        "compiled_step_compiled_ms": round(best["compiled"] * 1e3, 3),
-        "compiled_step_latency_ratio": round(
-            best["compiled"] / best["eager"], 3),
-        "compiled_step_speedup": round(
-            best["eager"] / best["compiled"], 2),
-        "compiled_step_backend": jax.default_backend(),
-        "compiled_step_parity_ulp": int(worst_ulp),
-        "compiled_step_retraces": cstep.retraces,
-        "compiled_step_compiled_total": cstep.compiled_steps,
-        "compiled_step_fallback_total": cstep.fallback_steps,
     }
 
 
@@ -799,93 +679,6 @@ def _armor_overhead_bench(iters=25, repeats=2):
     }
 
 
-def _compile_check_overhead_bench(iters=50, repeats=9):
-    """graftguard inertness: the EH3xx auditor armed on the compiled
-    whole-step path (note_call/guard bookkeeping, per-dispatch bake-hash
-    recheck, donated-buffer poison + sweep; the EH304 sentinel stays off
-    — it deliberately doubles the dispatch) vs the default-off path,
-    against the same CompiledStep.  The estimator is PAIRED: every
-    iteration times one off call and one armed call back-to-back
-    (alternating which mode goes first so warm-cache ordering bias
-    cancels), and the reported figure is the median of the per-pair
-    deltas over the pooled median off time.  The auditor's cost is a
-    few us on a ~ms step while this single-core box drifts by tens of
-    percent between separately-sampled windows (scheduler stalls, GC,
-    frequency scaling) — only samples taken microseconds apart share
-    enough machine state for the difference to mean anything, and a
-    GC hit on one side of a single pair lands in that pair's delta
-    alone, where the median discards it.  The off mode must be a
-    cached flag load (memoized env read, poison map empty) and the
-    armed rounds must report ZERO findings."""
-    import os
-
-    import incubator_mxnet_tpu as mx
-    from incubator_mxnet_tpu import gluon
-    from incubator_mxnet_tpu.analysis import compile_safety as csafety
-    from incubator_mxnet_tpu.gluon import step_compile as sc
-
-    # (16, 16) params like the other overhead benches — the auditor's
-    # cost is a fixed few us per step, so a microscopic step would
-    # report an overhead % no real workload sees
-    net = sc._make_net("bench_guard_", n_params=8, shape=(16, 16))
-    sc._seed_params(net)
-    tr = gluon.Trainer(net.collect_params(), "sgd",
-                       {"learning_rate": 0.01, "momentum": 0.9},
-                       kvstore=None)
-    cstep = sc.CompiledStep(tr, net, enabled=True)
-    x = mx.nd.array(
-        np.random.RandomState(3).rand(16, 16).astype(np.float32))
-    for _ in range(3):              # kv init + lazy trace + steady state
-        cstep(x)
-    assert cstep.compiled_steps >= 1, "bench never reached compiled path"
-
-    import statistics
-
-    all_offs, deltas = [], []
-
-    def paired_round(flip):
-        """One round of `iters` off/armed pairs appended to the pools;
-        `flip` swaps which mode runs first within each pair."""
-        for i in range(iters):
-            pair = {}
-            order = (False, True) if (i + flip) % 2 == 0 else (True, False)
-            for armed in order:
-                csafety.set_enabled(True if armed else None)
-                t0 = time.perf_counter()
-                cstep(x)
-                pair[armed] = time.perf_counter() - t0
-            all_offs.append(pair[False])
-            deltas.append(pair[True] - pair[False])
-        # off = one cached flag load on the hot path
-        csafety.set_enabled(None)
-        assert not csafety._ACTIVE[0] and not csafety._POISON, \
-            "auditor left armed state behind when off"
-
-    prev_every = os.environ.pop("GRAFT_COMPILE_CHECK_EVERY", None)
-    try:
-        for armed in (True, False):              # warm both modes once
-            csafety.set_enabled(True if armed else None)
-            for _ in range(4):
-                cstep(x)
-        for r in range(repeats):
-            paired_round(r)
-        aud = cstep._auditor
-        if aud is not None and aud.storms:
-            raise AssertionError(
-                "graftguard bench: %d storm report(s) on a static-shape "
-                "loop" % aud.storms)
-    finally:
-        csafety.set_enabled(None)
-        if prev_every is not None:
-            os.environ["GRAFT_COMPILE_CHECK_EVERY"] = prev_every
-    off_med = statistics.median(all_offs)
-    pct = statistics.median(deltas) / off_med * 100.0
-    return {
-        "compile_check_steps_per_sec": round(1.0 / off_med, 1),
-        "compile_check_overhead_pct": round(pct, 2),
-    }
-
-
 def _elastic_overhead_bench(iters=30, reps=200000, n_params=8,
                             shape=(16, 16)):
     """graftelastic enabled-idle cost: a Membership is attached and
@@ -969,12 +762,6 @@ def smoke():
     res = _fused_step_bench(iters=3)
     res.update(_overlap_step_bench(iters=4, repeats=2))
     res.update(_duplex_step_bench(iters=4, repeats=2))
-    res.update(_compiled_step_bench(iters=4, repeats=2))
-    # graftstep acceptance gate: the compiled steady-state step must
-    # beat bucketed-eager by >= 1.25x (ratio <= 0.8) on this model
-    assert res["compiled_step_latency_ratio"] <= 0.8, \
-        "compiled step is not fast enough: ratio %.3f > 0.8" \
-        % res["compiled_step_latency_ratio"]
     res.update(_quant_step_bench(iters=5, repeats=2))
     # graftzero acceptance gates: int8 wire >= 3.5x below f32, the off
     # escape hatch bit-identical at < 2% overhead
@@ -991,12 +778,6 @@ def smoke():
     res.update(_blackbox_overhead_bench(iters=10, repeats=3))
     res.update(_tsan_overhead_bench(iters=8, repeats=2))
     res.update(_armor_overhead_bench(iters=25, repeats=2))
-    res.update(_compile_check_overhead_bench(iters=50, repeats=9))
-    # graftguard acceptance gate: auditor armed (no sentinel) must cost
-    # < 2% on the compiled step
-    assert res["compile_check_overhead_pct"] < 2.0, \
-        "compile-check auditor overhead %.2f%% >= 2%%" \
-        % res["compile_check_overhead_pct"]
     res.update(_elastic_overhead_bench(iters=20, reps=100000))
     # graftelastic acceptance gate: enabled-idle step fence must cost
     # < 2% on the fused step
@@ -1154,9 +935,6 @@ def main():
     # -- graftduplex: full-duplex update_on_kvstore step (round 9) -------
     duplex = _duplex_step_bench(iters=ITERS // 2)
 
-    # -- graftstep: whole-step compiled training (round 16) --------------
-    compiled = _compiled_step_bench(iters=ITERS // 2)
-
     # -- graftzero: quantized wire + ZeRO-1 sharded update (round 19) ----
     quant = _quant_step_bench(iters=ITERS // 4)
     zero = _zero_step_bench(steps=ITERS // 6)
@@ -1174,7 +952,6 @@ def main():
         **fused,
         **overlap,
         **duplex,
-        **compiled,
         **quant,
         **zero,
         **blackbox_overhead,

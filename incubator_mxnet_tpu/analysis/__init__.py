@@ -22,9 +22,7 @@ three at once, and nothing checked the contracts until a user hit them):
   package sources, run by the graftlint CLI alongside the op contracts.
 * ``compile_safety`` — graftguard (pass 5): GL3xx compile-safety lint
   over trace-eligible closures (host round-trips, traced branching,
-  constant-baked hyperparameters, donation hazards) plus the EH3xx
-  runtime retrace/donation auditor for the whole-step compiled path
-  (``GRAFT_COMPILE_CHECK=1``).
+  constant-baked hyperparameters, donation hazards).
 
 Kept import-light on purpose: ``engine.py`` imports ``engine_check`` at
 module load, long before the ops package exists; ``tsan``/``lockstep``

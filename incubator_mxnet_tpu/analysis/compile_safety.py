@@ -1,9 +1,9 @@
-"""graftguard — compile-safety lint (GL3xx, pass 5) + runtime
-retrace/donation auditor (EH301-EH304) for the whole-step compiled path.
+"""graftguard — compile-safety lint (GL3xx, pass 5) over trace-eligible
+closures.
 
-PR 16 (graftstep) made ONE donated XLA program the steady-state unit of
-training.  That buys the dispatch win the TPU-compilation papers promise
-— and introduces a hazard class none of the existing passes can see:
+A train step that is ONE donated XLA program
+(``parallel.DataParallelTrainer``), a ``CachedOp`` forward or a fused
+optimizer bucket has a hazard class the other passes cannot see:
 
 * host round-trips hiding inside traced regions (a ``.asnumpy()`` in a
   loss function turns "one program" into "one program per step plus a
@@ -11,14 +11,12 @@ training.  That buys the dispatch win the TPU-compilation papers promise
 * Python control flow on traced values (works eagerly, explodes or
   silently specializes under ``jax.jit``),
 * values baked as compile-time constants that were supposed to vary
-  (the lr/wd/rescale bug class PR 16 fixed by hand),
+  (the lr/wd/rescale bug class),
 * reads of donated buffers after dispatch (XLA aliased the memory; the
   value is gone on real hardware, and only *sometimes* gone on CPU —
-  the worst kind of latent bug),
-* guard-key churn re-tracing every step with nothing naming WHICH of
-  the eight key components moved.
+  the worst kind of latent bug).
 
-Static pass (AST, no execution) — run by ``graftlint --all``:
+The pass is static (AST, no execution) and is run by ``graftlint --all``:
 
 GL301    host materialization inside trace-eligible code: ``.asnumpy()``
          / ``.item()`` / ``.tolist()`` / ``float()/int()/bool()`` /
@@ -39,52 +37,14 @@ GL305    hyperparameter-looking scalar (lr/wd/rescale/momentum/beta/
          doesn't take effect (or forces a retrace)
 GL306    a donated buffer referenced AFTER the donating dispatch in the
          same block: XLA aliased that memory for an output
-GL307    ``compile_step`` called under an open ``autograd.record()``
-         scope (the compiled step IS the whole record/backward/step
-         triple; nesting deadlocks the tape)
 GL308    a traced function parameter used ONLY for its shape/dtype —
          shape-polymorphic input with no value use: make it a static
-         argument or add a guard-key component, or every new shape
-         retraces a program that didn't need the data at all
-
-Runtime auditor (``GRAFT_COMPILE_CHECK=1``) — instruments
-``gluon.step_compile.CompiledStep``:
-
-EH301    retrace-storm detection with guard-key DIFFING: every miss is
-         diffed component-by-component against the last key and the
-         exact churned element (input-sig / input-fmt / param-set /
-         param-meta / optimizer-sig / n-ctx / kvstore-sig /
-         bucket-bytes) is journaled to the blackbox and counted in
-         ``graft_step_retraces_total{reason}``; >= 3 misses inside an
-         8-call window raises the storm (warn by default,
-         ``GRAFT_COMPILE_CHECK_ABORT=1`` to raise)
-EH302    donated-buffer use-after-dispatch: the NDArrays whose jax
-         buffers a dispatch donates are poisoned at dispatch; any
-         ``_read`` before the replacement ``_write`` lands raises with
-         BOTH stacks (dispatch + read), tsan-style.  Poisoning follows
-         the donation CONTRACT (argument positions 0/1), not
-         ``_donation_supported()`` — so CPU CI catches what only real
-         TPUs would corrupt
-EH303    constant-bake drift: the fused-formula config scalars
-         (momentum/beta/eps/clip) are hashed into the entry at trace
-         time and re-hashed per dispatch; a changed hash under an
-         unchanged guard key means a live value is silently frozen
-         inside the compiled program
-EH304    compiled-vs-eager divergence sentinel: every
-         ``GRAFT_COMPILE_CHECK_EVERY=N`` compiled steps, the entry's
-         UN-jitted twin programs replay the same operands (same rng
-         key) and outputs/params/states must agree within
-         ``GRAFT_COMPILE_CHECK_ULPS`` (default 64 — the un-jitted twin
-         is an independent computation path, so fusion/reassociation
-         legitimately moves reduction chains a few tens of ULP)
-
-The hot-path cost when disabled is one list-index check per NDArray
-read/write (the grafttsan convention) plus one memoized env parse per
-compiled call; ``bench_eager --smoke`` gates the enabled cost < 2%.
+         argument, or every new shape retraces a program that didn't
+         need the data at all
 
 CLI: ``python -m incubator_mxnet_tpu.analysis.compile_safety --selftest``
-forces every GL301-GL308 and EH301-EH304 diagnostic through the real
-lint / compile_step paths (lint tier 11).
+forces every rule through its fixture and its clean twin, and the
+package and registry walks over the tree (lint tier 11).
 """
 
 from __future__ import annotations
@@ -94,16 +54,13 @@ import builtins
 import os
 import re
 import sys
-import warnings
 
 from .contracts import Diagnostic, _fcompute_tree, suppressions_for
 from .concurrency import _line_suppressions, package_root
 
 __all__ = [
-    "RULES", "EH_RULES", "GUARD_COMPONENTS", "CompileSafetyError",
-    "StepAuditor", "diff_guard_key", "enabled", "set_enabled", "refresh",
-    "lint_source", "lint_file", "lint_package", "lint_registry",
-    "lint_callable", "on_read", "on_write", "selftest", "main",
+    "RULES", "lint_source", "lint_file", "lint_package", "lint_registry",
+    "lint_callable", "selftest", "main",
 ]
 
 RULES = {
@@ -117,104 +74,9 @@ RULES = {
     "GL305": "hyperparameter scalar closed over as a trace-time "
              "constant instead of riding as a traced operand",
     "GL306": "donated buffer referenced after the donating dispatch",
-    "GL307": "compile_step under an open autograd.record() scope",
     "GL308": "traced parameter used only for shape/dtype (shape-"
-             "polymorphic input without a guard-key component)",
+             "polymorphic input with no value use)",
 }
-
-EH_RULES = {
-    "EH301": "retrace storm (guard-key churn; diff names the component)",
-    "EH302": "donated-buffer read after dispatch, before the "
-             "replacement landed",
-    "EH303": "constant-bake drift under an unchanged guard key",
-    "EH304": "compiled-vs-eager ULP divergence on a sentinel step",
-}
-
-# the nine components of CompiledStep._guard_key, in tuple order
-GUARD_COMPONENTS = ("input-sig", "input-fmt", "param-set", "param-meta",
-                    "optimizer-sig", "n-ctx", "kvstore-sig",
-                    "bucket-bytes", "quant-cfg")
-
-
-# ---------------------------------------------------------------------------
-# switches (memoized on the RAW env string so tests
-# and live sessions flipping the var mid-process still take effect)
-# ---------------------------------------------------------------------------
-
-_OFF_VALUES = ("", "0", "false", "no", "off")
-_enabled_override = None
-_check_env_memo = ["\x00", False]
-
-# raw flag for the NDArray read/write hot path: one list-index load when
-# the auditor is off (grafttsan convention); refreshed per compiled call
-_ACTIVE = [False]
-
-
-def enabled():
-    if _enabled_override is not None:
-        return bool(_enabled_override)
-    raw = os.environ.get("GRAFT_COMPILE_CHECK", "0")
-    if raw != _check_env_memo[0]:
-        _check_env_memo[1] = raw.strip().lower() not in _OFF_VALUES
-        _check_env_memo[0] = raw
-    return _check_env_memo[1]
-
-
-def set_enabled(flag):
-    """Force the auditor on/off (None restores the env var)."""
-    global _enabled_override
-    _enabled_override = flag
-    refresh()
-
-
-def refresh():
-    """Re-read the switch into the hot-path flag; returns the state."""
-    _ACTIVE[0] = enabled()
-    if not _ACTIVE[0] and _POISON:
-        _POISON.clear()
-    return _ACTIVE[0]
-
-
-_every_memo = ["\x00", 0]
-
-
-def check_every():
-    """EH304 sentinel period (0 = sentinel off, the default).  Memoized
-    on the raw env string — this is read once per compiled call."""
-    raw = os.environ.get("GRAFT_COMPILE_CHECK_EVERY", "0")
-    if raw != _every_memo[0]:
-        try:
-            _every_memo[1] = max(0, int(raw))
-        except ValueError:
-            _every_memo[1] = 0
-        _every_memo[0] = raw
-    return _every_memo[1]
-
-
-def ulp_tol():
-    """EH304 tolerance.  The twin is UN-jitted on purpose (independent
-    computation path), so XLA fusion/reassociation legitimately moves
-    reduction chains a few tens of ULP — 64 absorbs that while still
-    catching any real bake/donation bug (those diverge by thousands)."""
-    try:
-        return max(0, int(os.environ.get("GRAFT_COMPILE_CHECK_ULPS",
-                                         "64")))
-    except ValueError:
-        return 64
-
-
-def abort_on_storm():
-    return os.environ.get("GRAFT_COMPILE_CHECK_ABORT",
-                          "0").strip().lower() not in _OFF_VALUES
-
-
-class CompileSafetyError(RuntimeError):
-    """A runtime EH3xx violation (code in ``.code``)."""
-
-    def __init__(self, code, message):
-        super().__init__(message)
-        self.code = code
-
 
 # ---------------------------------------------------------------------------
 # static pass: shared AST helpers
@@ -240,12 +102,12 @@ _HYPER_RE = re.compile(
     r"momentum|beta1|beta2|eps|epsilon|clip(?:_gradient)?)(?:_|$)")
 _BUILTIN_NAMES = frozenset(dir(builtins))
 
-# calls whose function-typed arguments get traced by jax / graftstep
+# calls whose function-typed arguments get traced by jax
 _TRACE_ENTRYPOINTS = frozenset({
     "jit", "pjit", "pmap", "vjp", "jvp", "grad", "value_and_grad",
     "eval_shape", "make_jaxpr", "linearize", "checkpoint_policy",
-    "compile_step", "functionalize", "serving_fn", "CompiledStep"})
-_TRACE_KWARGS = frozenset({"loss", "fun", "f", "fn"})
+    "functionalize", "serving_fn"})
+_TRACE_KWARGS = frozenset({"fun", "f", "fn"})
 
 
 def _dotted(node):
@@ -444,7 +306,6 @@ class _ModuleScan(object):
         self._collect_defs(self.tree, (), None)
         self.imports = self._import_aliases()
         self.assigned_funcs = {}  # name -> factory Call node
-        self.cstep_names = set()  # names bound from *.compile_step(...)
         self._collect_assignments()
         self.donated_names = {}   # callable name -> donated positions
         self.donated_keys = {}    # entry["..."] key -> donated positions
@@ -501,8 +362,6 @@ class _ModuleScan(object):
             if not isinstance(t, ast.Name) or not isinstance(v, ast.Call):
                 continue
             self.assigned_funcs.setdefault(t.id, v)
-            if _call_name(v) == "compile_step":
-                self.cstep_names.add(t.id)
 
     # -- donation map ------------------------------------------------------
     def _donate_positions(self, kw_value, jit_call):
@@ -958,14 +817,10 @@ class _ModuleScan(object):
                           "traced parameter %r is used only for its "
                           "shape/dtype — a shape-polymorphic input with "
                           "no value use retraces per shape for data it "
-                          "never reads (make it static or add a guard-"
-                          "key component)" % p)
+                          "never reads (make it a static argument)"
+                          % p)
 
-    # -- module-wide rules (GL306 / GL307) ---------------------------------
-    def check_module_rules(self):
-        self._gl306()
-        self._gl307()
-
+    # -- module-wide rule (GL306) ------------------------------------------
     def _stmt_blocks(self, fn):
         """Every statement list in ``fn`` + stmt -> (block, idx) map."""
         blocks, pos = [], {}
@@ -1028,38 +883,6 @@ class _ModuleScan(object):
                                          ast.AsyncFunctionDef)):
                         break
 
-    def _gl307(self):
-        def scan(node, recording):
-            for child in ast.iter_child_nodes(node):
-                rec = recording
-                if isinstance(child, (ast.With, ast.AsyncWith)):
-                    if any(isinstance(item.context_expr, ast.Call)
-                           and _call_name(item.context_expr) == "record"
-                           for item in child.items):
-                        rec = True
-                if isinstance(child, (ast.FunctionDef,
-                                      ast.AsyncFunctionDef, ast.Lambda)):
-                    scan(child, False)
-                    continue
-                if recording and isinstance(child, ast.Call):
-                    cn = _call_name(child)
-                    if cn == "compile_step" or (
-                            isinstance(child.func, ast.Name)
-                            and child.func.id in self.cstep_names):
-                        scope, _cls = self._enclosing(child)
-                        self.emit(
-                            "GL307",
-                            "%s.%s" % (self.module,
-                                       ".".join(scope) or "<module>"),
-                            child.lineno,
-                            "compile_step under an open "
-                            "autograd.record() scope: the compiled step "
-                            "IS the whole record/backward/step triple — "
-                            "call it outside any recording scope")
-                scan(child, rec)
-
-        scan(self.tree, False)
-
     # -- driver ------------------------------------------------------------
     def run(self, skip_registered=True):
         self.discover()
@@ -1069,7 +892,7 @@ class _ModuleScan(object):
             if skip_registered and self._is_registered(info["node"]):
                 continue          # fcomputes are linted by lint_registry
             self.check_traced(info, seeds)
-        self.check_module_rules()
+        self._gl306()
         return self._dedup(self.diags)
 
     def _is_registered(self, fn_node):
@@ -1115,8 +938,8 @@ def lint_file(path):
 
 def lint_package(root=None):
     """GL3xx over every .py file in the package (serving/, armor/,
-    gluon/step_compile.py and everything else os.walk finds — the same
-    walk the GL2xx pass uses, nothing opts out)."""
+    parallel/ and everything else os.walk finds — the same walk the
+    GL2xx pass uses, nothing opts out)."""
     root = root or package_root()
     diags = []
     for dirpath, dirnames, filenames in os.walk(root):
@@ -1218,7 +1041,8 @@ def lint_registry(names=None):
 
 def lint_callable(fn, taint_params=None, rules=None):
     """Lint one live function the way the package pass would lint a
-    traced closure (used on user functions handed to compile_step)."""
+    traced closure (a user's loss or step function, before it is
+    handed to ``jax.jit``)."""
     import inspect
     import textwrap
     try:
@@ -1248,312 +1072,7 @@ def lint_callable(fn, taint_params=None, rules=None):
 
 
 # ---------------------------------------------------------------------------
-# guard-key diffing (EH301 feed; also the always-on retrace metric label)
-# ---------------------------------------------------------------------------
-
-def _r(v, n=48):
-    s = repr(v)
-    return s if len(s) <= n else s[:n - 3] + "..."
-
-_PARAM_META_FIELDS = ("name", "shape", "dtype", "grad_req")
-_OPT_SIG_FIELDS = ("type", "multi_precision", "momentum",
-                   "clip_gradient", "beta1", "beta2", "epsilon")
-
-
-def diff_guard_key(old, new):
-    """(component, detail) naming the FIRST differing element of two
-    CompiledStep guard keys; ('cold', ...) when there is no prior key."""
-    if old is None:
-        return "cold", "no prior guard key (first trace)"
-    if old == new:
-        return "identical", None
-    for i, comp in enumerate(GUARD_COMPONENTS):
-        if i >= len(old) or i >= len(new) or old[i] == new[i]:
-            continue
-        o, n = old[i], new[i]
-        if comp == "input-sig":
-            detail = _diff_seq(o, n, "arg")
-        elif comp == "param-set":
-            detail = ("%d -> %d params" % (len(o), len(n))
-                      if len(o) != len(n) else
-                      "same count, different Parameter identities")
-        elif comp == "param-meta":
-            detail = _diff_meta(o, n)
-        elif comp == "optimizer-sig":
-            detail = _diff_fields(o, n, _OPT_SIG_FIELDS, "optimizer")
-        else:
-            detail = "%s -> %s" % (_r(o), _r(n))
-        return comp, detail
-    return "guard-key", "%s -> %s" % (_r(old), _r(new))
-
-
-def _diff_seq(o, n, what):
-    if len(o) != len(n):
-        return "%d -> %d %ss" % (len(o), len(n), what)
-    for i, (a, b) in enumerate(zip(o, n)):
-        if a != b:
-            return "%s %d: %s -> %s" % (what, i, _r(a), _r(b))
-    return "%s -> %s" % (_r(o), _r(n))
-
-
-def _diff_meta(o, n):
-    if len(o) != len(n):
-        return "%d -> %d params" % (len(o), len(n))
-    for a, b in zip(o, n):
-        if a == b:
-            continue
-        for f, (x, y) in zip(_PARAM_META_FIELDS[1:], zip(a[1:], b[1:])):
-            if x != y:
-                return "param %s: %s %s -> %s" % (a[0], f, _r(x), _r(y))
-        return "param %s -> %s" % (_r(a), _r(b))
-    return _r((o, n))
-
-
-def _diff_fields(o, n, fields, what):
-    for f, (x, y) in zip(fields, zip(o, n)):
-        if x != y:
-            return "%s %s: %s -> %s" % (what, f, _r(x), _r(y))
-    return "%s -> %s" % (_r(o), _r(n))
-
-
-# ---------------------------------------------------------------------------
-# runtime auditor
-# ---------------------------------------------------------------------------
-
-def _journal(code, msg, **fields):
-    try:
-        from ..telemetry import blackbox
-        blackbox.record("compile_check", code=code, msg=msg, **fields)
-    except Exception:
-        pass
-
-
-def _stack_summary(skip=2, limit=10):
-    import traceback
-    frames = traceback.extract_stack()[:-skip]
-    frames = [f for f in frames
-              if "/analysis/compile_safety" not in (f.filename or "")]
-    return "".join(traceback.format_list(frames[-limit:]))
-
-
-# id(nd) -> (nd, tag, dispatch_stack).  Holding the NDArray strongly for
-# the poison window (one dispatch) both keeps ids stable and lets sweep
-# name survivors; the window is closed by _write (replacement landing)
-# or StepAuditor.sweep() in the dispatch finally.
-_POISON = {}
-
-
-def on_read(nd):
-    """NDArray._read hook (armed only while _ACTIVE[0] is True)."""
-    rec = _POISON.get(id(nd))
-    if rec is None:
-        return
-    _nd, tag, dispatch_stack = rec
-    msg = ("EH302 donated-buffer read after dispatch: this NDArray's "
-           "jax buffer was donated to the compiled %r program — XLA "
-           "aliased that memory for an output, and the replacement "
-           "value has not landed yet.  On real hardware this read "
-           "returns freed memory.\n"
-           "--- dispatch (donation) stack ---\n%s"
-           "--- offending read stack ---\n%s"
-           % (tag, dispatch_stack, _stack_summary()))
-    _journal("EH302", "donated-buffer read after dispatch", tag=tag)
-    raise CompileSafetyError("EH302", msg)
-
-
-def on_write(nd):
-    """NDArray._write hook: the replacement landing re-arms the buffer."""
-    _POISON.pop(id(nd), None)
-
-
-class StepAuditor(object):
-    """Per-CompiledStep runtime auditor (EH301-EH304).
-
-    Created lazily by CompiledStep when GRAFT_COMPILE_CHECK is on; all
-    hooks are no-ops when the flag is off (raw-flag gated at the call
-    sites, so the disabled cost never exceeds one list-index check)."""
-
-    STORM_WINDOW = 8          # calls
-    STORM_MISSES = 3          # misses within the window -> storm
-    DEEP_EVERY = 4            # EH302/EH303 deep-check sampling (calls)
-
-    def __init__(self, label="trainer"):
-        self.label = label
-        self.calls = 0
-        self.storms = 0
-        self.sentinel_checks = 0
-        self.worst_sentinel_ulp = 0
-        self._miss_log = []               # (call_idx, component, detail)
-        self._since_sentinel = 0
-        self._since_deep = 0
-        self._poisoned = []
-        self._stack_memo = {}             # tag -> dispatch stack (stable)
-
-    # -- EH301 -------------------------------------------------------------
-    def note_call(self):
-        self.calls += 1
-
-    def note_miss(self, component, detail):
-        self._miss_log.append((self.calls, component, detail))
-        del self._miss_log[:-64]
-        recent = [m for m in self._miss_log
-                  if self.calls - m[0] < self.STORM_WINDOW]
-        if len(recent) < self.STORM_MISSES:
-            return
-        counts = {}
-        for _c, comp, _d in recent:
-            counts[comp] = counts.get(comp, 0) + 1
-        top = max(counts, key=lambda k: counts[k])
-        msg = ("EH301 retrace storm on %r: %d guard misses within the "
-               "last %d calls; churned component: %s (%s) — last diff: "
-               "%s" % (self.label, len(recent), self.STORM_WINDOW, top,
-                       ", ".join("%s x%d" % (k, counts[k])
-                                 for k in sorted(counts)),
-                       detail or "<no detail>"))
-        # graftxray: the retraces re-ran HLO cost analysis — name what
-        # actually got more expensive, not just which guard churned
-        try:
-            from ..telemetry import xray as _xray_mod
-            cost_growth = _xray_mod.cost_regressions()
-        except Exception:
-            cost_growth = ""
-        if cost_growth:
-            msg += " — cost growth since previous trace: " + cost_growth
-        self.storms += 1
-        self._miss_log = []     # re-arm: one report per storm burst
-        _journal("EH301", msg, component=top, detail=detail,
-                 cost_growth=cost_growth or None)
-        try:
-            from ..telemetry import metrics as _m
-            _m.step_retrace_storm()
-        except Exception:
-            pass
-        if abort_on_storm():
-            raise CompileSafetyError("EH301", msg)
-        warnings.warn("graftguard %s" % msg, RuntimeWarning,
-                      stacklevel=3)
-
-    # -- EH303 -------------------------------------------------------------
-    def check_bake(self, kinds, baked, live):
-        if baked == live:
-            return
-        where = "fused config"
-        for k, (b, l) in enumerate(zip(baked, live)):
-            if b == l:
-                continue
-            kind = kinds[k] if k < len(kinds) else "?"
-            fields = (("beta1", "beta2", "epsilon", "clip_gradient")
-                      if kind == "adam" else ("momentum",
-                                              "clip_gradient"))
-            where = "bucket %d (%s)" % (k, kind)
-            for f, (x, y) in zip(fields, zip(b, l)):
-                if x != y:
-                    where += ": %s baked=%s live=%s" % (f, _r(x), _r(y))
-                    break
-            break
-        msg = ("EH303 constant-bake drift under an UNCHANGED guard key: "
-               "%s — the compiled program is still using the trace-time "
-               "value; this scalar is baked as a constant (it must "
-               "either join the guard key or ride as a traced operand)"
-               % where)
-        _journal("EH303", msg)
-        raise CompileSafetyError("EH303", msg)
-
-    # -- EH302/EH303 sampling ----------------------------------------------
-    def deep_due(self):
-        """Deep-check sampling (EH302 poison window + EH303 bake
-        re-hash): arming every donated buffer on every call costs a
-        dict store per array at dispatch plus a pop per array at
-        write-back — it scales with param count and alone breaches the
-        < 2% budget on many-param models.  Both defects are structural
-        (a read-after-dispatch consumer runs every step; a drifted bake
-        stays drifted), so checking every DEEP_EVERY-th armed call
-        keeps the detection while capping the steady-state cost; tests
-        force a window with ``aud._since_deep = aud.DEEP_EVERY``."""
-        self._since_deep += 1
-        if self._since_deep < self.DEEP_EVERY:
-            return False
-        self._since_deep = 0
-        return True
-
-    # -- EH302 -------------------------------------------------------------
-    def poison(self, nds, tag):
-        # the dispatch site for a given tag is the same frames every
-        # step — capture once (extract_stack per dispatch would blow
-        # the < 2% budget on its own)
-        stack = self._stack_memo.get(tag)
-        if stack is None:
-            stack = self._stack_memo[tag] = _stack_summary()
-        ids = []
-        for nd in nds:
-            _POISON[id(nd)] = (nd, tag, stack)
-            ids.append(id(nd))
-        self._poisoned = ids
-
-    def sweep(self):
-        """Close the poison window (dispatch finally): anything the
-        write-back did not replace is unpoisoned here rather than left
-        armed across steps."""
-        for i in self._poisoned:
-            _POISON.pop(i, None)
-        self._poisoned = []
-
-    # -- EH304 -------------------------------------------------------------
-    def sentinel_due(self):
-        n = check_every()
-        if n <= 0:
-            return False
-        self._since_sentinel += 1
-        if self._since_sentinel < n:
-            return False
-        self._since_sentinel = 0
-        return True
-
-    def check_parity(self, tag, compiled, reference, tol=None):
-        from ..gluon.step_compile import max_ulp_diff
-        tol = ulp_tol() if tol is None else tol
-        worst, where = 0, tag
-        for path, a, b in _zip_leaves(tag, compiled, reference):
-            u = max_ulp_diff(a, b)
-            if u > worst:
-                worst, where = u, path
-        self.sentinel_checks += 1
-        if worst > self.worst_sentinel_ulp:
-            self.worst_sentinel_ulp = worst
-        if worst <= tol:
-            return worst
-        msg = ("EH304 compiled-vs-eager divergence on a sentinel step: "
-               "%s diverged by %s ULP (tolerance %d) — the compiled "
-               "program and its un-jitted twin no longer agree on the "
-               "same operands and rng key" % (where, worst, tol))
-        _journal("EH304", msg, ulp=int(worst), where=where)
-        raise CompileSafetyError("EH304", msg)
-
-
-def _zip_leaves(path, a, b):
-    if isinstance(a, (tuple, list)) and isinstance(b, (tuple, list)):
-        if len(a) != len(b):
-            raise CompileSafetyError(
-                "EH304", "EH304 structure mismatch at %s: %d vs %d "
-                "leaves" % (path, len(a), len(b)))
-        for i, (x, y) in enumerate(zip(a, b)):
-            yield from _zip_leaves("%s[%d]" % (path, i), x, y)
-        return
-    if isinstance(a, dict) and isinstance(b, dict):
-        if set(a) != set(b):
-            raise CompileSafetyError(
-                "EH304", "EH304 structure mismatch at %s: keys %s vs %s"
-                % (path, sorted(a), sorted(b)))
-        for k in sorted(a):
-            yield from _zip_leaves("%s[%r]" % (path, k), a[k], b[k])
-        return
-    if a is None and b is None:
-        return
-    yield path, a, b
-
-
-# ---------------------------------------------------------------------------
-# selftest: every GL301-GL308 + EH301-EH304 through the real paths
+# selftest: every GL301-GL308 through its fixture, and the tree itself
 # ---------------------------------------------------------------------------
 
 _GL_FIXTURES = {
@@ -1638,16 +1157,6 @@ _GL_FIXTURES = {
         "    prog = jax.jit(f, donate_argnums=(0, 1))\n"
         "    out = prog(w, s, x)\n"
         "    return out, x.sum()\n"),
-    "GL307": (
-        "from incubator_mxnet_tpu import autograd\n"
-        "def train(trainer, net, loss, x):\n"
-        "    with autograd.record():\n"
-        "        step = trainer.compile_step(net, loss=loss)\n"
-        "    return step(x)\n",
-        "from incubator_mxnet_tpu import autograd\n"
-        "def train(trainer, net, loss, x):\n"
-        "    step = trainer.compile_step(net, loss=loss)\n"
-        "    return step(x)\n"),
     "GL308": (
         "import jax\n"
         "import jax.numpy as jnp\n"
@@ -1709,192 +1218,7 @@ def selftest(verbose=False):
         problems.append("registry pass not clean: %s"
                         % "; ".join(repr(d) for d in reg[:8]))
 
-    # ---- guard-key diffing names exact components
-    old = ((((6, 5), "float32"),), "fmt", (1, 2), (("w0", (1, 5),
-            "float32", "write"),), ("SGD", False, 0.9, None, None, None,
-            None), 1, None, 1 << 20)
-    new_shape = ((((3, 5), "float32"),),) + old[1:]
-    comp, detail = diff_guard_key(old, new_shape)
-    if comp != "input-sig" or "arg 0" not in (detail or ""):
-        problems.append("guard diff misnamed a shape flip: %s / %s"
-                        % (comp, detail))
-    new_gr = (old[0], old[1], old[2],
-              (("w0", (1, 5), "float32", "null"),)) + old[4:]
-    comp, detail = diff_guard_key(old, new_gr)
-    if comp != "param-meta" or "grad_req" not in (detail or ""):
-        problems.append("guard diff misnamed a grad_req flip: %s / %s"
-                        % (comp, detail))
-
-    # ---- runtime: EH301-EH304 through the REAL compile_step path
-    problems.extend(_selftest_runtime(verbose))
     return problems
-
-
-def _selftest_runtime(verbose=False):
-    import numpy as np
-    import incubator_mxnet_tpu as mx
-    from ..gluon import Trainer
-    from ..gluon import step_compile as sc
-    from ..telemetry import blackbox
-
-    problems = []
-    prev_override = _enabled_override
-    prev_every = os.environ.get("GRAFT_COMPILE_CHECK_EVERY")
-    set_enabled(True)
-    try:
-        # EH301: forced shape-flip loop -> storm naming input-sig
-        net = sc._make_net("graftguard_eh301_")
-        sc._seed_params(net)
-        tr = Trainer(net.collect_params(), "sgd",
-                     {"learning_rate": 0.05, "momentum": 0.9},
-                     kvstore=None)
-        cstep = sc.CompiledStep(tr, net, enabled=True)
-        rng = np.random.RandomState(11)
-        with warnings.catch_warnings(record=True) as caught:
-            warnings.simplefilter("always")
-            for i in range(5):     # every step a NEW shape: pure churn
-                x = mx.nd.array(rng.uniform(
-                    0.5, 1.5, (2 + i, 5)).astype(np.float32))
-                cstep(x)
-        aud = cstep._auditor
-        if aud is None or aud.storms < 1:
-            problems.append("EH301: shape-flip loop raised no storm "
-                            "(auditor=%r)" % aud)
-        else:
-            storm = [str(w.message) for w in caught
-                     if "EH301" in str(w.message)]
-            if not storm or "input-sig" not in storm[-1]:
-                problems.append("EH301 storm did not name the churned "
-                                "component: %s" % (storm or "<no warn>"))
-            elif verbose:
-                print("EH301:", storm[-1][:120])
-        evs = [e for e in blackbox.events()
-               if e.get("kind") == "compile_check"
-               and e["data"].get("code") == "EH301"]
-        if not evs:
-            problems.append("EH301 storm was not journaled to blackbox")
-
-        # steady harness for EH302/303/304
-        net2 = sc._make_net("graftguard_eh_")
-        sc._seed_params(net2)
-        tr2 = Trainer(net2.collect_params(), "sgd",
-                      {"learning_rate": 0.05, "momentum": 0.9},
-                      kvstore=None)
-        cs2 = sc.CompiledStep(tr2, net2, enabled=True)
-        x = mx.nd.array(rng.uniform(0.5, 1.5, (4, 5)).astype(np.float32))
-        for _ in range(3):
-            cs2(x)
-        if cs2.compiled_steps < 2:
-            problems.append("runtime harness never reached the compiled "
-                            "path (compiled=%d)" % cs2.compiled_steps)
-
-        # EH302: a consumer reading a donated param before the
-        # replacement lands (interposed inside the real write-back)
-        real_wb = cs2._write_back
-        victim = {}
-
-        def bad_write_back(entry, new_w, new_s, state_nds, frozen_nds,
-                           aux):
-            nd = tr2._params[entry["trainable"][0]].list_data()[0]
-            victim["val"] = nd._read()        # donated, not yet replaced
-            return real_wb(entry, new_w, new_s, state_nds, frozen_nds,
-                           aux)
-
-        cs2._write_back = bad_write_back
-        cs2._auditor._since_deep = cs2._auditor.DEEP_EVERY
-        try:
-            cs2(x)
-            problems.append("EH302: donated read before write-back did "
-                            "not raise")
-        except CompileSafetyError as e:
-            if e.code != "EH302" or "dispatch" not in str(e) \
-                    or "read stack" not in str(e):
-                problems.append("EH302 raised without both stacks: %s"
-                                % str(e)[:160])
-            elif verbose:
-                print("EH302: raised with both stacks")
-        finally:
-            cs2._write_back = real_wb
-        cs2(x)                                 # clean step passes again
-
-        # EH303: drift a fused-config scalar UNDER the guard key (the
-        # guard reads optimizer attrs; _fused_config is monkeypatched so
-        # only the bake hash sees the drift — exactly the future-guard-
-        # regression this rule defends against)
-        from .. import optimizer as opt_mod
-        real_cfg = opt_mod._fused_config
-
-        def drifted_cfg(optimizer, kind):
-            cfg = real_cfg(optimizer, kind)
-            return (cfg[0] + 0.05,) + tuple(cfg[1:])
-
-        opt_mod._fused_config = drifted_cfg
-        cs2._auditor._since_deep = cs2._auditor.DEEP_EVERY
-        try:
-            import incubator_mxnet_tpu.gluon.step_compile as _sc
-            _sc.opt._fused_config = drifted_cfg
-            try:
-                cs2(x)
-                problems.append("EH303: baked-config drift did not "
-                                "raise")
-            except CompileSafetyError as e:
-                if e.code != "EH303" or "momentum" not in str(e):
-                    problems.append("EH303 did not name the drifted "
-                                    "field: %s" % str(e)[:160])
-                elif verbose:
-                    print("EH303:", str(e)[:120])
-        finally:
-            opt_mod._fused_config = real_cfg
-            _sc.opt._fused_config = real_cfg
-        cs2(x)
-
-        # EH304: sentinel replay clean, then a poisoned twin must raise
-        os.environ["GRAFT_COMPILE_CHECK_EVERY"] = "1"
-        try:
-            cs2(x)
-            aud2 = cs2._auditor
-            if aud2 is None or aud2.sentinel_checks < 1:
-                problems.append("EH304 sentinel never ran under "
-                                "GRAFT_COMPILE_CHECK_EVERY=1")
-            key = next(k for k in cs2._entries
-                       if isinstance(cs2._entries.get(k), dict))
-            entry = cs2._entries[key]
-            real_raw = entry["one_raw"]
-            entry["one_raw"] = (
-                lambda *a: _perturb(real_raw(*a)))
-            try:
-                cs2(x)
-                problems.append("EH304: perturbed twin did not raise")
-            except CompileSafetyError as e:
-                if e.code != "EH304" or "ULP" not in str(e):
-                    problems.append("EH304 raised oddly: %s"
-                                    % str(e)[:160])
-                elif verbose:
-                    print("EH304:", str(e)[:120])
-            finally:
-                entry["one_raw"] = real_raw
-            cs2(x)                             # clean sentinel again
-        finally:
-            if prev_every is None:
-                os.environ.pop("GRAFT_COMPILE_CHECK_EVERY", None)
-            else:
-                os.environ["GRAFT_COMPILE_CHECK_EVERY"] = prev_every
-
-        # disabled inertness: flag off -> hooks dormant, no poison left
-        set_enabled(False)
-        if _POISON:
-            problems.append("poison map not empty after disable")
-        cs2(x)
-    finally:
-        set_enabled(prev_override)
-    return problems
-
-
-def _perturb(res):
-    import jax.numpy as jnp
-    outs, aux, new_w, new_s = res
-    new_w = tuple(w + jnp.float32(1e-3) for w in new_w)
-    return outs, aux, new_w, new_s
 
 
 def main(argv=None):
@@ -1902,10 +1226,10 @@ def main(argv=None):
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     ap = argparse.ArgumentParser(
         prog="python -m incubator_mxnet_tpu.analysis.compile_safety",
-        description="graftguard compile-safety lint + auditor selftest")
+        description="graftguard compile-safety lint selftest")
     ap.add_argument("--selftest", action="store_true",
-                    help="force every GL3xx/EH3xx diagnostic through "
-                         "the real lint / compile_step paths (CI tier)")
+                    help="force every GL3xx diagnostic through its "
+                         "fixture, then lint the tree (CI tier)")
     ap.add_argument("--verbose", action="store_true")
     args = ap.parse_args(argv)
     if not args.selftest:
@@ -1917,16 +1241,9 @@ def main(argv=None):
             print("graftguard selftest FAIL: %s" % p, file=sys.stderr)
         return 1
     print("graftguard selftest OK (GL301-GL308 fixtures + clean twins, "
-          "suppression flow, guard-key diffing, EH301 storm named the "
-          "churned component, EH302 both-stack raise, EH303 bake drift, "
-          "EH304 sentinel parity, repo package+registry clean)")
+          "suppression flow, repo package+registry clean)")
     return 0
 
 
 if __name__ == "__main__":
-    # `python -m ...compile_safety` executes this file a SECOND time as
-    # __main__ while step_compile/ndarray hold the canonical sys.modules
-    # copy — set_enabled() on the __main__ twin would be invisible to
-    # them, so delegate to the canonical module's main().
-    from incubator_mxnet_tpu.analysis import compile_safety as _canon
-    sys.exit(_canon.main())
+    sys.exit(main())

@@ -140,9 +140,6 @@ def main(argv=None):
         rules.update(compile_safety.RULES)
         for code in sorted(rules):
             print("%s  %s" % (code, rules[code]))
-        for code in sorted(compile_safety.EH_RULES):
-            print("%s  %s (runtime, GRAFT_COMPILE_CHECK=1)"
-                  % (code, compile_safety.EH_RULES[code]))
         return 0
 
     _force_cpu_platform()

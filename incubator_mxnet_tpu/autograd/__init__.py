@@ -77,10 +77,8 @@ def is_recording():
 def head_seed(value):
     """THE backward seeding rule for a head with no explicit head_grad:
     ones of the head's shape/dtype (``d(sum)/d`` semantics, parity with
-    the reference's ``backward()``).  Single source of truth shared by
-    the tape walk (:func:`_run_backward`) and the compiled whole-step
-    vjp (``gluon/step_compile.py``), so ``loss.backward()`` and the
-    fused fwd+bwd program are seeded identically by construction."""
+    the reference's ``backward()``): what the tape walk
+    (:func:`_run_backward`) seeds a bare head with."""
     return jnp.ones_like(value)
 
 

@@ -95,7 +95,7 @@ from .telemetry import blackbox as _blackbox
 from .telemetry import metrics as _tmetrics
 from .telemetry import tracing as _ttracing
 
-__all__ = ["bulk", "offband", "in_bulk", "flush", "flush_stats",
+__all__ = ["bulk", "offband", "flush", "flush_stats",
            "reset_flush_stats",
            "EngineHazardError", "engine_check_enabled", "set_engine_check",
            "BoundedCache", "cache_sizes", "flatten_arrays", "unflatten",
@@ -332,12 +332,6 @@ class offband(object):
     the surrounding segment's pending program survives untouched and
     flushes at its own boundary.
 
-    graftstep rides the same rail: a compiled whole-step dispatch
-    (``gluon/step_compile.py``) flushes the caller's open segment first
-    (its inputs may be deferred) and then runs under this scope, so the
-    single fwd+bwd+update program and its boundary ``reduce_many`` never
-    join — or force — a user's bulk segment.
-
     Now documented for user code (ROADMAP "engine offband for user
     code"): any *dispatch now, alongside the open segment* need fits —
     async checkpointing, metric pushes, ad-hoc collectives::
@@ -360,16 +354,6 @@ class offband(object):
 
     def __exit__(self, *exc):
         _tls.state = self._prev
-
-
-def in_bulk():
-    """True when the calling thread has an open ``bulk`` segment.
-
-    The graftstep compiled dispatch consults this to decide whether its
-    pre-dispatch ``flush(cause="step_compile")`` has anything to land —
-    keeping the flush-cause labels honest (no zero-op "step_compile"
-    causes on the common non-bulk path)."""
-    return _current() is not None
 
 
 def maybe_defer(op, params, vals, is_train, kw, rec=False, nd_inputs=None,

@@ -7,7 +7,6 @@ the reference, TPU-native (see gluon/block.py for the CachedOp design).
 from .parameter import Parameter, Constant, ParameterDict, DeferredInitializationError
 from .block import Block, HybridBlock, SymbolBlock
 from .trainer import Trainer
-from .step_compile import CompiledStep, step_compile_enabled
 from . import nn
 from . import loss
 from . import utils

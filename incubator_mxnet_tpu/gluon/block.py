@@ -317,9 +317,6 @@ class CachedOp(object):
         self._param_names = sorted(self._params.keys())
         self._noted = None      # (entry, recorded or not) the registry has
         self._noted_backward = None     # (entry, cotangents left out) it has
-        # forward-use order of the params, recorded by first-touch hooks
-        # on the first trace (graftstep pull priority; empty until then)
-        self.touch_order = []
 
     def _make_fn(self, param_names, n_inputs, in_fmt, train):
         block = self.block
@@ -328,8 +325,6 @@ class CachedOp(object):
         # trace and telemetry.programs() call it
         def cachedop_forward(param_vals, input_vals, rng):
             shadows = {name: NDArray(param_vals[name]) for name in param_names}
-            if not self.touch_order:
-                _install_first_touch(shadows, self.touch_order)
             nd_in = [None if v is None else NDArray(v) for v in input_vals]
             args, _ = _regroup(nd_in, in_fmt)
             if not isinstance(args, list):
@@ -549,23 +544,6 @@ def _fmt_key(fmt):
     if isinstance(fmt, list):
         return tuple(_fmt_key(f) for f in fmt)
     return fmt
-
-
-def _install_first_touch(shadows, order):
-    """Arm one-shot first-touch hooks on a trace's shadow parameters:
-    the first ``_read`` of each shadow appends its param name to
-    ``order`` — the forward-USE order of the block's weights, recorded
-    during the trace itself at zero steady-state cost (hooks clear
-    themselves on first fire, the PullScheduler convention).  graftstep
-    feeds the recorded order into ``Trainer.note_first_touch_order``:
-    the duplex pull side then issues weight pulls in the order the next
-    forward will consume them, and ``GRAFT_BUCKET_ORDER=touch`` packs
-    buckets by it."""
-    for name, sh in shadows.items():
-        def hook(arr, _name=name):
-            arr._touch_hook = None
-            order.append(_name)
-        sh._touch_hook = hook
 
 
 class HybridBlock(Block):

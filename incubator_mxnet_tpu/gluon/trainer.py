@@ -291,51 +291,6 @@ class Trainer(object):
         elif self._scheduler._armed:
             self._scheduler.disarm()
 
-    def compile_step(self, block, loss=None, enabled=None):
-        """graftstep: whole-step compiled training — returns a
-        :class:`~.step_compile.CompiledStep` that re-dispatches the
-        steady-state ``record → backward → step(batch_size)`` triple for
-        ``block`` as ONE donated XLA program (two at a kvstore boundary:
-        fwd+bwd → ``reduce_many`` → donated fused update).  Call it in
-        place of the triple::
-
-            cstep = trainer.compile_step(net, loss=loss_fn)
-            out = cstep(data, label, batch_size=bs)
-
-        Any guard miss (shape/dtype change, param freeze/thaw, optimizer
-        hyperparam change — but NOT ``set_learning_rate``, lr rides as a
-        traced operand) runs the bit-identical eager triple and
-        re-traces lazily.  ``GRAFT_STEP_COMPILE=0`` kill-switches the
-        compilation; ``enabled`` overrides the env."""
-        from .step_compile import CompiledStep
-        return CompiledStep(self, block, loss=loss, enabled=enabled)
-
-    # graftstep pull priority: forward-use order of the params, fed by
-    # the compiled-step trace's first-touch hooks (None until recorded)
-    _first_touch_order = None
-
-    def note_first_touch_order(self, order):
-        """Record the forward first-touch parameter order (trainer param
-        indices, first-use first) the compiled-step trace observed.  The
-        duplex pull side immediately reorders its pull groups to match
-        — the first weights the next forward touches come off the wire
-        first — and ``GRAFT_BUCKET_ORDER=touch`` packs buckets by it
-        (which re-plans, costing the usual one serial step)."""
-        order = tuple(dict.fromkeys(int(i) for i in order
-                                    if 0 <= int(i) < len(self._params)))
-        if order and order != self._first_touch_order:
-            self._first_touch_order = order
-            from ..telemetry import blackbox as _blackbox
-            _blackbox.record("first_touch_order", n=len(order),
-                             head=order[:8])
-
-    def _touch_perm(self, indices):
-        """Sort ``indices`` by recorded first-touch order (untouched
-        params keep index order, after the touched ones)."""
-        pos = {i: k for k, i in enumerate(self._first_touch_order or ())}
-        return sorted(indices,
-                      key=lambda i: (0, pos[i]) if i in pos else (1, i))
-
     def allreduce_grads(self):
         """ref: trainer.py allreduce_grads (1.3+, for grad accumulation)."""
         if not self._kv_initialized:
@@ -397,14 +352,7 @@ class Trainer(object):
         async per ~bucket-size group with first-touch waits when the
         duplex pull side is on (graftduplex; the dist_async parameter
         service lands here and overlaps its pull RPC on a background
-        thread), the synchronous ``pull_many`` otherwise.
-
-        graftstep pull priority: when a compiled-step trace has recorded
-        the forward's first-touch order, pulls issue in that order — the
-        weights the next forward consumes first come off the wire first,
-        so its first-touch waits land on already-arrived buffers."""
-        if self._first_touch_order:
-            keys = self._touch_perm(keys)
+        thread), the synchronous ``pull_many`` otherwise."""
         _overlap.pull_round(
             self._pull_scheduler, self._kvstore_obj, keys,
             [self._params[i].list_data() for i in keys],
@@ -567,20 +515,9 @@ class Trainer(object):
         invalidate a cached plan and trigger the serial fallback step a
         rebuild costs; a rebuild for a real reason (tape change, shape
         change) picks up the latest lateness.
-        ``GRAFT_BUCKET_ORDER=index`` reverts to plain index packing.
-        ``GRAFT_BUCKET_ORDER=touch`` packs by the compiled-step trace's
-        recorded forward first-touch order (graftstep;
-        ``note_first_touch_order``) — untouched params after the touched
-        ones in index order, plain index order until a trace has
-        recorded anything.  The recorded order is part of ``sig_perm``,
-        so a NEW recording re-plans once (the usual one serial step) and
-        then stays cached."""
+        ``GRAFT_BUCKET_ORDER=index`` reverts to plain index packing."""
         n = len(self._params)
-        mode = _overlap.bucket_order()
-        if mode == "touch":
-            perm = tuple(self._touch_perm(range(n)))
-            return ("touch", perm, perm)
-        if mode != "tape":
+        if _overlap.bucket_order() != "tape":
             perm = tuple(range(n))
             return ("index", perm, perm)
         pos = []

@@ -349,8 +349,7 @@ class KVStore(object):
         have nothing to reduce, but the push/pull byte counters still
         observe the payload so fused vs per-param runs report comparable
         kvstore telemetry.  ``label`` names the flight-recorder bracket
-        (graftstep tags its program-boundary reduce "compiled_step" so a
-        hang between the fwd+bwd and update programs is attributable)."""
+        (the ZeRO weight allgather tags its own "zero_allgather")."""
         if not values:
             return values
         raw = sum(_nd_bytes(v) for v in values)

@@ -30,7 +30,6 @@ from ..ops.registry import get_op, Operator
 from .. import random_state
 from .. import config as _config
 from ..analysis import tsan as _tsan
-from ..analysis import compile_safety as _csafety
 
 # MXTPU_ENGINE_TYPE=NaiveEngine → block after every dispatch (the
 # reference's synchronous debug engine, src/engine/naive_engine.cc);
@@ -141,13 +140,6 @@ class NDArray:
             th(self)
         if _tsan._ACTIVE[0]:
             _tsan.on_read(self)     # EH204 for tracked shared arrays
-        if _csafety._POISON and id(self) in _csafety._POISON:
-            # graftguard EH302 donated-buffer read poison.  Gated on the
-            # poison map rather than the armed flag: the map is only
-            # populated inside an armed dispatch window, so the armed
-            # steady-state read cost outside the window is the same one
-            # truthiness check the disabled path pays
-            _csafety.on_read(self)
         eng = _engine_mod()
         if self._base is None:
             if type(self._data) is eng._Pending:
@@ -217,10 +209,6 @@ class NDArray:
             # raw flag (not enabled()) keeps the disabled cost of this
             # hot path to one attribute load + index
             _tsan.on_write(self)
-        if _csafety._POISON:
-            # graftguard EH302: a replacement landing re-arms a donated
-            # buffer (map-truthiness gate, see the _read hook above)
-            _csafety.on_write(self)
         eng = _engine_mod()
         if type(value) is eng._Pending:
             value.owners.append(weakref.ref(self))

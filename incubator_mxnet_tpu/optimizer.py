@@ -767,25 +767,20 @@ def _fused_config(optimizer, kind):
     raise ValueError("unknown fused kind %r" % kind)
 
 
-def fused_formula_applier(kind, cfg, has_state, scope=None):
-    """The per-bucket multi-tensor update as a PURE function —
-    ``apply(weights, gs, states, lrs, wds, rescale) -> (new_w, new_s)``
-    — composable into a LARGER trace (the graftstep whole-step program
-    fuses it after ``jax.vjp``'s backward, ``gluon/step_compile.py``).
+def _build_fused_program(kind, cfg, shapes, flat_mode, has_state,
+                         lrs, wds, rescale):
+    """One unflatten→update→reflatten program over a whole bucket: the
+    multi-tensor update, each param through the registered op formula
+    the per-param path runs.
 
-    ``scope`` (graftxray): an optional ``jax.named_scope`` name wrapped
-    around the formula math so the ops carry it in their HLO op_name
-    metadata (telemetry/xray.py attribution).  Default None emits NO
-    scope — the eager graftfuse constant layout must stay bit-identical
-    to the per-param path, so only the compiled step passes one.
-
-    ``lrs``/``wds``/``rescale`` may be python floats (the constant
-    layout :func:`_build_fused_program` bakes — bit-identical to the
-    per-param path) or traced scalar operands (the compiled whole-step
-    path, where ``set_learning_rate`` must NOT retrace; operands can
-    shift LLVM's fma-contraction choices by ~1 ULP vs the constant
-    layout — measured on bf16 mp_sgd — which is the documented
-    EH104-style tolerance the graftstep parity tests assert under)."""
+    lr/wd/rescale are baked in as python-float CONSTANTS, exactly as the
+    per-param path bakes them into each op's jitted partial — traced
+    scalar operands occasionally shift LLVM's fma-contraction choices by
+    1 ULP (measured on bf16 mp_sgd), and constants are the only layout
+    that compiles each param's formula identically to its standalone
+    program.  The per-param ``Operator.bind`` cache keys on the same
+    scalars, so a changing lr schedule costs the fused path exactly the
+    retraces it already cost the per-param path."""
     if kind in ("sgd", "mp_sgd"):
         momentum, clip = cfg
     else:
@@ -796,8 +791,9 @@ def fused_formula_applier(kind, cfg, has_state, scope=None):
     mp_sgd_mom_fc = get_op("mp_sgd_mom_update").fcompute
     adam_fc = get_op("adam_update").fcompute
 
-    # graftlint: disable=GL305 -- cfg scalars (momentum/beta/eps/clip) are deliberately baked: the fused program cache AND the graftstep guard key both key on them
-    def apply(weights, gs, states, lrs, wds, rescale):
+    # graftlint: disable=GL305 -- baked by design: constants are the only layout bit-identical to the per-param path, and the program cache keys on every one of them (cfg, lrs, wds, rescale; see docstring)
+    def trainer_bucket_update(weights, grads, states):
+        gs = unflatten(grads, shapes) if flat_mode else grads
         new_w, new_s = [], []
         for k, w in enumerate(weights):
             g = gs[k]
@@ -838,38 +834,6 @@ def fused_formula_applier(kind, cfg, has_state, scope=None):
                 new_w.append(w2)
                 new_s.append((m2, v2))
         return tuple(new_w), tuple(new_s)
-
-    if scope is None:
-        return apply
-
-    def scoped_apply(weights, gs, states, lrs, wds, rescale):
-        with jax.named_scope(scope):
-            return apply(weights, gs, states, lrs, wds, rescale)
-
-    return scoped_apply
-
-
-def _build_fused_program(kind, cfg, shapes, flat_mode, has_state,
-                         lrs, wds, rescale):
-    """One unflatten→update→reflatten program over a whole bucket.
-
-    lr/wd/rescale are baked in as python-float CONSTANTS, exactly as the
-    per-param path bakes them into each op's jitted partial — traced
-    scalar operands occasionally shift LLVM's fma-contraction choices by
-    1 ULP (measured on bf16 mp_sgd), and constants are the only layout
-    that compiles each param's formula identically to its standalone
-    program.  The per-param ``Operator.bind`` cache keys on the same
-    scalars, so a changing lr schedule costs the fused path exactly the
-    retraces it already cost the per-param path.  The formulas
-    themselves come from :func:`fused_formula_applier` — one source,
-    shared with the graftstep whole-step program (which passes the same
-    scalars as traced operands instead)."""
-    apply = fused_formula_applier(kind, cfg, has_state)
-
-    # graftlint: disable=GL305 -- lr/wd/rescale baked by design here: constants are the only layout bit-identical to the per-param path, and the program cache keys on them (see docstring)
-    def trainer_bucket_update(weights, grads, states):
-        gs = unflatten(grads, shapes) if flat_mode else grads
-        return apply(weights, gs, states, lrs, wds, rescale)
 
     return jax.jit(trainer_bucket_update)
 

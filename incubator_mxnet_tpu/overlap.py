@@ -68,16 +68,9 @@ def bucket_order():
     finalize FIRST (the last-used layers) pack into the first buckets,
     so the first reduce goes on the wire earlier in the walk and the
     overlap window covers more of backward.  ``index`` reverts to plain
-    parameter-index packing (the PR 4 behavior).  ``touch`` packs by the
-    FORWARD first-touch order the compiled-step trace records
-    (graftstep: ``Trainer.note_first_touch_order``) — pulls and buckets
-    then mirror the order the next forward consumes weights in, which
-    fronts the duplex pull pipeline's first-touch waits; params with no
-    recorded touch yet pack after the touched ones in index order."""
+    parameter-index packing (the PR 4 behavior)."""
     v = os.environ.get("GRAFT_BUCKET_ORDER", "tape").strip().lower()
-    if v in ("index", "touch"):
-        return v
-    return "tape"
+    return "index" if v == "index" else "tape"
 
 
 def overlap_pull_enabled(override=None):

@@ -293,8 +293,7 @@ class PipelineTrainer(object):
 
         # lr rides as a traced OPERAND (GL305): baking self.learning_rate
         # here would silently pin the schedule to its _build_jit-time
-        # value — the exact constant-freeze the whole-step compiled path
-        # (step_compile.py) already avoids for lr/wd/rescale
+        # value (DataParallelTrainer's step takes its lr the same way)
         def step(state, x, y, lr):
             loss, grads = jax.value_and_grad(objective)(state, x, y)
             new_state = jax.tree.map(lambda p, g: p - lr * g, state, grads)

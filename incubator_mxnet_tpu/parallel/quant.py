@@ -1,7 +1,7 @@
 """graftzero wire: block-scaled quantized bucket allreduce.
 
 EQuARX-style (arXiv:2506.17615) block-scaled quantization for the
-bucketed gradient wire (graftfuse/graftlap/graftduplex/graftstep): each
+bucketed gradient wire (graftfuse/graftlap/graftduplex): each
 bucket's flat gradient is cut into blocks of ``GRAFT_QUANT_BLOCK``
 elements (default 256), every block gets one f32 scale, and the values
 ride as narrow integer codes:

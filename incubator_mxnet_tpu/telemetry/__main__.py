@@ -261,26 +261,6 @@ def _render_blackbox_text(report):
                             "  ERROR %s" % (e.get("error_phase")
                                             or e.get("error"))
                             if ("error" in e or "error_phase" in e) else ""))
-    comp = report.get("compiled") or {}
-    if comp.get("steps_total") or comp.get("last_transitions") \
-            or comp.get("auditor_reports"):
-        lines.append("")
-        lines.append("compiled path (graftstep/graftguard):")
-        lines.append("  %s of %s journaled steps ran compiled"
-                     % (comp.get("steps_compiled", 0),
-                        comp.get("steps_total", 0)))
-        for e in comp.get("last_transitions") or []:
-            # the diffed guard-key component is the interesting name;
-            # the structural reason only matters when there is no diff
-            lines.append("  %9.3fs ago  %-10s %s%s"
-                         % (e.get("age_s", 0.0), e.get("event"),
-                            e.get("component") or e.get("reason") or "",
-                            "  (%s)" % e["detail"]
-                            if e.get("detail") else ""))
-        for e in comp.get("auditor_reports") or []:
-            lines.append("  %9.3fs ago  %-10s %s"
-                         % (e.get("age_s", 0.0), e.get("code"),
-                            (e.get("msg") or "")[:120]))
     if report["last_collectives"]:
         lines.append("")
         lines.append("last collectives:")
@@ -345,6 +325,8 @@ def _render_analyze_text(report):
         lines.append("")
         lines.append("blame (times last-to-enter): %s"
                      % json.dumps(s["blame"]))
+        lines.append("waiting caused (s, by last-to-enter): %s"
+                     % json.dumps(s["wait_caused_s"]))
         lines.append("worst rank: %s   max enter spread: %.3fms   "
                      "mean: %.3fms"
                      % (s["worst_rank"], s["max_enter_spread_s"] * 1e3,

@@ -18,7 +18,10 @@ profiler and/or graftwatch flight-recorder dumps, mixed freely — into:
   straggler;
 * **a straggler table**: per (step, collective): last-to-enter rank,
   last-to-exit rank, enter-spread and exit-spread seconds, plus a blame
-  summary counting how often each rank entered last.
+  summary: how often each rank entered last and how many seconds its
+  peers waited for it; the worst rank is the one that cost the most
+  seconds, so a dozen ties at start-up, a tenth of a millisecond apart,
+  do not outvote four waits of 200 ms.
 
 Clock alignment uses the sync points the system already has: the
 piggybacked heartbeat ``(ts, step)`` samples (graftwatch, PR 6) and
@@ -412,6 +415,7 @@ def straggler_table(artifacts, offsets=None):
     offsets = offsets if offsets is not None else clock_offsets(artifacts)
     rows = []
     blame = {a["rank"]: 0 for a in artifacts}
+    waited = {a["rank"]: 0.0 for a in artifacts}    # for it, by its peers
     for key, rcs in sorted(_matched_collectives(artifacts).items(),
                            key=lambda kv: str(kv[0])):
         if len(rcs) < 2:
@@ -442,11 +446,15 @@ def straggler_table(artifacts, offsets=None):
             "exit_spread_s": exit_spread,
         })
         blame[last_enter] = blame.get(last_enter, 0) + 1
+        waited[last_enter] = waited.get(last_enter, 0.0) \
+            + rows[-1]["enter_spread_s"]
     matched = len(rows)
     summary = {
         "collectives_matched": matched,
         "blame": {str(r): n for r, n in sorted(blame.items())},
-        "worst_rank": (max(blame, key=lambda r: blame[r])
+        "wait_caused_s": {str(r): round(w, 6)
+                          for r, w in sorted(waited.items())},
+        "worst_rank": (max(waited, key=lambda r: waited[r])
                        if matched else None),
         "max_enter_spread_s": round(max((r["enter_spread_s"]
                                          for r in rows), default=0.0), 6),

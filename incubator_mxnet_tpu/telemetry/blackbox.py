@@ -818,18 +818,6 @@ def summarize_dump(doc, last=10):
         counts[e.get("kind", "?")] = counts.get(e.get("kind", "?"), 0) + 1
     workers = {r: dict(v, info_age_s=round(t_dump - v.get("at", t_dump), 3))
                for r, v in (doc.get("workers") or {}).items()}
-    # graftstep/graftguard: the compiled-path view — how many journaled
-    # steps ran compiled, the last trace/miss/ineligible transitions
-    # (each miss names the churned guard component), and any EH3xx
-    # auditor reports
-    step_rows = [e for e in evs if e.get("kind") == "step"]
-    compiled = {
-        "steps_compiled": sum(1 for e in step_rows
-                              if e["data"].get("compiled")),
-        "steps_total": len(step_rows),
-        "last_transitions": tail("step_compile", 5),
-        "auditor_reports": tail("compile_check", 5),
-    }
     return {
         "reason": doc.get("reason"),
         "pid": doc.get("pid"),
@@ -843,7 +831,6 @@ def summarize_dump(doc, last=10):
         "failures": doc.get("failures") or [],
         "last_flushes": tail("engine_flush"),
         "last_steps": tail("step", 5),
-        "compiled": compiled,
         "last_collectives": tail("collective", 5),
         "slow_collectives": tail("slow_collective", 5),
         "watchdog": doc.get("watchdog"),

@@ -641,39 +641,6 @@ def trainer_fused_update(n_params):
         n_params)
 
 
-def trainer_compiled_step(n_params):
-    """One whole-step compiled dispatch (graftstep: fwd+bwd+fused update
-    as a single donated XLA program, gluon/step_compile.py)."""
-    if not enabled():
-        return
-    r = _REGISTRY
-    r.counter("graft_trainer_compiled_steps_total",
-              "Whole-step compiled training dispatches").inc()
-    r.counter("graft_trainer_compiled_params_total",
-              "Parameters updated through whole-step compiled "
-              "dispatches").inc(n_params)
-
-
-def trainer_compiled_retrace():
-    """One graftstep guard miss that built (or rebuilt) a compiled-step
-    entry — steady-state loops must show zero of these after step 2."""
-    if not enabled():
-        return
-    _REGISTRY.counter("graft_trainer_compiled_retraces_total",
-                      "Compiled-step guard misses that re-traced").inc()
-
-
-def trainer_compiled_fallback(reason):
-    """One graftstep step that ran the bucketed-eager fallback instead
-    of the compiled program, labeled by why."""
-    if not enabled():
-        return
-    _REGISTRY.counter("graft_trainer_compiled_fallbacks_total",
-                      "Compiled-step dispatches that fell back to the "
-                      "bucketed-eager path",
-                      ("reason",)).inc(reason=reason)
-
-
 def flash_attention_trace(path, window=None):
     """One trace of ``ops.attention.flash_attention``, forward or backward,
     labeled by the path it took and by its window (``"none"`` or the
@@ -914,39 +881,6 @@ def moe_assignments(load, assignments):
             "graft_moe_expert_load_max_over_mean",
             "Largest held expert's assignments over the mean, last eager "
             "grouped call").set(float(max(load)) * len(load) / held)
-
-
-def step_retrace(reason):
-    """One compiled-step guard miss, labeled by WHICH guard-key
-    component churned (graftguard diff: input-sig / param-meta /
-    optimizer-sig / …, or the structural miss reason) — the signal that
-    separates 'new shape showed up once' from a retrace storm."""
-    if not enabled():
-        return
-    _REGISTRY.counter("graft_step_retraces_total",
-                      "Compiled-step guard misses by churned guard-key "
-                      "component", ("reason",)).inc(reason=reason)
-
-
-def step_guard_entries(n):
-    """Live compiled-step guard-cache population (entries + ineligible
-    markers) — a monotonically climbing gauge is the retrace-storm
-    shape."""
-    if not enabled():
-        return
-    _REGISTRY.gauge("graft_step_guard_entries",
-                    "Entries held in the compiled-step guard "
-                    "cache").set(n)
-
-
-def step_retrace_storm():
-    """One EH301 retrace-storm report (graftguard: >= 3 guard misses in
-    an 8-call window with the churned component named)."""
-    if not enabled():
-        return
-    _REGISTRY.counter("graft_step_retrace_storms_total",
-                      "EH301 retrace storms reported by the compile "
-                      "auditor").inc()
 
 
 # -- graftwatch: watchdog + dist liveness ------------------------------------

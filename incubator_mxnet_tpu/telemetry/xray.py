@@ -4,11 +4,11 @@ A fused train step is ONE donated XLA program, opaque from outside: a
 profiler names its device ops after post-fusion HLO instructions.  This
 module reopens the program:
 
-* **Phase provenance at trace time.**  ``parallel/data_parallel.py`` and
-  ``gluon/step_compile.py`` thread ``jax.named_scope`` markers
-  (``xray:forward``, ``xray:backward``, ``xray:update``) through their
-  traces, and a ``HybridBlock`` adds its name while it is traced, so every
-  HLO op of the compiled program carries its phase and its Block in its
+* **Phase provenance at trace time.**  ``parallel/data_parallel.py``
+  threads ``jax.named_scope`` markers (``xray:forward``,
+  ``xray:backward``, ``xray:update``) through its trace, and a
+  ``HybridBlock`` adds its name while it is traced, so every HLO op of
+  the compiled program carries its phase and its Block in its
   ``op_name`` metadata — fusion keeps the representative op's scope, so
   the attribution survives XLA's optimizer.  :func:`op_paths_from_hlo`
   parses the OPTIMIZED HLO of the compiled executable (the names the
@@ -16,9 +16,8 @@ module reopens the program:
 
 * **The program registry.**  The train paths hand their jitted programs
   over by name (:func:`register_program`: ``dp_train_step``,
-  ``cachedop_forward``, ``cachedop_backward``, ``trainer_bucket_update``;
-  ``step_compile`` its compiled ones, :func:`note_program`), lazily:
-  nothing is lowered or printed until a reader asks :func:`programs`
+  ``cachedop_forward``, ``cachedop_backward``, ``trainer_bucket_update``),
+  lazily: nothing is lowered or printed until a reader asks :func:`programs`
   (``telemetry.programs()``) for a program's ops or memory numbers.
 
 * **Exact-sum conservation.**  :func:`attribute` partitions a chrome
@@ -27,13 +26,6 @@ module reopens the program:
   span`` holds EXACTLY (``conservation_ok``).  The capture is whoever's
   profiler session it is (``mx.profiler.set_config(xprof_dir=...)``, the
   chip benchmark's tracer); this module starts none.
-
-* **Cost ledger.**  Each program ``step_compile`` compiles registers its
-  ``jax.stages.Compiled.cost_analysis()`` / ``memory_analysis()``
-  summary at trace time; retraces diff against the previous build of
-  the same program, the diff journals to the blackbox
-  (``xray_cost_diff``) and :func:`cost_regressions` hands EH301 storm
-  reports a one-line "what got more expensive" summary.
 """
 from __future__ import annotations
 
@@ -41,17 +33,12 @@ import gzip
 import json
 import re
 import threading
-import time
 import weakref
-from collections import deque
 
 import jax
 
-from . import blackbox as _blackbox
-
 __all__ = [
-    "reset", "note_program", "register_program", "programs", "Program", "abstract",
-    "cost_regressions", "cost_history",
+    "reset", "register_program", "programs", "Program", "abstract",
     "scope_map_from_hlo", "op_paths_from_hlo", "phase_of", "attribute",
     "parse_trace",
     "merge_intervals", "device_pids", "is_device_event", "step_spans",
@@ -272,14 +259,12 @@ def _norm_module(name):
 
 
 # ---------------------------------------------------------------------------
-# program registry + cost ledger — step_compile.note_program() feeds it
-# at trace time, captures resolve scope maps from it lazily
+# program registry — the train paths feed it (register_program), captures
+# resolve scope maps from it lazily
 # ---------------------------------------------------------------------------
 
 _reg_lock = threading.Lock()
 _programs = {}              # name -> Program
-_cost_history = {}          # name -> [cost dict, ...] (last few builds)
-_cost_diffs = deque(maxlen=8)   # latest retrace diffs, newest last
 
 
 def _cost_summary(compiled):
@@ -309,19 +294,6 @@ def _cost_summary(compiled):
                 out[key] = float(v)
     except Exception:
         pass
-    return out
-
-
-def diff_costs(old, new):
-    """Per-field (old, new) pairs for fields that changed by more than
-    0.5% (or appeared/disappeared) between two cost summaries."""
-    out = {}
-    for k in sorted(set(old) | set(new)):
-        a, b = old.get(k), new.get(k)
-        if a is None or b is None:
-            out[k] = (a, b)
-        elif abs(b - a) > 0.005 * max(abs(a), 1e-12):
-            out[k] = (a, b)
     return out
 
 
@@ -403,55 +375,6 @@ def programs():
     """The registered programs by name (:class:`Program`)."""
     with _reg_lock:
         return dict(_programs)
-
-
-def note_program(name, compiled, label=None):
-    """Register one compiled program (called by ``CompiledStep`` at
-    trace time).  Journals the cost summary to the blackbox
-    (``xray_cost``), and — when a program of the same name was
-    registered before (a retrace) — journals the per-field diff
-    (``xray_cost_diff``) so EH301 storm reports can name what got more
-    expensive, not just what churned."""
-    costs = _cost_summary(compiled)
-    with _reg_lock:
-        prev = _cost_history.get(name, [])
-        diff = diff_costs(prev[-1], costs) if prev else {}
-        _cost_history.setdefault(name, []).append(dict(costs))
-        del _cost_history[name][:-4]
-        _programs[name] = Program(name, weakref.ref(compiled))
-        if diff:
-            _cost_diffs.append({"program": name, "diff": dict(diff),
-                                "at": time.time()})
-    if _blackbox.enabled():
-        _blackbox.record("xray_cost", program=name, label=label, **costs)
-        if diff:
-            _blackbox.record(
-                "xray_cost_diff", program=name,
-                **{k: {"old": v[0], "new": v[1]} for k, v in diff.items()})
-    return costs
-
-
-def cost_history(name=None):
-    """Registered cost summaries (per program, oldest first)."""
-    with _reg_lock:
-        if name is not None:
-            return [dict(c) for c in _cost_history.get(name, [])]
-        return {n: [dict(c) for c in cs] for n, cs in _cost_history.items()}
-
-
-def cost_regressions():
-    """One human line naming the latest retrace cost growth ('' when no
-    retrace changed any cost field) — appended to EH301 storm reports."""
-    with _reg_lock:
-        diffs = list(_cost_diffs)
-    parts = []
-    for d in diffs[-3:]:
-        grown = ["%s %.3g→%.3g" % (k, v[0], v[1])
-                 for k, v in sorted(d["diff"].items())
-                 if v[0] is not None and v[1] is not None and v[1] > v[0]]
-        if grown:
-            parts.append("%s: %s" % (d["program"], ", ".join(grown)))
-    return "; ".join(parts)
 
 
 def _scope_maps():
@@ -541,8 +464,6 @@ def parse_trace(path_or_doc, scope_maps=None):
 
 
 def reset():
-    """Drop the cost ledger and the program registry (tests)."""
+    """Drop the program registry (tests)."""
     with _reg_lock:
         _programs.clear()
-        _cost_history.clear()
-        _cost_diffs.clear()

@@ -1,11 +1,12 @@
-"""Step-id threading through the flight recorder, the cross-rank
-aggregator + straggler table, metadata/flow trace validation, the
-rank-suffixed dump path, and the 2-proc dist harness with a deliberately
-delayed rank.
+"""``telemetry/aggregate.py``: step-id threading through the flight
+recorder, the cross-rank aggregator + straggler table, metadata/flow trace
+validation, the rank-suffixed dump path, and the 2-proc dist harness with
+a deliberately delayed rank.  (The file was ``test_lens.py`` until PR 43,
+after a module PR 28 deleted.)
 
 ``--analyze`` over two ranks' artifacts must produce a schema-valid
 merged chrome trace with per-rank tracks, cross-rank flow links per
-reduced bucket, and a straggler table naming the delayed rank.
+reduce, and a straggler table naming the delayed rank.
 """
 import json
 import os
@@ -214,6 +215,38 @@ def test_aggregate_blames_delayed_rank(tmp_path):
     assert any(str(f).startswith("xr/") for f in flow_ids)
     with open(merged_path) as f:
         assert ttracing.validate_chrome_trace(json.load(f)) == []
+
+
+def test_worst_rank_is_who_cost_the_most_seconds_not_the_most_rows(tmp_path):
+    """Eight pulls at a store's start tie to a tenth of a millisecond and
+    rank 0 loses each tie; rank 1 then enters three reduces 0.2 s late.
+    Rank 0 has the rows, rank 1 made everyone wait."""
+    delay, base = 0.2, 1700000000.0
+    paths = []
+    for rank in (0, 1):
+        doc = aggregate._synthetic_dump(rank, delay,
+                                        buckets=("reduce_many",))
+        for e in doc["events"]:             # the reduces follow the pulls
+            if e["kind"] == "collective":
+                e["data"]["seq"] += 8
+        ties = []
+        for seq in range(1, 9):
+            exit_ = base + 0.01 * seq + (1e-4 if rank == 0 else 0.0)
+            ties.append({"ts": exit_, "kind": "collective", "data": {
+                "path": "pull", "seq": seq, "step": 1, "n_keys": 1,
+                "nbytes": 256, "rank": rank, "latency_ms": 0.0}})
+        doc["events"] = ties + doc["events"]
+        doc["events_total"] = len(doc["events"])
+        p = tmp_path / ("rank%d.json" % rank)
+        p.write_text(json.dumps(doc))
+        paths.append(str(p))
+    report, _trace = aggregate.analyze(paths)
+    assert report["problems"] == []
+    s = report["straggler_summary"]
+    assert s["blame"] == {"0": 8, "1": 3}
+    assert s["wait_caused_s"]["1"] == pytest.approx(3 * delay, abs=0.05)
+    assert s["wait_caused_s"]["0"] < 0.01
+    assert s["worst_rank"] == 1
 
 
 def test_async_collectives_never_corrupt_clock_or_exit_blame(tmp_path):
@@ -435,7 +468,9 @@ def test_two_process_straggler_analysis(tmp_path):
     """ISSUE-8 acceptance: train on the real 2-proc dist_sync wire with
     rank 1 deliberately delayed, dump both flight recorders, and the
     aggregator must name rank 1 in a schema-valid merged trace with
-    cross-rank flow links per reduced bucket."""
+    cross-rank flow links per reduce.  The store's initialisation adds
+    eight ``pull`` rows that tie within a fraction of a millisecond: they
+    are in the table and carry no blame that counts."""
     src = _skipwrap(_LENS_WORKER % {"dir": str(tmp_path)})
     out = _launch_two(tmp_path, src, timeout=300)
     assert "WORKER 0 LENS OK" in out and "WORKER 1 LENS OK" in out, \
@@ -451,12 +486,19 @@ def test_two_process_straggler_analysis(tmp_path):
     s = report["straggler_summary"]
     assert s["worst_rank"] == 1, report["straggler_summary"]
     assert s["max_enter_spread_s"] > 0.05
-    # every reduced bucket got a matched row + flow link
-    bucket_rows = [r for r in report["stragglers"]
-                   if str(r["label"]).startswith("bucket[")]
-    assert bucket_rows, report["stragglers"]
-    assert all(r["last_to_enter"] == 1 for r in bucket_rows)
-    assert report["cross_rank_flow_links"] >= len(bucket_rows)
+    # every step's reduce got a matched row + flow link.  The worker
+    # reduces serially, so a step's buckets go out as ONE ``reduce_many``
+    # with no bucket label (``bucket[...]`` names an overlapped bucket's
+    # ``reduce_many_async``)
+    reduce_rows = [r for r in report["stragglers"]
+                   if r["label"] == "reduce_many"]
+    assert sorted(r["step"] for r in reduce_rows) == [1, 2, 3, 4], \
+        report["stragglers"]
+    # (the first step's spread is the two ranks' compile times as well)
+    steady = [r for r in reduce_rows if r["step"] > 1]
+    assert all(r["last_to_enter"] == 1 for r in steady)
+    assert min(r["enter_spread_s"] for r in steady) > 0.05
+    assert report["cross_rank_flow_links"] >= len(reduce_rows)
     pids = {e["pid"] for e in trace["traceEvents"]
             if e.get("ph") == "M" and e["name"] == "process_name"}
     assert pids == {0, 1}

@@ -8,6 +8,7 @@ in the checkout whatever the working directory.
 """
 import json
 import os
+import shutil
 import subprocess
 import sys
 
@@ -26,15 +27,25 @@ def _env(**extra):
     return env
 
 
-def _listing(path):
-    return sorted(os.listdir(path)) if os.path.isdir(path) else None
+def _checkout_of_its_own(tmp_path):
+    """The script copied, the package and the native sources linked: the
+    package finds its default cache beside where it was imported from, so
+    this checkout's ``.jax_cache`` is a directory that no other test's
+    process writes (the real checkout's is every worker's)."""
+    root = tmp_path / "checkout"
+    root.mkdir()
+    shutil.copy(SMOKE, root / "chip_smoke.py")
+    for name in ("incubator_mxnet_tpu", "src"):
+        os.symlink(os.path.join(REPO, name), root / name)
+    return root
 
 
 def test_rehearsal_runs_and_caches_where_the_environment_says(tmp_path):
     cache = tmp_path / "cache"
-    before = _listing(DEFAULT_CACHE)
+    checkout = _checkout_of_its_own(tmp_path)
     r = subprocess.run(
-        [sys.executable, SMOKE, "--rehearse"], cwd=str(tmp_path),
+        [sys.executable, str(checkout / "chip_smoke.py"), "--rehearse"],
+        cwd=str(tmp_path),
         env=_env(JAX_COMPILATION_CACHE_DIR=str(cache)),
         capture_output=True, text=True, timeout=600)
     assert r.returncode == 0, r.stderr[-3000:]
@@ -62,7 +73,7 @@ def test_rehearsal_runs_and_caches_where_the_environment_says(tmp_path):
     assert out["compile_cache"]["compile_cache_dir"] == str(cache)
     assert out["compile_cache"]["compile_cache_dir_from_env"] is True
     assert os.listdir(cache)
-    assert _listing(DEFAULT_CACHE) == before
+    assert not (checkout / ".jax_cache").exists()
 
 
 def test_no_chip_no_result():

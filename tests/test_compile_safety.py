@@ -1,5 +1,4 @@
-"""graftguard: compile-safety lint (GL3xx) + runtime retrace/donation
-auditor (EH3xx) for the whole-step compiled path.
+"""graftguard: compile-safety lint (GL3xx) over trace-eligible closures.
 
 The contract under test (analysis/compile_safety.py):
 
@@ -12,33 +11,17 @@ The contract under test (analysis/compile_safety.py):
   level and at def (scope) level, keeps its justification, and never
   hides a different code.
 * **Coverage** — the package walk reaches serving/, armor/ and
-  gluon/step_compile.py (planted-finding regression), and the repo
-  itself holds ZERO active findings on both the package and registry
-  passes.
-* **Runtime auditor** (``GRAFT_COMPILE_CHECK=1``) — EH301 retrace
-  storms name the exact churned guard-key component (and land in the
-  retrace metric + flight recorder), EH302 turns a donated-buffer read
-  before write-back into a typed two-stack error, EH303 catches a
-  fused-config scalar drifting under an unchanged guard key, EH304
-  replays the un-jitted twin on sentinel steps and raises on ULP
-  divergence — and the whole auditor is INERT when the flag is off.
+  parallel/ (planted-finding regression), and the repo itself holds
+  ZERO active findings on both the package and registry passes.
 * **Baseline** — ``graftlint --baseline`` masks snapshot findings by
   per-key count budget and fails only on NEW ones.
 """
 import os
-import warnings
 
-import numpy as np
 import pytest
 
-import incubator_mxnet_tpu as mx
-from incubator_mxnet_tpu import gluon
 from incubator_mxnet_tpu.analysis import compile_safety as cs
 from incubator_mxnet_tpu.analysis import contracts, graftlint
-from incubator_mxnet_tpu.analysis.compile_safety import (
-    GUARD_COMPONENTS, CompileSafetyError, StepAuditor, diff_guard_key)
-from incubator_mxnet_tpu.gluon import step_compile as sc
-from incubator_mxnet_tpu.telemetry import blackbox, metrics
 
 
 def active_codes(src, **kw):
@@ -63,8 +46,8 @@ def test_gl_clean_twin_silent(code):
 
 
 def test_rule_tables_cover_all_codes():
-    assert sorted(cs.RULES) == ["GL30%d" % i for i in range(1, 9)]
-    assert sorted(cs.EH_RULES) == ["EH30%d" % i for i in range(1, 5)]
+    assert sorted(cs.RULES) == ["GL30%d" % i for i in range(1, 9)
+                                if i != 7]
     assert set(cs._GL_FIXTURES) == set(cs.RULES)
 
 
@@ -207,22 +190,22 @@ def test_suppression_does_not_hide_other_codes():
 
 
 # ---------------------------------------------------------------------------
-# coverage: the walk reaches serving/armor/step_compile; the repo is clean
+# coverage: the walk reaches serving/armor/parallel; the repo is clean
 # ---------------------------------------------------------------------------
 
 def test_package_walk_reaches_subsystem_dirs(tmp_path):
     bad, _ = cs._GL_FIXTURES["GL301"]
     pkg = tmp_path / "fakepkg"
-    for sub in ("serving", "armor", "gluon"):
+    for sub in ("serving", "armor", "parallel"):
         (pkg / sub).mkdir(parents=True)
         (pkg / sub / "__init__.py").write_text("")
     (pkg / "__init__.py").write_text("")
     (pkg / "serving" / "batcher.py").write_text(bad)
     (pkg / "armor" / "faults.py").write_text(bad)
-    (pkg / "gluon" / "step_compile.py").write_text(bad)
+    (pkg / "parallel" / "data_parallel.py").write_text(bad)
     diags = [d for d in cs.lint_package(root=str(pkg)) if not d.suppressed]
     hit = {os.path.basename(d.file) for d in diags}
-    assert hit == {"batcher.py", "faults.py", "step_compile.py"}
+    assert hit == {"batcher.py", "faults.py", "data_parallel.py"}
 
 
 def test_repo_package_pass_clean():
@@ -233,7 +216,7 @@ def test_repo_package_pass_clean():
     # their reasons (audit trail, not silence)
     sup = [d for d in diags if d.suppressed]
     assert any("optimizer.py" in (d.file or "") for d in sup)
-    assert any("step_compile.py" in (d.file or "") for d in sup)
+    assert any("block.py" in (d.file or "") for d in sup)
     assert all(d.justification for d in sup)
 
 
@@ -252,48 +235,6 @@ def test_registry_seeds_only_array_params():
     diags = cs.lint_registry(names={"FullyConnected", "Convolution",
                                     "SequenceMask"})
     assert [d for d in diags if not d.suppressed] == []
-
-
-# ---------------------------------------------------------------------------
-# guard-key diffing (the EH301 component namer / retrace metric label)
-# ---------------------------------------------------------------------------
-
-def _synthetic_key(**over):
-    base = {
-        "input-sig": ((("f32", (4, 5)),),),
-        "input-fmt": ("leaf",),
-        "param-set": ("w0", "w1"),
-        "param-meta": ((("w0", (1, 5), "f32", "write"),),),
-        "optimizer-sig": ("sgd", False, 0.9, None, 0.0, 0.0, 1e-8),
-        "n-ctx": 1,
-        "kvstore-sig": None,
-        "bucket-bytes": 4 << 20,
-        "quant-cfg": None,
-    }
-    base.update(over)
-    return tuple(base[c] for c in GUARD_COMPONENTS)
-
-
-def test_diff_guard_key_cold_and_identical():
-    k = _synthetic_key()
-    comp, detail = diff_guard_key(None, k)
-    assert comp == "cold"
-    comp, detail = diff_guard_key(k, k)
-    assert comp == "identical" and detail is None
-
-
-@pytest.mark.parametrize("component,change", [
-    ("input-sig", ((("f32", (6, 5)),),)),
-    ("param-set", ("w0", "w1", "w2")),
-    ("optimizer-sig", ("sgd", False, 0.95, None, 0.0, 0.0, 1e-8)),
-    ("kvstore-sig", "dist_sync"),
-])
-def test_diff_guard_key_names_first_changed_component(component, change):
-    old = _synthetic_key()
-    new = _synthetic_key(**{component: change})
-    comp, detail = diff_guard_key(old, new)
-    assert comp == component
-    assert detail
 
 
 # ---------------------------------------------------------------------------
@@ -331,237 +272,6 @@ def test_baseline_suppressed_findings_stay_out(tmp_path):
     new, masked = graftlint.apply_baseline(
         path, [_diag("GL302", "mod.fn", "/a/x.py", 10)])
     assert len(new) == 1 and not masked
-
-
-# ---------------------------------------------------------------------------
-# runtime auditor harness (EH301-EH304) — one compiled step per module
-# ---------------------------------------------------------------------------
-
-def make_cstep(prefix, n_params=4):
-    net = sc._make_net(prefix, n_params=n_params)
-    sc._seed_params(net)
-    tr = gluon.Trainer(net.collect_params(), "sgd",
-                       {"learning_rate": 0.05, "momentum": 0.9},
-                       kvstore=None)
-    return sc.CompiledStep(tr, net, enabled=True), tr, net
-
-
-@pytest.fixture
-def guarded():
-    prev_every = os.environ.pop("GRAFT_COMPILE_CHECK_EVERY", None)
-    cs.set_enabled(True)
-    try:
-        yield
-    finally:
-        cs.set_enabled(None)
-        if prev_every is not None:
-            os.environ["GRAFT_COMPILE_CHECK_EVERY"] = prev_every
-
-
-@pytest.fixture(scope="module")
-def steady():
-    """A warmed compiled step shared by the EH302/303/304 tests (one
-    trace, reused; each test arms/disarms the auditor itself)."""
-    cstep, tr, net = make_cstep("tguard_steady_")
-    x = mx.nd.array(
-        np.random.RandomState(5).rand(4, 5).astype(np.float32))
-    cs.set_enabled(True)
-    try:
-        for _ in range(3):
-            cstep(x)
-    finally:
-        cs.set_enabled(None)
-    assert cstep.compiled_steps >= 1
-    return cstep, tr, x
-
-
-def test_eh301_storm_names_churned_component(guarded):
-    cstep, _tr, _net = make_cstep("tguard_eh301_")
-    rng = np.random.RandomState(2)
-    before = metrics.registry().snapshot().get(
-        "graft_step_retrace_storms_total", {"samples": []})
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        for i in range(5):       # every step a NEW shape: pure churn
-            x = mx.nd.array(
-                rng.rand(2 + i, 5).astype(np.float32))
-            cstep(x)
-    storm = [str(w.message) for w in caught if "EH301" in str(w.message)]
-    assert storm, "shape-flip loop raised no storm warning"
-    # the report must name the exact churned guard-key component
-    assert "input-sig" in storm[-1]
-    assert cstep._auditor is not None and cstep._auditor.storms >= 1
-    # journaled to the flight recorder ...
-    evs = [e for e in blackbox.events()
-           if e.get("kind") == "compile_check"
-           and e["data"].get("code") == "EH301"]
-    assert evs and evs[-1]["data"].get("component") == "input-sig"
-    # ... and counted: retraces labeled by component, storms totaled
-    snap = metrics.registry().snapshot()
-    labels = {s["labels"].get("reason")
-              for s in snap["graft_step_retraces_total"]["samples"]}
-    assert "input-sig" in labels
-    after = snap.get("graft_step_retrace_storms_total", {"samples": []})
-    total = lambda m: sum(s["value"] for s in m["samples"])  # noqa: E731
-    assert total(after) > total(before)
-
-
-def test_eh301_static_loop_no_storm(guarded):
-    cstep, _tr, _net = make_cstep("tguard_eh301_quiet_")
-    x = mx.nd.array(
-        np.random.RandomState(3).rand(4, 5).astype(np.float32))
-    for _ in range(6):
-        cstep(x)
-    aud = cstep._auditor
-    assert aud is not None and aud.storms == 0
-
-
-def test_eh302_donated_read_raises_with_both_stacks(guarded, steady):
-    cstep, tr, x = steady
-    real_wb = cstep._write_back
-
-    def bad_write_back(entry, new_w, new_s, state_nds, frozen_nds, aux):
-        nd = tr._params[entry["trainable"][0]].list_data()[0]
-        nd._read()               # donated, replacement not landed yet
-        return real_wb(entry, new_w, new_s, state_nds, frozen_nds, aux)
-
-    cstep._write_back = bad_write_back
-    # force the sampled EH302 window onto this exact call
-    cstep._auditor._since_deep = cstep._auditor.DEEP_EVERY
-    try:
-        with pytest.raises(CompileSafetyError) as ei:
-            cstep(x)
-    finally:
-        cstep._write_back = real_wb
-    assert ei.value.code == "EH302"
-    msg = str(ei.value)
-    assert "dispatch" in msg and "read stack" in msg
-    cstep(x)                     # clean step passes again
-
-
-def test_eh302_normal_write_back_unpoisons(guarded, steady):
-    cstep, tr, x = steady
-    # force an armed window: the clean write-back must close it
-    cstep._auditor._since_deep = cstep._auditor.DEEP_EVERY
-    cstep(x)
-    assert not cs._POISON        # sweep closed the dispatch window
-    # params are freely readable between steps
-    for p in list(tr._params)[:2]:
-        p.list_data()[0]._read()
-
-
-def test_eh302_window_is_sampled(guarded):
-    """The EH302/EH303 deep checks run every DEEP_EVERY-th armed call,
-    not every call — the per-array dict store / write-back pop is the
-    one auditor cost that scales with param count."""
-    cstep, _tr, _net = make_cstep("tguard_sample_")
-    x = mx.nd.array(
-        np.random.RandomState(11).rand(4, 5).astype(np.float32))
-    cstep(x)                     # build
-    aud = cstep._auditor
-    aud._since_deep = 0
-    armed = []
-    real_poison = cs.StepAuditor.poison
-
-    def counting_poison(self, nds, tag):
-        armed.append(tag)
-        return real_poison(self, nds, tag)
-
-    cs.StepAuditor.poison = counting_poison
-    try:
-        for _ in range(2 * aud.DEEP_EVERY):
-            cstep(x)
-    finally:
-        cs.StepAuditor.poison = real_poison
-    assert len(armed) == 2
-
-
-def test_eh303_bake_drift_names_field(guarded, steady):
-    cstep, _tr, x = steady
-    from incubator_mxnet_tpu import optimizer as opt_mod
-    real_cfg = opt_mod._fused_config
-
-    def drifted(optimizer, kind):
-        cfg = real_cfg(optimizer, kind)
-        return (cfg[0] + 0.05,) + tuple(cfg[1:])
-
-    opt_mod._fused_config = drifted
-    sc.opt._fused_config = drifted
-    # force the sampled deep-check window onto this exact call
-    cstep._auditor._since_deep = cstep._auditor.DEEP_EVERY
-    try:
-        with pytest.raises(CompileSafetyError) as ei:
-            cstep(x)
-    finally:
-        opt_mod._fused_config = real_cfg
-        sc.opt._fused_config = real_cfg
-    assert ei.value.code == "EH303"
-    assert "momentum" in str(ei.value)
-    cstep(x)
-
-
-def test_eh304_sentinel_parity_and_divergence(guarded, steady):
-    cstep, _tr, x = steady
-    os.environ["GRAFT_COMPILE_CHECK_EVERY"] = "1"
-    try:
-        before = cstep._auditor.sentinel_checks if cstep._auditor else 0
-        cstep(x)                 # clean sentinel: twin agrees
-        aud = cstep._auditor
-        assert aud is not None and aud.sentinel_checks > before
-        key = next(k for k in cstep._entries
-                   if isinstance(cstep._entries.get(k), dict))
-        entry = cstep._entries[key]
-        real_raw = entry["one_raw"]
-        entry["one_raw"] = lambda *a: cs._perturb(real_raw(*a))
-        try:
-            with pytest.raises(CompileSafetyError) as ei:
-                cstep(x)
-        finally:
-            entry["one_raw"] = real_raw
-        assert ei.value.code == "EH304"
-        assert "ULP" in str(ei.value)
-        cstep(x)
-    finally:
-        os.environ.pop("GRAFT_COMPILE_CHECK_EVERY", None)
-
-
-def test_auditor_off_is_inert(steady):
-    cstep, tr, x = steady
-    cs.set_enabled(False)
-    try:
-        assert cs.refresh() is False
-        assert not cs._ACTIVE[0] and not cs._POISON
-        calls_before = cstep._auditor.calls if cstep._auditor else 0
-        cstep(x)
-        cstep(x)
-        calls_after = cstep._auditor.calls if cstep._auditor else 0
-        assert calls_after == calls_before
-    finally:
-        cs.set_enabled(None)
-
-
-def test_guard_entries_gauge_tracks_cache(guarded, steady):
-    cstep, _tr, x = steady
-    cstep(x)
-    snap = metrics.registry().snapshot()
-    vals = [s["value"]
-            for s in snap["graft_step_guard_entries"]["samples"]]
-    assert vals and vals[-1] >= 1
-
-
-def test_blackbox_compiled_section(steady):
-    cstep, _tr, x = steady
-    cs.set_enabled(True)
-    try:
-        cstep(x)
-    finally:
-        cs.set_enabled(None)
-    report = blackbox.summarize_dump(blackbox.snapshot())
-    comp = report.get("compiled")
-    assert comp is not None
-    assert comp["steps_compiled"] >= 1
-    assert isinstance(comp["last_transitions"], list)
-    assert isinstance(comp["auditor_reports"], list)
 
 
 def test_selftest_is_green():

@@ -218,3 +218,32 @@ def test_multipart_record_roundtrip(tmp_path, monkeypatch):
                 break
             ngot.append(bytes(s))
         assert ngot == payloads
+
+
+def test_prefetch_depth_knob(monkeypatch):
+    """``GRAFT_PREFETCH_DEPTH`` sets the DataLoader's batches in flight
+    (default 2, floor 1); a live ``set_prefetch_depth`` beats the env,
+    and no depth changes what the loader yields."""
+    from incubator_mxnet_tpu.gluon.data import DataLoader
+    from incubator_mxnet_tpu.gluon.data.dataloader import (
+        prefetch_depth_default)
+    from incubator_mxnet_tpu.gluon.data.dataset import ArrayDataset
+    assert prefetch_depth_default() == 2    # the double-buffer default
+    monkeypatch.setenv("GRAFT_PREFETCH_DEPTH", "5")
+    assert prefetch_depth_default() == 5
+    monkeypatch.setenv("GRAFT_PREFETCH_DEPTH", "0")
+    assert prefetch_depth_default() == 1    # floor: one in flight
+    monkeypatch.setenv("GRAFT_PREFETCH_DEPTH", "junk")
+    assert prefetch_depth_default() == 2
+    ds = ArrayDataset(mx.nd.array(np.arange(32, dtype=np.float32)))
+    loader = DataLoader(ds, batch_size=4, prefetch_device=False)
+    try:
+        assert loader.prefetch_depth() == 2
+        loader.set_prefetch_depth(6)        # live override beats the env
+        assert loader.prefetch_depth() == 6
+        loader.set_prefetch_depth(0)
+        assert loader.prefetch_depth() == 1
+        out = [b for b in loader]
+        assert len(out) == 8                # depth never changes content
+    finally:
+        loader.close()

@@ -3,8 +3,10 @@
 ``telemetry/`` is the package's lowest layer.  The core reaches it through
 five modules (``tracing``, ``metrics``, ``blackbox``, ``watchdog``,
 ``xray``), and it reaches nothing that trains, serves or talks on the
-wire.  Both are read off the source by ``ast``, imports inside functions
-included, so nothing is imported to find out.
+wire.  ``ndarray/``, the lowest data layer, takes the race detector's
+hooks from ``analysis/`` and nothing else: no linter, and no auditor of a
+path above it.  All are read off the source by ``ast``, imports inside
+functions included, so nothing is imported to find out.
 """
 import ast
 import glob
@@ -20,11 +22,19 @@ ROOT = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
 CORE_MODULES = [
     "ndarray/ndarray.py", "engine.py", "autograd/__init__.py",
     "kvstore.py", "io.py", "profiler.py", "overlap.py",
-    "gluon/trainer.py", "gluon/step_compile.py",
+    "gluon/trainer.py",
     "gluon/data/dataloader.py", "serving/batcher.py", "parallel/dist.py",
     "telemetry/tracing.py", "telemetry/blackbox.py",
 ]
 SURFACE = {"tracing", "metrics", "blackbox", "watchdog", "xray"}
+
+# (module, the layer it reaches into, what it may take from there)
+REACHES = [(m, "telemetry", SURFACE) for m in CORE_MODULES] + [
+    # every eager op reads and writes through ndarray/: it carries
+    # analysis.tsan's two flag-gated hooks, and imports neither
+    # analysis.compile_safety (a 1,200-line AST linter) nor anything else
+    ("ndarray/" + os.path.basename(p), "analysis", {"tsan"})
+    for p in sorted(glob.glob(os.path.join(ROOT, PKG, "ndarray", "*.py")))]
 
 TELEMETRY_FILES = sorted(
     os.path.basename(p)
@@ -73,11 +83,12 @@ def _under(names, prefix):
             if n == prefix or n.startswith(prefix + ".")}
 
 
-@pytest.mark.parametrize("module", CORE_MODULES)
-def test_core_reaches_telemetry_through_its_surface(module):
+@pytest.mark.parametrize("module,layer,surface", REACHES,
+                         ids=["%s->%s" % r[:2] for r in REACHES])
+def test_core_reaches_a_layer_through_its_surface(module, layer, surface):
     taken = {n.split(".")[0]
-             for n in _under(_package_imports(module), "telemetry") if n}
-    assert taken <= SURFACE, sorted(taken - SURFACE)
+             for n in _under(_package_imports(module), layer) if n}
+    assert taken <= surface, sorted(taken - surface)
 
 
 @pytest.mark.parametrize("module", TELEMETRY_FILES)

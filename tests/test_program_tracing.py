@@ -462,20 +462,6 @@ def _loop_module_update(n):
     return "step", {"fwd", "bwd", "update"}
 
 
-def _loop_compile_step(n):
-    net = _mlp("wt_compiled_")
-    x, y = _batch()
-    trainer = gluon.Trainer(net.collect_params(), "sgd",
-                            {"learning_rate": 0.1}, kvstore=None)
-    cstep = trainer.compile_step(
-        net, loss=gluon.loss.SoftmaxCrossEntropyLoss(), enabled=True)
-    for _ in range(n):
-        out = cstep(mx.nd.array(x), mx.nd.array(y), batch_size=x.shape[0])
-    out.asnumpy()
-    assert cstep.compiled_steps >= n - 2
-    return "step", {"update"}
-
-
 def _loop_serving_batches(n):
     from incubator_mxnet_tpu import serving
     net = _mlp("wt_serve_")
@@ -495,7 +481,7 @@ def _package_threads():
 
 @pytest.mark.parametrize("loop", [
     _loop_deferred_bulk, _loop_unbulked_eager, _loop_trainer_local_kvstore,
-    _loop_module_update, _loop_compile_step, _loop_serving_batches],
+    _loop_module_update, _loop_serving_batches],
     ids=lambda f: f.__name__[len("_loop_"):])
 def test_loop_leaves_spans_and_no_watcher_thread(loop):
     n = 4

@@ -633,70 +633,3 @@ def test_armor_shard_ownership_error_both_directions():
     assert exc.value.saved is None
     assert exc.value.current == {"axis": "ctx", "n": 8, "rank": 0}
     del os.environ["GRAFT_SHARD_OPTIMIZER"]
-
-
-# ---------------------------------------------------------------------------
-# compiled step: in-program quantize/dequantize + guard retrace-once
-# ---------------------------------------------------------------------------
-
-def _compiled_pair():
-    import sys
-    sys.path.insert(0, os.path.dirname(__file__))
-    from test_step_compile import make_pair, eager_step, xbatch
-    return make_pair, eager_step, xbatch
-
-
-def test_compiled_step_quantizes_in_program():
-    make_pair, eager_step, xbatch = _compiled_pair()
-    os.environ["GRAFT_QUANT_REDUCE"] = "int8"
-    net_e, tr_e, net_c, tr_c, cstep = make_pair(
-        "sgd", {"learning_rate": 0.05, "momentum": 0.9},
-        kvstore="dist_sync")
-    rng = np.random.RandomState(11)
-    for _ in range(5):
-        x = xbatch(rng)
-        eager_step(net_e, tr_e, x)
-        cstep(x)
-    assert cstep.retraces == 1, "static quant loop retraced"
-    assert cstep.compiled_steps >= 4
-    # parity vs the EAGER-quant twin: same quantized math, operand-vs-
-    # constant fma drift only (the EH104 ULP convention, not bitwise)
-    for name in sorted(net_e.collect_params()):
-        a = net_e.collect_params()[name].data().asnumpy()
-        b = net_c.collect_params()[
-            name.replace("sce_", "scc_")].data().asnumpy()
-        assert np.abs(a - b).max() < 1e-5, name
-    # both twins carry the SAME EF residual namespace in their stores
-    assert _residual_keys(tr_e) == _residual_keys(tr_c) != []
-
-
-def test_compiled_step_quant_toggle_retraces_exactly_once():
-    make_pair, eager_step, xbatch = _compiled_pair()
-    os.environ["GRAFT_QUANT_REDUCE"] = "int8"
-    _net_e, _tr_e, _net_c, _tr_c, cstep = make_pair(
-        "sgd", {"learning_rate": 0.05}, kvstore="dist_sync")
-    rng = np.random.RandomState(3)
-    for _ in range(3):
-        cstep(xbatch(rng))
-    assert cstep.retraces == 1
-    # OFF: one guard miss (the quant-cfg component), then steady state
-    os.environ["GRAFT_QUANT_REDUCE"] = "0"
-    cstep(xbatch(rng))
-    cstep(xbatch(rng))
-    assert cstep.retraces == 2, \
-        "quant toggle must retrace exactly once, got %d" % cstep.retraces
-    # back ON: the int8 entry is still cached under its guard key — the
-    # toggle back costs ZERO new traces
-    os.environ["GRAFT_QUANT_REDUCE"] = "int8"
-    cstep(xbatch(rng))
-    cstep(xbatch(rng))
-    assert cstep.retraces == 2
-    # the guard-key differ names the quant component (regression: a
-    # None-vs-tuple quant slot must not crash the retrace-reason diff)
-    from incubator_mxnet_tpu.analysis import compile_safety as cs
-    assert "quant-cfg" in cs.GUARD_COMPONENTS
-    old = cstep._guard_key((None,))
-    os.environ["GRAFT_QUANT_REDUCE"] = "0"
-    new = cstep._guard_key((None,))
-    comp, _detail = cs.diff_guard_key(old, new)
-    assert comp == "quant-cfg"

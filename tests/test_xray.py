@@ -1,24 +1,22 @@
 """graftxray tests (ISSUE 18): scope-map parsing from optimized HLO,
 conservation-exact phase attribution over synthetic profiler traces,
 the ONE shared parser core behind both ``attribute`` and the offline
-``--ingest-xla`` CLI, and the at-trace-time cost ledger + retrace cost
-diffing (the EH301 feed).  The triggered-capture harness these once
-covered went in PR 23; the registry's lazy side and the scopes of the
-train paths are in ``test_program_tracing.py``."""
+``--ingest-xla`` CLI, and scope maps resolved lazily from the program
+registry.  The triggered-capture harness these once covered went in PR
+23, the cost ledger with the step compiler that fed it in PR 43; the
+registry's lazy side and the scopes of the train paths are in
+``test_program_tracing.py``."""
 import json
-import time
-import types
-import warnings
 
 import pytest
 
 import incubator_mxnet_tpu as mx  # noqa: F401
-from incubator_mxnet_tpu.telemetry import aggregate, blackbox, xray
+from incubator_mxnet_tpu.telemetry import aggregate, xray
 
 
 @pytest.fixture
 def fresh_xray():
-    """A clean registry and cost ledger for one test."""
+    """A clean registry for one test."""
     xray.reset()
     yield xray
     xray.reset()
@@ -29,7 +27,7 @@ def fresh_xray():
 # ---------------------------------------------------------------------------
 
 _HLO = """\
-HloModule jit_gstep_one, entry_computation_layout={(f32[1,5]{1,0})->f32[1,5]{1,0}}
+HloModule jit_dp_train_step, entry_computation_layout={(f32[1,5]{1,0})->f32[1,5]{1,0}}
 
 %fused_computation (p0: f32[1,5]) -> f32[1,5] {
   %p0 = f32[1,5]{1,0} parameter(0)
@@ -38,10 +36,10 @@ HloModule jit_gstep_one, entry_computation_layout={(f32[1,5]{1,0})->f32[1,5]{1,0
 
 ENTRY %main.42 (param_0: f32[1,5]) -> f32[1,5] {
   %param_0 = f32[1,5]{1,0} parameter(0)
-  %fusion.1 = f32[1,5]{1,0} fusion(%param_0), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(gstep_one)/jit(main)/xray:forward/mul" source_file="net.py" source_line=7}
-  %loop_add = f32[1,5]{1,0} add(%fusion.1, %fusion.1), metadata={op_name="jit(gstep_one)/jit(main)/xray:update[0]/xray:inner/add"}
-  %copy.9 = f32[1,5]{1,0} copy(%loop_add), metadata={op_name="jit(gstep_one)/jit(main)/convert"}
-  ROOT %sub.3 = f32[1,5]{1,0} subtract(%copy.9, %fusion.1), metadata={op_name="jit(gstep_one)/jit(main)/xray:backward/sub"}
+  %fusion.1 = f32[1,5]{1,0} fusion(%param_0), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(dp_train_step)/jit(main)/xray:forward/mul" source_file="net.py" source_line=7}
+  %loop_add = f32[1,5]{1,0} add(%fusion.1, %fusion.1), metadata={op_name="jit(dp_train_step)/jit(main)/xray:update[0]/xray:inner/add"}
+  %copy.9 = f32[1,5]{1,0} copy(%loop_add), metadata={op_name="jit(dp_train_step)/jit(main)/convert"}
+  ROOT %sub.3 = f32[1,5]{1,0} subtract(%copy.9, %fusion.1), metadata={op_name="jit(dp_train_step)/jit(main)/xray:backward/sub"}
 }
 """
 
@@ -71,9 +69,9 @@ def test_phase_of_first_token_wins_and_hyphen_spelling_is_excluded():
 
 
 def test_norm_module_strips_jit_prefix_and_uniquifier():
-    assert xray._norm_module("jit_gstep_one.5") == "gstep_one"
-    assert xray._norm_module("jit_gstep_update") == "gstep_update"
-    assert xray._norm_module("gstep_one") == "gstep_one"
+    assert xray._norm_module("jit_dp_train_step.5") == "dp_train_step"
+    assert xray._norm_module("jit_trainer_bucket_update") == "trainer_bucket_update"
+    assert xray._norm_module("dp_train_step") == "dp_train_step"
     assert xray._norm_module(None) == ""
 
 
@@ -81,7 +79,7 @@ def test_norm_module_strips_jit_prefix_and_uniquifier():
 # attribution: the conservation-exact partition
 # ---------------------------------------------------------------------------
 
-def _dev_ev(name, ts_us, dur_us, op=None, module="jit_gstep_one.3",
+def _dev_ev(name, ts_us, dur_us, op=None, module="jit_dp_train_step.3",
             step=None, pid=7):
     args = {}
     if op is not None:
@@ -104,7 +102,7 @@ def test_attribute_exact_conservation_with_fractional_us():
     EXACTLY: durations accumulate as integer nanoseconds, so the phase
     partition + unattributed == program span is integer equality, not
     a float tolerance."""
-    scope_maps = {"gstep_one": {"fusion.1": "forward",
+    scope_maps = {"dp_train_step": {"fusion.1": "forward",
                                 "loop_add": "update[0]",
                                 "sub.3": "backward"}}
     events = [
@@ -139,7 +137,7 @@ def test_attribute_exact_conservation_with_fractional_us():
     assert rep["span"]["t0"] == pytest.approx(100.0e-6)
     assert rep["span"]["t1"] == pytest.approx((139.0 + 3.3) * 1e-6)
     # modules roll up by normalized name
-    assert set(rep["modules"]) == {"gstep_one", "warmup"}
+    assert set(rep["modules"]) == {"dp_train_step", "warmup"}
     # the shared ledger produced one row per step stamp
     steps = [r["step"] for r in rep["ledger"]["steps"]]
     assert steps == [0, 1]
@@ -172,7 +170,7 @@ def test_parse_trace_offline_twin(tmp_path):
     p = tmp_path / "t.trace.json"
     p.write_text(json.dumps(doc))
     rep = xray.parse_trace(str(p),
-                           scope_maps={"gstep_one": {"fusion.1": "fwd"}})
+                           scope_maps={"dp_train_step": {"fusion.1": "fwd"}})
     assert rep["phases"]["fwd"]["device_s"] == pytest.approx(4.0e-6)
     assert rep["conservation_ok"]
 
@@ -207,102 +205,44 @@ def test_ingest_xla_and_attribute_agree_on_step_rows(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# cost ledger + retrace diffing (the EH301 feed)
+# scope maps from the registry
 # ---------------------------------------------------------------------------
 
-class _FakeCompiled(object):
-    """Weakref-able stand-in for jax.stages.Compiled."""
+class _FakeJitted(object):
+    """Weakref-able stand-in for a jitted function whose executable's
+    text is ``hlo``: ``lower(...).compile().as_text()``."""
 
-    def __init__(self, flops, hlo=""):
-        self._flops = float(flops)
+    def __init__(self, hlo):
         self._hlo = hlo
 
-    def cost_analysis(self):
-        return {"flops": self._flops, "bytes accessed": 4096.0}
+    def lower(self, *avals):
+        return self
 
-    def memory_analysis(self):
-        return types.SimpleNamespace(temp_size_in_bytes=128,
-                                     argument_size_in_bytes=256,
-                                     output_size_in_bytes=64,
-                                     generated_code_size_in_bytes=32)
+    def compile(self):
+        return self
 
     def as_text(self):
         return self._hlo
 
+    def cost_analysis(self):
+        return {"flops": 1.0}
 
-def test_note_program_journals_costs_and_retrace_diffs(fresh_xray):
-    marker = time.time()
-    c1 = _FakeCompiled(1000.0)
-    c2 = _FakeCompiled(2500.0)
-    costs = xray.note_program("gstep_one", c1, label="one/4p/2b")
-    assert costs["flops"] == 1000.0
-    assert costs["bytes_accessed"] == 4096.0
-    assert costs["temp_bytes"] == 128.0
-    assert xray.cost_regressions() == ""          # first build: no diff
-    xray.note_program("gstep_one", c2, label="one/4p/2b")
-    hist = xray.cost_history("gstep_one")
-    assert [h["flops"] for h in hist] == [1000.0, 2500.0]
-    line = xray.cost_regressions()
-    assert "gstep_one" in line and "flops" in line
-    assert "1e+03" in line and "2.5e+03" in line
-    evs = [e for e in blackbox.events() if e.get("ts", 0) >= marker]
-    kinds = [e["kind"] for e in evs]
-    assert kinds.count("xray_cost") == 2
-    diffs = [e for e in evs if e["kind"] == "xray_cost_diff"]
-    assert len(diffs) == 1
-    assert diffs[0]["data"]["program"] == "gstep_one"
-    assert diffs[0]["data"]["flops"] == {"old": 1000.0, "new": 2500.0}
-    del c1, c2
-
-
-def test_cost_regressions_ignores_shrinkage(fresh_xray):
-    """The storm report names what got MORE expensive; a program that
-    got cheaper is not a regression."""
-    xray.note_program("p", _FakeCompiled(2000.0))
-    xray.note_program("p", _FakeCompiled(500.0))
-    assert xray.cost_regressions() == ""
-
-
-def test_diff_costs_threshold():
-    old = {"flops": 1000.0, "temp_bytes": 64.0}
-    assert xray.diff_costs(old, {"flops": 1001.0, "temp_bytes": 64.0}) \
-        == {}                                # < 0.5%: noise, not a diff
-    d = xray.diff_costs(old, {"flops": 1200.0})
-    assert d["flops"] == (1000.0, 1200.0)
-    assert d["temp_bytes"] == (64.0, None)   # disappeared fields surface
+    def memory_analysis(self):
+        return None
 
 
 def test_scope_maps_resolve_lazily_from_live_executables(fresh_xray):
-    c = _FakeCompiled(1.0, hlo=_HLO)
-    xray.note_program("gstep_one", c)
+    fn = _FakeJitted(_HLO)
+    xray.register_program("dp_train_step", fn, ())
     maps = xray._scope_maps()
-    assert maps["gstep_one"]["fusion.1"] == "forward"
-    # a collected executable drops out instead of erroring
-    xray.note_program("gone", _FakeCompiled(1.0))
+    assert maps["dp_train_step"]["fusion.1"] == "forward"
+    # a collected program drops out instead of erroring
+    xray.register_program("gone", _FakeJitted(_HLO), ())
     import gc
     gc.collect()
-    assert "gone" not in xray._scope_maps() or \
-        xray._scope_maps().get("gone") is not None
-    del c
-
-
-def test_eh301_storm_report_names_cost_growth(fresh_xray):
-    """The retrace-storm warning must carry the cost-ledger diff: not
-    just WHICH guard churned but what got more expensive."""
-    from incubator_mxnet_tpu.analysis.compile_safety import StepAuditor
-    xray.note_program("gstep_one", _FakeCompiled(1000.0))
-    xray.note_program("gstep_one", _FakeCompiled(3000.0))
-    aud = StepAuditor(label="t")
-    with warnings.catch_warnings(record=True) as got:
-        warnings.simplefilter("always")
-        for _ in range(StepAuditor.STORM_MISSES):
-            aud.note_call()
-            aud.note_miss("bspecs", "bucket count 2 -> 3")
-    storm = [w for w in got if "EH301" in str(w.message)]
-    assert storm, "no EH301 storm warning raised"
-    msg = str(storm[-1].message)
-    assert "cost growth since previous trace" in msg
-    assert "gstep_one" in msg and "flops" in msg
+    assert "gone" not in xray._scope_maps()
+    assert xray.programs()["gone"].error == "the program is gone"
+    del fn
 
 
 # ---------------------------------------------------------------------------

@@ -47,26 +47,12 @@
 #    additionally reports armor_overhead_pct (retry plumbing with zero
 #    faults armed) against its < 2% budget in BENCH JSON.
 # 9. (the autotuner's selftest went with the autotuner, PR 28.)
-# 10. graftstep smoke — gluon.step_compile --selftest drives the
-#    whole-step compiled training path: one lazy trace on a static-shape
-#    loop (zero retraces after step 2), a set_learning_rate that must
-#    NOT retrace (lr rides as a traced operand), at most one guarded
-#    retrace per shape change, and ULP-tolerance parity of params +
-#    optimizer states against the bucketed-eager triple at every stage;
-#    bench_eager --smoke (tier 3) additionally gates the
-#    compiled_step_latency_ratio (compiled steady-state <= 0.8x the
-#    bucketed-eager step on the 64-param dist_sync bench) in BENCH JSON.
+# 10. (the whole-step compiler's selftest went with the compiler, PR 43;
+#    the fused step's behaviours are tier-1 tests: tests/test_fused_step.py.)
 # 11. graftguard smoke — analysis.compile_safety --selftest forces every
 #    GL30x fixture (plus its clean twin) through the compile-safety
-#    linter and every EH30x diagnostic through the real CompiledStep
-#    paths: an EH301 retrace storm that must name the churned guard-key
-#    component, an EH302 donated-buffer read-after-dispatch raising with
-#    both stacks, an EH303 constant-bake drift under an unchanged guard
-#    key, and an EH304 compiled-vs-eager ULP sentinel; graftlint --all
-#    (tier 1) also runs the GL3xx pass over the package sources and the
-#    op registry; bench_eager --smoke (tier 3) additionally reports
-#    compile_check_overhead_pct (auditor armed, zero findings) against
-#    its < 2% budget in BENCH JSON.
+#    linter and runs the GL3xx pass over the package sources and the op
+#    registry (graftlint --all, tier 1, runs the same pass).
 # 12. (graftxray's capture-harness smoke went with the harness, PR 23;
 #    its scope map, registry and exact-sum attribution are tier-1 tests:
 #    tests/test_xray.py, tests/test_program_tracing.py.)
@@ -118,9 +104,6 @@ JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python bench_serving.py --smoke \
     || exit $?
 JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
     python -m incubator_mxnet_tpu.armor --selftest \
-    || exit $?
-JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
-    python -m incubator_mxnet_tpu.gluon.step_compile --selftest \
     || exit $?
 JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
     python -m incubator_mxnet_tpu.analysis.compile_safety --selftest \
