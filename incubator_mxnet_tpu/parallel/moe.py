@@ -48,9 +48,21 @@ Three dispatch modes behind one module interface:
   moved a row in 34.5 ns where over Kimi-VL's 100.7 MB it took 6.3-9.1
   (PERF.md, S16 (a)).  The parts come from the shapes and the rows' dtype
   alone (``_parts``: Kimi-VL's row of 4096 one, LFM2's and Trinity-Mini's
-  two, Mellum2's four), no argument and no switch; the experts' weights
-  reach the parts in float32 so that their gradient is summed over the
-  parts in float32 and rounded once, as one part's is.
+  two, Mellum2's four), no argument and no switch.  **The parts' weight
+  gradient** (PR 46): the parts go through each of the layer's three
+  products side by side under one rule (``parted_dot``), so that an expert
+  weight's gradient is one product over all parts' rows
+  (``parallel/tgmm_parts.py``: megablox's ``tgmm`` over 2p row buffers, a
+  group's row tiles of every part one after another), accumulated in one
+  float32 accumulator in VMEM and written once.  Until then each part's
+  ``tgmm`` wrote a whole float32 (held, d, h) result, autodiff added the p
+  results and Adam's update read all p: a third to a half of what the parts
+  had won in the gathers (PERF.md section 6, PR 46).  One part keeps
+  ``grouped_dot`` and megablox's kernel: Kimi-VL's step is the program it
+  was.  The experts' weights reach the parts in float32 and the gradient
+  leaves the kernel in float32, rounded once where the cast is transposed,
+  as one part's is; off the TPU the rule adds the parts' ``ragged_dot``
+  transposes in float32.
   **The products' tiles** (PR 42; ``_tiling``, from the shapes and the
   dtypes' sizes alone): rows in tiles of 256; the dimension a ``gmm``
   contracts whole up to 2048 (``_whole``); a weight dimension that a kernel
@@ -93,7 +105,8 @@ On eager calls the layer counts itself: ``graft_moe_dispatch_traces_total
 ``graft_moe_buffer_parts`` and ``graft_moe_buffer_part_bytes`` of the last
 ``grouped`` call traced, and ``graft_moe_product_tile{product, dim}`` with
 ``graft_moe_ragged_tile_traces_total{product}`` of the Pallas kernels
-traced), and for ``grouped``
+traced, and ``graft_moe_weight_grad_traces_total{form, parts}``, how the
+experts' weight gradient is formed), and for ``grouped``
 ``graft_moe_assignments_total{held}`` and the gauge
 ``graft_moe_expert_load_max_over_mean`` (``last_expert_load`` has the
 counts).  Inside a compiled step the same counts go out with the step's
@@ -105,6 +118,7 @@ from __future__ import annotations
 
 import functools
 import math
+import operator
 
 import numpy as np
 
@@ -293,11 +307,13 @@ def _whole(k):
 _VMEM_BYTES = 16 << 20
 
 
-def _block_bytes(product, tiles, itemsize, out_itemsize):
-    """The bytes of VMEM that the kernel ``product`` holds at ``tiles``."""
+def _block_bytes(product, tiles, itemsize, out_itemsize, parts=1):
+    """The bytes of VMEM that the kernel ``product`` holds at ``tiles``;
+    ``tgmm`` over the row buffers of ``parts`` parts (``tgmm_parts``) holds
+    every part's operand blocks twice and masks one part's at a time."""
     rows, tk, tn = tiles
     if product == "tgmm":
-        return ((2 * itemsize + 4) * rows * (tk + tn)
+        return ((2 * itemsize * parts + 4) * rows * (tk + tn)
                 + (2 * out_itemsize + 4) * tk * tn)
     return (2 * itemsize * (rows * tk + tk * tn)
             + (2 * out_itemsize + 4) * rows * tn)
@@ -378,17 +394,24 @@ def _ragged_bwd(lhs, rhs, group_sizes, g):
     return pullback(g.astype(rhs.dtype))
 
 
-def _gmm_bwd(lhs, rhs, group_sizes, g):
-    megablox = _megablox()
+def _gmm_t(lhs, rhs, group_sizes, g):
+    """The rows' cotangent, ``g`` times the groups' ``rhs`` transposed."""
     k, n = rhs.shape[1:]
-    rows, size = lhs.shape[0], lhs.dtype.itemsize
+    size = lhs.dtype.itemsize
+    return _megablox().gmm(g, rhs.astype(lhs.dtype), group_sizes, lhs.dtype,
+                           _tiling("gmm_t", lhs.shape[0], k, n, size, size),
+                           transpose_rhs=True)
+
+
+def _gmm_bwd(lhs, rhs, group_sizes, g):
+    k, n = rhs.shape[1:]
+    _metrics.moe_weight_grad_trace("tgmm", 1)
     with jax.named_scope(_SCOPE):
-        d_lhs = megablox.gmm(g, rhs.astype(lhs.dtype), group_sizes, lhs.dtype,
-                             _tiling("gmm_t", rows, k, n, size, size),
-                             transpose_rhs=True)
-        d_rhs = megablox.tgmm(lhs.swapaxes(0, 1), g, group_sizes, rhs.dtype,
-                              _tiling("tgmm", rows, k, n, size,
-                                      rhs.dtype.itemsize))
+        d_lhs = _gmm_t(lhs, rhs, group_sizes, g)
+        d_rhs = _megablox().tgmm(
+            lhs.swapaxes(0, 1), g, group_sizes, rhs.dtype,
+            _tiling("tgmm", lhs.shape[0], k, n, lhs.dtype.itemsize,
+                    rhs.dtype.itemsize))
     return d_lhs, d_rhs
 
 
@@ -418,23 +441,101 @@ def _grouped_dot_bwd(res, g):
 grouped_dot.defvjp(_grouped_dot_fwd, _grouped_dot_bwd)
 
 
+# The same product over the row buffers of several parts of the tokens
+# (module docstring, "the tokens in parts"), with a rule that spans the
+# parts: forward and the rows' cotangents are a part's own kernels, as
+# ``grouped_dot``'s; the weights' cotangent is one product over all parts'
+# rows, accumulated in float32 and written once (``tgmm_parts``), where
+# ``grouped_dot`` a part would write a whole float32 result a part and leave
+# the p results to be read again and added.
+
+def _of_parts(operands):
+    """``(*lhs, *g, *group_sizes, rhs)`` as three tuples and ``rhs``."""
+    *parted, rhs = operands
+    parts = len(parted) // 3
+    return tuple(tuple(parted[i * parts:(i + 1) * parts])
+                 for i in range(3)) + (rhs,)
+
+
+def _ragged_parts_bwd(*operands):
+    lhs, gs, group_sizes, rhs = _of_parts(operands)
+    wide = rhs.astype(jnp.float32)
+    d_lhs, d_rhs = zip(*(_ragged_bwd(rows, wide, sizes, g)
+                         for rows, g, sizes in zip(lhs, gs, group_sizes)))
+    return d_lhs, functools.reduce(operator.add, d_rhs).astype(rhs.dtype)
+
+
+def _gmm_parts_bwd(*operands):
+    from .tgmm_parts import tgmm_parts
+    lhs, gs, group_sizes, rhs = _of_parts(operands)
+    k, n = rhs.shape[1:]
+    sizes = lhs[0].dtype.itemsize, rhs.dtype.itemsize
+    _metrics.moe_weight_grad_trace("tgmm_parts", len(lhs))
+    with jax.named_scope(_SCOPE):
+        d_lhs = tuple(_gmm_t(rows, rhs, of_part, g)
+                      for rows, g, of_part in zip(lhs, gs, group_sizes))
+        # the tiles of one part's ``tgmm``; the VMEM that one gets and the
+        # other parts' operand blocks on top
+        tiles = _tiling("tgmm", lhs[0].shape[0], k, n, *sizes)
+        d_rhs = tgmm_parts(
+            lhs, gs, group_sizes, rhs.dtype, tiles,
+            vmem_bytes=_VMEM_BYTES + _block_bytes("tgmm", tiles, *sizes,
+                                                  parts=len(lhs))
+            - _block_bytes("tgmm", tiles, *sizes))
+    return d_lhs, d_rhs
+
+
+@jax.custom_vjp
+def parted_dot(lhs, rhs, group_sizes):
+    """``grouped_dot(lhs[j], rhs, group_sizes[j])`` for the row buffers of p
+    parts, a tuple in and a tuple out.  The cotangent of ``rhs`` is the
+    float32 sum over every part's rows, rounded once to ``rhs``'s dtype."""
+    return tuple(_kernel_or_ragged((rows, rhs, sizes), _gmm, _ragged)
+                 for rows, sizes in zip(lhs, group_sizes))
+
+
+def _parted_dot_fwd(lhs, rhs, group_sizes):
+    return parted_dot(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
+
+
+def _parted_dot_bwd(res, gs):
+    lhs, rhs, group_sizes = res
+    d_lhs, d_rhs = _kernel_or_ragged((*lhs, *gs, *group_sizes, rhs),
+                                     _gmm_parts_bwd, _ragged_parts_bwd)
+    return d_lhs, d_rhs, (None,) * len(group_sizes)
+
+
+parted_dot.defvjp(_parted_dot_fwd, _parted_dot_bwd)
+
+
 def _experts(xs, w1, w3, w2, group_sizes, in_group):
     """Rows ``xs`` (A, d), sorted by expert, through the experts whose
-    stacked weights are given.  ``in_group`` (A, 1) marks the rows that lie
-    in a group; the hidden products' rows behind them are set to zero at
-    once: they are whatever the kernel's buffer held, a NaN among it, and
-    zero times that is no zero in the products of the backward pass.  The
-    last product's rows behind the groups stay as the kernel left them:
+    stacked weights are given; ``xs``, ``group_sizes`` and ``in_group`` are
+    tuples, an entry for each part of the tokens, and so is the result: the
+    parts go through each product side by side, so that a product's rule
+    sees all of them (``parted_dot``).  ``in_group`` (A, 1) marks the rows
+    that lie in a group; the hidden products' rows behind them are set to
+    zero at once: they are whatever the kernel's buffer held, a NaN among
+    it, and zero times that is no zero in the products of the backward pass.
+    The last product's rows behind the groups stay as the kernel left them:
     the caller reads none of them (``_to_tokens``).  Products accumulate in
     float32 and leave in xs's dtype, as a Dense layer's do; the activation
     is taken in float32."""
+    def products(rows, w):
+        if len(rows) == 1:
+            return (grouped_dot(rows[0], w, group_sizes[0]),)
+        return parted_dot(tuple(rows), w, tuple(group_sizes))
+
     def dot(rows, w):
-        return jnp.where(in_group, grouped_dot(rows, w, group_sizes),
-                         jnp.zeros((), rows.dtype))
-    h = dot(xs, w1).astype(jnp.float32)
-    h = (jax.nn.relu(h) if w3 is None
-         else jax.nn.silu(h) * dot(xs, w3).astype(jnp.float32))
-    return grouped_dot(h.astype(xs.dtype), w2, group_sizes)
+        return [jnp.where(held, y, jnp.zeros((), y.dtype)).astype(jnp.float32)
+                for held, y in zip(in_group, products(rows, w))]
+    h = dot(xs, w1)
+    if w3 is None:
+        h = [jax.nn.relu(a) for a in h]
+    else:
+        h = [jax.nn.silu(a) for a in h]
+        h = [a * b for a, b in zip(h, dot(xs, w3))]
+    return products([a.astype(rows.dtype) for a, rows in zip(h, xs)], w2)
 
 
 # Rows move between the tokens' order and the buffer's by two gathers that
@@ -555,10 +656,12 @@ def grouped_moe_apply(x, chosen, weights, w1, w3, w2, first):
 
     The tokens go through in ``_parts`` equal parts, by their shapes alone:
     a token's output does not depend on which tokens share its buffer, so
-    every part is the whole layer over its tokens (``_grouped_part``), no
-    row can overflow and nothing is bounded.  The experts' weights reach
-    the parts in float32, so that their gradient is the parts' float32
-    products summed in float32 and rounded once, as one part's is."""
+    every part is the whole layer over its tokens, no row can overflow and
+    nothing is bounded (``_grouped_parts``: the parts side by side, so that
+    an expert weight's gradient is formed over all of them at once).  The
+    experts' weights reach the parts in float32, so that their gradient
+    leaves its kernel as it was accumulated and is rounded once, as one
+    part's is."""
     n, k = chosen.shape
     row_bytes = x.shape[1] * x.dtype.itemsize
     parts = _parts(n, k, x.shape[1], x.dtype.itemsize)
@@ -568,10 +671,9 @@ def grouped_moe_apply(x, chosen, weights, w1, w3, w2, first):
         return out.astype(x.dtype), load
     w1, w3, w2 = (w if w is None else w.astype(jnp.float32)
                   for w in (w1, w3, w2))
-    outs, loads = zip(*(
-        _grouped_part(*of_part, w1, w3, w2, first)
-        for of_part in zip(*(jnp.split(a, parts)
-                             for a in (x, chosen, weights)))))
+    outs, loads = _grouped_parts(
+        *(jnp.split(a, parts) for a in (x, chosen, weights)), w1, w3, w2,
+        first)
     # joined in float32 and narrowed once, as one part's sum is: with each
     # part narrowed before the join Trinity-Mini's whole step compiled to
     # 52 MB more of temporaries than with one part, so to 30 MB fewer
@@ -580,35 +682,54 @@ def grouped_moe_apply(x, chosen, weights, w1, w3, w2, first):
 
 def _grouped_part(x, chosen, weights, w1, w3, w2, first):
     """``grouped_moe_apply`` over tokens that share one buffer, the
-    weighted sum still in float32.
+    weighted sum still in float32."""
+    (out,), (load,) = _grouped_parts((x,), (chosen,), (weights,), w1, w3, w2,
+                                     first)
+    return out, load
+
+
+def _dispatch(x, chosen, first, count):
+    """The tokens ``x`` of one buffer at its rows: ``(xs, group_sizes,
+    in_group, slot, order)``.
 
     The A = N·k assignments, numbered choice × N + token, are sorted by
     held expert, those of absent experts behind them; both ways the rows
     move by a gather over a permutation of A (the sort's, and its inverse),
     so neither direction of the gradient adds rows serially."""
     n, k = chosen.shape
-    count = w1.shape[0]
+    local = chosen.T.reshape(-1) - first                      # (A,)
+    held = (local >= 0) & (local < count)
+    # absent experts sort behind every held one, as group ``count``
+    key = jnp.where(held, local, count).astype(jnp.int32)
+    order = jnp.argsort(key, stable=True)
+    group_sizes = jnp.bincount(key, length=count + 1)[:count].astype(
+        jnp.int32)
+    back = jnp.argsort(order)                     # the inverse permutation
+    in_groups = group_sizes.sum()
+    in_group = (jnp.arange(n * k) < in_groups)[:, None]
+    slot = jnp.where(back < in_groups, back, n * k)
+    return _to_buffer(x, order, slot, k), group_sizes, in_group, slot, order
+
+
+def _grouped_parts(x, chosen, weights, w1, w3, w2, first):
+    """The dropless layer over the parts of the tokens, a buffer a part
+    (``x``, ``chosen`` and ``weights`` hold an entry a part): the weighted
+    sums (N / p, d), still in float32, and the parts' ``group_sizes``.  The
+    parts go through side by side: every part's dispatch, then the experts'
+    products over all parts, then every part's combine."""
     with jax.named_scope("moe_dispatch"):
-        local = chosen.T.reshape(-1) - first                  # (A,)
-        held = (local >= 0) & (local < count)
-        # absent experts sort behind every held one, as group ``count``
-        key = jnp.where(held, local, count).astype(jnp.int32)
-        order = jnp.argsort(key, stable=True)
-        group_sizes = jnp.bincount(key, length=count + 1)[:count].astype(
-            jnp.int32)
-        back = jnp.argsort(order)                 # the inverse permutation
-        in_groups = group_sizes.sum()
-        in_group = (jnp.arange(n * k) < in_groups)[:, None]
-        slot = jnp.where(back < in_groups, back, n * k)
-        xs = _to_buffer(x, order, slot, k)
+        xs, group_sizes, in_group, slot, order = zip(*(
+            _dispatch(*of_part, first, w1.shape[0])
+            for of_part in zip(x, chosen)))
     with jax.named_scope(_SCOPE):
         ys = _experts(xs, w1, w3, w2, group_sizes, in_group)
     with jax.named_scope("moe_combine"):
         # back to (choice, token) order, then the weighted sum over a
         # token's choices: the weights meet the rows where both lie in the
         # router's order, so no scalar is gathered
-        out = _to_tokens(ys, weights.T, slot, order)
-    return out, group_sizes
+        outs = [_to_tokens(*of_part) for of_part in zip(
+            ys, (w.T for w in weights), slot, order)]
+    return outs, group_sizes
 
 
 class ExpertParallelMoE(HybridBlock):

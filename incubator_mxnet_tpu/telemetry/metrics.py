@@ -777,6 +777,22 @@ def moe_buffer_parts(parts, part_bytes):
                     "grouped dispatch").set(part_bytes)
 
 
+def moe_weight_grad_trace(form, parts):
+    """One trace of the Pallas branch of a grouped product's backward rule
+    in ``parallel.moe``: how the experts' weight gradient is formed
+    (``form``: ``tgmm``, megablox's kernel over the one buffer of a layer
+    whose tokens are one part; ``tgmm_parts``, one kernel over the row
+    buffers of ``parts`` parts, each block of the result written once).
+    Beside ``graft_moe_buffer_parts``: what a run's reader asks to see
+    which form its step staged."""
+    if not enabled():
+        return
+    _REGISTRY.counter("graft_moe_weight_grad_traces_total",
+                      "Grouped-product backward traces by the form of the "
+                      "experts' weight gradient",
+                      ("form", "parts")).inc(form=form, parts=str(parts))
+
+
 def moe_product_tile(product, dims, tiles):
     """One trace of a kernel of ``parallel.moe.grouped_dot`` on its Pallas
     path (``product``: ``gmm``, the rows' cotangent ``gmm_t``, the weights'

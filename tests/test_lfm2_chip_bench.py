@@ -554,7 +554,9 @@ def test_routed_layer_compiles_for_the_chip_at_the_cells_shape(
     """Forward and backward of the grouped dispatch over a cell's row of
     tokens with experts 0-7 held, in bf16 (LFM2: top-4 of 32; Mellum2:
     top-8 of 64; Kimi-VL: top-6 of 64; Trinity-Mini: top-8 of 128): the
-    products are the Pallas grouped matmul (``gmm``, ``tgmm``), not XLA's
+    products are the Pallas grouped matmul (``gmm``, ``tgmm``, and over the
+    row buffers of several parts ``tgmm_parts``, which the TPU's compiler
+    takes at LFM2's, Mellum2's and Trinity-Mini's shapes), not XLA's
     expansion of ``ragged_dot``, no row moves by a scatter, no pass masks
     the buffer at the tokens' width, and no view of the buffer by choice is
     written out.  PR 41: a buffer over the layer's limit is worked in
@@ -598,10 +600,20 @@ def test_routed_layer_compiles_for_the_chip_at_the_cells_shape(
              if "tpu_custom_call" in line and " custom-call(" in line]
     names = [line.split("=")[0].strip().lstrip("%").split(".")[0]
              for line in calls]
-    # a part: three products forward, three for the rows and three for the
-    # weights
-    assert (names.count("gmm"), names.count("tgmm")) == (
-        6 * parts, 3 * parts), names
+    # a part: three products forward and three for the rows; three for the
+    # weights whatever the parts (PR 46: until then three a part), megablox's
+    # ``tgmm`` over one buffer and ``tgmm_parts`` over the buffers of several
+    assert (names.count("gmm"), names.count("tgmm"),
+            names.count("tgmm_parts")) == (
+        6 * parts, 3 * (parts == 1), 3 * (parts > 1)), names
+    # ... each a weight-shaped result written once, in float32 where the
+    # tokens go in parts, and no float32 sum of that shape beside them
+    weight_shaped = r" = (\w+)\[%d,(?:%d,%d|%d,%d)\]\S* " % (held, d, h, h, d)
+    assert [found for line in calls
+            for found in re.findall(weight_shaped + r"custom-call\(", line)
+            ] == ["f32" if parts > 1 else "bf16"] * 3
+    assert not re.search(weight_shaped.replace(r"(\w+)", "f32") + r"add\(",
+                         text)
     big_scatters = [line for line in text.splitlines()
                     if " scatter(" in line
                     and "[%d,%d]" % (part_rows, d) in line]

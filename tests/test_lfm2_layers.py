@@ -598,15 +598,17 @@ def test_grouped_masks_no_row_of_the_tokens_width():
             if primitive == "select_n"].count((assignments, WIDTH)) == 4
 
 
-def _outputs(jaxpr):
+def _outputs(jaxpr, by=None):
     """(primitive, type) of every equation's outputs, sub-jaxprs
-    included."""
+    included; with ``by="name"`` the equation's ``name`` parameter (a
+    jitted function's) in the primitive's place."""
     for eqn in jaxpr.eqns:
         for v in eqn.outvars:
             if hasattr(v.aval, "shape"):
-                yield eqn.primitive.name, v.aval
+                yield (eqn.params.get(by) if by else eqn.primitive.name,
+                       v.aval)
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _outputs(sub)
+            yield from _outputs(sub, by)
 
 
 # ---------------------------------------------------------------------------
@@ -681,12 +683,30 @@ def test_parted_weight_gradients_in_bf16_are_one_rounding(monkeypatch):
     monkeypatch.setattr(moe, "_PART_BYTES", _limit_for(4, 4, "bfloat16"))
     parted = _value_and_gradients(moe.grouped_moe_apply, chosen, half)
     assert gap(parted[3:]) <= 1.1 * one
-    # every sum of the parts' weight cotangents is a float32 sum
+    # PR 46: no sum of the parts' weight cotangents is left to autodiff
+    # (until then three float32 ``add_any`` a weight): the rule over the
+    # parts adds them itself, off the TPU three float32 ``add`` a weight
     jaxpr = jax.make_jaxpr(lambda *a: _value_and_gradients(
         moe.grouped_moe_apply, chosen, a))(*half)
-    sums = [aval.dtype for primitive, aval in _outputs(jaxpr.jaxpr)
-            if primitive == "add_any" and aval.ndim == 3]
+    made = list(_outputs(jaxpr.jaxpr))
+    assert not [aval for primitive, aval in made
+                if primitive == "add_any" and aval.ndim == 3]
+    sums = [aval.dtype for primitive, aval in made
+            if primitive == "add" and aval.ndim == 3]
     assert len(sums) == 3 * 3 and set(sums) == {jnp.dtype("float32")}
+    # ... and in the branch a TPU takes (rows in whole tiles of 128) one
+    # kernel over all parts' rows: a float32 result a weight, no ``tgmm``
+    rows = (jnp.zeros((128, WIDTH), jnp.bfloat16),) * 4
+    sizes = (jnp.zeros((8,), jnp.int32),) * 4
+    kernels = [(name, aval.shape, aval.dtype.name) for name, aval in _outputs(
+        jax.make_jaxpr(lambda lhs, w, g: jax.vjp(
+            lambda lhs, w: moe.parted_dot(lhs, w, sizes), lhs, w)[1](g))(
+                rows, args[2], (jnp.zeros((128, HIDDEN), jnp.bfloat16),) * 4
+            ).jaxpr, "name") if name in ("gmm", "tgmm", "tgmm_parts")]
+    assert sorted(kernels) == (
+        [("gmm", (128, WIDTH), "bfloat16")] * 4         # the rows' cotangents
+        + [("gmm", (128, HIDDEN), "bfloat16")] * 4      # forward
+        + [("tgmm_parts", (8, WIDTH, HIDDEN), "float32")])
     # ... and what bf16 parts added in bf16 would have given: each part's
     # rows alone (their cotangent is the whole layer's, cos(out))
     x = half[0]
@@ -697,6 +717,113 @@ def test_parted_weight_gradients_in_bf16_are_one_rounding(monkeypatch):
                                      (x[rows],) + half[1:])[3:]
         naive = [n + g for n, g in zip(naive, grads)]
     assert gap(naive) > gap(parted[3:])
+
+
+def _ragged_weight_gradient(lhs, rhs, sizes):
+    """The float32 sum over the parts of ``lax.ragged_dot``'s transpose for
+    the weights, rows behind a part's groups counting nothing."""
+    total = 0
+    for rows, g, of_part in zip(lhs, rhs, sizes):
+        held = (jnp.arange(rows.shape[0]) < of_part.sum())[:, None]
+        rows, g = (jnp.where(held, v.astype(jnp.float32), 0)
+                   for v in (rows, g))
+        _, pullback = jax.vjp(
+            lambda w: lax.ragged_dot(rows, w, of_part, precision="highest"),
+            jnp.zeros((len(of_part), rows.shape[1], g.shape[1])))
+        total = total + pullback(g)[0]
+    return total
+
+
+# rows a group has in each part, row tiles of 128 over buffers of 256 rows:
+# group 1 is empty in every part, group 0 in one, group 3 in most; borders
+# lie inside a row tile (100, 137), on one (128, 256) and a part is full
+_PART_SIZES = {2: [[100, 0, 37, 60], [0, 0, 200, 56]],
+               4: [[10, 0, 20, 30], [0, 0, 256, 0], [128, 0, 128, 0],
+                   [1, 0, 0, 3]]}
+
+
+@pytest.mark.parametrize("parts", sorted(_PART_SIZES))
+def test_the_parts_row_tiles_are_visited_group_by_group(parts):
+    """``tgmm_parts._steps`` against megablox's own ``make_group_metadata``
+    of each part: a part's steps are the (group, row tile) pairs megablox
+    visits for that part's sizes, in its order (where a group has rows; a
+    group without rows gets one step in either), and the parts' steps are
+    laid out group-major, part after part within a group.  Every part's
+    operands show its own tile at its own steps and its last own tile in
+    between."""
+    import importlib
+    from incubator_mxnet_tpu.parallel import tgmm_parts
+    megablox = importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+    rs = np.random.RandomState(parts)
+    cases = [_PART_SIZES[parts]] + [
+        rs.multinomial(rs.randint(0, 1025), np.ones(5) / 5, size=parts)
+        * (rs.rand(parts, 5) < 0.7) for _ in range(6)]
+    for sizes in cases:
+        sizes = [jnp.asarray(s, jnp.int32) for s in sizes]
+        groups, rows, tm = len(sizes[0]), 1024, 128
+        (offsets, group_ids, part_ids, tile_at), steps = tgmm_parts._steps(
+            sizes, rows, tm)
+        steps, slots = int(steps), len(group_ids)
+        assert slots == parts * (rows // tm + groups - 1) >= steps
+        np.testing.assert_array_equal(
+            offsets.reshape(parts, -1)[:, 1:], np.cumsum(sizes, axis=1))
+        tile_at = np.asarray(tile_at).reshape(parts, slots)
+        assert tile_at.min() >= 0 and tile_at.max() < rows // tm
+        order = list(zip(np.asarray(group_ids[:steps]),
+                         np.asarray(part_ids[:steps])))
+        assert order == sorted(order)
+        for part, of_part in enumerate(sizes):
+            (_, ids, tiles), count = megablox.make_group_metadata(
+                group_sizes=of_part, m=rows, tm=tm, start_group=0,
+                num_nonzero_groups=groups, visit_empty_groups=True)
+            own = np.asarray(part_ids[:steps]) == part
+            assert own.sum() == int(count)
+            np.testing.assert_array_equal(group_ids[:steps][own],
+                                          ids[:int(count)])
+            with_rows = np.asarray(of_part)[np.asarray(ids[:int(count)])] > 0
+            np.testing.assert_array_equal(
+                tile_at[part, :steps][own][with_rows],
+                np.asarray(tiles[:int(count)])[with_rows])
+            # between its own steps a part's operands stay where they were
+            shown = tile_at[part, :steps]
+            assert (np.diff(shown)[~own[1:]] == 0).all()
+            assert shown[0] == 0 and (np.diff(shown) >= 0).all()
+
+
+@pytest.mark.parametrize("out_dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("parts", sorted(_PART_SIZES))
+def test_the_weight_gradient_over_the_parts_row_buffers(parts, out_dtype):
+    """``tgmm_parts`` in the interpreter over the bf16 row buffers of 2 and 4
+    parts against the float32 sum of the parts' ``lax.ragged_dot``
+    transposes: under skewed sizes, with a group empty in one part, a group
+    empty in every part (its block is zeros), borders inside a row tile,
+    NaN in the rows behind the groups, and a column tile that does not
+    divide its dimension.  In float32 it is the sum to float32's rounding,
+    in bf16 that sum rounded once."""
+    from incubator_mxnet_tpu.parallel.tgmm_parts import tgmm_parts
+    rs = np.random.RandomState(46)
+    rows, k, n = 256, 256, 384
+    sizes = tuple(jnp.asarray(s, jnp.int32) for s in _PART_SIZES[parts])
+
+    def buffers(width):
+        made = (jnp.asarray(rs.randn(rows, width), jnp.bfloat16)
+                for _ in sizes)
+        return tuple(v.at[int(s.sum()):].set(jnp.nan)
+                     for v, s in zip(made, sizes))
+
+    lhs, rhs = buffers(k), buffers(n)
+    want = _ragged_weight_gradient(lhs, rhs, sizes)
+    with _time_limit(240):
+        got = tgmm_parts(lhs, rhs, sizes, jnp.dtype(out_dtype),
+                         (128, 128, 256), interpret=True)
+    assert got.dtype == out_dtype and np.isfinite(got).all()
+    assert not np.asarray(got[1]).any() and np.asarray(want[0]).any()
+    if out_dtype == "float32":
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4)
+    else:
+        np.testing.assert_allclose(np.asarray(got, np.float32), want,
+                                   rtol=2.0 ** -8, atol=1e-4)
 
 
 @pytest.mark.parametrize("cell,shape,parts", [
@@ -855,6 +982,34 @@ def test_grouped_product_tile_gauges():
     assert ragged() == [before[0], before[1], before[2] + 1]
 
 
+def test_weight_gradient_form_counter():
+    """``graft_moe_weight_grad_traces_total{form, parts}``: a trace of the
+    backward rule's Pallas branch counts how the weights' gradient is
+    formed, megablox's ``tgmm`` over one buffer or ``tgmm_parts`` over the
+    buffers of p parts; nothing runs."""
+    from incubator_mxnet_tpu.parallel import moe
+    registry = mx.telemetry.registry()
+
+    def counts():
+        found = registry.snapshot().get(
+            "graft_moe_weight_grad_traces_total", {"samples": []})["samples"]
+        return {(s["labels"]["form"], s["labels"]["parts"]): s["value"]
+                for s in found}
+
+    def spec(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    before = counts()
+    sizes, w = spec(2, dtype=jnp.int32), spec(2, 256, 384)
+    jax.eval_shape(moe._gmm_bwd, spec(256, 256), w, sizes, spec(256, 384))
+    jax.eval_shape(moe._gmm_parts_bwd, *[spec(256, 256)] * 4,
+                   *[spec(256, 384)] * 4, *[sizes] * 4, w)
+    moved = {key: value - before.get(key, 0)
+             for key, value in counts().items()}
+    assert {k: v for k, v in moved.items() if v} == {
+        ("tgmm", "1"): 1, ("tgmm_parts", "4"): 1}
+
+
 # (k, n) of the routed cells' stacked weights, w1 / w3 then w2, the bytes
 # of an element of ``tgmm``'s result (4 where the tokens go in parts), and
 # the tiles (rows, k, n) in megablox's order of the three kernels over each.
@@ -908,6 +1063,49 @@ def test_product_tiles_by_cell(cell, kn, out_itemsize, product, tiling):
     assert product == "tgmm" or k % tk == 0
     assert moe._VMEM_BYTES == 16 << 20
     assert moe._block_bytes(product, tiling, 2, out_itemsize) <= 16 << 20
+
+
+@pytest.mark.parametrize("cell,kn,parts,tiling,blocks", [
+    pytest.param(cell, kn, parts, tilings[2], blocks,
+                 id="%s-%dx%d" % ((cell.split("_f")[0],) + kn))
+    for (cell, kn, _, tilings), (parts, blocks) in zip(_CELL_TILES[:6], [
+        (2, 16908288), (2, 16908288), (4, 16777216), (4, 16777216),
+        (2, 18874368), (2, 18874368)])])
+def test_weight_gradient_blocks_of_the_parted_cells(cell, kn, parts, tiling,
+                                                    blocks):
+    """``tgmm_parts`` at the three parted cells' weights: the tiles are one
+    part's ``tgmm``'s (``_tiling`` does not ask how many parts there are),
+    ``_block_bytes`` counts every part's operand blocks twice, and the kernel
+    is given the VMEM one part's kernel gets and those blocks on top, which
+    holds what it reckons to hold (LFM2's and Trinity-Mini's are over the
+    compiler's own 16 MiB: the TPU's compiler refused Trinity-Mini's at it)."""
+    from incubator_mxnet_tpu.parallel import moe
+    rows, tk, tn = tiling
+    assert moe._tiling("tgmm", 32768 // parts * 2, *kn, 2, 4) == tiling
+    one = moe._block_bytes("tgmm", tiling, 2, 4)
+    assert one <= moe._VMEM_BYTES
+    assert moe._block_bytes("tgmm", tiling, 2, 4, parts=parts) == blocks
+    assert blocks - one == (parts - 1) * 2 * 2 * rows * (tk + tn)
+    given = []
+
+    def kernel(lhs, rhs, group_sizes, out_dtype, tiles, vmem_bytes):
+        given.append((tiles, vmem_bytes))
+        return jnp.zeros((8,) + kn, out_dtype)
+
+    def spec(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    import incubator_mxnet_tpu.parallel.tgmm_parts as module
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(module, "tgmm_parts", kernel)
+        _, d_rhs = jax.eval_shape(
+            moe._gmm_parts_bwd, *[spec(rows * 4, kn[0])] * parts,
+            *[spec(rows * 4, kn[1])] * parts,
+            *[spec(8, dtype=jnp.int32)] * parts,
+            spec(8, *kn, dtype=jnp.float32))
+    assert d_rhs.dtype == jnp.float32
+    assert given == [(tiling, moe._VMEM_BYTES + blocks - one)]
+    assert blocks <= given[0][1] <= 24 << 20
 
 
 @contextlib.contextmanager
