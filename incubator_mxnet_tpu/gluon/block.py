@@ -280,10 +280,16 @@ _KEPT_PRIMITIVES = frozenset((
     "reduce_or", "reduce_xor", "argmax", "argmin",
     "reduce_window", "reduce_window_sum", "reduce_window_max",
     "reduce_window_min"))
+# ... and the values an operator names for it
+# (``jax.ad_checkpoint.checkpoint_name``), these names and no other: the one
+# BatchNorm's backward rule reads as it is, its input.
+_KEPT_NAMES = frozenset(("bn_input",))
 
 
-def _kept(prim, *_avals, **_params):
+def _kept(prim, *_avals, **params):
     """``jax.checkpoint`` policy of the recorded forward."""
+    if prim.name == "name":
+        return params["name"] in _KEPT_NAMES
     return prim.name in _KEPT_PRIMITIVES
 
 

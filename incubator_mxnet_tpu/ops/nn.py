@@ -19,8 +19,10 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from ..telemetry import metrics as _metrics
+from ..telemetry.tracing import step_counter
 from .registry import register
 
 
@@ -237,60 +239,129 @@ def _bn_reduce_layout(data, axis):
     return axis, red, bshape, m
 
 
-def _bn_train_stats(data, axis):
-    """Batch mean/var in f32 over a (possibly bf16) activation.
+# The one-pass variance S2/m - mean^2 is a difference of two float32 numbers
+# 1 + mean^2/var times its size, each as good as its own sum's rounding (which
+# the two-pass form's mean square has as well): it keeps float32's 24 bits less
+# log2(1 + mean^2/var).  With mean^2 <= 2^8 var the relative error of var is
+# about 2^8 * 2^-24 = 1.5e-5 times that sum's own factor (half of it in
+# rsqrt(var + eps)), under the 2^-9 to which a bf16 result is rounded (2^-12 a
+# float16 one) and far under a batch statistic's own sampling noise.  Past it
+# (a channel whose batch mean lies more than 16 of its own standard deviations
+# from zero) the exact two-pass form is taken.  A constant, not a setting:
+# what it trades is a rounding, and the op decides from its own sums.
+#
+# A float32 activation shows every bit the difference loses, and is given the
+# two passes as before: with this limit a 53-layer float32 ResNet at batch 4
+# stands 1.5e-4 to 2.7e-4 of its largest logit from its two-pass reference
+# (the two-pass form itself 2e-5 to 3e-5, sums in another order); with 2^4 it
+# stands 4e-5 from it, but then the first layers of a ResNet-50 take the
+# guarded branch in every step, which on the TPU costs more than the second
+# pass it guards (the conditional is handed the activation in another layout).
+_BN_ONE_PASS_LIMIT = 2.0 ** 8
 
-    Two passes, both reading the input at its native precision with an f32
-    accumulator (XLA converts in-register — no f32 copy of the activation
-    ever hits HBM).  Pass 2 fuses convert+sub+square into the reduction.
-    The shifted two-pass form stays cancellation-safe where the fused
-    E[x²]−E[x]² single pass silently loses channels with |mean| ≫ std.
+
+def _bn_train_stats(data, axis):
+    """Batch mean/var in f32 over a (possibly bf16) activation, and whether
+    the second pass was taken (int32 0 or 1).
+
+    A 16-bit activation, one pass: ``S1 = sum(x)`` and ``S2 = sum(x^2)``.
+    Neither sum needs the other's result, so XLA takes both into the fusion
+    that writes the activation (two ``jnp.sum``: the TPU compiler joins them
+    in a convolution's output fusion and leaves a variadic ``lax.reduce`` a
+    pass of its own).  Both read the input at its native precision with an
+    f32 accumulator (the convert is in-register: no f32 copy of the
+    activation in HBM).  ``mean = S1/m``, ``var = S2/m - mean^2``.
+
+    The sums are taken about zero, a constant, and not about the layer's
+    running mean: a shift that is auxiliary state would make the training
+    forward a function of that state (the same weights and batch giving
+    another loss in the last bits step after step, and a poisoned running
+    mean reaching the training), which it never was.
+
+    The guard: where any channel's mean lies further from zero than
+    ``_BN_ONE_PASS_LIMIT`` allows (|mean| >> std, where the difference
+    cancels) the variance is the two-pass form's, the mean square about the
+    mean, under a ``lax.cond``; the mean is the same sum either way.  The
+    predicate is written so that a NaN (unordered) takes the exact branch.
+
+    Any other activation (float32): the two passes, always.
     """
-    _, red, bshape, _ = _bn_reduce_layout(data, axis)
-    mean = jnp.mean(data, axis=red, dtype=jnp.float32)
-    var = jnp.mean(
-        jnp.square(data.astype(jnp.float32) - mean.reshape(bshape)), axis=red)
-    return mean, var
+    _, red, bshape, m = _bn_reduce_layout(data, axis)
+    mean = jnp.sum(data, axis=red, dtype=jnp.float32) / m
+
+    def second_pass(x):
+        return jnp.mean(
+            jnp.square(x.astype(jnp.float32) - mean.reshape(bshape)), axis=red)
+
+    if data.dtype.itemsize != 2:
+        return mean, second_pass(data), jnp.ones((), jnp.int32)
+    var = jnp.maximum(
+        jnp.sum(jnp.square(data.astype(jnp.float32)), axis=red) / m
+        - jnp.square(mean), 0.0)
+    far = jnp.any(~(jnp.square(mean) <= _BN_ONE_PASS_LIMIT * var))
+    # Two barriers hold the conditional to what it is given and what it
+    # gives.  On its operand: partitioned over a mesh, the branch's parameter
+    # gets the default layout and the activation was copied into it outside
+    # the conditional, in every step (15 ms of a 109-ms step at dp=4); behind
+    # the barrier the copy stays inside the branch.  On its result: without
+    # it XLA's conditional code motion takes the normalise pass's x - mean
+    # into both branches and hands it out as a float32 copy of the
+    # activation.
+    held = lax.optimization_barrier(data)
+    var = lax.cond(far, lambda: second_pass(held), lambda: var)
+    return mean, lax.optimization_barrier(var), far.astype(jnp.int32)
 
 
 def _bn_train_core_fwd(data, gamma, beta, axis, eps, fix_gamma):
     axis, _, bshape, _ = _bn_reduce_layout(data, axis)
-    mean, var = _bn_train_stats(data, axis)
+    mean, var, second_pass = _bn_train_stats(data, axis)
     inv = lax.rsqrt(var + eps)
     g = jnp.ones_like(inv) if fix_gamma else gamma.astype(jnp.float32)
     scale = g * inv
     out = ((data.astype(jnp.float32) - mean.reshape(bshape))
            * scale.reshape(bshape)
            + beta.astype(jnp.float32).reshape(bshape)).astype(data.dtype)
-    return out, mean, var, inv, scale
+    return out, mean, var, second_pass, inv, scale
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _bn_train_core(data, gamma, beta, axis, eps, fix_gamma):
-    """Training-mode BN with a hand-derived backward.
+    """Training-mode BN with a hand-derived backward; returns ``(out,
+    batch_mean, batch_var, second_pass)``.
 
-    Autodiff of the two-pass statistics chain costs ~2 extra full passes
+    Forward: the statistics in one pass, ``_bn_train_stats``;
+    ``second_pass`` (int32, 1 where the guard sent the variance through the
+    exact second pass) is a result because a traced value must not leave the
+    rule any other way.
+
+    Autodiff of the statistics chain costs ~2 extra full passes
     over the activation in f32; the closed-form BN backward (the same
     d-gamma/d-beta/dx decomposition cuDNN and batch_norm.cc:89 use) needs
     exactly two fused reductions over (dy, x) plus one elementwise pass —
     on the ResNet-50 bench this was worth ~20% end-to-end.
     """
-    out, mean, var, _, _ = _bn_train_core_fwd(data, gamma, beta, axis, eps,
-                                              fix_gamma)
-    return out, mean, var
+    out, mean, var, second_pass, _, _ = _bn_train_core_fwd(
+        data, gamma, beta, axis, eps, fix_gamma)
+    return out, mean, var, second_pass
 
 
 def _bn_train_core_fwd_rule(data, gamma, beta, axis, eps, fix_gamma):
     # symbolic_zeros=True wraps primal inputs in CustomVJPPrimal
     data, gamma, beta = data.value, gamma.value, beta.value
-    out, mean, var, inv, scale = _bn_train_core_fwd(data, gamma, beta, axis,
-                                                    eps, fix_gamma)
-    return (out, mean, var), (data, gamma, mean, inv, scale)
+    # the backward reads the input as it is, and the guard's branch is handed
+    # it whole: under a ``jax.checkpoint`` that keeps by primitive
+    # (``CachedOp``'s) the name makes this the one value that is kept, where
+    # without it the forward would write its producer's result to keep and
+    # this one (that result plus a bias) for the ``cond``
+    data = checkpoint_name(data, "bn_input")
+    out, mean, var, second_pass, inv, scale = _bn_train_core_fwd(
+        data, gamma, beta, axis, eps, fix_gamma)
+    return (out, mean, var, second_pass), (data, gamma, mean, inv, scale)
 
 
 def _bn_train_core_bwd_rule(axis, eps, fix_gamma, res, cotangents):
     from jax.custom_derivatives import SymbolicZero
-    dy, ct_mean, ct_var = cotangents
+    dy, ct_mean, ct_var, _ = cotangents
     data, gamma, mean, inv, scale = res
     axis, red, bshape, m = _bn_reduce_layout(data, axis)
     xc = data.astype(jnp.float32) - mean.reshape(bshape)
@@ -327,6 +398,7 @@ _bn_train_core.defvjp(_bn_train_core_fwd_rule, _bn_train_core_bwd_rule,
 
 @register("BatchNorm", num_inputs=5, num_outputs=3, num_visible_outputs=1,
           takes_is_train=True, nograd_inputs=(3, 4), aliases=("BatchNorm_v1",),
+          step_counters=True,
           input_names=("data", "gamma", "beta", "moving_mean", "moving_var"),
           aux_input_names=("moving_mean", "moving_var"),
           finfer_params=_channel_param_shapes,
@@ -338,7 +410,10 @@ def _batch_norm(data, gamma, beta, moving_mean, moving_var, eps=1e-3, momentum=0
     front-end updates the moving_* aux states with `momentum` outside the op,
     mirroring how the reference mutates aux arrays in-place."""
     if is_train and not use_global_stats:
-        return _bn_train_core(data, gamma, beta, axis, eps, bool(fix_gamma))
+        out, mean, var, second_pass = _bn_train_core(
+            data, gamma, beta, axis, eps, bool(fix_gamma))
+        step_counter("bn_second_pass", second_pass)
+        return out, mean, var
     # inference / global-stats path: pure elementwise, autodiff is optimal
     axis, _, bshape, _ = _bn_reduce_layout(data, axis)
     mean = moving_mean.astype(jnp.float32)

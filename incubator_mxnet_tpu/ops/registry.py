@@ -30,6 +30,8 @@ from typing import Callable, Optional
 
 import jax
 
+from ..telemetry.tracing import jit_with_step_counters
+
 __all__ = ["Operator", "register", "get_op", "list_ops", "alias",
            "registration_log"]
 
@@ -91,6 +93,10 @@ class Operator:
     differentiable : whether vjp should be recorded on the tape.
     nograd_inputs : indices of inputs that never receive gradient
         (e.g. integer indices of ``take``).
+    step_counters : fcompute calls ``telemetry.step_counter``.  A traced
+        value cannot leave the op's ``jax.jit`` but as a result, so the jit
+        hands the counts out and they are counted again where the op was
+        called (``tracing.jit_with_step_counters``).
     """
 
     def __init__(self, name: str, fcompute: Callable, *, num_inputs: Optional[int] = 1,
@@ -99,7 +105,8 @@ class Operator:
                  takes_is_train: bool = False, nograd_inputs=(), mutate_inputs=(),
                  input_names=None, aux_input_names=(), fargnames=None,
                  finfer_params=None, fvisible=None, fnum_outputs=None,
-                 no_jit: bool = False, doc: str = ""):
+                 no_jit: bool = False, step_counters: bool = False,
+                 doc: str = ""):
         self.name = name
         self.fcompute = fcompute
         self.num_inputs = num_inputs
@@ -120,6 +127,7 @@ class Operator:
         self.no_jit = no_jit   # ops that manage their own device placement
         # (multi-device shard_map bodies): the eager micro-jit would pin
         # them to the default device and clash with the op's mesh
+        self.step_counters = step_counters
         self.doc = doc
         self._jit_cache: dict = {}
         # Populated EAGERLY so registry introspection (graftlint, symbol
@@ -185,6 +193,7 @@ class Operator:
             "has_fvisible": self.fvisible is not None,
             "has_fnum_outputs": self.fnum_outputs is not None,
             "no_jit": self.no_jit,
+            "step_counters": self.step_counters,
             "param_defaults": dict(self._defaults),
             "source_file": fname,
             "source_line": line,
@@ -215,7 +224,8 @@ class Operator:
             if self.takes_is_train:
                 kw["is_train"] = bool(is_train)
             raw = functools.partial(self.fcompute, **kw)
-            fn = jax.jit(raw)
+            fn = (jit_with_step_counters(raw) if self.step_counters
+                  else jax.jit(raw))
             self._jit_cache[key] = fn
         return fn
 

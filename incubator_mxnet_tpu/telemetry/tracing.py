@@ -41,8 +41,8 @@ from . import blackbox as _blackbox
 from . import metrics as _metrics
 
 __all__ = ["phase_span", "step_counter", "collect_step_counters",
-           "stack_step_counters", "current_step", "next_segment_id",
-           "record_active",
+           "stack_step_counters", "jit_with_step_counters", "current_step",
+           "next_segment_id", "record_active",
            "deferred_op_event", "segment_flush_span",
            "segment_summary", "validate_chrome_trace",
            "process_metadata_events", "trace_header"]
@@ -220,10 +220,12 @@ def collect_step_counters():
     ``jax.jit``'s tracers must not reach the outer step's results, so
     ``CachedOp``'s trace opens one and drops it."""
     outer = getattr(_counting, "open", None)
+    # graftlint: disable=GL304 -- the collection belongs to the trace in hand, opened and closed around it
     _counting.open = found = []
     try:
         yield found
     finally:
+        # graftlint: disable=GL304 -- as above: the outer trace's collection is put back
         _counting.open = outer
 
 
@@ -234,12 +236,53 @@ def step_counter(name, value, **labels):
     results and ``telemetry.step_counters()`` hands it out by step id; with
     none a traced value is dropped.  False for a concrete value with no
     collection open: an eager call, whose host counters are the caller's
-    to write."""
+    to write.  Called under a ``jax.jit`` of its own (a registered
+    operator's), the count has to leave that as a result first:
+    :func:`jit_with_step_counters`."""
     found = getattr(_counting, "open", None)
     if found is not None:
         found.append((name, labels, value))
         return True
     return isinstance(value, jax.core.Tracer)
+
+
+@jax.tree_util.register_pytree_node_class
+class _Counted:
+    """One step count as a result of a ``jax.jit``: the value is the leaf,
+    the name and the labels are the tree's static part."""
+
+    def __init__(self, name, labels, value):
+        self.name, self.labels, self.value = name, labels, value
+
+    def tree_flatten(self):
+        return (self.value,), (self.name, tuple(sorted(self.labels.items())))
+
+    @classmethod
+    def tree_unflatten(cls, static, leaves):
+        return cls(static[0], dict(static[1]), leaves[0])
+
+
+def jit_with_step_counters(fn):
+    """``jax.jit(fn)`` for a function that calls :func:`step_counter`: a
+    traced value leaves a ``jit`` only as a result, so the counts of
+    ``fn``'s trace are results of the jitted function, and each call counts
+    them again in the caller's collection (the whole step's, where the call
+    is part of its trace; none in an eager call, where they are dropped)."""
+
+    def counted(*args, **kwargs):
+        with collect_step_counters() as found:
+            out = fn(*args, **kwargs)
+        return out, [_Counted(*count) for count in found]
+
+    jitted = jax.jit(counted)
+
+    def call(*args, **kwargs):
+        out, counts = jitted(*args, **kwargs)
+        for count in counts:
+            step_counter(count.name, count.value, **count.labels)
+        return out
+
+    return call
 
 
 def stack_step_counters(found):

@@ -370,6 +370,79 @@ def test_a_mesh_gives_the_single_device_result(devices):
 
 
 # ---------------------------------------------------------------------------
+# what a layer counts inside the step
+# ---------------------------------------------------------------------------
+
+def two_batchnorms(momentum):
+    """BatchNorm on the input itself and on a hidden layer."""
+    net = nn.HybridSequential(prefix="c_")
+    with net.name_scope():
+        net.add(nn.BatchNorm(in_channels=8, momentum=momentum, prefix="bn0_"))
+        net.add(nn.Dense(16, in_units=8, use_bias=False, prefix="fc1_"))
+        net.add(nn.BatchNorm(in_channels=16, momentum=momentum,
+                             prefix="bn1_"))
+        net.add(nn.Activation("relu"))
+        net.add(nn.Dense(3, in_units=16, prefix="fc2_"))
+    return net
+
+
+def test_batchnorm_second_passes_leave_the_step_as_counts():
+    """``bn_second_pass`` by step from ``telemetry.step_counters()``, a 0 or
+    1 a BatchNorm layer in call order, in a bfloat16 step: where the input's
+    channels lie 100 standard deviations from zero the first layer takes the
+    exact second pass, in every such step; with the input around zero none
+    does.  The hidden layer, fed normalised values, never does.  (A float32
+    step takes the two passes in every layer.)"""
+    from incubator_mxnet_tpu import telemetry
+
+    rs = np.random.RandomState(5)
+    noise = rs.randn(16, 8).astype(np.float32)
+    x = np.asarray(jnp.asarray(100.0 + noise, jnp.bfloat16), np.float32)
+    y = rs.randint(0, 3, 16).astype(np.float32)
+    taken = {}
+    for dtype in ("bfloat16", None):
+        net = two_batchnorms(momentum=0.0)
+        net.initialize(mx.init.Xavier(), ctx=mx.cpu())
+        trainer = fused(net, hyper={"learning_rate": 0.01}, dtype=dtype)
+        before = len(telemetry.step_counters())
+        for batch in (x, x, noise, x):
+            trainer.step(batch, y)
+        records = telemetry.step_counters()[before:]
+        assert [step for step, _ in records] == [1, 2, 3, 4]
+        taken[dtype] = [counts["bn_second_pass"].tolist()
+                        for _, counts in records]
+        # the exact branch's statistics are the batch's: the running mean is
+        # the batch mean to float32's last bits, not 100 +- a cancelled
+        # difference
+        mean = np.asarray(trainer._params["c_bn0_running_mean"])
+        np.testing.assert_allclose(mean, x.mean(axis=0), rtol=3e-7)
+        var = np.asarray(trainer._params["c_bn0_running_var"])
+        np.testing.assert_allclose(var, x.astype(np.float64).var(axis=0),
+                                   rtol=1e-4)
+    assert taken["bfloat16"] == [[1, 0], [1, 0], [0, 0], [1, 0]]
+    assert taken[None] == [[1, 1]] * 4
+
+
+def test_a_step_at_rate_zero_repeats_its_loss_to_the_bit():
+    """Training BatchNorm reads its batch and its weights and nothing else:
+    at learning rate 0 the running statistics move (momentum) and the loss
+    of the same batch does not, in bfloat16 either.  ``benchmark/chip``'s
+    ``loss_fell`` tells a step that updates nothing by this."""
+    net = two_batchnorms(momentum=0.9)
+    net.initialize(mx.init.Xavier(), ctx=mx.cpu())
+    rs = np.random.RandomState(6)
+    x = (2.0 + rs.randn(16, 8)).astype(np.float32)
+    y = rs.randint(0, 3, 16).astype(np.float32)
+    trainer = fused(net, hyper={"learning_rate": 0.0}, dtype="bfloat16")
+    losses, means = [], []
+    for _ in range(4):
+        losses.append(np.asarray(trainer.step(x, y)).tobytes())
+        means.append(np.asarray(trainer._params["c_bn0_running_mean"]))
+    assert len(set(losses)) == 1
+    assert not np.array_equal(means[0], means[-1])
+
+
+# ---------------------------------------------------------------------------
 # what is donated
 # ---------------------------------------------------------------------------
 

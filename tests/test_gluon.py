@@ -671,6 +671,8 @@ def _made_by(jaxpr, var, outer=()):
             continue
         if eqn.primitive.name == "reduce_precision":
             return _made_by(jaxpr, eqn.invars[0], outer)
+        if eqn.primitive.name == "name":    # ``checkpoint_name``
+            return "name:" + eqn.params["name"]
         inner = eqn.params.get("jaxpr")
         if inner is not None:
             inner = getattr(inner, "jaxpr", inner)
@@ -682,6 +684,17 @@ def _made_by(jaxpr, var, outer=()):
         return _made_by(around, call.invars[jaxpr.invars.index(var)],
                         outer[:-1])
     return "an argument"
+
+
+def _residual_makers(entry, args, n_residuals):
+    """The residuals of a recorded forward as its jaxpr's variables, and the
+    primitive that made each."""
+    import jax
+    jaxpr = jax.make_jaxpr(entry["record"])(*args).jaxpr
+    (call,) = jaxpr.eqns
+    inner = call.params["jaxpr"].jaxpr
+    kept = inner.outvars[-n_residuals:]
+    return kept, [_made_by(inner, v) for v in kept]
 
 
 def test_backward_program_runs_no_forward_convolution(recorded_net):
@@ -728,15 +741,54 @@ def test_backward_program_runs_no_forward_convolution(recorded_net):
 
     # every residual was made by a matrix product or a reduction, and none
     # is a parameter or an input (those are the backward's own arguments)
-    jaxpr = jax.make_jaxpr(entry["record"])(*args).jaxpr
-    (call,) = jaxpr.eqns
-    inner = call.params["jaxpr"].jaxpr
     assert len(residuals) > 0
-    made = [_made_by(inner, v) for v in inner.outvars[-len(residuals):]]
-    assert set(made) <= block_module._KEPT_PRIMITIVES, made
-    assert ("conv_general_dilated" if n else "dot_general") in made
+    kept, made = _residual_makers(entry, args, len(residuals))
+    # ... but for BatchNorm's input, under the name its rule gave it
+    # (``checkpoint_name``: here a convolution's result, with or without a
+    # bias, which is then not kept a second time under the convolution's own)
+    named = [v for v, how in zip(kept, made) if how == "name:bn_input"]
+    rest = [how for how in made if how != "name:bn_input"]
+    assert set(rest) <= block_module._KEPT_PRIMITIVES, made
+    assert len(named) == n
+    assert all(v.aval.shape == (4, 8, 12, 12) for v in named)
+    assert ("dot_general" in rest) != bool(n)
+    assert "conv_general_dilated" not in rest
     assert entry["residual_bytes"] == _counters()["residual_bytes"] == sum(
         int(np.prod(r.shape)) * r.dtype.itemsize for r in residuals)
+
+
+def test_a_bfloat16_batchnorm_keeps_its_input_and_the_guards_sum():
+    """In 16 bits BatchNorm's variance is the one-pass form's under a guard:
+    of a layer the recorded forward keeps the input once, under its name,
+    and besides the reductions only the exact second pass's sum, a
+    per-channel vector that leaves the ``cond`` (``_KEPT_PRIMITIVES`` has no
+    ``cond``: the branch's own reduction is what the policy kept)."""
+    import jax
+    from incubator_mxnet_tpu.gluon import block as block_module
+    mx.random.seed(29)
+    net, shape = _conv_net()
+    net.initialize(mx.init.Xavier())
+    net.cast("bfloat16")
+    x = mx.nd.array(np.random.RandomState(29).randn(*shape)).astype(
+        "bfloat16")
+    x.attach_grad()
+    net.hybridize()
+    with autograd.record():
+        _, loss = _loss(net, x)
+    loss.backward()
+    (entry,) = net._cached_op._cache.values()
+    params = {k: p.data()._read() for k, p in net.collect_params().items()}
+    args = (params, [x._read()], jax.random.PRNGKey(0))
+    _out, _aux, residuals, _pullback = jax.eval_shape(entry["record"], *args)
+    kept, made = _residual_makers(entry, args, len(residuals))
+    named = [v for v, how in zip(kept, made) if how == "name:bn_input"]
+    by_cond = [v for v, how in zip(kept, made) if how == "cond"]
+    rest = [how for how in made if how not in ("name:bn_input", "cond")]
+    assert set(rest) <= block_module._KEPT_PRIMITIVES, made
+    assert len(named) == len(by_cond) == _N_CONVOLUTIONS
+    assert all(v.aval.shape == (4, 8, 12, 12) for v in named)
+    assert all(v.aval.shape == (8,) for v in by_cond)
+    assert "conv_general_dilated" not in rest
 
 
 def test_outside_record_no_residual_is_made(recorded_net):

@@ -291,3 +291,127 @@ def test_batchnorm_large_mean_variance():
     want = (x - x.mean(axis=(0, 2, 3), keepdims=True)) / \
         np.sqrt(x.var(axis=(0, 2, 3), keepdims=True) + 1e-3)
     np.testing.assert_allclose(got, want, rtol=1e-2, atol=1e-2)
+
+
+def _bn_reference(x, gamma, beta, dy, axis, eps, fix_gamma):
+    """Training BatchNorm and its three gradients, two passes in float64."""
+    red = tuple(i for i in range(x.ndim) if i != axis % x.ndim)
+    m = x.size / x.shape[axis]
+    mean = x.mean(axis=red, keepdims=True)
+    var = ((x - mean) ** 2).mean(axis=red, keepdims=True)
+    inv = 1.0 / np.sqrt(var + eps)
+    g = np.ones_like(mean) if fix_gamma else gamma.reshape(mean.shape)
+    xhat = (x - mean) * inv
+    out = xhat * g + beta.reshape(mean.shape)
+    dbeta = dy.sum(axis=red, keepdims=True)
+    dgamma = (dy * xhat).sum(axis=red, keepdims=True)
+    dx = g * inv * (dy - (dbeta + xhat * dgamma) / m)
+    if fix_gamma:
+        dgamma = np.zeros_like(dgamma)
+    return out, mean.ravel(), var.ravel(), dx, dgamma.ravel(), dbeta.ravel()
+
+
+@pytest.mark.parametrize("fix_gamma", [False, True])
+@pytest.mark.parametrize("axis", [1, -1])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("off", [0.0, 1.0, 3.0, 10.0, 1e4])
+def test_batchnorm_one_pass_statistics(off, dtype, axis, fix_gamma):
+    """A bfloat16 activation's statistics are the sum and the sum of squares
+    in one pass, and the exact second pass where a channel's batch mean lies
+    ``off`` > 16 batch standard deviations from the sums' origin, zero; a
+    float32 activation's are the two passes always.  Results and gradients
+    are the two-pass reference's either way, and the second pass is counted
+    where it ran (the rule's extra result, as the op counts it)."""
+    import jax
+    import jax.numpy as jnp
+    from incubator_mxnet_tpu.ops import nn as ops_nn
+    from incubator_mxnet_tpu.telemetry.tracing import collect_step_counters
+
+    rs = np.random.RandomState(7)
+    shape = (16, 6, 5, 5) if axis == 1 else (16, 5, 5, 6)
+    cshape = [1, 1, 1, 1]
+    cshape[axis] = 6
+    red = tuple(i for i in range(4) if i != axis % 4)
+    spread = rs.uniform(0.2, 2.0, 6).reshape(cshape)
+    noise = rs.randn(*shape)
+    noise = (noise - noise.mean(axis=red, keepdims=True)) / noise.std(
+        axis=red, keepdims=True)
+    # every channel's mean ``off`` of its own standard deviations from zero
+    # (bfloat16's rounding of values that far out leaves the far case far)
+    data = jnp.asarray(spread * (noise + off), dtype)
+    gamma = jnp.asarray(rs.uniform(0.5, 1.5, 6), dtype)
+    beta = jnp.asarray(rs.uniform(-1.0, 1.0, 6), dtype)
+    dy = jnp.asarray(rs.randn(*shape), dtype)
+    eps = 1e-3
+    x64, g64, b64, dy64 = (np.asarray(v, np.float64)
+                           for v in (data, gamma, beta, dy))
+    want = _bn_reference(x64, g64, b64, dy64, axis, eps, fix_gamma)
+    # the running statistics are not read in training: poisoned ones (a
+    # forward that overflowed on a step the loss scaler then skipped) leave
+    # it as it is
+    running_mean = jnp.asarray([np.nan, np.inf, -np.inf, 3e38, 0.0, 1.0],
+                               jnp.float32)
+    running_var = jnp.asarray([np.nan, np.inf, 0.0, 3e38, 1.0, 1.0],
+                              jnp.float32)
+
+    def op(d, g, b):
+        return ops_nn._batch_norm(d, g, b, running_mean, running_var,
+                                  eps=eps, fix_gamma=fix_gamma, axis=axis,
+                                  is_train=True)
+
+    with collect_step_counters() as found:
+        (out, mean, var), pullback = jax.vjp(op, data, gamma, beta)
+    dx, dgamma, dbeta = pullback((dy, jnp.zeros_like(mean),
+                                  jnp.zeros_like(var)))
+    (name, _labels, second_pass), = found
+    assert name == "bn_second_pass"
+    limit = {"bfloat16": 16.0, "float32": -1.0}[dtype]
+    assert int(second_pass) == (1 if off > limit else 0)
+    # the rule's own extra result says the same
+    assert int(ops_nn._bn_train_core(data, gamma, beta, axis, eps,
+                                     fix_gamma)[3]) == int(second_pass)
+
+    assert out.dtype == data.dtype and mean.dtype == var.dtype == jnp.float32
+    # float32 statistics whatever the data.  The CPU's float32 sum of 400
+    # terms of one sign is good to 1e-5 of itself; the one-pass variance is
+    # the difference of two such numbers 1 + off^2 times its size (257 at
+    # the guard's limit).  The far case is the two-pass form's, as good as
+    # its mean: that sum's 1e-6 of ``off`` deviations, squared in the variance
+    if off <= limit:
+        loose = loose_var = 2e-5 * (1.0 + off ** 2)
+    else:
+        loose = max(2e-6 * off, 2e-5)
+        loose_var = 2e-5 + loose ** 2
+    np.testing.assert_allclose(mean, want[1], rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(var, want[2], rtol=loose_var)
+    tol = dict(rtol=loose, atol=loose) if dtype == "float32" else \
+        dict(rtol=2e-2, atol=2e-2)        # bfloat16 keeps 8 bits
+    got = [np.asarray(v, np.float64) for v in (out, dx, dgamma, dbeta)]
+    np.testing.assert_allclose(got[0], want[0], **tol)
+    np.testing.assert_allclose(got[1], want[3], **tol)
+    sums = dict(tol, atol=tol["atol"] * 20)   # sums of 400 terms
+    np.testing.assert_allclose(got[2], want[4], **sums)
+    np.testing.assert_allclose(got[3], want[5], **sums)
+
+
+def test_batchnorm_not_a_number_takes_the_exact_pass():
+    """The guard's predicate is written for unordered values: a channel whose
+    sums are not numbers (an overflowed activation) takes the exact branch,
+    which gives what the two passes always gave, and is counted."""
+    import jax.numpy as jnp
+    from incubator_mxnet_tpu.ops import nn as ops_nn
+
+    x = np.random.RandomState(1).randn(8, 3, 4, 4).astype(np.float32)
+    x[0, 1, 0, 0] = np.inf
+    ones, zeros = jnp.ones(3), jnp.zeros(3)
+    out, mean, var, second_pass = ops_nn._bn_train_core(
+        jnp.asarray(x), ones, zeros, 1, 1e-3, False)
+    assert int(second_pass) == 1
+    assert np.isinf(float(mean[1])) and np.isnan(float(var[1]))
+    good = [0, 2]
+    np.testing.assert_allclose(np.asarray(mean)[good],
+                               x[:, good].mean(axis=(0, 2, 3)), rtol=1e-5,
+                               atol=1e-6)
+    np.testing.assert_allclose(np.asarray(var)[good],
+                               x[:, good].var(axis=(0, 2, 3)), rtol=1e-5)
+    assert np.isfinite(np.asarray(out)[:, good]).all()

@@ -46,6 +46,67 @@ def test_data_parallel_trainer_matches_single_device():
     np.testing.assert_allclose(losses[1], losses[8], rtol=1e-4)
 
 
+@pytest.mark.parametrize("rows", ["apart", "together"])
+def test_batchnorm_statistics_are_the_whole_batch_under_a_dp_mesh(rows):
+    """BatchNorm's two sums are taken over the whole batch, not a device's
+    rows: the fused bfloat16 step over four devices gives the one-device
+    step's statistics, and the guard is decided once, from the reduced sums,
+    the same on every device.  ``apart``: device i's rows lie around 1000 i,
+    three of the quarters hundreds of their own deviations from zero but the
+    whole batch under two of its own: no second pass.  ``together``: all
+    rows around 1000: every device takes it, in both steps."""
+    import jax.numpy as jnp
+    from incubator_mxnet_tpu import telemetry
+    from incubator_mxnet_tpu.telemetry import blackbox
+
+    rs = np.random.RandomState(11)
+    centre = (np.repeat(np.arange(4.0), 4)[:, None] if rows == "apart"
+              else np.ones((16, 1)))
+    x = 1000.0 * centre + 8.0 * rs.randn(16, 8)
+    x = np.asarray(jnp.asarray(x, jnp.bfloat16), np.float32)
+    y = (rs.rand(16) * 3).astype(np.float32)
+
+    def build():
+        mx.random.seed(7)
+        net = nn.HybridSequential(prefix="bnmesh_")
+        with net.name_scope():
+            net.add(nn.BatchNorm(in_channels=8, momentum=0.0))
+            net.add(nn.Dense(3, in_units=8))
+        net.initialize(mx.init.Xavier())
+        return net
+
+    state, taken = {}, {}
+    for ndev in (1, 4):
+        tr = DataParallelTrainer(
+            build(), gluon.loss.SoftmaxCrossEntropyLoss(), optimizer="sgd",
+            optimizer_params={"learning_rate": 0.01},
+            mesh=make_mesh({"dp": ndev}), dtype="bfloat16")
+        before = len(telemetry.step_counters())
+        losses = [float(tr.step(mx.nd.array(x), mx.nd.array(y)))
+                  for _ in range(2)]
+        taken[ndev] = [counts["bn_second_pass"].tolist() for _, counts
+                       in telemetry.step_counters()[before:]]
+        state[ndev] = dict({n: np.asarray(v) for n, v in tr._params.items()},
+                           losses=np.asarray(losses))
+        # every device holds the same decision
+        _step, counts, _labels = blackbox._counts[-2]
+        copies = [np.asarray(s.data) for s
+                  in counts["bn_second_pass"].addressable_shards]
+        assert len(copies) == ndev
+        assert all((c == copies[0]).all() for c in copies)
+    assert taken[1] == taken[4] == ([[0], [0]] if rows == "apart"
+                                    else [[1], [1]])
+    mean = state[4]["bnmesh_batchnorm0_running_mean"]
+    np.testing.assert_allclose(mean, x.astype(np.float64).mean(axis=0),
+                               rtol=1e-6)
+    np.testing.assert_allclose(
+        state[4]["bnmesh_batchnorm0_running_var"],
+        x.astype(np.float64).var(axis=0), rtol=1e-4)
+    for name, want in state[1].items():
+        np.testing.assert_allclose(state[4][name], want, rtol=1e-4,
+                                   atol=1e-5, err_msg=name)
+
+
 def test_ring_attention_matches_reference():
     mesh = make_mesh({"sp": 8})
     rs = np.random.RandomState(0)

@@ -490,7 +490,9 @@ def test_the_cell_is_declared_as_the_issue_names_it():
         "moe_step_load_max_over_mean", "moe_experts_rows_roofline"]
     assert [m["name"] for m in mine] == ["attn_gate_ms_per_step"] + [
         n + "." + CELL for n in accepted]
-    assert spec["per_layer"][-len(mine):] == mine       # appended, together
+    # appended together (a later PR's entries come after them)
+    at = spec["per_layer"].index(mine[0])
+    assert spec["per_layer"][at:at + len(mine)] == mine
     by_name = {m["name"]: m for m in spec["per_layer"]}
     for m in mine:
         assert m["workloads"] == [CELL], m["name"]
