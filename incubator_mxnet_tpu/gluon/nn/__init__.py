@@ -4,3 +4,4 @@ from .basic_layers import *
 from .conv_layers import *
 from .attention import *
 from .ssm import *
+from .delta_net import *

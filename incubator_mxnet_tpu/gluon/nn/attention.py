@@ -89,6 +89,12 @@ class MultiHeadAttention(HybridBlock):
         ``attn_gate`` (projection, sigmoid and product) and counted by
         ``graft_attention_gate_traces_total``.  False (the default): no such
         parameter and the program the layer staged before.
+    rotary_dim : int or None
+        A partial rotary (needs ``rotary_base``): the positions turn the
+        first ``rotary_dim`` channels of a head, as a head of that width
+        would be turned, and the rest pass as they are
+        (``_contrib_RotaryEmbedding``'s ``dim``; Qwen3-Next turns 64 of
+        256).  None (the default): the whole head.
 
     With everything after ``fused_qkv`` left at its default the layer stages
     the program it staged before those arguments existed (the chip
@@ -99,6 +105,8 @@ class MultiHeadAttention(HybridBlock):
     ``trinitymini_gated_fused_1row`` 32 over 4 of 128 with ``gate`` and
     ``qk_norm``, four layers with ``window=2048`` and base 1e4 to one with
     no positions at all (``rotary_base=None``);
+    ``qwen3next_gdn_fused_1row`` 16 over 2 of 256 with ``gate``,
+    ``qk_norm`` and ``rotary_dim=64`` at base 1e7;
     ``kimivl_mla_fused_1row`` runs ``LatentAttention`` below, through the
     same kernel call.
     """
@@ -107,8 +115,11 @@ class MultiHeadAttention(HybridBlock):
                  use_bias=True, fused_qkv=False, weight_initializer=None,
                  num_kv_heads=None, qk_norm=False, rotary_base=None,
                  qk_norm_epsilon=1e-5, head_dim=None, window=None,
-                 rotary_scaling=None, gate=False, **kwargs):
+                 rotary_scaling=None, gate=False, rotary_dim=None, **kwargs):
         super().__init__(**kwargs)
+        if rotary_dim is not None and rotary_base is None:
+            raise ValueError("rotary_dim counts the channels rotary "
+                             "positions turn: it needs rotary_base")
         if head_dim is None and units % num_heads:
             raise ValueError("units (%d) must be divisible by num_heads (%d)"
                              % (units, num_heads))
@@ -131,6 +142,7 @@ class MultiHeadAttention(HybridBlock):
         self._rotary_base = rotary_base
         self._rotary_scaling = (None if rotary_scaling is None
                                 else dict(rotary_scaling))
+        self._rotary_dim = rotary_dim
         self._window = window
         self._head_dim = head_dim = (units // num_heads if head_dim is None
                                      else int(head_dim))
@@ -183,6 +195,8 @@ class MultiHeadAttention(HybridBlock):
         if positions and self._rotary_base is not None:
             scaled = ({} if self._rotary_scaling is None
                       else {"scaling": self._rotary_scaling})
+            if self._rotary_dim is not None:
+                scaled["dim"] = self._rotary_dim
             x = F._contrib_RotaryEmbedding(x, base=self._rotary_base,
                                            **scaled)
         return x

@@ -18,6 +18,7 @@ from . import rnn         # noqa: F401  fused RNN + CTC
 from . import vision      # noqa: F401  detection/sampling (SSD/RCNN/STN)
 from . import attention   # noqa: F401  flash attention
 from . import ssm         # noqa: F401  selective scan (state-space layers)
+from . import delta_rule  # noqa: F401  gated delta rule (linear attention)
 from . import linalg      # noqa: F401  LAPACK la_op family + FFT/count_sketch
 from . import quantization  # noqa: F401  INT8 quantize/dequantize/quantized_*
 

@@ -498,12 +498,18 @@ def _host_table(seq, inv, split=64):
 
 
 @register("_contrib_RotaryEmbedding", num_inputs=1)
-def _rotary_embedding(data, base=10000.0, scaling=None):
+def _rotary_embedding(data, base=10000.0, scaling=None, dim=None):
     """Rotary positions (Su et al., arXiv:2104.09864) over the whole last
     axis of (B, H, S, D), rotate-half convention: channel i pairs with
     channel i + D/2, position t (row t of S) turns the pair by
     t * base^(-2i/D).  Angles and the rotation in float32; the result has
     the data's type.
+
+    ``dim``: None, or the even number of a head's leading channels that are
+    turned, as a head of ``dim`` channels would be (channel i pairs with
+    i + dim/2 and turns by t * base^(-2i/dim)); the other D - dim pass as
+    they are (a partial rotary: Qwen3-Next turns 64 of 256).  None stages
+    the program it staged before the argument existed.
 
     ``scaling``: None, or YaRN's parameters as a mapping with the keys
     ``factor``, ``original_max_position`` and optionally ``beta_fast``
@@ -516,10 +522,18 @@ def _rotary_embedding(data, base=10000.0, scaling=None):
     of radians is good to a hundredth of a radian and no better (PERF.md,
     PR 30: two compilations of the same formula parted by 1e-2 at 8192
     positions), which a layer that attends far back shows."""
+    if dim is not None and dim != data.shape[-1]:
+        if not 0 < dim < data.shape[-1]:
+            raise ValueError("dim (%d) counts channels of a head of %d"
+                             % (dim, data.shape[-1]))
+        with jax.named_scope("rope"):
+            turned = _rotary_embedding(data[..., :dim], base, scaling)
+            return jnp.concatenate([turned, data[..., dim:]], axis=-1)
     d, seq = data.shape[-1], data.shape[-2]
     if d % 2:
         raise ValueError("rotary positions need an even head dimension, "
                          "got %d" % d)
+    _metrics.rotary_dim(d)
     with jax.named_scope("rope"):
         if scaling is None:
             inv = 1.0 / (base ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))

@@ -762,6 +762,13 @@ class ExpertParallelMoE(HybridBlock):
         to the routed sum unweighted; replicated under an ``ep`` mesh and
         outside ``experts_held``.  Needs ``in_units``.  Counted by
         ``graft_moe_shared_traces_total``.
+    shared_gate : a scalar gate on the shared expert (Qwen3-Next's
+        ``shared_expert_gate``): a parameter ``shared_gate_weight``
+        (units,) whose dot product with the token, through a sigmoid,
+        scales the shared expert's output, ``sigmoid(w_s . x) *
+        Shared(x)``, under the scope ``moe_shared``.  Needs
+        ``shared_hidden_size``.  False (the default): no such parameter and
+        the program the layer staged before.
 
     The defaults are the layer as it was (softmax, ReLU experts, dense);
     the chip benchmark's ``lfm2moe_fused_s8192`` runs ``grouped`` with
@@ -775,7 +782,8 @@ class ExpertParallelMoE(HybridBlock):
                  dispatch="dense", capacity_factor=1.25, experts_held=None,
                  router="softmax", selection_bias=False, norm_topk=None,
                  scaling=1.0, gated=False, in_units=0,
-                 shared_hidden_size=None, prefix=None, params=None, **kwargs):
+                 shared_hidden_size=None, shared_gate=False, prefix=None,
+                 params=None, **kwargs):
         super().__init__(prefix=prefix, params=params, **kwargs)
         self._hidden = hidden_size
         self._num_experts = num_experts
@@ -829,7 +837,10 @@ class ExpertParallelMoE(HybridBlock):
                 "expert_bias", shape=(num_experts,), grad_req="null",
                 init="zeros" if selection_bias is True else selection_bias
             ) if selection_bias else None
-            self.shared_experts = None
+            self.shared_experts = self.shared_gate_weight = None
+            if shared_gate and shared_hidden_size is None:
+                raise ValueError("shared_gate gates the shared expert: it "
+                                 "needs shared_hidden_size")
             if shared_hidden_size is not None:
                 if not in_units:
                     raise ValueError("a shared expert is built with its "
@@ -838,6 +849,9 @@ class ExpertParallelMoE(HybridBlock):
                 from ..gluon.nn.basic_layers import GatedMLP
                 self.shared_experts = GatedMLP(in_units, shared_hidden_size,
                                                prefix="shared_experts_")
+                if shared_gate:
+                    self.shared_gate_weight = self.params.get(
+                        "shared_gate_weight", shape=(in_units,))
         # shard the expert dimension over "ep": each device owns E/ep
         # experts' weights and their compute.  ``ep_axis=None``: the layer
         # runs on a mesh without that axis (one chip's share, held whole)
@@ -857,13 +871,14 @@ class ExpertParallelMoE(HybridBlock):
                 self.expert_w3.shape = (count, d, self._hidden)
 
     def hybrid_forward(self, F, x, gate_weight=None, expert_w1=None,
-                       expert_w2=None, expert_w3=None, expert_bias=None):
+                       expert_w2=None, expert_w3=None, expert_bias=None,
+                       shared_gate_weight=None):
         """x: (N, d) → (N, d).  Top-k routing with a score-weighted
         combine; the expert products carry the sharded E dimension."""
-        xv, gw, w1, w2, w3, bias = (
+        xv, gw, w1, w2, w3, bias, ws = (
             v._read() if isinstance(v, NDArray) else v
             for v in (x, gate_weight, expert_w1, expert_w2, expert_w3,
-                      expert_bias))
+                      expert_bias, shared_gate_weight))
 
         if self._dispatch == "capacity":
             out = self._capacity_forward(xv, gw, w1, w2)
@@ -873,8 +888,13 @@ class ExpertParallelMoE(HybridBlock):
             _metrics.moe_shared_trace()
             with jax.named_scope("moe_shared"):
                 shared = self.shared_experts(x)
-            out = out + (shared._read() if isinstance(shared, NDArray)
-                         else shared)
+                shared = (shared._read() if isinstance(shared, NDArray)
+                          else shared)
+                if ws is not None:
+                    gate = jax.nn.sigmoid(jnp.dot(
+                        xv, ws, preferred_element_type=jnp.float32))
+                    shared = shared * gate[:, None].astype(shared.dtype)
+            out = out + shared
         return NDArray(out) if isinstance(x, NDArray) else out
 
     def _routed(self, xv, gw, w1, w2, w3, bias):

@@ -718,6 +718,43 @@ def ssm_scan_shape(chunk, state_elems):
                     "call").set(float(state_elems))
 
 
+def delta_rule_trace(form):
+    """One trace of ``ops.delta_rule.gated_delta_rule``, labeled by the form
+    it took: ``chunked`` (the forward: every chunk's operands as batched
+    matmuls, then a scan over the chunks' states) or ``chunked_bwd`` (its
+    backward rule, from the operands and the chunk borders' states)."""
+    if not enabled():
+        return
+    _REGISTRY.counter("graft_delta_rule_traces_total",
+                      "gated_delta_rule traces by form", ("form",)).inc(
+        form=form)
+
+
+def delta_rule_shape(chunk, state_bytes):
+    """The chunk (time steps between two saved states) and the bytes of the
+    float32 state (rows x heads x key_dim x value_dim x 4) of the
+    ``gated_delta_rule`` call just traced: two gauges, set from shapes."""
+    if not enabled():
+        return
+    _REGISTRY.gauge("graft_delta_rule_chunk",
+                    "Time steps between two saved states of the last traced "
+                    "gated_delta_rule call").set(float(chunk))
+    _REGISTRY.gauge("graft_delta_rule_state_bytes",
+                    "Bytes of the float32 state of the last traced "
+                    "gated_delta_rule call").set(float(state_bytes))
+
+
+def rotary_dim(dim):
+    """The channels of a head that the ``_contrib_RotaryEmbedding`` call just
+    traced turns (the whole head, or the first ``dim`` of it): a gauge, set
+    from shapes."""
+    if not enabled():
+        return
+    _REGISTRY.gauge("graft_rotary_dim",
+                    "Channels of a head turned by the last traced rotary "
+                    "embedding").set(float(dim))
+
+
 def shared_kv_read():
     """One trace of an attention layer that projects no k, v of its own and
     attends onto another layer's (``DifferentialAttention(cross=True)``)."""
