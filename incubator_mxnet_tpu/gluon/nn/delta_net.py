@@ -58,9 +58,12 @@ class GatedDeltaNet(HybridBlock):
 
     The projections are staged under the scope ``gdn_proj``, the convolution
     and its SiLU under ``short_conv``, the L2 norms, gates, decays and the
-    scan under ``gdn_scan``, the gated head norm under ``gdn_norm``; the
-    chip benchmark's ``qwen3next_gdn_fused_1row`` runs it at 2048 -> 16 key
-    and 32 value heads of 128.
+    rule under ``gdn_scan`` (on a TPU, at heads whole lane tiles wide, its
+    walk over the chunks' states is the Mosaic kernels ``delta_rule_pallas``
+    and ``delta_rule_bwd``, whose calls lie under the same scope), the gated
+    head norm under ``gdn_norm``; the chip benchmark's
+    ``qwen3next_gdn_fused_1row`` runs it at 2048 -> 16 key and 32 value
+    heads of 128.
     """
 
     def __init__(self, units, num_k_heads, num_v_heads, head_k_dim,

@@ -719,10 +719,14 @@ def ssm_scan_shape(chunk, state_elems):
 
 
 def delta_rule_trace(form):
-    """One trace of ``ops.delta_rule.gated_delta_rule``, labeled by the form
-    it took: ``chunked`` (the forward: every chunk's operands as batched
-    matmuls, then a scan over the chunks' states) or ``chunked_bwd`` (its
-    backward rule, from the operands and the chunk borders' states)."""
+    """One trace of ``ops.delta_rule.gated_delta_rule``, forward or (with
+    ``_bwd`` after the name) its backward rule, labeled by the form the walk
+    over the chunks' states took: ``kernel`` (concrete operands on a TPU:
+    the Mosaic kernels), ``lowering_platform`` (traced operands: the kernels
+    when the enclosing program is lowered for a TPU, the ``lax.scan``
+    otherwise) or ``chunked`` (the ``lax.scan``: off the TPU, or a shape the
+    kernels do not take).  Every chunk's operands are batched matmuls in all
+    three."""
     if not enabled():
         return
     _REGISTRY.counter("graft_delta_rule_traces_total",

@@ -923,7 +923,8 @@ def test_delta_rule_compiles_at_the_cells_shape(bench_catalog, one_chip,
                                                 no_compile_cache):
     """Value and gradient of the rule at 16 key and 32 value heads of 128
     over a row of 4096 in bf16: what it keeps between the two passes is the
-    operands and the 64 border states, 134 MB; no array a token a state."""
+    operands and the 64 border states, 134 MB; no array a token a state;
+    the walk as the two kernels (PR 49)."""
     import jax
     import jax.numpy as jnp
     from incubator_mxnet_tpu.ops import delta_rule
@@ -940,4 +941,10 @@ def test_delta_rule_compiles_at_the_cells_shape(bench_catalog, one_chip,
             argnums=(0, 1, 2, 3, 4))).lower(qk, qk, v, gate, gate).compile()
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 3 << 30
-    assert "4096,128,128]" not in compiled.as_text()
+    text = compiled.as_text()
+    assert "4096,128,128]" not in text
+    # lowered for the TPU the walk over the chunks' states is the two Mosaic
+    # kernels, and no ``while`` is left of the scan
+    for kernel in ("delta_rule_pallas", "delta_rule_bwd"):
+        assert kernel in text, kernel
+    assert " while(" not in text
