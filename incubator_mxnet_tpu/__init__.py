@@ -11,13 +11,24 @@ Import convention mirrors the reference:
     import incubator_mxnet_tpu as mx
     x = mx.nd.zeros((2, 3), ctx=mx.tpu(0))
 """
+import sys as _sys
+import time as _time
+
+# the start-up record's ``package_import`` (telemetry.startup()): this
+# file's first and last line and the end of each group of imports, as
+# plain floats until the recorder is there to take them
+_stamps = [("start", _time.perf_counter())]
+_jax_preloaded = "jax" in _sys.modules
+
 __version__ = "0.1.0"
 
 from .base import MXNetError
 from . import context
 from .context import Context, cpu, gpu, tpu, current_context, num_devices
+_stamps.append(("base_context", _time.perf_counter()))
 
 from . import ops
+_stamps.append(("ops", _time.perf_counter()))   # JAX's import, unless preloaded
 from . import ndarray
 from . import ndarray as nd  # canonical alias, as in mxnet
 from .ndarray import NDArray
@@ -26,6 +37,7 @@ from . import autograd
 from . import engine
 from . import random
 from . import random_state
+_stamps.append(("ndarray_engine", _time.perf_counter()))
 
 from . import attribute
 from .attribute import AttrScope
@@ -52,7 +64,9 @@ from . import monitor
 from . import module
 from . import module as mod  # alias, as in mxnet
 from . import model
+_stamps.append(("symbol_module", _time.perf_counter()))
 from . import gluon
+_stamps.append(("gluon", _time.perf_counter()))
 from . import parallel
 from . import contrib
 from . import operator
@@ -74,3 +88,7 @@ if config.get_bool("PROFILER_AUTOSTART"):
     _atexit.register(lambda: profiler.set_state("stop"))
 if config.get_int("SEED") is not None:
     random.seed(config.get_int("SEED"))
+
+_stamps.append(("parallel_rest", _time.perf_counter()))
+telemetry.blackbox.package_imported(_stamps, _jax_preloaded)
+del _stamps, _jax_preloaded

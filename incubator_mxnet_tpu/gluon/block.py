@@ -651,10 +651,13 @@ class HybridBlock(Block):
                          any(p.name not in shadows for p in deferred)):
             # layer-local shape inference from the live input (the reference
             # resolves deferred shapes via symbolic infer_shape,
-            # block.py _deferred_infer_shape; here each layer fills its own)
-            self._pre_infer(*args)
-            for p in deferred:
-                p._finish_deferred_init()
+            # block.py _deferred_infer_shape; here each layer fills its own),
+            # under the set-up span ``deferred_init``: the initializers'
+            # programs are put down to it
+            with _ttracing.phase_span("deferred_init"):
+                self._pre_infer(*args)
+                for p in deferred:
+                    p._finish_deferred_init()
         for name, p in self._reg_params.items():
             if shadows is not None and p.name in shadows:
                 params[name] = shadows[p.name]
@@ -717,8 +720,11 @@ class HybridBlock(Block):
 
     def _run_deferred_init(self, *args):
         """First-call shape resolution: one eager pass lets every layer in
-        the subtree fill its own deferred parameter shapes."""
-        with autograd.pause():
+        the subtree fill its own deferred parameter shapes.  Under the
+        set-up span ``deferred_init``, whoever makes the pass (a first call
+        here or of the ``CachedOp``, ``DataParallelTrainer``): the
+        operators' programs it builds are put down to it."""
+        with _ttracing.phase_span("deferred_init"), autograd.pause():
             self.hybrid_forward_dispatch(*args)
 
     def hybrid_forward(self, F, x, *args, **kwargs):

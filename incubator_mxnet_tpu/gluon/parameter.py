@@ -19,6 +19,7 @@ from ..ndarray import NDArray
 from .. import ndarray as _nd
 from .. import initializer
 from .. import autograd
+from ..telemetry import tracing as _ttracing
 
 __all__ = ["DeferredInitializationError", "Parameter", "Constant", "ParameterDict"]
 
@@ -449,13 +450,15 @@ class ParameterDict(object):
                 self._params[k] = v
 
     def initialize(self, init=None, ctx=None, verbose=False, force_reinit=False):
-        """ref: parameter.py ParameterDict.initialize."""
+        """ref: parameter.py ParameterDict.initialize.  ``Block.initialize``
+        comes here: the set-up span ``initialize``."""
         if init is None:
             init = initializer.Uniform()
         if verbose:
             init.set_verbosity(verbose=verbose)
-        for _, v in self.items():
-            v.initialize(None, ctx, init, force_reinit=force_reinit)
+        with _ttracing.phase_span("initialize"):
+            for _, v in self.items():
+                v.initialize(None, ctx, init, force_reinit=force_reinit)
 
     def zero_grad(self):
         for v in self.values():

@@ -30,7 +30,7 @@ from typing import Callable, Optional
 
 import jax
 
-from ..telemetry.tracing import jit_with_step_counters
+from ..telemetry.tracing import jit_with_step_counters, operator_jitted
 
 __all__ = ["Operator", "register", "get_op", "list_ops", "alias",
            "registration_log"]
@@ -224,6 +224,7 @@ class Operator:
             if self.takes_is_train:
                 kw["is_train"] = bool(is_train)
             raw = functools.partial(self.fcompute, **kw)
+            operator_jitted(self.fcompute)  # its builds: the library's
             fn = (jit_with_step_counters(raw) if self.step_counters
                   else jax.jit(raw))
             self._jit_cache[key] = fn

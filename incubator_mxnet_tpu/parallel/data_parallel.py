@@ -143,6 +143,10 @@ class DataParallelTrainer(object):
 
     # -- parameter plumbing ------------------------------------------------
     def _gather_params(self, example_x):
+        """The Block's parameters and their optimizer state onto the mesh,
+        under the set-up spans ``deferred_init`` (inside
+        ``_run_deferred_init``, where shapes are still open) and
+        ``gather_params`` (the rest)."""
         blk_params = self.block.collect_params()
         if any(p._data is None and p._deferred_init
                for p in blk_params.values()):
@@ -157,6 +161,10 @@ class DataParallelTrainer(object):
             if jnp.issubdtype(row.dtype, jnp.integer):
                 row = row.astype(jnp.float32)
             self.block._run_deferred_init(NDArray(row))
+        with phase_span("gather_params"):
+            self._put_params(blk_params)
+
+    def _put_params(self, blk_params):
         repl = NamedSharding(self.mesh, P())
         multihost = _spans_processes(repl)
         vals = {n: p.data()._read() for n, p in blk_params.items()}
@@ -444,9 +452,10 @@ class DataParallelTrainer(object):
         from .mesh import use_mesh
         with use_mesh(self.mesh):
             x, y = self._prepare_inputs(data, label, P("dp"))
-            return self.compile(x, y).lower(*_xray.abstract((
-                self._params, self._opt_state, self._rng_key, x, y,
-                self._lr_dev))).compile()
+            with phase_span("memory_analysis"):
+                return self.compile(x, y).lower(*_xray.abstract((
+                    self._params, self._opt_state, self._rng_key, x, y,
+                    self._lr_dev))).compile()
 
     @property
     def learning_rate(self):

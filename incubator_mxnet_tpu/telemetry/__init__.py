@@ -32,6 +32,12 @@ What the program marks in itself, for a reader of a profiler trace
   profiler's trace beside the device events, and a record (name, start,
   end, parent, step id, on ``time.perf_counter()``) that :func:`spans`
   hands out, oldest first.
+* :func:`startup` — the start-up record, which no eviction touches: the
+  package's import, the set-up spans (``initialize``, ``deferred_init``,
+  ``gather_params``, ``memory_analysis``) and a build record for every stage
+  (trace, lower, backend compile or cache read) of every program the
+  process builds, each put down to the span that was open when JAX built
+  it.
 * :func:`step_counter` — a count made inside a compiled step (the rows a
   routed layer's held experts got): it leaves the fused step beside the
   loss and :func:`step_counters` hands it out by the ``step`` span's id.
@@ -67,7 +73,7 @@ from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       compact_snapshot, enabled, parse_prometheus_text,
                       registry, set_enabled, write_snapshot)
 from .tracing import phase_span, step_counter
-from .blackbox import spans, step_counters
+from .blackbox import spans, startup, step_counters
 from .xray import programs
 
 __all__ = ["metrics", "tracing", "blackbox", "watchdog",
@@ -75,7 +81,7 @@ __all__ = ["metrics", "tracing", "blackbox", "watchdog",
            "Counter", "Gauge", "Histogram", "MetricsRegistry",
            "registry", "enabled", "set_enabled", "parse_prometheus_text",
            "compact_snapshot", "write_snapshot", "phase_span", "spans",
-           "step_counter", "step_counters", "programs"]
+           "startup", "step_counter", "step_counters", "programs"]
 
 _snapshot_path = _os.environ.get("GRAFT_TELEMETRY_SNAPSHOT")
 if _snapshot_path:

@@ -609,11 +609,6 @@ _merge_intervals = _xray.merge_intervals
 _DEVICE_PID_HINTS = _xray.DEVICE_PID_HINTS
 
 
-def _is_device_event(ev, device_pids):
-    """Shared-core device-span detection (see xray.is_device_event)."""
-    return _xray.is_device_event(ev, device_pids)
-
-
 def ingest_xla(path_or_doc):
     """Rebuild the per-step device ledger OFFLINE from a chrome trace
     (``mx.profiler`` sync mode, external XLA profiler captures).

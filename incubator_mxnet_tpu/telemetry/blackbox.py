@@ -48,8 +48,9 @@ from ..analysis import lockstep as _lockstep
 __all__ = ["enabled", "set_enabled", "record", "events", "stats",
            "in_flight", "inflight_entries", "progress", "last_progress",
            "collective", "phase_begin", "phase_end", "spans", "step_journal",
-           "step_counts", "step_counters",
-           "current_step", "advance_step",
+           "step_counts", "step_counters", "startup", "startup_add",
+           "package_imported",
+           "SETUP_SPANS", "current_step", "advance_step",
            "workers_seen", "set_rank", "set_clock_offset", "dump",
            "snapshot", "default_path", "validate_dump", "summarize_dump",
            "install_hooks", "configure", "selftest", "SCHEMA",
@@ -97,6 +98,21 @@ _spans = deque(maxlen=_ring_size())
 # what the compiled steps counted (tracing.step_counter), oldest first:
 # (step id, {name: device array}, {name: [labels of each row]}), unread
 _counts = deque(maxlen=_ring_size())
+# the start-up record: how the process got going, and every program it
+# built.  ``(kind, name, start, end, parent, step, attrs)`` on
+# time.perf_counter(): the package's import (kind "import"), the set-up
+# spans (kind "span": SETUP_SPANS, which go to the span ring as well) and a
+# record a stage of every program JAX built (kind "build": tracing.py's
+# listener).  Set-up is the beginning of a process, so the OLDEST are kept:
+# once full the record takes no more and counts what it turned away.  The
+# set-up of the benchmark's largest cells leaves some 1,100 records (three a
+# program); the span ring's default size holds them with room for a job's
+# later rebuilds
+STARTUP_SIZE = _DEFAULT_SIZE
+SETUP_SPANS = frozenset(["initialize", "deferred_init", "gather_params",
+                         "memory_analysis"])
+_startup = []
+_startup_dropped = [0]
 _rank = [0]
 _clock_offset = [None]          # latest heartbeat clock/arrival offset
 #                                 estimate vs the freshest-arriving rank
@@ -138,6 +154,53 @@ def record(kind, **fields):
     fields.setdefault("step", current_step())
     _stats[0] += 1
     _ring.append((time.time(), kind, fields))
+
+
+def startup_add(kind, name, start, end, parent=None, step=None, attrs=None):
+    """Keep one start-up record, unless the recorder is off or the record
+    full (the oldest are kept, the rest counted)."""
+    if not enabled():
+        return
+    if len(_startup) >= STARTUP_SIZE:
+        _startup_dropped[0] += 1
+        return
+    _startup.append((kind, name, start, end, parent, step,
+                     {} if attrs is None else attrs))
+
+
+def package_imported(stamps, jax_preloaded):
+    """The ``package_import`` record, from the stamps the package's
+    ``__init__`` took on ``time.perf_counter()``: ``[(group, its end)]``
+    from ``("start", its first line)`` on.  ``attrs``: the groups' seconds
+    in import order, whether ``jax`` had been imported before the package
+    (else the first group that needs it holds JAX's import) and whether a
+    backend was open by the end (importing the package opens none)."""
+    from jax._src import xla_bridge
+    groups = {name: end - begun for (name, end), (_, begun)
+              in zip(stamps[1:], stamps)}
+    startup_add("import", "package_import", stamps[0][1], stamps[-1][1],
+                attrs={"groups_s": groups, "jax_preloaded": jax_preloaded,
+                       "backend_open": xla_bridge.backends_are_initialized()})
+
+
+class _Startup(list):
+    """What :func:`startup` hands out: the records, oldest first, and how
+    many the full record turned away (``dropped``)."""
+    dropped = 0
+
+
+def startup():
+    """The start-up record: ``(kind, name, start_s, end_s, parent, step,
+    attrs)`` on ``time.perf_counter()``, oldest first, which no eviction
+    touches.  ``kind`` is ``"import"`` (``package_import``), ``"span"`` (a
+    set-up span: ``SETUP_SPANS``) or ``"build"`` (one stage of one program
+    JAX built: ``attrs`` has its ``stage``, ``owner`` and, for the
+    ``backend`` stage, what the persistent cache said).  The list's
+    ``dropped`` counts the records that came once it held
+    ``STARTUP_SIZE``."""
+    held = _Startup(_startup)
+    held.dropped = _startup_dropped[0]
+    return held
 
 
 def events():
@@ -421,6 +484,8 @@ def phase_end(entry, phase, start, end, parent=None, step=None,
     if not enabled():
         return
     _spans.append((phase, start, end, parent, step))
+    if phase in SETUP_SPANS:
+        startup_add("span", phase, start, end, parent, step)
     j = getattr(_tls, "step", None)
     if j is not None and parent is None:
         j["phases"][phase] = j["phases"].get(phase, 0.0) + end - start
@@ -637,6 +702,9 @@ def snapshot(reason="manual", extra=None):
         # [name, start, end, parent, step] on the perf_counter clock, which
         # perf_anchor ties to the events' wall clock
         "spans": [list(sp) for sp in spans()],
+        # the same clock: [kind, name, start, end, parent, step, attrs]
+        "startup": {"records": [list(r) for r in _startup],
+                    "dropped": _startup_dropped[0]},
         "step_counters": _newest_step_counts(),
         "perf_anchor": {"perf_s": time.perf_counter(), "wall_s": now},
         "threads": _thread_stacks(),

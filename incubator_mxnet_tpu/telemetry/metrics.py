@@ -501,6 +501,29 @@ def phase(name, seconds):
                         buckets=_PHASE_BUCKETS).observe(seconds, phase=name)
 
 
+def program_build(owner, stage, seconds, cache=None):
+    """One stage (``trace``, ``lower``, ``backend``) of one program JAX
+    built, from the package's listener on ``jax.monitoring``
+    (``tracing._on_jax_event``).  ``owner`` is the span that was open when
+    JAX built it (``dispatch``, ``fwd``, ``bwd``, ``update``,
+    ``engine_flush``, ``deferred_init``...), ``eager`` for a registered
+    operator's program under no span, ``user`` for any other.  The
+    ``backend`` stage comes with what the persistent cache said (``hit``,
+    ``miss``, ``off``) and counts the program."""
+    if not enabled():
+        return
+    r = _REGISTRY
+    r.counter("graft_program_build_seconds_total",
+              "Seconds JAX spent building programs, by the span that asked "
+              "and the stage", ("owner", "stage")
+              ).inc(seconds, owner=owner, stage=stage)
+    if cache is not None:
+        r.counter("graft_program_builds_total",
+                  "Programs JAX built (a backend compile or a read of the "
+                  "persistent cache), by the span that asked",
+                  ("owner", "cache")).inc(owner=owner, cache=cache)
+
+
 def _collect_device_memory(reg):
     """Snapshot-time gauges from the XLA per-device allocator (falls back
     to live_arrays accounting — see profiler.device_memory)."""

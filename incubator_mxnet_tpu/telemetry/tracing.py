@@ -26,7 +26,10 @@ profiler's trace as ``mx:<name>`` (beside the device events, on their
 clock), in the flight recorder's span record (``telemetry.spans()``), in
 the chrome trace (cat ``phase``) and in the ``graft_phase_seconds``
 histogram.  Beside it :func:`step_counter`, the one primitive for a count
-made *inside* a compiled step, where no span can look.
+made *inside* a compiled step, where no span can look, and the program's one
+listener on ``jax.monitoring`` (:func:`_on_jax_event`): a build record for
+every stage of every program the process builds, put down to the span that
+was open when JAX built it (``telemetry.startup()``).
 """
 from __future__ import annotations
 
@@ -42,6 +45,7 @@ from . import metrics as _metrics
 
 __all__ = ["phase_span", "step_counter", "collect_step_counters",
            "stack_step_counters", "jit_with_step_counters", "current_step",
+           "operator_jitted",
            "next_segment_id", "record_active",
            "deferred_op_event", "segment_flush_span",
            "segment_summary", "validate_chrome_trace",
@@ -206,6 +210,129 @@ def phase_span(phase, args=None, step=None):
             and not _blackbox.enabled():
         return _NULL
     return _PhaseSpan(phase, args, step)
+
+
+# ---------------------------------------------------------------------------
+# build records: every program the process builds, by the span that asked
+# ---------------------------------------------------------------------------
+
+_COMPILE = "/jax/core/compile/"
+_STAGES = {_COMPILE + "jaxpr_trace_duration": "trace",
+           _COMPILE + "jaxpr_to_mlir_module_duration": "lower",
+           _COMPILE + "backend_compile_duration": "backend"}
+_CACHE = "/jax/compilation_cache/"
+_CACHE_SAID = {_CACHE + "cache_hits": "hit", _CACHE + "cache_misses": "miss"}
+_CACHE_RETRIEVAL = _CACHE + "cache_retrieval_time_sec"
+
+class _Building(threading.local):
+    """This thread's program in the making."""
+
+    def __init__(self):
+        # the trace stages nothing has followed yet, the newest last:
+        # (name, start, end, span, step, enclosed)
+        self.traces = []
+        self.name = None        # the function the program was traced from
+        # what the persistent cache said since the last stage
+        self.cache, self.retrieval_s = "off", 0.0
+
+
+_building = _Building()
+_TRACES_HELD = 4096
+# names the operator library jits under: jit_with_step_counters' own
+# function, and those operator_jitted is told of
+_operators = {"counted"}
+
+
+def operator_jitted(fcompute):
+    """``ops/registry.py`` says which function it is about to ``jax.jit``:
+    JAX calls the trace by that function's ``__name__``, and a build of that
+    name under no span is the operator library's (owner ``eager``)."""
+    _operators.add(getattr(fcompute, "__name__", None))
+
+
+def _on_jax_event(event, seconds=None, fun_name=None, **_):
+    """The package's one listener on ``jax.monitoring``, for its plain and
+    its duration events alike.  JAX reports a stage of a program when the
+    stage ends, on the thread that asked for the program.
+
+    A trace is kept back, and is a record only as the first stage of a
+    build: JAX reports one for every call that takes its Python path, a
+    cached one too (each operator under ``autograd.record()``, 10 us), and
+    traces nest (a step's trace holds those of the jitted functions it
+    calls, a lowering traces what its rules stage late), the enclosed ending
+    first.  So a later stage that began before a trace counts it among its
+    ``enclosed``, the lowering that follows a trace takes it for its
+    program's, and the others are dropped.  Silent, but for that, once every
+    shape is warm: JAX has nothing else to report of a cached program."""
+    stage = _STAGES.get(event)
+    build = _building
+    if stage is None:
+        if event in _CACHE_SAID:
+            build.cache = _CACHE_SAID[event]
+        elif event == _CACHE_RETRIEVAL:
+            build.retrieval_s = seconds
+        return
+    end = time.perf_counter()
+    start = end - max(seconds, 0.0)     # JAX's are wall-clock seconds
+    traces = build.traces
+    enclosed = 0
+    while traces and traces[-1][1] >= start:
+        enclosed += 1 + traces.pop()[5]
+    stack = getattr(_open, "stack", None)
+    span = stack[-1] if stack else None
+    step = span.step if span is not None else current_step()
+    if stage == "trace":
+        if len(traces) >= _TRACES_HELD:
+            del traces[:_TRACES_HELD // 2]
+        traces.append((fun_name, start, end, span, step, enclosed))
+        return
+    attrs = {}
+    if stage == "lower":
+        # "jit(f)" from here on; a functools.partial (every operator's jit)
+        # is "jit(<unknown>)", and its trace had the function's name
+        build.name = fun_name[fun_name.index("(") + 1:-1] \
+            if fun_name.endswith(")") and "(" in fun_name else fun_name
+        if traces:
+            if build.name == "<unknown>":
+                build.name = traces[-1][0]
+            _build_record(build.name, "trace", *traces[-1][1:])
+        del traces[:]
+    else:
+        attrs = {"cache": build.cache, "retrieval_s": build.retrieval_s}
+    _build_record(build.name or fun_name, stage, start, end, span, step,
+                  enclosed, **attrs)
+    if stage == "backend":
+        build.name = None
+    # what the cache says from here on is of the stage that follows
+    build.cache, build.retrieval_s = "off", 0.0
+
+
+def _build_record(name, stage, start, end, span, step, enclosed, **attrs):
+    """One stage of a build into the start-up record
+    (``blackbox.startup_add``, kind ``build``) and the counters
+    (``metrics.program_build``): the stage's interval on
+    ``time.perf_counter()``, the innermost span open at the time as
+    ``parent`` with its step id, and in ``attrs`` the ``stage`` (``trace``,
+    ``lower``, ``backend``), the ``owner`` (the parent, or for a build under
+    no span ``eager`` where a registered operator's compute function was
+    traced and ``user`` otherwise), the traces it ``enclosed`` and, at
+    ``backend``, the persistent cache's answer (``cache``: ``hit``,
+    ``miss`` or ``off``; ``retrieval_s``)."""
+    if span is not None:
+        parent = owner = span.phase
+        if span.args:                   # an engine flush's cause
+            attrs = dict(span.args, **attrs)
+    else:
+        parent, owner = None, "eager" if name in _operators else "user"
+    attrs["stage"], attrs["owner"] = stage, owner
+    if enclosed:
+        attrs["enclosed"] = enclosed
+    _blackbox.startup_add("build", name, start, end, parent, step, attrs)
+    _metrics.program_build(owner, stage, end - start, attrs.get("cache"))
+
+
+jax.monitoring.register_event_listener(_on_jax_event)
+jax.monitoring.register_event_duration_secs_listener(_on_jax_event)
 
 
 _counting = threading.local()    # .open: the collection of the trace in hand
